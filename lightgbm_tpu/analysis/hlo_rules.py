@@ -142,9 +142,8 @@ def check_dus_not_scatter(program) -> List[Finding]:
                         f"({spec_len}); record emission regressed"))
     hlo = program.compiled_text
     if hlo is not None and not out:
-        dus_tree = [ln for ln in hlo.splitlines()
-                    if "dynamic-update-slice" in ln and "tree.py" in ln]
-        if not dus_tree:
+        if not walker.compiled_lines_from_file(
+                hlo, "dynamic-update-slice", "tree.py"):
             out.append(Finding(
                 rule="HLO004", file=program.source,
                 message=f"program {program.name}: compiled HLO carries "

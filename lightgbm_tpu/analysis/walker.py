@@ -11,6 +11,7 @@ matter how deeply XLA's control-flow nesting buries it.
 """
 from __future__ import annotations
 
+import re
 from typing import Iterator, List, Optional, Set
 
 
@@ -142,6 +143,36 @@ HOST_CALLBACK_PRIMITIVES = frozenset({
 def count_op(text: str, op: str) -> int:
     """Occurrences of a StableHLO op name in lowered module text."""
     return text.count(op)
+
+
+def compiled_lines_from_file(hlo: str, op: str, filename: str) -> List[str]:
+    """Instruction lines of compiled HLO text that run ``op`` and whose
+    innermost source frame lies in a file ending with ``filename``.
+
+    The HLO printer (jaxlib 0.9) no longer writes ``source_file=`` on
+    each instruction: metadata carries ``stack_frame_id=N`` and the
+    module ends with FileNames / FileLocations / StackFrames tables,
+    frame -> location -> file."""
+    def table(name: str, value: str) -> dict:
+        """``{row id: captured value}`` of one trailing table."""
+        m = re.search(rf"^{name}\n((?:\d+ .*\n?)+)", hlo, re.M)
+        rows = re.findall(rf"^(\d+) .*?{value}", m.group(1), re.M) \
+            if m else []
+        return {int(k): v for k, v in rows}
+
+    files = table("FileNames", r'"([^"]*)"')
+    loc_file = table("FileLocations", r"file_name_id=(\d+)")
+    frame_loc = table("StackFrames", r"file_location_id=(\d+)")
+    out = []
+    for ln in hlo.splitlines():
+        m = re.search(r"stack_frame_id=(\d+)", ln)
+        if m is None or f" {op}(" not in ln:
+            continue
+        loc = int(frame_loc.get(int(m.group(1)), 0))
+        name = files.get(int(loc_file.get(loc, 0)), "")
+        if name.endswith(filename):
+            out.append(ln)
+    return out
 
 
 def dynamic_shape_markers(text: str) -> List[str]:

@@ -305,9 +305,11 @@ def predict_level_ensemble_pallas(stack: LevelEnsemble, X2: jax.Array,
     2F) row blocks while every ensemble table is a full-array VMEM
     block — the stacked ensemble stays chip-resident across the whole
     batch instead of re-streaming from HBM per level.  Validated on the
-    interpret seam (this container has no chip); `predict_kernel=
-    pallas` is the one-flag on-chip A/B, same protocol as
-    hist_leaf_partition r6."""
+    interpret seam only.  On a v5e (jax 0.9.0 / libtpu 0.0.34, PR 21)
+    the Pallas TPU lowering refuses the kernel — the 1-D table gathers
+    of ``_level_step`` raise ``NotImplementedError: Only 2D gather is
+    supported`` — so ``predict_kernel=pallas`` fails loudly there; it
+    never degrades to another kernel.  ROADMAP D1 decides its fate."""
     PREDICT_TELEMETRY["traces"] += 1
     from ..telemetry import TELEMETRY
     TELEMETRY.note_trace("predict.level_ensemble_pallas",

@@ -340,19 +340,17 @@ class BinLayout:
 def _native_pack(lib, logical: np.ndarray, packed_groups: int,
                  out: np.ndarray) -> bool:
     """Native nibble pack (``ltpu_pack_nibbles``); False -> numpy path
-    (stale prebuilt libltpu.so without the entry point).  Nibble-only:
-    callers must not reach here with a crumb section."""
+    (a strided buffer).  Nibble-only: callers must not reach here with
+    a crumb section."""
     import ctypes
-    fn = getattr(lib, "ltpu_pack_nibbles", None)
-    if fn is None or not getattr(fn, "argtypes", None):
-        return False
     if not (logical.flags.c_contiguous and out.flags.c_contiguous):
         return False
     n, g = logical.shape
-    fn(logical.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
-       n, g, packed_groups,
-       out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
-       out.shape[1])
+    lib.ltpu_pack_nibbles(
+        logical.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
+        n, g, packed_groups,
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_ubyte)),
+        out.shape[1])
     return True
 
 

@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import Config
-from ..utils.log import Log
 
 DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
@@ -54,9 +53,12 @@ def build_mesh(config: Config) -> Optional[Mesh]:
         axes = tuple(config.mesh_axes) or (DATA_AXIS,)
         n = int(np.prod(shape))
         if n > len(devices):
-            Log.warning(f"mesh_shape {shape} needs {n} devices, have "
-                        f"{len(devices)}; falling back to serial")
-            return None
+            # a requested mesh that cannot be built is an error: a
+            # serial run under a distributed config's name would hide
+            # the missing devices from whoever reads the result
+            raise ValueError(
+                f"mesh_shape {shape} needs {n} devices, jax.devices() "
+                f"has {len(devices)}")
         return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
     n = len(devices)
     if n == 1:

@@ -33,13 +33,28 @@ OOM_MARKERS = (
     "resource_exhausted", "resource exhausted", "out of memory",
     "failed to allocate", "allocation failure", "oom killed",
 )
+# a kernel the compiler REFUSES for its on-core memory carries the same
+# RESOURCE_EXHAUSTED status as a run-time allocation failure ("XLA:TPU
+# compile permanent error. Ran out of memory in memory space vmem" —
+# libtpu 0.0.34 on a v5e, PR 21).  A kernel's VMEM footprint depends on
+# its block shape, not on the chunk length or the row bucket, so a
+# smaller chunk or bucket is refused the same way — these must not
+# enter a downshift ladder, where each rung would pay a fresh
+# multi-second compile before the error finally surfaced.  (HBM
+# exhaustion, at compile time or run time, does shrink with the
+# chunk/bucket and stays in the ladder.)
+VMEM_REFUSAL_MARKER = "memory space vmem"
 
 
 def is_oom(exc: BaseException) -> bool:
-    """Whether ``exc`` is a device/host memory-exhaustion error (the
-    degradation ladders key on this; jax raises XlaRuntimeError with a
-    RESOURCE_EXHAUSTED status on device OOM)."""
+    """Whether ``exc`` is a RUN-TIME device/host memory-exhaustion
+    error (the degradation ladders key on this; jax raises
+    XlaRuntimeError with a RESOURCE_EXHAUSTED status on device OOM).
+    A kernel refused for VMEM at compile time is not: see
+    VMEM_REFUSAL_MARKER."""
     msg = str(exc).lower()
+    if VMEM_REFUSAL_MARKER in msg:
+        return False
     return any(m in msg for m in OOM_MARKERS)
 
 

@@ -621,17 +621,15 @@ class Telemetry:
         can land in that window), so read the distributed global state
         directly.  Returns (process_id, num_processes, initialized)."""
         import sys
-        jax = sys.modules.get("jax")
-        if jax is None:
+        if "jax" not in sys.modules:
             return 0, 1, False
-        try:
-            from jax._src import distributed as _dist
-            st = _dist.global_state
-            return (int(getattr(st, "process_id", 0) or 0),
-                    int(getattr(st, "num_processes", 1) or 1),
-                    getattr(st, "client", None) is not None)
-        except Exception:  # pragma: no cover - jax-version-dependent
-            return 0, 1, False
+        # private module: jax has no public way to read the process id
+        # without booting a backend.  No handler — if a jax upgrade
+        # moves it, this must fail loudly, not tag every shard host 0
+        from jax._src import distributed as _dist
+        st = _dist.global_state
+        return (int(st.process_id or 0), int(st.num_processes or 1),
+                st.client is not None)
 
     def host(self) -> int:
         """This process's host id for trace-shard tagging:
@@ -1253,17 +1251,12 @@ def _compile_cache_event(event: str, **kwargs) -> None:
 def watch_compile_cache() -> None:
     """Register the jax monitoring listener mapping persistent-cache
     hit/miss events to ``compile_cache_hits``/``compile_cache_misses``
-    counters.  Idempotent; a jax version without the monitoring
-    surface degrades to log-only (the pre-r14 behavior)."""
+    counters.  Idempotent."""
     if _CACHE_WATCH["armed"]:
         return
-    try:
-        from jax._src import monitoring as _monitoring
-        _monitoring.register_event_listener(_compile_cache_event)
-        _CACHE_WATCH["armed"] = True
-    except Exception as e:  # pragma: no cover - jax-version-dependent
-        Log.debug(f"compile-cache telemetry unavailable "
-                  f"({type(e).__name__}: {e})")
+    import jax.monitoring
+    jax.monitoring.register_event_listener(_compile_cache_event)
+    _CACHE_WATCH["armed"] = True
 
 
 _RETRACE_WARN_DEFAULT = 8

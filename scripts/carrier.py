@@ -3,7 +3,7 @@
 The reference groups row INDICES contiguously by leaf and gathers
 feature bytes through them (src/treelearner/data_partition.hpp:109-161)
 — free on a cache-hierarchy CPU, dead on TPU (XLA row gather measured
-36 GB/s vs a 534 GB/s stream, scripts/kbench_gather.py).  Instead the
+36 GB/s vs a 534 GB/s stream, docs/ROOFLINE.md r5).  Instead the
 per-row DATA physically rides the partition: everything a tree round
 touches lives in one int8 "carrier" laid out as (T, R, 128) — T
 128-column tiles of R byte-rows per column — and splitting a leaf

@@ -41,15 +41,7 @@ def device_op_table(tdir):
         tdir, "**", "*.xplane.pb"), recursive=True))
     if not pbs:
         return None
-    if not hasattr(jax.profiler, "ProfileData"):
-        # this jaxlib cannot parse xplanes in-process; the serialized
-        # trace is still on disk for TensorBoard/xprof
-        print(f"(xplane written to {pbs[-1]}; this jax has no "
-              "ProfileData parser — open it in xprof/TensorBoard)",
-              file=sys.stderr)
-        return None
-    data = jax.profiler.ProfileData.from_serialized_xspace(
-        open(pbs[-1], "rb").read())
+    data = jax.profiler.ProfileData.from_file(pbs[-1])
     phase = defaultdict(float)
     agg = defaultdict(float)
     cnt = defaultdict(int)

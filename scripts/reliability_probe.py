@@ -64,7 +64,10 @@ def child(out_model: str) -> None:
 
 
 def run_child(out_model: str, fault_plan: str = ""):
-    env = dict(os.environ, PYTHONPATH=REPO)
+    # CPU-pinned like the other probes' children: one is SIGKILLed
+    # mid-run and the next starts at once — on a chip host it would
+    # find the chip still held
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     env.pop("LTPU_FAULT_PLAN", None)
     if fault_plan:
         env["LTPU_FAULT_PLAN"] = fault_plan

@@ -58,8 +58,7 @@ def test_hlo_rules_pass_on_registered_entry_points(analysis_programs):
 
 
 def test_hlo001_flags_f64_fixture():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: x * 2)(
             jnp.zeros(3, jnp.float64)).jaxpr
     findings = check_no_f64(_prog(jaxpr=jaxpr))

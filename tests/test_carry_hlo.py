@@ -111,7 +111,9 @@ def test_compiled_while_carries_packed_record_stack(analysis_programs):
     buffer the dispatch scan carries."""
     prog = analysis_programs.fused_chunk(4)
     rec = prog.meta["record_size"]
-    pat = re.compile(r"while\(.*u8\[%d,1,%d\]" % (4, rec))
+    # "%while.N = (carried tuple type) while(%operand), ..." — the
+    # printer writes the carried types before the opcode
+    pat = re.compile(r"u8\[%d,1,%d\][^)]*\) while\(" % (4, rec))
     assert any(pat.search(ln)
                for ln in prog.compiled_text.splitlines()), (
         f"no while loop carries the packed u8[4,1,{rec}] record "

@@ -28,6 +28,57 @@ def test_wheel_builds(tmp_path):
     assert any(n.endswith("text_loader.cpp") for n in names)
 
 
+def test_native_library_is_rebuilt_not_reused_when_inputs_change(tmp_path):
+    """libltpu.so / liblgbm_tpu.so are a pure function of their inputs
+    (native.build_shared): a library on disk whose sidecar key does not
+    match the current sources, flags and CPU is REBUILT, never loaded —
+    the tree is copied between machines with ignored files included,
+    and the flags carry -march=native."""
+    import ctypes
+    import shutil
+
+    from lightgbm_tpu import native
+
+    src = tmp_path / "probe.cpp"
+    lib = str(tmp_path / "libprobe.so")
+    flags = ["-O1", "-shared", "-fPIC"]
+    src.write_text('extern "C" int answer() { return 1; }\n')
+    assert native.build_shared(lib, [str(src)], flags) == lib
+
+    def answer():
+        # dlopen caches by path and maps the file: load a private copy,
+        # never the path this test is about to overwrite
+        answer.n = getattr(answer, "n", 0) + 1
+        copy = str(tmp_path / f"loaded{answer.n}.so")
+        shutil.copy(lib, copy)
+        return ctypes.CDLL(copy).answer()
+
+    assert answer() == 1
+    built_at = os.stat(lib).st_mtime_ns
+    # unchanged inputs: reused as is
+    native.build_shared(lib, [str(src)], flags)
+    assert os.stat(lib).st_mtime_ns == built_at
+    # a NEWER library that was not built from these sources (another
+    # machine's, a stale checkout's): the mtime rule would have loaded
+    # it; the key rule rebuilds
+    with open(lib, "wb") as f:
+        f.write(b"not the library these sources build")
+    os.utime(lib, ns=(built_at + 10**9, built_at + 10**9))
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    native.build_shared(lib, [str(src)], flags)
+    assert answer() == 2
+    # flags and the CPU identity are inputs too
+    k = native.build_key([str(src)], flags)
+    assert native.build_key([str(src)], flags + ["-DX"]) != k
+    assert native._cpu_identity(), "no CPU identity for -march=native"
+    # and the package's own library followed the rule
+    assert native.get_lib() is not None, native.build_error
+    with open(native._LIB_PATH + ".key") as f:
+        srcs = [os.path.join(native._SRC_DIR, n)
+                for n in os.listdir(native._SRC_DIR) if n.endswith(".cpp")]
+        assert f.read() == native.build_key(srcs, native._FLAGS)
+
+
 def test_docker_files_present():
     for f in ("docker/dockerfile-cli", "docker/dockerfile-python",
               "docker/README.md", "pmml/README.md"):

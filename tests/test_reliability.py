@@ -155,6 +155,19 @@ def test_error_classification():
     # OOM is never transient: re-dispatching the same allocation
     # cannot succeed — the degradation ladder owns it
     assert not is_transient(RuntimeError("RESOURCE_EXHAUSTED: oops"))
+    # the two messages a v5e gave in PR 21 (libtpu 0.0.34).  Run-time
+    # HBM exhaustion shrinks with the chunk/bucket: the ladder's case
+    assert is_oom(ValueError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting "
+        "to allocate 4.00G. That was not possible. There are 3.62G "
+        "free.; (0x0x0_HBM0)"))
+    # a kernel refused for VMEM at COMPILE time carries the same
+    # status, but a smaller chunk compiles the same kernel: it must
+    # surface at once, not after a fresh compile per ladder rung
+    assert not is_oom(RuntimeError(
+        "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+        "of memory in memory space vmem. Used 256.00M of 128.00M vmem. "
+        "Exceeded vmem capacity by 128.00M."))
 
 
 def test_retry_backoff_bounded_and_exhausts():

@@ -19,6 +19,7 @@ Pins the tentpole's contracts:
   500 + flight-dump path without tearing down the listener.
 """
 import json
+import os
 import threading
 import urllib.error
 import urllib.request
@@ -573,6 +574,64 @@ def test_compile_cache_hit_miss_counters():
     assert (c.get("compile_cache_misses", 0) > miss0
             or c.get("compile_cache_hits", 0) > hit0), (
         "a fresh jit compilation produced no cache counter")
+
+
+def test_compile_cache_dir_resolution_rule(monkeypatch):
+    """ONE rule places the compile cache (config.resolve_compile_cache_dir):
+    JAX_COMPILATION_CACHE_DIR set -> the program sets NO directory in
+    code, whatever compile_cache_dir says; unset -> <checkout>/.jax_cache
+    for the default, the given path otherwise, "" disables.  Never a
+    temp name, pid or timestamp: the path is part of jax's cache key."""
+    import jax
+
+    import conftest
+    from lightgbm_tpu import config as C
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert Config().compile_cache_dir == "auto"
+    assert C.DEFAULT_COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    suite_dir = jax.config.jax_compilation_cache_dir
+    try:
+        # nothing placed from outside: the code picks the directory
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert C.resolve_compile_cache_dir("auto") == \
+            C.DEFAULT_COMPILE_CACHE_DIR
+        assert C.resolve_compile_cache_dir("/some/where") == "/some/where"
+        assert C.resolve_compile_cache_dir("") is None
+        # placed from outside: the code sets nothing
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        assert C.resolve_compile_cache_dir("auto") is None
+        assert C.resolve_compile_cache_dir("/some/where") is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        # an embedding application that configured jax itself counts too
+        jax.config.update("jax_compilation_cache_dir", "/embedder")
+        assert C.resolve_compile_cache_dir("auto") is None
+    finally:
+        jax.config.update("jax_compilation_cache_dir", suite_dir)
+    # the harness obeys the same rule: with the variable unset it chose
+    # its fixed in-checkout directory, and that is what is live now
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        assert os.path.samefile(os.path.dirname(conftest.SUITE_CACHE_DIR),
+                                repo)
+        assert suite_dir == conftest.SUITE_CACHE_DIR
+
+
+def test_conftest_leaves_an_outside_cache_alone(tmp_path):
+    """tests/conftest.py under JAX_COMPILATION_CACHE_DIR: the variable's
+    directory is the live one, not .jax_cache_cpu."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PLATFORMS="cpu")
+    run = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; sys.path.insert(0, 'tests'); import conftest, jax; "
+         "from lightgbm_tpu.config import Config; Config(); "
+         "print(jax.config.jax_compilation_cache_dir)"],
+        capture_output=True, text=True, env=env, cwd=repo, timeout=120)
+    assert run.returncode == 0, run.stderr[-2000:]
+    assert run.stdout.strip().splitlines()[-1] == str(tmp_path)
 
 
 def test_prometheus_exposes_serving_families():

@@ -7,12 +7,14 @@ calls (``lgb.Dataset``, ``lgb.train``, ``Booster.predict``,
 model the repo has always measured — binary, 1,000,000 x 28,
 ``num_leaves=255``, ``max_bin=63`` (BASELINE.json config 1) — with the
 depth cut to 64 rounds and data drawn from a seed.  It fails unless JAX
-reports a TPU, and prints as the LAST line of stdout one JSON object:
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N},
-...}`` with the installed versions, each leg's wall / compile seconds /
-compile-cache hits and misses, and the kernel plan each leg actually
-ran.  Wall times are observations, not metrics: the benchmark is
-bench.py.
+reports a TPU.  It prints two JSON lines on stdout.  The LAST is the
+verdict and nothing else — exactly ``{"ok": true, "device": {"platform":
+"tpu", "kind": ..., "count": N}}``, the device as JAX reports it (the
+driver refuses any other key there).  The line BEFORE it is the report:
+``{"report": {...}}`` with the installed versions, each leg's wall /
+compile seconds / compile-cache hits and misses, and the kernel plan
+each leg actually ran.  Wall times are observations, not metrics: the
+benchmark is bench.py.
 
 Legs (each a function of its sizes; tests/test_chip_smoke.py runs A-C
 at a tiny shape on the CPU interpret seam):
@@ -262,6 +264,15 @@ def leg_multichip(lgb, X, y, Xv, rounds, n_chips=4):
             "mean_abs_vs_one_chip": float(delta.mean())}
 
 
+def verdict(ok, device):
+    """The last stdout line, to the driver's contract: exactly the keys
+    ``ok`` and ``device``, and in ``device`` exactly ``platform``,
+    ``kind``, ``count``.  Everything else belongs in the report line."""
+    return json.dumps({"ok": bool(ok), "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}})
+
+
 def main():
     import jax
     dev = jax.devices()[0]
@@ -276,9 +287,9 @@ def main():
     from lightgbm_tpu import native
 
     watch = CompileWatch()
-    out = {"ok": False,
-           "device": {"platform": dev.platform, "kind": dev.device_kind,
-                      "count": len(jax.devices())},
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    out = {"device": device,
            "versions": {p: version(p) for p in ("jax", "jaxlib", "libtpu")},
            "shape": {"rows": ROWS, "features": FEATURES,
                      "valid_rows": VALID_ROWS, "rounds": ROUNDS,
@@ -319,8 +330,8 @@ def main():
     out["compile_s"] = round(watch.compile_s, 2)
     out["cache_hits"], out["cache_misses"] = watch.hits, watch.misses
     out["wall_s"] = round(time.time() - t_all, 2)
-    out["ok"] = True
-    print(json.dumps(out))
+    print(json.dumps({"report": out}))
+    print(verdict(True, device), flush=True)
     return 0
 
 

@@ -68,3 +68,15 @@ def test_main_refuses_without_a_tpu(capsys):
     captured = capsys.readouterr()
     assert captured.out == "", "a refused run must print no result"
     assert "no TPU" in captured.err
+
+
+@pytest.mark.fast
+def test_verdict_line_has_the_contract_keys_and_no_others():
+    # the driver refuses the PR on any extra key in the last stdout line
+    import json
+    line = chip_smoke.verdict(True, {"platform": "tpu",
+                                     "kind": "TPU v5 lite", "count": 1,
+                                     "extra": "dropped"})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}

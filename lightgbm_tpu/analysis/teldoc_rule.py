@@ -1,5 +1,5 @@
-"""TEL001 — telemetry span/phase names <-> docs/OBSERVABILITY.md span
-map, both directions (re-homed from
+"""TEL001 — telemetry span/stage/phase names <-> docs/OBSERVABILITY.md
+span map, both directions (re-homed from
 ``scripts/check_telemetry_coverage.py``, now a thin wrapper here).
 
 The span map is the contract between the instrumentation and anyone
@@ -16,8 +16,8 @@ from typing import Dict, List, Set
 from .core import Finding, rule
 
 CALL_RE = re.compile(
-    r"\.(?:span|start_span|phase)\(\s*(?:f?)([\"'])([^\"']+)\1")
-DYNAMIC_RE = re.compile(r"\.(?:span|start_span|phase)\(\s*[^\"')]")
+    r"\.(?:span|start_span|stage|phase)\(\s*(?:f?)([\"'])([^\"']+)\1")
+DYNAMIC_RE = re.compile(r"\.(?:span|start_span|stage|phase)\(\s*[^\"')]")
 DOC = "docs/OBSERVABILITY.md"
 
 # telemetry.py itself defines the API (its internal span("device_wait")
@@ -44,7 +44,7 @@ def dynamic_span_findings(sources: Dict[str, str]) -> List[Finding]:
             # allow the API definition sites in telemetry.py and
             # variable-forwarding helpers that pass a `name` parameter
             if rel.endswith("telemetry.py") or re.match(
-                    r"\.(?:span|start_span|phase)\(\s*(?:self|name|f?\")",
+                    r"\.(?:span|start_span|stage|phase)\(\s*(?:self|name|f?\")",
                     frag):
                 continue
             line = src[:m.start()].count("\n") + 1

@@ -129,7 +129,7 @@ class Dataset:
             from .data_loader import load_file_streaming
             from .telemetry import TELEMETRY
             t0 = _time.perf_counter()
-            with TELEMETRY.span("binning"):
+            with TELEMETRY.stage("binning"):
                 self._core = load_file_streaming(data, config)
             wall = _time.perf_counter() - t0
             if wall > 0:
@@ -198,7 +198,7 @@ class Dataset:
             else:
                 from .sharded import ShardedDataset, save_shard_cache
                 t0 = _time.perf_counter()
-                with TELEMETRY.span("binning", rows=int(data.shape[0])):
+                with TELEMETRY.stage("binning", rows=int(data.shape[0])):
                     self._core = ShardedDataset.construct_sharded(
                         data, label=label, weight=self.weight,
                         group=self.group, init_score=self.init_score,
@@ -219,7 +219,7 @@ class Dataset:
                     self.data = None
                 return self._core
         t0 = _time.perf_counter()
-        with TELEMETRY.span("binning", rows=int(data.shape[0])):
+        with TELEMETRY.stage("binning", rows=int(data.shape[0])):
             # host-side bin-mapper fit + matrix binning — the one
             # pre-device phase of training, decomposed into the
             # fit_mappers/bin/pack sub-spans (docs/OBSERVABILITY.md)

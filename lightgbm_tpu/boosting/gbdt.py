@@ -96,8 +96,11 @@ class GBDT:
         self.num_class = config.num_tree_per_iteration
         self.shrinkage_rate = config.learning_rate
 
+        # per-row metadata goes to the device in the "upload" set-up
+        # stage, with the grower's bin matrix (docs/OBSERVABILITY.md)
         if self.objective is not None:
-            self.objective.init(train_set.metadata, self.num_data)
+            with TELEMETRY.stage("upload"):
+                self.objective.init(train_set.metadata, self.num_data)
 
         self.grower = TreeGrower(train_set, config)
         # multi-host (finalize_global): device metadata arrays must
@@ -134,9 +137,10 @@ class GBDT:
             base += train_set.metadata.init_score.reshape(
                 self.num_class, self.num_data).astype(np.float32)
         base += self.init_score
-        padded = np.stack([self.grower.pad_rows(base[c])
-                           for c in range(self.num_class)])
-        self.scores = self.grower.policy.place_score_rows(padded)
+        with TELEMETRY.stage("upload"):
+            padded = np.stack([self.grower.pad_rows(base[c])
+                               for c in range(self.num_class)])
+            self.scores = self.grower.policy.place_score_rows(padded)
 
         # per-phase wall-clock accounting (the TIMETAG analog,
         # reference gbdt.cpp:21-29/52-61); reported at Log.debug level
@@ -195,13 +199,16 @@ class GBDT:
 
         # row weights as count channel (bagging multiplies into this)
         w = train_set.metadata.weight
-        self._full_counts = self.grower.policy.place_rows(
-            self.grower.pad_rows(np.ones(self.num_data,
-                                         dtype=np.float32)))
-        self._weights_dev = (None if w is None else
-                             self.grower.policy.place_rows(
-                                 self.grower.pad_rows(
-                                     w.astype(np.float32))))
+        with TELEMETRY.stage("upload"):
+            self._full_counts = self.grower.policy.place_rows(
+                self.grower.pad_rows(np.ones(self.num_data,
+                                             dtype=np.float32)))
+            self._weights_dev = (None if w is None else
+                                 self.grower.policy.place_rows(
+                                     self.grower.pad_rows(
+                                         w.astype(np.float32))))
+            if TELEMETRY.on:
+                jax.block_until_ready((self.scores, self._full_counts))
         self._bag_mask: Optional[jax.Array] = None
 
         # EVERY O(N) device array must cross the jit boundary as an
@@ -357,8 +364,9 @@ class GBDT:
     # ------------------------------------------------------------------
     def _update_train_scores(self, scores, leaf_id, leaf_value, class_idx,
                              shrinkage):
-        delta = leaf_value_broadcast(leaf_id, leaf_value) * shrinkage
-        return scores.at[class_idx].add(delta)
+        with TELEMETRY.phase("score_update"):
+            delta = leaf_value_broadcast(leaf_id, leaf_value) * shrinkage
+            return scores.at[class_idx].add(delta)
 
     def _predict_valid(self, tree: TreeArrays, bins):
         # train and reference-aligned validation matrices share ONE
@@ -479,53 +487,69 @@ class GBDT:
                    shrinkage, fresh_bag, vbins, ohb=None):
         """One boosting iteration's device body — shared by the
         per-iteration fused step and the multi-iteration chunk
-        (``fresh_bag`` may be a python bool or a traced scalar)."""
+        (``fresh_bag`` may be a python bool or a traced scalar).
+        Every op here lies under a ``tel.<phase>`` scope (the grower
+        scopes its own), so a device trace splits by phase
+        (docs/OBSERVABILITY.md, device phases)."""
         cfg = self.config
         use_bag = self._use_bagging_fused()
         n_pad = self.grower.n_padded
-        g, h = self._compute_gradients(scores)
-        kb, ks = jax.random.split(key)
-        if use_bag:
-            u = jax.random.uniform(kb, (n_pad,))
-            new_mask = (u < cfg.bagging_fraction) & (self._full_counts > 0)
-            bag_mask = jnp.where(fresh_bag, new_mask, bag_mask)
-            counts = jnp.where(bag_mask, 1.0, 0.0)
-        else:
-            counts = self._full_counts
-        if self._sample_active():
-            g, h, counts = self._sample_rows_fused(g, h, counts, ks)
-        g, h = self._mask_gradients(g, h, counts)
+        with TELEMETRY.phase("gradients"):
+            g, h = self._compute_gradients(scores)
+        with TELEMETRY.phase("sampling"):
+            kb, ks = jax.random.split(key)
+            if use_bag:
+                u = jax.random.uniform(kb, (n_pad,))
+                new_mask = (u < cfg.bagging_fraction) \
+                    & (self._full_counts > 0)
+                bag_mask = jnp.where(fresh_bag, new_mask, bag_mask)
+                counts = jnp.where(bag_mask, 1.0, 0.0)
+            else:
+                counts = self._full_counts
+            if self._sample_active():
+                g, h, counts = self._sample_rows_fused(g, h, counts, ks)
+            g, h = self._mask_gradients(g, h, counts)
         trees = []
         nl = jnp.int32(1)
         new_vscores = list(vscores)
         # stochastic-rounding key for the int8 quantization (folded off
         # the iteration key so the bagging/GOSS streams are untouched)
-        kq = (jax.random.fold_in(key, 0x51AB)
-              if self._quant_stochastic() else None)
+        with TELEMETRY.phase("quantize"):
+            kq = (jax.random.fold_in(key, 0x51AB)
+                  if self._quant_stochastic() else None)
         for k in range(self.num_class):
+            with TELEMETRY.phase("gradients"):
+                g_k, h_k, fmask_k = g[k], h[k], fmask[k]
+            with TELEMETRY.phase("quantize"):
+                qkey = None if kq is None else jax.random.fold_in(kq, k)
             tree, leaf_id, row_val = self.grower._train_tree_impl(
-                g[k], h[k], counts, fmask[k], ohb,
-                qkey=None if kq is None else jax.random.fold_in(kq, k))
-            tree = self._finalize_tree(tree, leaf_id, k, scores, counts)
-            # a no-split tree must contribute nothing (the reference
-            # skips UpdateScore when num_leaves==1, gbdt.cpp:427-460)
-            ok = (tree.num_leaves > 1).astype(jnp.float32)
-            tree = tree._replace(leaf_value=tree.leaf_value * ok)
+                g_k, h_k, counts, fmask_k, ohb, qkey=qkey)
+            with TELEMETRY.phase("finalize_tree"):
+                tree = self._finalize_tree(tree, leaf_id, k, scores,
+                                           counts)
+                # a no-split tree must contribute nothing (the
+                # reference skips UpdateScore when num_leaves==1,
+                # gbdt.cpp:427-460)
+                ok = (tree.num_leaves > 1).astype(jnp.float32)
+                tree = tree._replace(leaf_value=tree.leaf_value * ok)
             renew = (self.objective is not None
                      and self.objective.is_renew_tree_output)
-            if row_val is not None and not renew:
-                # fused path: the exit-route already carried each row's
-                # leaf value — skip the separate (N, L) broadcast
-                delta = row_val * ok * shrinkage
-            else:
-                delta = leaf_value_broadcast(leaf_id,
-                                             tree.leaf_value) * shrinkage
-            scores = scores.at[k].add(delta)
-            for i, vb in enumerate(vbins):
-                pv = self._predict_valid(tree, vb)
-                new_vscores[i] = new_vscores[i].at[k].add(pv * shrinkage)
+            with TELEMETRY.phase("score_update"):
+                if row_val is not None and not renew:
+                    # fused path: the exit-route already carried each
+                    # row's leaf value — skip the separate (N, L)
+                    # broadcast
+                    delta = row_val * ok * shrinkage
+                else:
+                    delta = leaf_value_broadcast(
+                        leaf_id, tree.leaf_value) * shrinkage
+                scores = scores.at[k].add(delta)
+                for i, vb in enumerate(vbins):
+                    pv = self._predict_valid(tree, vb)
+                    new_vscores[i] = new_vscores[i].at[k].add(
+                        pv * shrinkage)
+                nl = jnp.maximum(nl, tree.num_leaves)
             trees.append(tree)
-            nl = jnp.maximum(nl, tree.num_leaves)
         return scores, tuple(new_vscores), bag_mask, tuple(trees), nl
 
     def _build_fused_chunk(self, n_iters: int):
@@ -560,8 +584,9 @@ class GBDT:
                     scores, vscores, bag_mask, key, fmask, shrinkage,
                     fresh_bag, vb, ohb)
                 if packed:
-                    trees = jnp.stack(
-                        [self.grower.emit_tree_record(t) for t in trees])
+                    with TELEMETRY.phase("tree_record"):
+                        trees = jnp.stack([self.grower.emit_tree_record(t)
+                                           for t in trees])
                 return (scores, vscores, bag_mask), (trees, nl)
 
             with self._bound_captives(cap):
@@ -596,68 +621,72 @@ class GBDT:
         # wall too, and the pre-r9 bench timed the whole call — the
         # counter must cover the same window for series continuity
         t0 = time.perf_counter() if tm.on else 0.0
-        cfg = self.config
-        chunk_key = (n_iters, len(self.valid_sets), self.shrinkage_rate,
-                     self._sample_active())
-        if self._fused_chunk_n != chunk_key:
-            self._fused_chunk = self._build_fused_chunk(n_iters)
-            self._fused_chunk_n = chunk_key
-        use_bag = self._use_bagging_fused()
-        if self._bag_state is None:
-            self._bag_state = self._full_counts > 0
-        # the per-iteration seed and feature-mask draws below consume
-        # host RNG state BEFORE the dispatch can fail — snapshot the
-        # streams so a failed dispatch restores them and a retry or
-        # engine-level chunk downshift re-draws the IDENTICAL
-        # sequence (the byte-identity guarantee under failure,
-        # docs/RELIABILITY.md)
-        _rng_snap = (self._iter_key_rng.get_state(),
-                     self._feat_rng.get_state())
-        seeds = np.asarray([self._iter_key_rng.randint(0, 2**31 - 1)
-                            for _ in range(n_iters)], np.uint32)
-        if self._np_keys_ok and not use_bag \
-                and not self._sample_active() \
-                and not self._quant_stochastic():
-            # keys unused by the chunk body (no bagging draw, no GOSS
-            # sampling, no stochastic quantization rounding): reuse a
-            # cached device array and skip the per-chunk host->device
-            # transfer entirely
-            cache = getattr(self, "_chunk_keys", None)
-            if cache is None or cache.shape[0] != n_iters:
-                cache = jnp.zeros((n_iters, 2), jnp.uint32)
-                self._chunk_keys = cache
-            keys = cache
-        elif self._np_keys_ok:
-            keys = jnp.asarray(np.stack(
-                [np.zeros(n_iters, np.uint32), seeds], axis=1))
-        else:  # pragma: no cover - unexpected key layout
-            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-        if self.config.feature_fraction >= 1.0:
-            cache = getattr(self, "_chunk_fmasks", None)
-            if cache is None or cache.shape[0] != n_iters:
-                cache = jnp.ones(
-                    (n_iters, self.num_class, self.grower.num_features),
-                    bool)
-                self._chunk_fmasks = cache
-            fmasks = cache
-        else:
-            fmasks = jnp.asarray(np.stack(
-                [np.stack([self._feature_mask_np()
-                           for _ in range(self.num_class)])
-                 for _ in range(n_iters)]))
-        if use_bag:
-            fresh = np.zeros(n_iters, bool)
-            for j in range(n_iters):
-                fresh[j] = (self.iter_ + j) % cfg.bagging_freq == 0
-        else:
-            # all-False flags never change: cache the device constant
-            cache = getattr(self, "_chunk_fresh", None)
-            if cache is None or cache.shape[0] != n_iters:
-                cache = jnp.zeros(n_iters, bool)
-                self._chunk_fresh = cache
-            fresh = cache
+        span = tm.start_span("train_chunk", first_iter=self.iter_,
+                             iters=n_iters)
+        built = False
+        with tm.span("chunk_prep"):
+            cfg = self.config
+            chunk_key = (n_iters, len(self.valid_sets), self.shrinkage_rate,
+                         self._sample_active())
+            if self._fused_chunk_n != chunk_key:
+                self._fused_chunk = self._build_fused_chunk(n_iters)
+                self._fused_chunk_n = chunk_key
+                built = True
+            use_bag = self._use_bagging_fused()
+            if self._bag_state is None:
+                self._bag_state = self._full_counts > 0
+            # the per-iteration seed and feature-mask draws below consume
+            # host RNG state BEFORE the dispatch can fail — snapshot the
+            # streams so a failed dispatch restores them and a retry or
+            # engine-level chunk downshift re-draws the IDENTICAL
+            # sequence (the byte-identity guarantee under failure,
+            # docs/RELIABILITY.md)
+            _rng_snap = (self._iter_key_rng.get_state(),
+                         self._feat_rng.get_state())
+            seeds = np.asarray([self._iter_key_rng.randint(0, 2**31 - 1)
+                                for _ in range(n_iters)], np.uint32)
+            if self._np_keys_ok and not use_bag \
+                    and not self._sample_active() \
+                    and not self._quant_stochastic():
+                # keys unused by the chunk body (no bagging draw, no GOSS
+                # sampling, no stochastic quantization rounding): reuse a
+                # cached device array and skip the per-chunk host->device
+                # transfer entirely
+                cache = getattr(self, "_chunk_keys", None)
+                if cache is None or cache.shape[0] != n_iters:
+                    cache = jnp.zeros((n_iters, 2), jnp.uint32)
+                    self._chunk_keys = cache
+                keys = cache
+            elif self._np_keys_ok:
+                keys = jnp.asarray(np.stack(
+                    [np.zeros(n_iters, np.uint32), seeds], axis=1))
+            else:  # pragma: no cover - unexpected key layout
+                keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+            if self.config.feature_fraction >= 1.0:
+                cache = getattr(self, "_chunk_fmasks", None)
+                if cache is None or cache.shape[0] != n_iters:
+                    cache = jnp.ones(
+                        (n_iters, self.num_class, self.grower.num_features),
+                        bool)
+                    self._chunk_fmasks = cache
+                fmasks = cache
+            else:
+                fmasks = jnp.asarray(np.stack(
+                    [np.stack([self._feature_mask_np()
+                               for _ in range(self.num_class)])
+                     for _ in range(n_iters)]))
+            if use_bag:
+                fresh = np.zeros(n_iters, bool)
+                for j in range(n_iters):
+                    fresh[j] = (self.iter_ + j) % cfg.bagging_freq == 0
+            else:
+                # all-False flags never change: cache the device constant
+                cache = getattr(self, "_chunk_fresh", None)
+                if cache is None or cache.shape[0] != n_iters:
+                    cache = jnp.zeros(n_iters, bool)
+                    self._chunk_fresh = cache
+                fresh = cache
         self.timer.start("tree")
-        span = tm.start_span("train_chunk", iters=n_iters)
 
         def _enqueue():
             # fault seam BEFORE the dispatch: an injected failure (or
@@ -686,8 +715,13 @@ class GBDT:
                 # to-return is the host/dispatch cost (the async
                 # enqueue); the optional fence attributes the
                 # remainder to device execution
-                tm.add("host_dispatch_ms",
-                       (time.perf_counter() - t0) * 1e3)
+                host_ms = (time.perf_counter() - t0) * 1e3
+                tm.add("host_dispatch_ms", host_ms)
+                if built:
+                    # this dispatch traced, lowered and compiled (or
+                    # loaded from the cache) a chunk program: set-up
+                    # on the first chunk, a stall if it is a later one
+                    tm.add("chunk_program_build_ms", host_ms)
                 tm.fence_ready(scores)
                 tm.add("trees_dispatched", n_iters * self.num_class)
                 tm.add("iterations", n_iters)
@@ -705,13 +739,13 @@ class GBDT:
             self.timer.stop("tree")
             tm.end_span(span)
             raise
-        tm.end_span(span)
         if tm.on and self.grower.policy.nproc > 1:
             # per-host step wall -> fleet max/min/mean + straggler
             # ratio via a tiny allgather (all hosts run this SPMD
             # loop in lockstep, so the collective is safe here)
             from ..parallel.monitor import record_step_wall
             record_step_wall(time.perf_counter() - t0)
+        commit = tm.start_span("chunk_commit")
         self.scores = scores
         for vs, s in zip(self.valid_sets, vscores):
             vs.scores = s
@@ -745,6 +779,8 @@ class GBDT:
         self.iter_ += n_iters
         self.timer.stop("tree")
         self._transport_epoch_tick()
+        tm.end_span(commit)
+        tm.end_span(span)
         if self._nl_count >= self._stop_check_every:
             return self._check_stop_window()
         return False
@@ -805,6 +841,12 @@ class GBDT:
                 "probe_per_tree_s": times, "base_s": base_s,
                 "slope_s_per_iter": slope_s, "dispatch_s": dispatch_s,
                 "chunk": chunk}
+        for c, per_tree_s in times.items():
+            TELEMETRY.gauge(f"dispatch_probe_ms_per_tree_{c}",
+                            per_tree_s * 1e3)
+        TELEMETRY.gauge("dispatch_probe_return_ms", dispatch_s * 1e3)
+        TELEMETRY.gauge("dispatch_chunk_slope_ms", slope_s * 1e3)
+        TELEMETRY.gauge("dispatch_chunk_base_ms", base_s * 1e3)
         Log.debug(f"dispatch_chunk=auto fit: base {base_s * 1e3:.2f} ms "
                   f"+ {slope_s * 1e3:.4f} ms/iter·chunk, dispatch "
                   f"{dispatch_s * 1e3:.1f} ms -> chunk {chunk}")

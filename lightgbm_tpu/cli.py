@@ -66,7 +66,7 @@ def run(argv: List[str]) -> int:
     from .telemetry import TELEMETRY
     if TELEMETRY.on and config.telemetry_out:
         # explicit export at task end (the atexit hook is only the
-        # safety net): telemetry=trace telemetry_out=/tmp/run writes
+        # safety net): telemetry=spans telemetry_out=/tmp/run writes
         # /tmp/run.jsonl + /tmp/run.perfetto.json (ui.perfetto.dev);
         # multi-host runs write per-host .host<i> shards — merge with
         # `python -m lightgbm_tpu.telemetry merge`

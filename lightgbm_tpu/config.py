@@ -585,16 +585,16 @@ class Config:
     # kernel paths (incl. the fused-route grower wiring) in interpret
     # mode on CPU — slow, for CI coverage of the TPU-only code paths
     telemetry: str = "off"          # runtime telemetry subsystem
-    # (docs/OBSERVABILITY.md): "off" records nothing and is pinned to
-    # change NO compiled program; "counters" keeps named counters and
-    # gauges (trees dispatched, compiles observed, serving bucket
-    # hit/miss, RSS watermark) with zero device interference; "spans"
-    # adds nested timing spans plus a per-dispatch device fence that
-    # splits wall time into host_dispatch_ms vs device_wait_ms (the
-    # r7 bench split, now first-class); "trace" additionally annotates
-    # the grower's trace-time phases (histogram, split finder,
-    # partition) with jax.named_scope so profiler xplanes attribute
-    # device ops to them — metadata-only HLO change
+    # (docs/OBSERVABILITY.md): "off" records nothing; "counters" keeps
+    # named counters and gauges (trees dispatched, compiles observed,
+    # set-up stage times, serving bucket hit/miss, RSS watermark) with
+    # zero device interference and shows its spans to an active
+    # profiler session; "spans" adds nested timing spans in memory
+    # plus a per-dispatch device fence that splits wall time into
+    # host_dispatch_ms vs device_wait_ms (the r7 bench split, now
+    # first-class); "trace" is an accepted alias of "spans".  Every
+    # mode compiles the SAME programs: the tel.<phase> named scopes
+    # over the chunk program are op metadata and are always on
     telemetry_out: str = ""         # export path prefix: on process
     # exit (and after each CLI task) telemetry writes <prefix>.jsonl
     # (newline-JSON events + a final counter snapshot) and

@@ -449,8 +449,8 @@ class Dataset:
         byte-identical at every thread count."""
         from .telemetry import TELEMETRY
         threads = resolve_construct_threads(config)
-        with TELEMETRY.span("fit_mappers", features=len(sample_vals),
-                            threads=threads):
+        with TELEMETRY.stage("fit_mappers", features=len(sample_vals),
+                             threads=threads):
             return find_bin_mappers(
                 sample_vals, total_sample_cnt, config.max_bin,
                 config.min_data_in_bin, config.min_data_in_leaf, cat_set,
@@ -469,7 +469,7 @@ class Dataset:
         each feature occupies [offset, offset+num_bin-1) with its
         default bin collapsed into slot 0."""
         from .telemetry import TELEMETRY
-        with TELEMETRY.span("pack"):
+        with TELEMETRY.stage("pack"):
             self._build_groups_impl(reference, sample_nonzero, sample_cnt)
 
     def _build_groups_impl(self, reference: Optional["Dataset"],
@@ -567,7 +567,7 @@ class Dataset:
         chunk, regardless of N."""
         from .telemetry import TELEMETRY
         out = self.group_bins[row_start:row_start + data.shape[0]]
-        with TELEMETRY.span("bin", rows=int(data.shape[0])):
+        with TELEMETRY.stage("bin", rows=int(data.shape[0])):
             if self.bin_layout is None:
                 self._bin_rows_dense_into(data, out)
                 return
@@ -830,7 +830,7 @@ class Dataset:
                 bin_feature(f)
 
         threads = resolve_construct_threads(self.config)
-        with TELEMETRY.span("bin", rows=int(N)):
+        with TELEMETRY.stage("bin", rows=int(N)):
             if threads > 1 and len(by_group) > 1:
                 from concurrent.futures import ThreadPoolExecutor
                 with ThreadPoolExecutor(

@@ -149,6 +149,12 @@ class TreeGrower:
     (see parallel/mesh.py)."""
 
     def __init__(self, dataset: Dataset, config: Config, policy=None):
+        # set-up stages (docs/OBSERVABILITY.md): "grower_init" is the
+        # constructor less the "upload" and "binsT" stages inside it
+        with TELEMETRY.stage("grower_init"):
+            self._setup(dataset, config, policy)
+
+    def _setup(self, dataset: Dataset, config: Config, policy) -> None:
         from ..parallel.mesh import ShardingPolicy, build_mesh
         if policy is None:
             policy = ShardingPolicy(config, build_mesh(config))
@@ -270,45 +276,50 @@ class TreeGrower:
         self._mh_local: Optional[int] = getattr(
             dataset, "_mh_local_rows", None) if getattr(
                 dataset, "_multihost", False) else None
-        if self._mh_local is not None:
-            self._mh_nproc = max(1, self.policy.nproc)
-            per_host = ((self._mh_local + self.chunk - 1)
-                        // self.chunk) * self.chunk
-            self._mh_per_host = per_host
-            self.n_padded = per_host * self._mh_nproc
-            loc_pad = per_host - self._mh_local
-            bins_local = np.concatenate(
-                [dataset.group_bins,
-                 np.zeros((loc_pad, dataset.group_bins.shape[1]),
-                          dtype=np.uint8)])
-            self.bins = self.policy.place_local_rows(bins_local)
-            self._row_valid = self.policy.place_local_rows(
-                np.concatenate([np.ones(self._mh_local, bool),
-                                np.zeros(loc_pad, bool)]))
-        else:
-            self.n_padded = ((n + self.chunk - 1)
-                             // self.chunk) * self.chunk
-            pad = self.n_padded - n
-            shard_bins = getattr(dataset, "shard_bins", None)
-            if shard_bins:
-                # sharded-construct dataset (lightgbm_tpu/sharded/):
-                # per-participant shards are placed straight onto
-                # their mesh devices; the logical global layout (rows
-                # in order, tail pad) is identical to the
-                # single-matrix route, so the compiled program and
-                # the trained trees are byte-identical across routes
-                self.bins = self.policy.place_row_shards(shard_bins,
-                                                         self.n_padded)
+        with TELEMETRY.stage("upload"):
+            if self._mh_local is not None:
+                self._mh_nproc = max(1, self.policy.nproc)
+                per_host = ((self._mh_local + self.chunk - 1)
+                            // self.chunk) * self.chunk
+                self._mh_per_host = per_host
+                self.n_padded = per_host * self._mh_nproc
+                loc_pad = per_host - self._mh_local
+                bins_local = np.concatenate(
+                    [dataset.group_bins,
+                     np.zeros((loc_pad, dataset.group_bins.shape[1]),
+                              dtype=np.uint8)])
+                self.bins = self.policy.place_local_rows(bins_local)
+                self._row_valid = self.policy.place_local_rows(
+                    np.concatenate([np.ones(self._mh_local, bool),
+                                    np.zeros(loc_pad, bool)]))
             else:
-                bins_np = dataset.group_bins
-                if pad:
-                    bins_np = np.concatenate(
-                        [bins_np,
-                         np.zeros((pad, bins_np.shape[1]),
-                                  dtype=np.uint8)])
-                self.bins = self.policy.place_bins(bins_np)
-            self._row_valid = self.policy.place_rows(
-                np.concatenate([np.ones(n, bool), np.zeros(pad, bool)]))
+                self.n_padded = ((n + self.chunk - 1)
+                                 // self.chunk) * self.chunk
+                pad = self.n_padded - n
+                shard_bins = getattr(dataset, "shard_bins", None)
+                if shard_bins:
+                    # sharded-construct dataset (lightgbm_tpu/sharded/):
+                    # per-participant shards are placed straight onto
+                    # their mesh devices; the logical global layout (rows
+                    # in order, tail pad) is identical to the
+                    # single-matrix route, so the compiled program and
+                    # the trained trees are byte-identical across routes
+                    self.bins = self.policy.place_row_shards(shard_bins,
+                                                             self.n_padded)
+                else:
+                    bins_np = dataset.group_bins
+                    if pad:
+                        bins_np = np.concatenate(
+                            [bins_np,
+                             np.zeros((pad, bins_np.shape[1]),
+                                      dtype=np.uint8)])
+                    self.bins = self.policy.place_bins(bins_np)
+                self._row_valid = self.policy.place_rows(
+                    np.concatenate([np.ones(n, bool), np.zeros(pad, bool)]))
+            if TELEMETRY.on:
+                # the stage ends when the matrix is on the device;
+                # nothing but Python overlaps the transfer today
+                jax.block_until_ready((self.bins, self._row_valid))
         # the Pallas kernel path: single TPU device only (its sequential
         # -grid accumulation is a Mosaic property); the XLA formulation
         # stays for CPU simulation, GSPMD meshes (where the sharded
@@ -545,8 +556,12 @@ class TreeGrower:
         # transposed on DEVICE from the already-uploaded bins: a host
         # transpose + second upload of the (N, G) matrix doubles the
         # host->device traffic at the 10.5M scale
-        self.binsT = (jnp.transpose(self.bins)
-                      if self.use_fused or self.use_tiled else None)
+        self.binsT = None
+        if self.use_fused or self.use_tiled:
+            with TELEMETRY.stage("binsT"):
+                self.binsT = jnp.transpose(self.bins)
+                if TELEMETRY.on:
+                    jax.block_until_ready(self.binsT)
         self._route_cols = 15 + (self.max_feature_bin + 7) // 8
         # trace-scoped override: callers thread the one-hot through
         # their jit boundary as an ARGUMENT (a multi-hundred-MB closure
@@ -587,8 +602,8 @@ class TreeGrower:
             # the grower's resolved kernel plan as gauges: the fused
             # device phases cannot be host-timed per iteration (one
             # compiled program), so telemetry records WHAT was selected
-            # — device-time attribution per phase comes from
-            # telemetry=trace + scripts/profile_train.py xplanes
+            # — device time per phase comes from a profiler trace and
+            # the tel.<phase> scopes (docs/OBSERVABILITY.md)
             if self.leaf_part:
                 hk = "seg_tiled(leaf_partition)"
             elif self.use_tiled:
@@ -762,10 +777,9 @@ class TreeGrower:
     def _hist_kernel(self, grad, hess, counts, leaf_id, slots=None,
                      num_leaves=None, quant=None):
         """Frontier histogram dispatch: Pallas on a real single chip,
-        XLA one-hot contraction under meshes / CPU simulation.
-        ``telemetry=trace`` annotates the phase (named_scope metadata)
-        so xplane device events attribute to ``histogram``; any other
-        telemetry mode leaves the lowered program untouched."""
+        XLA one-hot contraction under meshes / CPU simulation.  The
+        ``tel.histogram`` scope (op metadata, at every telemetry mode)
+        lets a profiler trace attribute the device events to it."""
         with TELEMETRY.phase("histogram"):
             return self._hist_kernel_impl(grad, hess, counts, leaf_id,
                                           slots, num_leaves, quant)
@@ -1199,37 +1213,54 @@ class TreeGrower:
 
     def _train_tree_inner(self, grad, hess, counts, feature_mask,
                           qkey=None):
-        state = self._init_state(grad, hess, counts)
+        # every op of a tree lies under a tel.<phase> scope (innermost
+        # wins where they nest), so a device trace splits by phase
+        with TELEMETRY.phase("init_state"):
+            state = self._init_state(grad, hess, counts)
         if self._is_voting:
             def body_fn(st):
-                return self._round_voting(st, grad, hess, counts,
-                                          feature_mask)
+                # histograms and the vote run inside one shard_map
+                with TELEMETRY.phase("split_finder"):
+                    return self._round_voting(st, grad, hess, counts,
+                                              feature_mask)
         elif self._is_feature_par:
             def body_fn(st):
-                return self._round_feature(st, grad, hess, counts,
-                                           feature_mask)
+                with TELEMETRY.phase("split_finder"):
+                    return self._round_feature(st, grad, hess, counts,
+                                               feature_mask)
         else:
             # gradients are fixed for the whole tree, so the int8
             # quantization (one scale per channel) happens once here;
             # qkey enables the stochastic rounding the skewed-gradient
             # objectives need (see quantize_gradients)
-            quant = (quantize_gradients(grad, hess, counts, key=qkey)
-                     if self.use_quant else None)
-            if quant is not None and (self.use_fused or self.use_tiled):
-                # the fused/tiled kernels stream weights lane-major
-                quant = (quant[0].T, quant[1])          # (3, N)
+            with TELEMETRY.phase("quantize"):
+                quant = (quantize_gradients(grad, hess, counts, key=qkey)
+                         if self.use_quant else None)
+                if quant is not None \
+                        and (self.use_fused or self.use_tiled):
+                    # the fused/tiled kernels stream weights lane-major
+                    quant = (quant[0].T, quant[1])          # (3, N)
 
             def body_fn(st):
                 return self._round(st, grad, hess, counts, feature_mask,
                                    quant)
 
         def cond(st: GrowerState):
-            return ~st.done
+            # reads the flag apply_split left
+            with TELEMETRY.phase("apply_split"):
+                return ~st.done
 
         def body(st: GrowerState):
             return body_fn(st)
 
         final = jax.lax.while_loop(cond, body, state)
+        with TELEMETRY.phase("route"):
+            leaf_id, row_val = self._exit_route(final)
+        tree = final.tree._replace(num_leaves=final.num_leaves)
+        return tree, leaf_id, row_val
+
+    def _exit_route(self, final: GrowerState):
+        """(final leaf ids, per-row post-route leaf value or None)."""
         leaf_id = final.leaf_id
         row_val = None
         if self.use_fused:
@@ -1255,8 +1286,7 @@ class TreeGrower:
                     self.bins, leaf_id, final.route_tab,
                     values=final.tree.leaf_value,
                     packed_groups=self.pack_P)
-        tree = final.tree._replace(num_leaves=final.num_leaves)
-        return tree, leaf_id, row_val
+        return leaf_id, row_val
 
     # ------------------------------------------------------------------
     def _run_finders(self, hist, sum_grad, sum_hess, count, min_c, max_c,
@@ -1293,10 +1323,11 @@ class TreeGrower:
             # no-cache mode — parents passes), then run the segment-
             # addressed kernel whose LHS carries no leaf one-hot
             from ..ops.histogram import route_only_tiled
-            new_leaf = route_only_tiled(
-                self.binsT, st.leaf_id, st.route_tab,
-                block=self.pallas_block_tiled, interpret=self._interp,
-                packed_groups=self.pack_P)
+            with TELEMETRY.phase("route"):
+                new_leaf = route_only_tiled(
+                    self.binsT, st.leaf_id, st.route_tab,
+                    block=self.pallas_block_tiled, interpret=self._interp,
+                    packed_groups=self.pack_P)
             st = st._replace(leaf_id=new_leaf)
             part = self._build_partition(new_leaf, quant)
             right_hist = self._hist_kernel_seg(part, rights)
@@ -1304,10 +1335,11 @@ class TreeGrower:
             # split-route: apply the pending table in a dedicated
             # Pallas pass, then histogram with the route-free kernel
             from ..ops.histogram import route_only_tiled
-            new_leaf = route_only_tiled(
-                self.binsT, st.leaf_id, st.route_tab,
-                block=self.pallas_block_tiled, interpret=self._interp,
-                packed_groups=self.pack_P)
+            with TELEMETRY.phase("route"):
+                new_leaf = route_only_tiled(
+                    self.binsT, st.leaf_id, st.route_tab,
+                    block=self.pallas_block_tiled, interpret=self._interp,
+                    packed_groups=self.pack_P)
             st = st._replace(leaf_id=new_leaf)
             right_hist = self._hist_kernel_q_tiled(new_leaf, rights,
                                                    quant)
@@ -1355,33 +1387,34 @@ class TreeGrower:
         # covering the valid slots — a lax.cond ladder mirroring
         # _packed_dispatch, so the (2W, F, B) threshold sweep stops
         # paying the full frontier cap on the 1-2-leaf early rounds
-        W = parents.shape[0]
+        with TELEMETRY.phase("split_finder"):
+            W = parents.shape[0]
 
-        def refresh_at(w):
-            def go(_):
-                if w >= W:
-                    return self._refresh_cand(st, new_slots, h_new,
-                                              feature_mask)
-                slots_w = jnp.concatenate([parents[:w], rights[:w]])
-                h_w = jnp.concatenate([left_hist[:w], right_hist[:w]])
-                return self._refresh_cand(st, slots_w, h_w, feature_mask)
-            return go
+            def refresh_at(w):
+                def go(_):
+                    if w >= W:
+                        return self._refresh_cand(st, new_slots, h_new,
+                                                  feature_mask)
+                    slots_w = jnp.concatenate([parents[:w], rights[:w]])
+                    h_w = jnp.concatenate([left_hist[:w], right_hist[:w]])
+                    return self._refresh_cand(st, slots_w, h_w, feature_mask)
+                return go
 
-        rungs = [s for s in (PACKED_STRIP, 2 * PACKED_STRIP) if s < W]
-        if not self.split_ladder or not rungs:
-            cand, forced_cand = refresh_at(W)(None)
-        else:
-            kv = jnp.sum(rights >= 0)
-            wide = refresh_at(W)
-            if len(rungs) == 1:
-                cand, forced_cand = jax.lax.cond(
-                    kv <= rungs[0], refresh_at(rungs[0]), wide, None)
+            rungs = [s for s in (PACKED_STRIP, 2 * PACKED_STRIP) if s < W]
+            if not self.split_ladder or not rungs:
+                cand, forced_cand = refresh_at(W)(None)
             else:
-                cand, forced_cand = jax.lax.cond(
-                    kv <= rungs[0], refresh_at(rungs[0]),
-                    lambda _: jax.lax.cond(kv <= rungs[1],
-                                           refresh_at(rungs[1]), wide,
-                                           None), None)
+                kv = jnp.sum(rights >= 0)
+                wide = refresh_at(W)
+                if len(rungs) == 1:
+                    cand, forced_cand = jax.lax.cond(
+                        kv <= rungs[0], refresh_at(rungs[0]), wide, None)
+                else:
+                    cand, forced_cand = jax.lax.cond(
+                        kv <= rungs[0], refresh_at(rungs[0]),
+                        lambda _: jax.lax.cond(kv <= rungs[1],
+                                               refresh_at(rungs[1]), wide,
+                                               None), None)
         return st._replace(hist_cache=cache, cand=cand,
                            forced_cand=forced_cand)
 
@@ -1581,62 +1614,66 @@ class TreeGrower:
         all — the while_loop exits first."""
         L = self.num_leaves
         W = self.frontier
-        st = self._refresh(st, st.pend_parents, st.pend_rights, grad,
-                           hess, counts, feature_mask, quant)
+        with TELEMETRY.phase("histogram"):
+            st = self._refresh(st, st.pend_parents, st.pend_rights,
+                               grad, hess, counts, feature_mask,
+                               quant)
 
-        c = st.cand
-        best_gain = c[:, CAND_GAIN]
-        best_f = c[:, CAND_FEATURE].astype(jnp.int32)
-        thr = c[:, CAND_THRESHOLD].astype(jnp.int32)
-        dleft = c[:, CAND_DEFAULT_LEFT] > 0.5
-        lsg, lsh, lsc = c[:, CAND_LSG], c[:, CAND_LSH], c[:, CAND_LSC]
-        lout, rout = c[:, CAND_LOUT], c[:, CAND_ROUT]
-        cat_mask = c[:, CAND_COLS:] > 0.5
+        with TELEMETRY.phase("split_finder"):
+            c = st.cand
+            best_gain = c[:, CAND_GAIN]
+            best_f = c[:, CAND_FEATURE].astype(jnp.int32)
+            thr = c[:, CAND_THRESHOLD].astype(jnp.int32)
+            dleft = c[:, CAND_DEFAULT_LEFT] > 0.5
+            lsg, lsh, lsc = c[:, CAND_LSG], c[:, CAND_LSH], c[:, CAND_LSC]
+            lout, rout = c[:, CAND_LOUT], c[:, CAND_ROUT]
+            cat_mask = c[:, CAND_COLS:] > 0.5
 
-        forced_valid = None
-        if self.forced_count:
-            fc = st.forced_cand
-            fc_gain = fc[:, FORCED_GAIN]
-            fc_thr = fc[:, FORCED_THRESHOLD].astype(jnp.int32)
-            s_node = jnp.clip(st.leaf_forced, 0, self.forced_count - 1)
-            ff = self.forced_feature[s_node]
-            forced_valid = (st.leaf_forced >= 0) & (fc_gain > NEG_INF)
-            best_f = jnp.where(forced_valid, ff, best_f)
-            best_gain = jnp.where(forced_valid, fc_gain, best_gain)
-            thr = jnp.where(forced_valid, fc_thr, thr)
-            dleft = jnp.where(forced_valid,
-                              fc[:, FORCED_DEFAULT_LEFT] > 0.5, dleft)
-            lsg = jnp.where(forced_valid, fc[:, FORCED_LSG], lsg)
-            lsh = jnp.where(forced_valid, fc[:, FORCED_LSH], lsh)
-            lsc = jnp.where(forced_valid, fc[:, FORCED_LSC], lsc)
-            lout = jnp.where(forced_valid, fc[:, FORCED_LOUT], lout)
-            rout = jnp.where(forced_valid, fc[:, FORCED_ROUT], rout)
-            fmask = (jnp.arange(self.max_feature_bin, dtype=jnp.int32)[None]
-                     == fc_thr[:, None])
-            cat_mask = jnp.where(forced_valid[:, None], fmask, cat_mask)
+            forced_valid = None
+            if self.forced_count:
+                fc = st.forced_cand
+                fc_gain = fc[:, FORCED_GAIN]
+                fc_thr = fc[:, FORCED_THRESHOLD].astype(jnp.int32)
+                s_node = jnp.clip(st.leaf_forced, 0, self.forced_count - 1)
+                ff = self.forced_feature[s_node]
+                forced_valid = (st.leaf_forced >= 0) & (fc_gain > NEG_INF)
+                best_f = jnp.where(forced_valid, ff, best_f)
+                best_gain = jnp.where(forced_valid, fc_gain, best_gain)
+                thr = jnp.where(forced_valid, fc_thr, thr)
+                dleft = jnp.where(forced_valid,
+                                  fc[:, FORCED_DEFAULT_LEFT] > 0.5, dleft)
+                lsg = jnp.where(forced_valid, fc[:, FORCED_LSG], lsg)
+                lsh = jnp.where(forced_valid, fc[:, FORCED_LSH], lsh)
+                lsc = jnp.where(forced_valid, fc[:, FORCED_LSC], lsc)
+                lout = jnp.where(forced_valid, fc[:, FORCED_LOUT], lout)
+                rout = jnp.where(forced_valid, fc[:, FORCED_ROUT], rout)
+                fmask = (jnp.arange(self.max_feature_bin,
+                                    dtype=jnp.int32)[None]
+                         == fc_thr[:, None])
+                cat_mask = jnp.where(forced_valid[:, None], fmask, cat_mask)
 
-        slot = jnp.arange(L, dtype=jnp.int32)
-        active = slot < st.num_leaves
-        depth_ok = (self.max_depth <= 0) | \
-            (st.tree.leaf_depth < self.max_depth)
-        cand_m = active & depth_ok & (best_gain > 0.0)
-        if forced_valid is not None:
-            forced_valid = forced_valid & active
-            cand_m = cand_m | forced_valid
+            slot = jnp.arange(L, dtype=jnp.int32)
+            active = slot < st.num_leaves
+            depth_ok = (self.max_depth <= 0) | \
+                (st.tree.leaf_depth < self.max_depth)
+            cand_m = active & depth_ok & (best_gain > 0.0)
+            if forced_valid is not None:
+                forced_valid = forced_valid & active
+                cand_m = cand_m | forced_valid
 
-        key = jnp.where(cand_m, best_gain, NEG_INF)
-        if forced_valid is not None:
-            key = jnp.where(forced_valid, jnp.inf, key)
-        # W-bounded selection (round 7): only the top W leaves — the
-        # most a round can split — ever receive a rank, replacing two
-        # full-L argsorts.  lax.top_k keeps the lower index first on
-        # ties, exactly the stable argsort(-key) order it replaces.
-        top_i = jax.lax.top_k(key, W)[1].astype(jnp.int32)
-        rank = jnp.full(L, L, jnp.int32).at[top_i].set(
-            jnp.arange(W, dtype=jnp.int32))
-        budget = L - st.num_leaves
-        do_split = cand_m & (rank < budget) & (rank < W)
-        k = do_split.sum().astype(jnp.int32)
+            key = jnp.where(cand_m, best_gain, NEG_INF)
+            if forced_valid is not None:
+                key = jnp.where(forced_valid, jnp.inf, key)
+            # W-bounded selection (round 7): only the top W leaves — the
+            # most a round can split — ever receive a rank, replacing two
+            # full-L argsorts.  lax.top_k keeps the lower index first on
+            # ties, exactly the stable argsort(-key) order it replaces.
+            top_i = jax.lax.top_k(key, W)[1].astype(jnp.int32)
+            rank = jnp.full(L, L, jnp.int32).at[top_i].set(
+                jnp.arange(W, dtype=jnp.int32))
+            budget = L - st.num_leaves
+            do_split = cand_m & (rank < budget) & (rank < W)
+            k = do_split.sum().astype(jnp.int32)
 
         st2 = self._apply_selection(st, do_split, rank, k, best_gain,
                                     best_f, thr, dleft, lsg, lsh, lsc,
@@ -1645,10 +1682,11 @@ class TreeGrower:
         # queue this round's new leaves for the NEXT round's refresh:
         # top_i[w] is the leaf with split-rank w (its slot hosts the
         # left child); the matching right child is num_leaves_old + w
-        w_iota = jnp.arange(W, dtype=jnp.int32)
-        split_ok = w_iota < k
-        parents = jnp.where(split_ok, top_i, -1)
-        rights = jnp.where(split_ok, st.num_leaves + w_iota, -1)
+        with TELEMETRY.phase("apply_split"):
+            w_iota = jnp.arange(W, dtype=jnp.int32)
+            split_ok = w_iota < k
+            parents = jnp.where(split_ok, top_i, -1)
+            rights = jnp.where(split_ok, st.num_leaves + w_iota, -1)
         return st2._replace(pend_parents=parents, pend_rights=rights)
 
     # ==================================================================

@@ -327,12 +327,14 @@ def _slot_prep(num_leaves: int, slots: Optional[jax.Array]):
     return num_leaves, m_leaf, 3 * m_leaf, slot_row
 
 
-def _run_hist_kernel(kern, bins, w, leaf_id, const_inputs, *, block,
+def _run_hist_kernel(kern, bins, w, leaf_id, const_inputs, *, name, block,
                      m_leaf, m_pad, num_leaves, max_group_bin, out_dtype,
                      interpret, raw_out=False):
     """Shared pallas_call plumbing: row-blocked (bins, w, leaf) inputs,
     VMEM-resident constants, one (m_pad, G*B) accumulator; returns the
-    (L, G, B, 3) histogram view."""
+    (L, G, B, 3) histogram view.  ``name`` pins the kernel's name in
+    a device trace: the jitted wrapper's, which is what the lowering
+    derived before and what perfbench/metrics/*.json match."""
     n, num_groups = bins.shape
     if n % block != 0:
         raise ValueError(f"N ({n}) must be a multiple of block ({block})")
@@ -348,7 +350,7 @@ def _run_hist_kernel(kern, bins, w, leaf_id, const_inputs, *, block,
         ] + [pl.BlockSpec(c.shape, lambda i: (0, 0)) for c in consts],
         out_specs=pl.BlockSpec((m_pad, gb), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, gb), out_dtype),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(bins, w, leaf_id[:, None], *consts)
     if raw_out:
         return out
@@ -374,6 +376,7 @@ def compute_group_histograms_pallas_paired(
                              max_group_bin=max_group_bin, m_pad=m_pad)
     return _run_hist_kernel(
         kern, bins, w, leaf_id, [slot_row], block=block, m_leaf=m_leaf,
+        name="compute_group_histograms_pallas_paired",
         m_pad=m_pad, num_leaves=num_leaves, max_group_bin=max_group_bin,
         out_dtype=jnp.float32, interpret=interpret)
 
@@ -514,6 +517,7 @@ def compute_group_histograms_pallas_q(
                              int8_bins=int8_bins)
     hist = _run_hist_kernel(
         kern, bins, wq, leaf_id, [emat, bcol, slot_row], block=block,
+        name="compute_group_histograms_pallas_q",
         m_leaf=m_leaf, m_pad=m_pad, num_leaves=num_leaves,
         max_group_bin=max_group_bin, out_dtype=jnp.int32,
         interpret=interpret)
@@ -561,6 +565,7 @@ def compute_group_histograms_pallas(bins: jax.Array, grad: jax.Array,
                              max_group_bin=max_group_bin, m_pad=m_pad)
     return _run_hist_kernel(
         kern, bins, w, leaf_id, [emat, bcol, slot_row], block=block,
+        name="compute_group_histograms_pallas",
         m_leaf=m_leaf, m_pad=m_pad, num_leaves=num_leaves,
         max_group_bin=max_group_bin, out_dtype=jnp.float32,
         interpret=interpret)
@@ -818,7 +823,7 @@ def _hist_kernel_body_pre_packed(ohb_ref, w_ref, leaf_ref, slots_ref,
             out_ref[:, p * gbp_pad:(p + 1) * gbp_pad] += contrib
 
 
-def _run_hist_kernel_pre(kern, ohb, w, leaf_id, slot_row, *, block,
+def _run_hist_kernel_pre(kern, ohb, w, leaf_id, slot_row, *, name, block,
                          m_pad, out_dtype, interpret, out_cols=None):
     """pallas_call plumbing for the streamed-one-hot bodies: the (N,
     G*B[/pack]) one-hot is row-blocked like the weights; output is the
@@ -841,7 +846,7 @@ def _run_hist_kernel_pre(kern, ohb, w, leaf_id, slot_row, *, block,
         ],
         out_specs=pl.BlockSpec((m_pad, out_cols), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, out_cols), out_dtype),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(ohb, w, leaf_id[:, None], slot_row)
     return out
 
@@ -883,6 +888,7 @@ def compute_group_histograms_pre(
                              quant=quant, pack=pack)
     out = _run_hist_kernel_pre(
         kern, ohb, w, leaf_id, slot_row, block=block, m_pad=m_pad,
+        name="compute_group_histograms_pre",
         out_dtype=jnp.int32 if quant else jnp.float32,
         interpret=interpret,
         out_cols=None if pack == 1 else pack * ohb.shape[1])
@@ -958,6 +964,7 @@ def compute_group_histograms_q_packed(
                              strips=strips, int8_bins=int8_bins)
     out = _run_hist_kernel(
         kern, bins, wq, leaf_id, [emat, bcol, slot_row], block=block,
+        name="compute_group_histograms_q_packed",
         m_leaf=128 * strips, m_pad=128 * strips, num_leaves=cap,
         max_group_bin=max_group_bin, out_dtype=jnp.int32,
         interpret=interpret, raw_out=True)
@@ -1094,7 +1101,7 @@ def compute_group_histograms_q_tiled(
                                lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, num_tiles * tile_w),
                                        jnp.int32),
-        interpret=interpret,
+        interpret=interpret, name="compute_group_histograms_q_tiled",
     )(binsT, wT, leaf_id[None, :], slot_col)
     hist = _tiled_out_to_hist(out, strips, num_groups, b)
     return hist.astype(jnp.float32) * scales[None, None, None, :]
@@ -1127,6 +1134,7 @@ def compute_group_histograms_pre_packed(
                              quant=quant, pack=pack)
     out = _run_hist_kernel_pre(
         kern, ohb, w, leaf_id, slot_row, block=block, m_pad=128 * strips,
+        name="compute_group_histograms_pre_packed",
         out_dtype=jnp.int32 if quant else jnp.float32,
         interpret=interpret,
         out_cols=None if pack == 1 else pack * ohb.shape[1])
@@ -1399,7 +1407,7 @@ def compute_group_histograms_fused(
                                  jnp.int32 if quant else jnp.float32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="compute_group_histograms_fused",
     )(ohb, binsT, wT, leaf_id[None, :], routeT, slot_col)
     hist = _departition_planes(hist, pack, gb)
     out = _unpack_strip_channels(hist, strips, num_groups,
@@ -1510,7 +1518,7 @@ def compute_group_histograms_fused_tiled(
             jax.ShapeDtypeStruct((m_pad, num_tiles * tile_w), jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="compute_group_histograms_fused_tiled",
     )(binsT, wT, leaf_id[None, :], routeT, slot_col)
     hist = _tiled_out_to_hist(out, strips, num_groups, b).astype(
         jnp.float32) * scales[None, None, None, :]
@@ -1608,7 +1616,7 @@ def compute_group_histograms_seg_tiled(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_out, num_tiles * tile_w),
                                        jnp.int32),
-        interpret=interpret,
+        interpret=interpret, name="compute_group_histograms_seg_tiled",
     )(blk_slot.astype(jnp.int32), binsT_p, wT_p)
     # slot k's channels live in rows [8k, 8k+3); tile layout matches
     # the tiled-iota kernels (per_tile groups per 128-lane tile)
@@ -1698,7 +1706,7 @@ def route_only_tiled(binsT: jax.Array, leaf_id: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        interpret=interpret,
+        interpret=interpret, name="route_only_tiled",
     )(binsT, leaf_id[None, :], routeT)
     return leaf_out[0]
 
@@ -1750,7 +1758,7 @@ def route_apply_tiled(binsT: jax.Array, leaf_id: jax.Array,
             jax.ShapeDtypeStruct((1, n), jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="route_apply_tiled",
     )(binsT, leaf_id[None, :], routeT)
     return leaf_out[0], val_out[0]
 

@@ -356,7 +356,8 @@ def predict_level_ensemble_pallas(stack: LevelEnsemble, X2: jax.Array,
         + [pl.BlockSpec((tile, f2_dim), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, K), jnp.float32),
-        interpret=interpret)(*fields, X2)
+        interpret=interpret,
+        name="predict_level_ensemble_pallas")(*fields, X2)
 
 
 class RawTreeStack(NamedTuple):

@@ -7,17 +7,18 @@ instrumentation private to ``bench.py`` — invisible to a real training
 or serving run.  This module is the one code path both worlds share:
 ``bench.py`` reads its counters for the host-dispatch / device-wait
 split, and a production process gets the same attribution in-process
-via ``telemetry=counters|spans|trace``.
+via ``telemetry=counters|spans``.
 
 Design constraints (pinned by ``tests/test_telemetry.py``):
 
-- **Compiled-out by default.**  ``telemetry=off`` (the default) adds
-  ZERO changes to any jitted program — all instrumentation lives at
-  host seams (dispatch boundaries, trace-time Python), and the
-  off/counters/spans modes lower byte-identical StableHLO
-  (``test_off_mode_hlo_identity``).  Only ``trace`` mode adds
-  ``jax.named_scope`` METADATA inside traced functions so profiler
-  xplanes attribute device ops to grower phases.
+- **One program at every mode.**  All instrumentation lives at host
+  seams (dispatch boundaries, trace-time Python), and every mode
+  lowers byte-identical StableHLO (``test_off_mode_hlo_identity``).
+  ``phase`` scopes (``jax.named_scope("tel.<phase>")``) are ALWAYS
+  entered, ``off`` included: a named scope writes op-location
+  METADATA only, which ``as_text()`` does not print and which costs
+  nothing at dispatch — so a profiler trace of a run at any mode
+  attributes device ops to the program's own phases.
 - **Zero dependencies.**  Stdlib only; jax is imported lazily and only
   for the optional device fence / named-scope / live-array features.
 - **Thread-safe.**  Span stacks are thread-local; counters, gauges and
@@ -33,14 +34,19 @@ Modes (``Config.telemetry``):
                   device pipeline is untouched (``device_wait_ms``
                   stays empty unless a fence is explicitly enabled,
                   as ``bench.py`` does).
-- ``spans``     — counters + nested timing spans + a per-dispatch
-                  ``jax.block_until_ready`` fence attributing wall
-                  time to host dispatch vs device wait.  The fence is
-                  host-side only (no program change) but serializes
-                  chunk overlap — a documented observer effect.
-- ``trace``     — spans + ``jax.named_scope`` phase annotation at
-                  trace time (metadata-only HLO change) for device-op
-                  attribution in ``scripts/profile_train.py``.
+                  Spans and stages also enter a
+                  ``jax.profiler.TraceAnnotation("ltpu.<name>")``, so
+                  an active profiler session shows them in its host
+                  plane, on the device trace's clock (a flag test when
+                  no session is active).
+- ``spans``     — counters + nested timing spans recorded in memory
+                  + a per-dispatch ``jax.block_until_ready`` fence
+                  attributing wall time to host dispatch vs device
+                  wait.  The fence is host-side only (no program
+                  change) but serializes chunk overlap — a documented
+                  observer effect.
+- ``trace``     — accepted alias of ``spans`` (it once gated the
+                  ``phase`` scopes, which are now always on).
 
 Export (``Config.telemetry_out`` = path prefix): ``<prefix>.jsonl``
 (newline-JSON span events + one final snapshot line) and
@@ -79,9 +85,11 @@ from __future__ import annotations
 import atexit
 import bisect
 import collections
+import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -89,8 +97,8 @@ from typing import Any, Dict, List, Optional
 from .utils.log import Log
 from .utils import log as _log_mod
 
-MODES = ("off", "counters", "spans", "trace")
-_OFF, _COUNTERS, _SPANS, _TRACE = range(4)
+MODES = ("off", "counters", "spans", "trace")     # "trace" == "spans"
+_OFF, _COUNTERS, _SPANS = range(3)
 
 # hard bound on retained span events: a week-long serving process must
 # not grow its heap linearly in requests.  Overflow increments the
@@ -491,8 +499,20 @@ class _NullCtx:
 _NULL = _NullCtx()
 
 
+def _annotation(name: str, attrs):
+    """The span as a ``jax.profiler.TraceAnnotation("ltpu.<name>")``:
+    a host event of an active profiler session, on the device trace's
+    clock.  A process that never imported jax has no such session (and
+    a pure-host tool must not import it for this), so it gets the
+    no-op context."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NULL
+    return jax.profiler.TraceAnnotation("ltpu." + name, **(attrs or {}))
+
+
 class _Span:
-    __slots__ = ("_tm", "name", "attrs", "t0", "_depth")
+    __slots__ = ("_tm", "name", "attrs", "t0", "_depth", "_ann")
 
     def __init__(self, tm: "Telemetry", name: str, attrs):
         self._tm = tm
@@ -503,11 +523,14 @@ class _Span:
         stack = self._tm._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann = _annotation(self.name, self.attrs)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
         stack = self._tm._stack()
         # reentrancy guard: pop OUR frame even if an inner span leaked
         while stack and stack[-1] is not self:
@@ -563,14 +586,15 @@ class Telemetry:
     def configure(self, mode: str = "counters", out: str = "",
                   fence: Optional[bool] = None,
                   retrace_warn: Optional[int] = None) -> "Telemetry":
-        """Set the global mode.  ``fence=None`` resolves to the mode
-        default (on for spans/trace, off for counters).  ``out`` arms
-        an atexit export to ``<out>.jsonl`` / ``<out>.perfetto.json``."""
+        """Set the global mode (``trace`` is ``spans``).  ``fence=None``
+        resolves to the mode default (on for spans, off for counters).
+        ``out`` arms an atexit export to ``<out>.jsonl`` /
+        ``<out>.perfetto.json``."""
         if mode not in MODES:
             raise ValueError(f"telemetry mode must be one of {MODES}, "
                              f"got {mode!r}")
         with self._lock:
-            self.mode = MODES.index(mode)
+            self.mode = min(MODES.index(mode), _SPANS)
             if not self.run_id:
                 import uuid
                 self.run_id = uuid.uuid4().hex[:12]
@@ -680,27 +704,70 @@ class Telemetry:
         return st
 
     def span(self, name: str, **attrs):
-        """Nested timing span (context manager).  No-op below
-        ``spans`` mode — safe on any hot path."""
-        if self.mode < _SPANS:
+        """Nested timing span (context manager).  No-op at ``off``; at
+        ``counters`` only the profiler annotation (``_annotation``);
+        at ``spans`` also recorded in memory — safe on any hot path."""
+        if self.mode < _COUNTERS:
             return _NULL
+        if self.mode < _SPANS:
+            return _annotation(name, attrs)
         return _Span(self, name, attrs or None)
 
     def start_span(self, name: str, **attrs):
         """Explicit begin/end form for spans that cannot wrap a lexical
-        block (pair with ``end_span(token)``).  Deliberately does NOT
-        touch the thread-local nesting stack, so an exception between
-        start and end cannot corrupt later spans' depths; the event is
-        recorded at depth 0 (Perfetto nests by time overlap anyway)."""
-        if self.mode < _SPANS:
+        block (pair with ``end_span(token)`` on the same thread).
+        Deliberately does NOT touch the thread-local nesting stack, so
+        an exception between start and end cannot corrupt later spans'
+        depths; the event is recorded at depth 0 (Perfetto nests by
+        time overlap anyway)."""
+        if self.mode < _COUNTERS:
             return None
-        return (name, time.perf_counter(), attrs or None)
+        ann = _annotation(name, attrs)
+        ann.__enter__()
+        t0 = time.perf_counter() if self.mode >= _SPANS else None
+        return (name, t0, attrs or None, ann)
 
     def end_span(self, token) -> None:
         if token is None:
             return
-        name, t0, attrs = token
-        self._record(name, t0, time.perf_counter() - t0, 0, attrs)
+        name, t0, attrs, ann = token
+        ann.__exit__(None, None, None)
+        if t0 is not None:
+            self._record(name, t0, time.perf_counter() - t0, 0, attrs)
+
+    @contextlib.contextmanager
+    def stage(self, name: str, **attrs):
+        """A set-up stage that runs once per job (binning, upload, the
+        grower's constructor): the span ``name``, plus — the part a
+        benchmark at ``counters`` mode can read — its OWN wall time
+        (less the stages nested in it, so the stages of a job add up)
+        in counter ``setup_<name>_ms`` and the resident set around it
+        in gauges ``rss_mb_before_<name>`` / ``rss_mb_after_<name>``
+        (a /proc read each, which also raises ``rss_mb_peak``).
+        Nothing at ``off``."""
+        if self.mode < _COUNTERS:
+            yield
+            return
+        rss = self.sample_memory()
+        if rss is not None:
+            self.gauge(f"rss_mb_before_{name}", rss)
+        nested = getattr(self._tls, "stage_ms", None)
+        if nested is None:
+            nested = self._tls.stage_ms = []
+        nested.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            with self.span(name, **attrs):
+                yield
+        finally:
+            total = (time.perf_counter() - t0) * 1e3
+            inside = nested.pop()
+            if nested:
+                nested[-1] += total
+            self.add(f"setup_{name}_ms", total - inside)
+            rss = self.sample_memory()
+            if rss is not None:
+                self.gauge(f"rss_mb_after_{name}", rss)
 
     def _record(self, name, t0, dur, depth, attrs):
         if self.flight.out:
@@ -821,16 +888,15 @@ class Telemetry:
         self.add(counter, dt * 1e3)
         return dt
 
-    # -- trace-mode phase annotation ------------------------------------
+    # -- device phase annotation -----------------------------------------
     def phase(self, name: str):
-        """``jax.named_scope`` wrapper for code inside jitted bodies:
-        at ``trace`` mode the phase name lands in the HLO op metadata
-        (so xplane device events attribute to it); below ``trace`` it
-        is the shared no-op context, leaving lowered programs
-        byte-identical.  Effective only if telemetry is configured
-        before the function's first trace (jit caches the program)."""
-        if self.mode < _TRACE:
-            return _NULL
+        """``jax.named_scope("tel.<name>")`` for code inside jitted
+        bodies, at EVERY mode: the phase lands in the op metadata of the
+        HLO (``op_name``), so a profiler trace attributes each device
+        event to the innermost phase it ran under.  Trace-time Python
+        only — nothing at dispatch; the scope writes op locations, which
+        ``lowered.as_text()`` does not print, so programs lower
+        byte-identical with and without it."""
         import jax
         return jax.named_scope(f"tel.{name}")
 
@@ -866,17 +932,20 @@ class Telemetry:
             return {fn: len(s) for fn, s in self._traces.items()}
 
     # -- memory watch ---------------------------------------------------
-    def sample_memory(self, device: bool = False) -> None:
-        """Record RSS (and optionally device-buffer) watermarks.
-        Called at chunk/predict boundaries — a /proc read per call."""
+    def sample_memory(self, device: bool = False) -> Optional[float]:
+        """Record RSS (and optionally device-buffer) watermarks and
+        return the RSS read, in MiB (None at ``off`` or without
+        /proc).  Called at chunk/predict boundaries and after each
+        set-up stage — a /proc read per call."""
         if self.mode < _COUNTERS:
-            return
+            return None
+        rss = None
         try:
             with open("/proc/self/status") as f:
                 for ln in f:
                     if ln.startswith("VmRSS:"):
-                        self.gauge_max("rss_mb_peak",
-                                       round(int(ln.split()[1]) / 1024, 1))
+                        rss = round(int(ln.split()[1]) / 1024, 1)
+                        self.gauge_max("rss_mb_peak", rss)
                         break
         except (OSError, ValueError, IndexError):
             pass
@@ -889,6 +958,7 @@ class Telemetry:
                                round(nbytes / (1 << 20), 1))
             except Exception:  # pragma: no cover - backend-dependent
                 pass
+        return rss
 
     # -- snapshot / export ----------------------------------------------
     def snapshot(self) -> Dict[str, Any]:

@@ -1,75 +1,59 @@
 """Profile a training chunk on top of the runtime telemetry subsystem.
 
-Round 9 rewrite: this used to be a standalone one-off with private
-timers; it now drives the SAME instrumentation a production run uses
-(``telemetry=trace`` — docs/OBSERVABILITY.md):
+Drives the SAME instrumentation a production run uses
+(``telemetry=spans`` — docs/OBSERVABILITY.md):
 
-1. trains a warm-up + a measured chunk under telemetry trace mode
-   (host spans, device fence, named-scope phase annotation),
+1. trains a warm-up + a measured chunk under telemetry spans mode
+   (host spans, device fence; the ``tel.<phase>`` scopes are on at
+   every mode),
 2. exports the telemetry Perfetto file + newline-JSON events
    (load the ``.perfetto.json`` in ui.perfetto.dev),
 3. prints the counter snapshot (host-dispatch vs device-wait per
    tree — the ROOFLINE headroom #3 split), and
-4. when a jax profiler xplane is available, aggregates device-op time
-   by telemetry phase (the ``tel.histogram`` / ``tel.split_finder`` /
-   ... named scopes the trace mode stamps into the HLO metadata) plus
-   the top ops, as before.
+4. takes a jax profiler trace of the measured chunk and reduces it
+   with the benchmark's own readers: ``perfbench/xplane.py`` (busy
+   time, top ops, idle gaps) and ``perfbench/program_trace.py``
+   (device time per ``tel.<phase>``) — written against real traces;
+   this script no longer keeps a reduction of its own.
 
 Usage: python scripts/profile_train.py [rows] [iters] [out_prefix]
   out_prefix default: /tmp/lgbtpu_profile/telemetry
   env: BENCH_PARAMS='{...}' param overrides (as in bench.py)
 """
-import glob
 import os
 import sys
-from collections import defaultdict
 
-sys.path.insert(0, os.path.join(os.path.dirname(
-    os.path.abspath(__file__)), ".."))
+REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+sys.path[:0] = [REPO, os.path.join(REPO, "perfbench")]
 
 import numpy as np
 
 
-def device_op_table(tdir):
-    """Aggregate device-plane op durations from the newest xplane in
-    ``tdir``, grouped by telemetry phase (named-scope prefix ``tel.``)
-    and by op name.  Returns (phase_ms, op_ms, op_calls, total_ms) or
-    None when no device plane exists (CPU seam without an xplane)."""
-    import jax
-
-    pbs = sorted(glob.glob(os.path.join(
-        tdir, "**", "*.xplane.pb"), recursive=True))
-    if not pbs:
-        return None
-    data = jax.profiler.ProfileData.from_file(pbs[-1])
-    phase = defaultdict(float)
-    agg = defaultdict(float)
-    cnt = defaultdict(int)
-    total = 0.0
-    for plane in data.planes:
-        if "TPU" not in plane.name and "/device" not in plane.name:
-            continue
-        for line in plane.lines:
-            if "Ops" not in line.name:
-                continue
-            for ev in line.events:
-                dur = ev.duration_ns / 1e6
-                agg[ev.name] += dur
-                cnt[ev.name] += 1
-                total += dur
-                # telemetry trace mode stamps jax.named_scope("tel.X")
-                # into op metadata; xplane op names carry the scope
-                # path, so a substring match attributes the op
-                name = ev.name
-                tag = "(unattributed)"
-                if "tel." in name:
-                    # scope path "…/tel.<phase>/…" -> "tel.<phase>"
-                    tag = "tel." + name.split("tel.", 1)[1].split(
-                        "/", 1)[0]
-                phase[tag] += dur
-    if not agg:
-        return None
-    return phase, agg, cnt, total
+def device_report(tdir, iters):
+    """Device ms/tree by phase and the top ops of the newest xplane
+    under ``tdir``; nothing where the backend left no device plane
+    (the CPU seam)."""
+    import program_trace
+    import xplane
+    try:
+        pb = xplane.newest_xplane(tdir)
+        planes = xplane.load(pb)
+        base = xplane.reduce(planes)
+    except xplane.NoDeviceTrace as e:
+        print(f"\n(no device trace: {e}; the spans above are the "
+              "host-side record)")
+        return
+    phases = program_trace.phase_seconds(
+        planes, program_trace.book(program_trace.hlo_instructions(pb)))
+    print(f"\n== device, {pb} ==")
+    print(f"busy {1e3 * base['busy_s'] / iters:.3f} ms/tree")
+    if phases is not None:
+        rows = dict(phases["phases"], kernel=phases["kernel"],
+                    unscoped=phases["unscoped"])
+        for tag, sec in sorted(rows.items(), key=lambda kv: -kv[1]):
+            print(f"{1e3 * sec / iters:9.3f} ms/tree  {tag}")
+    for name, sec in base["device_ops"]:
+        print(f"{1e3 * sec / iters:9.3f} ms/tree  op {name}")
 
 
 def main():
@@ -86,9 +70,7 @@ def main():
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.telemetry import TELEMETRY
 
-    # trace mode BEFORE the first compile: the named-scope phase
-    # annotation is stamped at trace time
-    TELEMETRY.configure("trace", out=out)
+    TELEMETRY.configure("spans", out=out)
 
     X, y, w = bench.make_data(rows, bench.BENCH_FEATURES)
     params = {
@@ -140,23 +122,8 @@ def main():
         if k.startswith("phase_"):
             print(f"  {k} = {snap['counters'][k]:.1f}")
 
-    table = device_op_table(tdir) if profiled else None
-    if table is None:
-        print("\n(no device xplane — per-op attribution needs a chip "
-              "or a profiler-enabled backend; telemetry spans above "
-              "are the host-side record)")
-        return
-    phase, agg, cnt, total = table
-    print(f"\n== device time by telemetry phase ==")
-    for tag, ms in sorted(phase.items(), key=lambda kv: -kv[1]):
-        print(f"{ms / iters:9.3f} ms/tree {100 * ms / total:5.1f}%  "
-              f"{tag}")
-    print(f"\n== device op time over {iters} trees ==")
-    print(f"{'ms/tree':>9} {'pct':>6} {'calls':>7}  op")
-    for name, ms in sorted(agg.items(), key=lambda kv: -kv[1])[:25]:
-        print(f"{ms / iters:9.3f} {100 * ms / total:5.1f}% "
-              f"{cnt[name]:7d}  {name[:90]}")
-    print(f"{total / iters:9.3f} 100.0%          TOTAL device")
+    if profiled:
+        device_report(tdir, iters)
 
 
 if __name__ == "__main__":

@@ -263,20 +263,38 @@ def _lowered_chunk_text(chunk=4):
 
 
 def test_off_mode_hlo_identity():
-    """telemetry=off must change NO compiled program — and because
-    every non-trace mode instruments only host seams, off, counters
-    and spans all lower byte-identical StableHLO for the fused
-    training chunk.  A future hook that reaches into a jitted body
-    (io_callback, an unconditional named_scope, a debug print) breaks
+    """Every telemetry mode lowers byte-identical StableHLO for the
+    fused training chunk: all instrumentation lives at host seams, and
+    the ``tel.<phase>`` named scopes, which are on at EVERY mode (off
+    included), write op locations only, which ``as_text()`` does not
+    print.  A hook that reaches into a jitted body with more than
+    metadata (io_callback, a debug print, a mode-dependent op) breaks
     this test instead of silently de-optimizing production."""
     TELEMETRY.configure("off")
     base = _lowered_chunk_text()
-    TELEMETRY.configure("counters")
-    assert _lowered_chunk_text() == base, (
-        "telemetry=counters changed the lowered fused chunk")
-    TELEMETRY.configure("spans")
-    assert _lowered_chunk_text() == base, (
-        "telemetry=spans changed the lowered fused chunk")
+    assert "tel." not in base
+    for mode in ("counters", "spans", "trace"):
+        TELEMETRY.configure(mode)
+        assert _lowered_chunk_text() == base, (
+            f"telemetry={mode} changed the lowered fused chunk")
+
+
+def _model_text(mode):
+    TELEMETRY.configure(mode)
+    TELEMETRY.reset()
+    rng = np.random.RandomState(11)
+    X = rng.randn(600, 6)
+    y = (X[:, 0] - 0.5 * X[:, 2] > 0).astype(float)
+    bst = lgb.train({"objective": "binary", "num_leaves": 7, "verbose": -1,
+                     "min_data_in_leaf": 5, "dispatch_chunk": 3},
+                    lgb.Dataset(X, label=y), 6)
+    return bst.model_to_string()
+
+
+@pytest.mark.parametrize("mode", ["counters", "spans", "trace"])
+def test_every_mode_trains_identical_trees(mode):
+    """The modes differ in what the host records, never in the model."""
+    assert _model_text(mode) == _model_text("off")
 
 
 def _lowered_collective_text():

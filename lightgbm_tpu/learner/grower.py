@@ -597,6 +597,18 @@ class TreeGrower:
             # packed byte straddles two logical groups, so packed
             # datasets take the constraint-sharded fallback instead
             and self.pack_P == 0)
+        # factored low rungs of the fused tiled ladder: in force only
+        # where a group fills a 256-lane tile (max_group_bin > 128)
+        self.hist_factored_rungs = ()
+        if self.use_tiled and self.use_fused:
+            from ..ops.histogram import factored_rungs
+            self.hist_factored_rungs = factored_rungs(self.max_group_bin,
+                                                      self.pack_P)
+        # their accumulators are a fraction of a strip's, so they take
+        # the row block the strips cannot (v5e, 2^24 x 67 x 255 bins:
+        # 4096 is 6-9% a pass under 2048, 8192 adds under 2%)
+        self.pallas_block_factored = (
+            4096 if self.n_padded % 4096 == 0 else self.pallas_block_tiled)
         self._train_tree = jax.jit(self._train_tree_impl)
         if TELEMETRY.on:
             # the grower's resolved kernel plan as gauges: the fused
@@ -619,6 +631,8 @@ class TreeGrower:
             else:
                 hk = "xla"
             TELEMETRY.gauge("grower.hist_kernel", hk)
+            TELEMETRY.gauge("grower.hist_factored_rungs", ",".join(
+                f"{k}:{a}x{b}" for k, a, b in self.hist_factored_rungs))
             TELEMETRY.gauge("grower.quantized", int(self.use_quant))
             TELEMETRY.gauge("grower.hist_precision",
                             "tiered" if self.use_quant else "f32")
@@ -940,13 +954,26 @@ class TreeGrower:
             wT = jnp.stack([grad, hess, counts], axis=0)
             scales, q = None, False
 
+        # low rungs (256-lane tiles only): few active slots leave the
+        # strip's rows idle, so the bin index is factored across both
+        # sides of the dot (ops/histogram.py FACTORED_RUNGS).  The
+        # dequantize multiply stays where the strips ladder alone has it
+        # — inside the branch when that ladder is a cond, in the open
+        # when it is a plain call (W <= one strip) — so that the
+        # compiler meets it in the same fusion with and without the
+        # rungs and the floats are the same floats (XLA:CPU contracts it
+        # with the parent-minus-right subtraction it is fused with)
+        rungs = [r for r in self.hist_factored_rungs if r[0] <= W]
+        late_scale = bool(rungs) and W <= PACKED_STRIP
+        in_scales = jnp.ones_like(scales) if late_scale else scales
+
         def run(strips):
             def go(_):
                 if self.use_tiled:
                     from ..ops.histogram import \
                         compute_group_histograms_fused_tiled
                     h, leaf2 = compute_group_histograms_fused_tiled(
-                        self.binsT, wT, scales, st.leaf_id,
+                        self.binsT, wT, in_scales, st.leaf_id,
                         st.route_tab, rights, max_group_bin=B,
                         block=self.pallas_block_tiled, strips=strips,
                         interpret=self._interp,
@@ -970,15 +997,44 @@ class TreeGrower:
                 return jnp.concatenate([h, pad]), leaf2
             return go
 
-        if W <= PACKED_STRIP:
-            return run(1)(None)
+        def strips_ladder(_):
+            if W <= PACKED_STRIP:
+                return run(1)(None)
+            k = jnp.sum(rights >= 0)
+            if W <= 2 * PACKED_STRIP:
+                return jax.lax.cond(k <= PACKED_STRIP, run(1), run(2),
+                                    None)
+            return jax.lax.cond(
+                k <= PACKED_STRIP, run(1),
+                lambda _: jax.lax.cond(k <= 2 * PACKED_STRIP, run(2),
+                                       run(3), None), None)
+
+        def run_factored(k_cap, a):
+            def go(_):
+                from ..ops.histogram import \
+                    compute_group_histograms_fused_factored
+                h, leaf2 = compute_group_histograms_fused_factored(
+                    self.binsT, wT, in_scales, st.leaf_id, st.route_tab,
+                    rights, max_group_bin=B, k_cap=k_cap, a=a,
+                    block=self.pallas_block_factored,
+                    interpret=self._interp)
+                pad = jnp.zeros((W - k_cap,) + h.shape[1:], h.dtype)
+                return jnp.concatenate([h, pad]), leaf2
+            return go
+
+        if not rungs:
+            return strips_ladder(None)
         k = jnp.sum(rights >= 0)
-        if W <= 2 * PACKED_STRIP:
-            return jax.lax.cond(k <= PACKED_STRIP, run(1), run(2), None)
-        return jax.lax.cond(
-            k <= PACKED_STRIP, run(1),
-            lambda _: jax.lax.cond(k <= 2 * PACKED_STRIP, run(2), run(3),
-                                   None), None)
+        caps = [r[0] for r in rungs]
+        h, leaf2 = jax.lax.cond(
+            k <= caps[-1],
+            lambda _: jax.lax.switch(
+                sum((k > cap).astype(jnp.int32) for cap in caps[:-1]),
+                [run_factored(k_cap, a) for k_cap, a, _ in rungs], None),
+            strips_ladder, None)
+        if late_scale:
+            h = h * scales[None, None, None, :]
+        return h, leaf2
 
     # ------------------------------------------------------------------
     def _hist_kernel_q_tiled(self, leaf_id, slots, quant):

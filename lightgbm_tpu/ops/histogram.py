@@ -1525,6 +1525,205 @@ def compute_group_histograms_fused_tiled(
     return hist, leaf_out[0]
 
 
+#: Factored rungs of the narrow-frontier ladder, ``(k_cap, a, b)`` by
+#: rising ``k_cap``: a pass with at most ``k_cap`` active slots splits
+#: each bin index as ``hi * b + lo`` (``a * b`` = the 256-lane tile) and
+#: moves ``hi`` to the slot side of the dot, so the streamed one-hot is
+#: ``b`` lanes a group instead of 256 and the other operand carries
+#: ``3 * k_cap * a`` rows a group instead of a 128-row strip.  A pass
+#: costs what that operand's rows cost the MXU, in steps of 32
+#: (``_factored_rows``), so the caps sit where a step ends.  Wider
+#: frontiers keep the strip ladder.  Settled on the chip: PERF.md, PR 27.
+FACTORED_RUNGS = ((2, 4, 64), (10, 2, 128), (16, 2, 128), (32, 2, 128))
+
+#: feature tiles a trip of the factored kernel's loop (Mosaic schedules
+#: one trip's operand builds under the dots before them)
+_FACTORED_UNROLL = 8
+
+
+def factored_rungs(max_group_bin: int, packed_groups: int = 0):
+    """The factored rung table in force for a bin matrix: the module's
+    where a group fills a 256-lane tile (``max_group_bin`` > 128, which
+    is ``max_bin=255``) of byte-wide bins, else empty — narrower tiles
+    have no lanes to give back."""
+    if packed_groups or tiled_hist_width(1, max_group_bin) != 256:
+        return ()
+    return FACTORED_RUNGS
+
+
+def _factored_rows(k_cap: int, a: int):
+    """32-bit word rows of the factored kernel's slot-side operand, four
+    int8 rows (channel, slot, hi) to a word: ``(a channel, a group)`` —
+    a channel starts a word, a group fills whole 8-sublane registers."""
+    per_channel = -(-k_cap * a // 4)
+    return per_channel, _round_up(3 * per_channel, 8)
+
+
+def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
+                                  slots_ref, hist_ref, leaf_out_ref,
+                                  key4_ref, ksh_ref, lo4_ref, bit_ref, *,
+                                  k_cap, a, b, num_groups, nb):
+    """Fused route + FACTORED int8 histogram for narrow frontiers.
+
+        hist[slot, ch, g, hi, lo] =
+            sum_r (w[ch, r] [leaf_r = slot] [hi_g,r = hi]) [lo_g,r = lo]
+
+    The only one-hot-wide operand is ``[lo = .]``, ``b`` lanes a group,
+    so ``pack = 128 // b`` groups share one 128-row tile; the other
+    operand carries ``3 * k_cap * a`` rows a group (channel, slot, hi).
+    One dot per ``pack`` groups, contraction over the block's rows as in
+    the tiled kernel; its output holds the histogram on the blocks whose
+    two groups agree (the wrapper takes that diagonal).  Same integers
+    as the tiled kernel's.
+
+    Both int8 operands are built four rows to a 32-bit word
+    (``pltpu.bitcast``: int8 row 4s+q is byte q of word row s): a row's
+    key names ONE word row and ONE byte of it, so an operand costs a
+    compare and a select a word and nothing is converted to int8.  The
+    per-group rows (word row, byte shift, for both operands) are made
+    for the whole block at once and read back a row at a time."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        hist_ref[:] = jnp.zeros_like(hist_ref)
+
+    leaf = leafT_ref[:]                                  # (1, C) int32
+    binb = binsT_ref[:].astype(jnp.int32)                # (G, C)
+    new_leaf = _route_prologue_T(binb, leaf, routeT_ref[:],
+                                 num_groups=num_groups, nb=nb)
+    leaf_out_ref[:] = new_leaf
+
+    pack = 128 // b
+    ch_w, rows_w = _factored_rows(k_cap, a)
+    # slot index * a of each row; rows of no active slot get a key that
+    # no (slot, hi) row carries
+    sj = jax.lax.broadcasted_iota(jnp.int32, slots_ref.shape, 0) * a
+    skey = jnp.max(jnp.where(slots_ref[:] == new_leaf, sj, -4096),
+                   axis=0, keepdims=True)                # (1, C)
+    key = (binb >> (b.bit_length() - 1)) + skey          # slot*a + hi
+    lo = binb & (b - 1)
+    key4_ref[:] = key >> 2                               # word row ...
+    ksh_ref[:] = (key & 3) << 3                          # ... and byte
+    lo4_ref[:] = lo >> 2
+    bit_ref[:] = jnp.left_shift(jnp.ones((), jnp.int32), (lo & 3) << 3)
+    w = wT_ref[:] & 0xFF                                 # (3, C) bytes
+    # slot-side word row (ch, j // 4): channel ch's weight byte, moved
+    # to byte j % 4 where the row's key is j; pad rows match no key
+    sig = jax.lax.broadcasted_iota(jnp.int32, (rows_w, 1), 0)
+    ch = jnp.where(sig < ch_w, 0, jnp.where(sig < 2 * ch_w, 1, 2))
+    wsel = jnp.where(ch == 0, w[0:1, :],
+                     jnp.where(ch == 1, w[1:2, :], w[2:3, :]))
+    srow = jnp.where(sig < 3 * ch_w, sig - ch * ch_w, -7)
+    liota = jax.lax.broadcasted_iota(jnp.int32, (b // 4, 1), 0)
+    zero = jnp.zeros((), jnp.int32)
+    c = binb.shape[1]
+
+    def group_dot(t, gs):
+        """Accumulate tile ``t``: groups [t*pack, t*pack + gs)."""
+        lhs, rhs = [], []
+        for p in range(gs):
+            g = t * pack + p
+            lhs.append(jnp.where(
+                srow == key4_ref[pl.ds(g, 1), :],
+                jnp.left_shift(wsel, ksh_ref[pl.ds(g, 1), :]), zero))
+            rhs.append(jnp.where(liota == lo4_ref[pl.ds(g, 1), :],
+                                 bit_ref[pl.ds(g, 1), :], zero))
+        if gs < pack:
+            lhs.append(jnp.zeros(((pack - gs) * rows_w, c), jnp.int32))
+            rhs.append(jnp.zeros(((pack - gs) * (b // 4), c), jnp.int32))
+        lhs = jnp.concatenate(lhs) if pack > 1 else lhs[0]
+        rhs = jnp.concatenate(rhs) if pack > 1 else rhs[0]
+        hist_ref[t] += jax.lax.dot_general(
+            pltpu.bitcast(lhs, jnp.int8), pltpu.bitcast(rhs, jnp.int8),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
+
+    full_tiles = num_groups // pack
+    unroll = _FACTORED_UNROLL
+
+    def trip(t, carry):
+        for j in range(unroll):
+            group_dot(t * unroll + j, pack)
+        return carry
+
+    jax.lax.fori_loop(0, full_tiles // unroll, trip, 0)
+    for t in range(full_tiles // unroll * unroll, full_tiles):
+        group_dot(t, pack)
+    if num_groups % pack:
+        group_dot(full_tiles, num_groups % pack)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("max_group_bin", "block", "k_cap", "a",
+                              "interpret"))
+def compute_group_histograms_fused_factored(
+        binsT: jax.Array, wT: jax.Array, scales: jax.Array,
+        leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
+        max_group_bin: int, k_cap: int, a: int, block: int = 2048,
+        interpret: bool = False):
+    """Fused route + factored int8 histogram, one rung of
+    ``FACTORED_RUNGS``: the contract of
+    :func:`compute_group_histograms_fused_tiled` for at most ``k_cap``
+    active slots, which lead ``slots``.  Returns ``(hist, new_leaf)``
+    with ``hist`` (k_cap, G, B, 3) following ``slots[:k_cap]``, equal to
+    the tiled kernel's to the bit.  Byte-wide bins in a 256-lane tile
+    only (see :func:`factored_rungs`)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    num_groups, n = binsT.shape
+    tile_w = tiled_hist_width(1, max_group_bin)
+    b = tile_w // a
+    pack = 128 // b
+    if n % block != 0:
+        raise ValueError(f"N ({n}) must be a multiple of block ({block})")
+    kp = _round_up(k_cap, 8)
+    slot_col = jnp.full(kp, -2, jnp.int32).at[:k_cap].set(
+        jnp.where(slots[:k_cap] >= 0, slots[:k_cap], -2))[:, None]
+    routeT = _transpose_pad_route(route_tab)
+    num_tiles = (num_groups + pack - 1) // pack
+    ch_w, rows_w = _factored_rows(k_cap, a)
+    rows = 4 * rows_w                          # int8 rows a group, padded
+    kern = functools.partial(_fused_kernel_body_q_factored, k_cap=k_cap,
+                             a=a, b=b, num_groups=num_groups,
+                             nb=route_tab.shape[1] - ROUTE_FIXED_COLS)
+    out, leaf_out = pl.pallas_call(
+        kern,
+        grid=(n // block,),
+        in_specs=[
+            pl.BlockSpec((num_groups, block), lambda i: (0, i)),
+            pl.BlockSpec((3, block), lambda i: (0, i)),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec(routeT.shape, lambda i: (0, 0)),
+            pl.BlockSpec(slot_col.shape, lambda i: (0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((num_tiles, pack * rows, 128),
+                         lambda i: (0, 0, 0)),
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((num_tiles, pack * rows, 128), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.VMEM((num_groups, block), jnp.int32)
+                        for _ in range(4)],
+        interpret=interpret,
+        name=f"compute_group_histograms_fused_factored_k{k_cap}_a{a}",
+    )(binsT, wT, leaf_id[None, :], routeT, slot_col)
+    # rows (group-in-tile, channel, slot, hi) x lanes (group-in-tile, lo):
+    # the histogram is where the two groups agree
+    o = out.reshape(num_tiles, pack, rows, 128)[:, :, :12 * ch_w]
+    o = o.reshape(num_tiles, pack, 3, 4 * ch_w, 128)[:, :, :, :k_cap * a]
+    o = o.reshape(num_tiles, pack, 3, k_cap, a, pack, b)
+    diag = jnp.stack([o[:, p, :, :, :, p, :] for p in range(pack)], axis=1)
+    full = diag.reshape(num_tiles * pack, 3, k_cap,
+                        tile_w)[:num_groups, :, :, :max_group_bin]
+    hist = jnp.transpose(full, (2, 0, 3, 1)).astype(jnp.float32)
+    return hist * scales[None, None, None, :], leaf_out[0]
+
+
 def _hist_kernel_body_seg_tiled(blk_slot_ref, binsT_ref, wT_ref, out_ref,
                                 *, max_group_bin, num_groups,
                                 packed_groups=0):

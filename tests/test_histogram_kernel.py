@@ -1,6 +1,8 @@
 """Pallas histogram kernel vs XLA formulation parity (the analog of the
 reference's GPU_DEBUG_COMPARE CPU-vs-GPU histogram comparator,
 gpu_tree_learner.cpp:1020-1044)."""
+import functools
+
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -433,3 +435,175 @@ def test_split_route_no_cache_identical_trees():
                          hist_split_route=True),
                     lgb.Dataset(X, label=y), 8, verbose_eval=False)
     assert nc0.model_to_string() == nc1.model_to_string()
+
+
+# ---------------------------------------------------------------------------
+# factored low rungs (ops/histogram.py FACTORED_RUNGS): same integers as
+# the one-strip tiled kernel, from a dot whose one-hot is b lanes a group
+# ---------------------------------------------------------------------------
+_FACT_N, _FACT_G, _FACT_L = 512, 19, 40   # 19 groups: no pack divides it,
+#                                            and the kernel's loop makes a trip
+_FACT_SLOTS = [21, 3, -1, 9, 0, 14, 6, 11, 2, 17, 5, 8, 19, 1, 12, 4]
+
+
+def _factored_rung_cases():
+    from lightgbm_tpu.ops.histogram import FACTORED_RUNGS
+    return [(k_cap, a, b, k, B)
+            for k_cap, a, b in FACTORED_RUNGS
+            for k in (1, 2, 3, 4, 8, 16) if k <= k_cap
+            for B in (255, 256)]
+
+
+@functools.lru_cache(maxsize=None)
+def _factored_inputs(B):
+    """One table a bin width: padded rows (leaf -1), a route table that
+    moves rows, quantized weights, and the one-strip tiled kernel's
+    answer for every slot of ``_FACT_SLOTS``."""
+    from lightgbm_tpu.ops.histogram import (
+        compute_group_histograms_fused_tiled, quantize_gradients)
+    from lightgbm_tpu.ops.partition import (MISSING_NAN, MISSING_NONE,
+                                            MISSING_ZERO,
+                                            build_route_table)
+    rng = np.random.RandomState(B)
+    N, G, L = _FACT_N, _FACT_G, _FACT_L
+    bins = rng.randint(0, B, (N, G)).astype(np.uint8)
+    leaf = rng.randint(-1, 20, N).astype(np.int32)
+    wq, scales = quantize_gradients(
+        jnp.asarray(rng.randn(N).astype(np.float32)),
+        jnp.asarray(np.abs(rng.randn(N)).astype(np.float32)),
+        jnp.asarray((rng.rand(N) > 0.2).astype(np.float32)))
+    pad = [0] * (L - 4)
+    sm = np.zeros(L, bool)
+    sm[:4] = True
+    tab = build_route_table(
+        jnp.asarray(sm),
+        jnp.asarray(np.array([0, 2, 5, 3] + pad, np.int32)),
+        jnp.zeros(L, jnp.int32), jnp.full(L, B, jnp.int32),
+        jnp.zeros(L, jnp.int32), jnp.full(L, B - 1, jnp.int32),
+        jnp.asarray(np.array([0, 0, 0, 1] + pad, bool)),
+        jnp.asarray(np.array([70, 30, 110, 50] + pad, np.int32)),
+        jnp.asarray(np.array([1, 0, 1, 0] + pad, bool)),
+        jnp.asarray(np.array([MISSING_NONE, MISSING_ZERO, MISSING_NAN, 0]
+                             + pad, np.int32)),
+        jnp.asarray(np.array([0, 2, 0, 0] + pad, np.int32)),
+        jnp.full(L, B, jnp.int32),
+        jnp.asarray(rng.rand(L, B) > 0.5),
+        jnp.asarray(np.array([20, 21, 22, 23] + pad, np.int32)))
+    args = (jnp.asarray(bins.T), wq.T, scales, jnp.asarray(leaf), tab)
+    want_h, want_leaf = compute_group_histograms_fused_tiled(
+        *args, jnp.asarray(np.array(_FACT_SLOTS, np.int32)),
+        max_group_bin=B, block=256, strips=1, interpret=True)
+    want_leaf = np.asarray(want_leaf)
+    assert (want_leaf != leaf).sum() > 20       # the route table routes
+    assert (want_leaf == -1).sum() > 5          # padded rows stay out
+    return args, np.asarray(want_h), want_leaf
+
+
+@pytest.mark.parametrize("k_cap,a,b,k,B", _factored_rung_cases())
+def test_factored_rung_equals_one_strip_tiled_interpret(k_cap, a, b, k, B):
+    """Every rung, at every active-slot count it serves: the dequantized
+    histogram and the routed leaf ids equal the one-strip tiled kernel's
+    exactly (the int32 sums are the same integers)."""
+    from lightgbm_tpu.ops.histogram import \
+        compute_group_histograms_fused_factored
+    assert a * b == 256
+    args, want_h, want_leaf = _factored_inputs(B)
+    slots = np.full(126, -1, np.int32)
+    slots[:k] = _FACT_SLOTS[:k]
+    got_h, got_leaf = compute_group_histograms_fused_factored(
+        *args, jnp.asarray(slots), max_group_bin=B, block=256,
+        k_cap=k_cap, a=a, interpret=True)
+    got_h = np.asarray(got_h)
+    assert got_h.shape == (k_cap, _FACT_G, B, 3)
+    np.testing.assert_array_equal(np.asarray(got_leaf), want_leaf)
+    np.testing.assert_array_equal(got_h[:k], want_h[:k])
+    assert not got_h[k:].any()                  # invalid slots: zero rows
+    assert np.abs(got_h[0]).sum() > 0
+
+
+def _fast_255(leaves, **extra):
+    return dict({"objective": "binary", "num_leaves": leaves,
+                 "max_bin": 255, "verbose": -1, "min_data_in_leaf": 2,
+                 "quantized_grad": True, "hist_compute_dtype": "bfloat16",
+                 "quant_stochastic_rounding": 1,
+                 "force_pallas_interpret": True}, **extra)
+
+
+@pytest.mark.parametrize("leaves,extra", [
+    (31, {}), (255, {}), (31, {"histogram_pool_size": 0.001})],
+    ids=["31", "255", "31_no_cache"])
+def test_factored_rungs_grow_identical_trees(leaves, extra, monkeypatch):
+    """At max_bin=255 the factored rungs serve the narrow passes of every
+    tree (frontier one strip wide at 31 leaves, three at 255; with no
+    histogram cache the parents pass too) and the model is the model of
+    the strips ladder alone, byte for byte."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops import histogram as H
+    from lightgbm_tpu.telemetry import TELEMETRY
+
+    rng = np.random.RandomState(3)
+    X = rng.lognormal(size=(3000, 7)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] - X[:, 2] + 0.3 * rng.randn(3000)
+         > 0.5).astype(float)
+
+    def model(rungs):
+        monkeypatch.setattr(H, "FACTORED_RUNGS", rungs)
+        TELEMETRY.reset()
+        bst = lgb.train(_fast_255(leaves, telemetry="counters", **extra),
+                        lgb.Dataset(X, label=y), 3, verbose_eval=False)
+        gauge = TELEMETRY.gauges()["grower.hist_factored_rungs"]
+        return bst.model_to_string(), gauge
+
+    try:
+        with_rungs, gauge = model(H.FACTORED_RUNGS)
+        assert gauge == ",".join(f"{k}:{a}x{b}"
+                                 for k, a, b in H.FACTORED_RUNGS) != ""
+        without, gauge = model(())
+        assert gauge == ""
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    assert with_rungs == without
+
+
+def test_factored_rungs_leave_narrow_tiles_alone():
+    """max_bin=63 (every tile 128 lanes): no rung in force, the gauge is
+    empty and the tree program has no factored kernel in it."""
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops.histogram import FACTORED_RUNGS, factored_rungs
+    from lightgbm_tpu.telemetry import TELEMETRY
+
+    assert factored_rungs(255) == factored_rungs(129) == FACTORED_RUNGS
+    assert factored_rungs(128) == factored_rungs(63) == ()
+    assert factored_rungs(255, packed_groups=3) == ()
+
+    rng = np.random.RandomState(3)
+    X = rng.randn(1024, 6)
+    y = (X[:, 0] + 0.4 * X[:, 1] > 0).astype(float)
+
+    def tree_program(max_bin):
+        TELEMETRY.configure("counters")
+        TELEMETRY.reset()
+        cfg = Config.from_params(dict(_fast_255(255), max_bin=max_bin,
+                                      telemetry="counters"))
+        gr = GBDT(cfg, lgb.Dataset(X, label=y).construct(cfg)).grower
+        gauge = TELEMETRY.gauges()["grower.hist_factored_rungs"]
+        n = gr.n_padded
+        f32 = jax.ShapeDtypeStruct((n,), np.float32)
+        text = jax.jit(gr._train_tree_impl).lower(
+            f32, f32, f32, np.ones(gr.num_features, bool), None, gr.bins,
+            gr.binsT, gr._row_valid, jax.random.PRNGKey(0)).as_text()
+        return gauge, text
+
+    try:
+        gauge, text = tree_program(63)
+        assert gauge == "" and "fused_tiled" in text
+        assert "factored" not in text
+        gauge, text = tree_program(255)
+        assert gauge != "" and "factored" in text
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()

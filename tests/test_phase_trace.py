@@ -159,7 +159,14 @@ def _kernel_cases():
          dict(block=256, interpret=True)),
         (H.route_apply_tiled, (binsT, leaf, tab, _s((L,), jnp.float32)),
          dict(block=256, interpret=True)),
-    ]
+    ] + [   # the factored rungs: 256-lane tiles only, one kernel a rung
+        (H.compute_group_histograms_fused_factored,
+         (binsT, wT, scales, leaf,
+          _s((L, ROUTE_FIXED_COLS + 32), jnp.float32),
+          _s((32,), jnp.int32)),
+         dict(max_group_bin=255, block=256, interpret=True, k_cap=k_cap,
+              a=a))
+        for k_cap, a, _ in H.FACTORED_RUNGS]
 
 
 def _pallas_names(jaxpr):
@@ -186,6 +193,10 @@ KERNEL_NAMES = [          # what a device trace showed before they were pinned
     "compute_group_histograms_seg_tiled",
     "route_only_tiled",
     "route_apply_tiled",
+    "compute_group_histograms_fused_factored_k2_a4",    # PR 27: pinned
+    "compute_group_histograms_fused_factored_k10_a2",   # from the start
+    "compute_group_histograms_fused_factored_k16_a2",
+    "compute_group_histograms_fused_factored_k32_a2",
 ]
 
 
@@ -202,6 +213,28 @@ def test_pallas_call_carries_its_pinned_name(i):
         text = jax.jit(lambda *a: fn(*a, **kw)).lower(*args).as_text(
             debug_info=True)
         assert f"{want}/" in text
+
+
+def test_histogram_kernel_names_match_the_benchmarks_pattern():
+    """perfbench/metrics/hist_ms_per_tree.json books device time to the
+    histogram pass by kernel name: a histogram kernel whose name falls
+    outside its pattern would be booked to nonhist_device_ms_per_tree
+    and hist_roofline would read a kernel that no longer runs."""
+    import json
+    import re
+    with open(os.path.join(os.path.dirname(HERE), "perfbench", "metrics",
+                           "hist_ms_per_tree.json")) as f:
+        patterns = [re.compile(p) for p in json.load(f)["params"]["patterns"]]
+    from lightgbm_tpu.ops.histogram import FACTORED_RUNGS
+    hist = [n for n in KERNEL_NAMES if not n.startswith("route_")]
+    assert len(hist) == len(KERNEL_NAMES) - 2
+    assert sum("factored" in n for n in hist) == len(FACTORED_RUNGS) > 0
+    for name in hist:
+        # an event is named by the instruction's whole text (xplane.py)
+        for event in (name, f"%{name}.5 = (s32[17,96,128]{{2,1,0}}, ..."):
+            assert any(r.search(event) for r in patterns), event
+    for name in set(KERNEL_NAMES) - set(hist):
+        assert not any(r.search(name) for r in patterns), name
 
 
 def test_predict_kernel_carries_its_name():

@@ -232,3 +232,56 @@ def test_onchip_route_apply_tiled(case):
                                   np.asarray(want_leaf))
     np.testing.assert_array_equal(np.asarray(got_val),
                                   np.asarray(want_val))
+
+
+def test_onchip_fused_factored_kernel():
+    """Every factored rung (ops/histogram.py FACTORED_RUNGS) against the
+    one-strip fused tiled kernel, 255 bins: the same leaf ids and the
+    same histogram to the bit.  The rungs build their int8 operands four
+    rows to a 32-bit word (pltpu.bitcast), whose byte order only the
+    chip can pin."""
+    from lightgbm_tpu.ops.histogram import (
+        FACTORED_RUNGS, compute_group_histograms_fused_factored,
+        compute_group_histograms_fused_tiled)
+    rng = np.random.RandomState(2)
+    N, G, B, L = 16384, 13, 255, 40
+    binsT = jnp.asarray(rng.randint(0, B, (G, N)).astype(np.uint8))
+    leaf = jnp.asarray(rng.randint(-1, 36, N).astype(np.int32))
+    wq, scales = quantize_gradients(
+        jnp.asarray(rng.randn(N).astype(np.float32)),
+        jnp.asarray(np.abs(rng.randn(N)).astype(np.float32)),
+        jnp.asarray((rng.rand(N) > 0.2).astype(np.float32)))
+    sm = np.zeros(L, bool)
+    sm[:6] = True
+    tab = build_route_table(
+        jnp.asarray(sm),
+        jnp.asarray(rng.randint(0, G, L).astype(np.int32)),
+        jnp.zeros(L, jnp.int32), jnp.full(L, B, jnp.int32),
+        jnp.zeros(L, jnp.int32), jnp.full(L, B - 1, jnp.int32),
+        jnp.asarray(np.array([0, 1] * 20, bool)),
+        jnp.asarray(rng.randint(0, B, L).astype(np.int32)),
+        jnp.asarray(rng.rand(L) > 0.5),
+        jnp.asarray(rng.randint(0, 3, L).astype(np.int32)),
+        jnp.asarray(rng.randint(0, 4, L).astype(np.int32)),
+        jnp.full(L, B, jnp.int32),
+        jnp.asarray(rng.rand(L, B) > 0.5),
+        jnp.asarray((np.arange(L) + 36).astype(np.int32) % L))
+    order = rng.permutation(36).astype(np.int32)
+    for k_cap, a, _ in FACTORED_RUNGS:
+        slots = np.full(126, -1, np.int32)
+        slots[:k_cap] = order[:k_cap]
+        if k_cap > 2:
+            slots[1] = -1
+        slots = jnp.asarray(slots)
+        want, want_leaf = compute_group_histograms_fused_tiled(
+            binsT, wq.T, scales, leaf, tab, slots, max_group_bin=B,
+            block=2048, strips=1)
+        got, got_leaf = compute_group_histograms_fused_factored(
+            binsT, wq.T, scales, leaf, tab, slots, max_group_bin=B,
+            block=4096, k_cap=k_cap, a=a)
+        np.testing.assert_array_equal(np.asarray(got_leaf),
+                                      np.asarray(want_leaf), err_msg=str(k_cap))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want)[:k_cap],
+                                      err_msg=str(k_cap))
+        assert float(jnp.abs(got).sum()) > 0

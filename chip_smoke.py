@@ -29,7 +29,9 @@ at a tiny shape on the CPU interpret seam):
      reference's own GPU-vs-CPU tolerance).
   C  predict: device vs host walk, bulk and bucketed small batches,
      then save -> reload -> predict bit-identical.
-  D  tree_learner=data over four chips, when the host has them.
+  D  tree_learner=data over four chips, when the host has them: the
+     fast path of A on every chip, the chips' histograms added as
+     integers; the model text must equal one chip's.
 
 Any leg that raises fails the run: no leg sits inside a handler.
 """
@@ -46,7 +48,6 @@ ROUNDS = 64          # >= 60 remaining rounds opens the auto-chunk branch
 MULTICHIP_ROUNDS = 5
 SMALL_BATCHES = (1, 3, 16, 40)   # below/at/above the 16-row min bucket
 AUC_GATE = 1e-3
-MULTICHIP_MAX_GATE, MULTICHIP_MEAN_GATE = 1e-2, 1e-4
 # the repo's device-vs-host predict gate (bench.py run_predict_scale,
 # tests/test_predict_parity.py): f32 device accumulation vs the f64 walk
 PREDICT_RTOL, PREDICT_ATOL = 2e-5, 2e-7
@@ -209,21 +210,22 @@ def leg_predict(lgb, bst, Xv, small_batches=SMALL_BATCHES, interpret=False):
             "max_abs_dev_vs_host": float(np.max(np.abs(dev - host)))}
 
 
-def leg_multichip(lgb, X, y, Xv, rounds, n_chips=4):
-    """Leg D: the row-sharded XLA formulation over ``n_chips`` real
-    devices (the Pallas kernel under shard_map is ROADMAP Speed 3),
-    with the checks __graft_entry__.dryrun_multichip makes on virtual
-    CPU devices, and agreement with one chip on the same config.
+def leg_multichip(lgb, X, y, Xv, rounds, n_chips=4, extra=None,
+                  interpret=False):
+    """Leg D: ``tree_learner=data`` over ``n_chips`` devices on the
+    fast path — every row shard runs the quantized fused ladder inside
+    ``shard_map`` and the shards' int32 accumulators are added exactly
+    (``hist_kernel=pallas`` makes a program that cannot do so raise
+    instead of running the XLA formulation under this leg's name).
 
-    The agreement gate is the repo's own (tests/test_parallel.py):
-    "same algorithm, different reduction order".  The first tree is
-    identical; from the second on the f32 score cache differs in its
-    last bits between the sharded and the serial program, a near-tie
-    split can flip, and the few rows under it move by a leaf value's
-    difference — so the max is bounded at 1e-2 and the mean at 1e-4,
-    and the measured max and mean are reported (four v5e chips, PR 21:
-    max 7.6e-6, mean 9e-9)."""
-    params = {**BASE_PARAMS, "tree_learner": "data",
+    The gate is identity: the integer sum does not depend on how many
+    chips hold the rows, so the model text equals one chip's on the
+    same rows and parameters, character for character.  Stochastic
+    rounding is on, so the per-row draws are under the gate too."""
+    from lightgbm_tpu.telemetry import TELEMETRY
+    fast = {**BASE_PARAMS, **FAST_PARAMS, "quant_stochastic_rounding": 1,
+            **(extra or {})}
+    params = {**fast, "tree_learner": "data", "hist_kernel": "pallas",
               "mesh_shape": (n_chips,), "mesh_axes": ("data",)}
     # SPMD partitioner warnings are C++ logging on fd 2
     with tempfile.TemporaryFile(mode="w+") as cap:
@@ -243,6 +245,20 @@ def leg_multichip(lgb, X, y, Xv, rounds, n_chips=4):
     g = bst.gbdt.grower
     check(g.policy.mesh is not None and g.policy.mesh.size == n_chips,
           f"mesh is {g.policy.mesh}")
+    plan = grower_plan(g)
+    gauges = TELEMETRY.gauges()
+    check(g.use_quant and g.use_tiled and g.use_fused
+          and g.row_shards == n_chips and g._interp is interpret,
+          f"leg D ran a downgraded kernel plan under the mesh: {plan}")
+    check(gauges.get("grower.quantized") == 1
+          and gauges.get("grower.hist_kernel") == "fused_tiled",
+          f"leg D's gauges: {gauges.get('grower.quantized')}, "
+          f"{gauges.get('grower.hist_kernel')}")
+    kernels = chunk_kernel_names(bst.gbdt)
+    check(any(k.startswith("compute_group_histograms_fused_")
+              for k in kernels),
+          f"no fused histogram kernel in the mesh's chunk program: "
+          f"{kernels}")
     shards = g.bins.addressable_shards
     devices = {s.device for s in shards}
     check(len(devices) == n_chips, f"bins sit on {devices}")
@@ -250,18 +266,42 @@ def leg_multichip(lgb, X, y, Xv, rounds, n_chips=4):
           "row shards are identical: replicated, not sharded")
     check(sum(s.data.shape[0] for s in shards) == g.n_padded,
           "row shards do not add up to the padded row count")
-    serial = lgb.train({**BASE_PARAMS, "tree_learner": "serial"},
+    serial = lgb.train({**fast, "tree_learner": "serial"},
                        lgb.Dataset(X, label=y), rounds, verbose_eval=False)
+    check(bst.model_to_string() == serial.model_to_string(),
+          f"{n_chips}-chip model text differs from one chip's on the "
+          "same rows")
     delta = np.abs(bst.predict(Xv, device=False)
                    - serial.predict(Xv, device=False))
-    check(delta.max() < MULTICHIP_MAX_GATE
-          and delta.mean() < MULTICHIP_MEAN_GATE,
-          f"{n_chips}-chip vs 1-chip predictions: max {delta.max()}, "
-          f"mean {delta.mean()}")
     return {"mesh": n_chips, "devices": sorted(str(d) for d in devices),
+            "plan": plan, "kernels": kernels,
             "rows_padded": int(g.n_padded), "trees": bst.num_trees(),
-            "max_abs_vs_one_chip": float(delta.max()),
-            "mean_abs_vs_one_chip": float(delta.mean())}
+            "model_text_equal_one_chip": True,
+            "max_abs_vs_one_chip": float(delta.max())}
+
+
+def chunk_kernel_names(gbdt):
+    """Names of the ``pallas_call`` sites in the booster's chunk program
+    (its jaxpr, sub-jaxprs included), at the chunk length it last
+    dispatched, or 1 where it dispatched single iterations."""
+    import jax
+    import jax.numpy as jnp
+    from jax._src import core as jax_core
+    names = set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.add(str(eqn.params["name"]))
+            for sub in jax_core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    n = gbdt._fused_chunk_n[0] if gbdt._fused_chunk_n else 1
+    fmasks = jnp.ones((n, gbdt.num_class, gbdt.grower.num_features), bool)
+    walk(jax.make_jaxpr(gbdt._build_fused_chunk(n))(
+        gbdt.scores, tuple(), gbdt._full_counts > 0,
+        jnp.zeros((n, 2), jnp.uint32), fmasks, jnp.zeros(n, bool),
+        gbdt.grower.ohb, gbdt._build_captives()).jaxpr)
+    return sorted(names)
 
 
 def verdict(ok, device):

@@ -172,6 +172,8 @@ class Dataset:
         # validation frames must encode pandas categoricals against the
         # TRAIN-time category lists (the reference aligns valid frames
         # to the train categories and errors on mismatch)
+        if _is_row_shards(data):
+            return self._construct_row_shards(data, label, config, ref_core)
         train_cats = getattr(ref_core, "pandas_categorical", None)
         pandas_cats = (train_cats if train_cats is not None
                        else _pandas_categories(data))
@@ -250,6 +252,39 @@ class Dataset:
             # drop the lazy handle's copy too (reference sets
             # Dataset.data = None after construction) — the binned
             # matrix is the training representation from here on
+            self.data = None
+        return self._core
+
+    # ------------------------------------------------------------------
+    def _construct_row_shards(self, shards, label, config, ref_core):
+        """``data`` is a list of 2-D float arrays, the table's row
+        shards in order: each is binned block-wise into its own uint8
+        matrix (sharded.ShardedDataset.from_row_shards), so a table the
+        host cannot hold twice (nor once as float64) still constructs.
+        Trees are those of the concatenated matrix."""
+        import time as _time
+
+        from .sharded import ShardedDataset
+        from .telemetry import TELEMETRY
+        if ref_core is not None or self.group is not None:
+            Log.fatal("a Dataset given as row shards takes no reference "
+                      "and no query groups yet — pass one matrix")
+        feature_names, cat_indices = self._resolve_columns(shards[0])
+        rows = sum(a.shape[0] for a in shards)
+        t0 = _time.perf_counter()
+        with TELEMETRY.stage("binning", rows=rows):
+            self._core = ShardedDataset.from_row_shards(
+                shards, label=label, weight=self.weight,
+                init_score=self.init_score, config=config,
+                categorical_features=cat_indices,
+                feature_names=feature_names)
+        wall = _time.perf_counter() - t0
+        if wall > 0:
+            TELEMETRY.gauge("construct_rows_per_s", round(rows / wall))
+        self._core._raw_data = None if self.free_raw_data \
+            else np.concatenate(shards)
+        self._core.pandas_categorical = None
+        if self.free_raw_data:
             self.data = None
         return self._core
 
@@ -341,6 +376,8 @@ class Dataset:
                       "file-backed Dataset")
         if _is_sparse(d):
             return d.shape[0]
+        if _is_row_shards(d):
+            return sum(a.shape[0] for a in d)
         return _to_matrix(d).shape[0]
 
     def num_feature(self) -> int:
@@ -348,6 +385,8 @@ class Dataset:
             return self._core.num_total_features
         if _is_sparse(self.data):
             return self.data.shape[1]
+        if _is_row_shards(self.data):
+            return self.data[0].shape[1]
         return _to_matrix(self.data).shape[1]
 
     def set_reference(self, reference: "Dataset") -> "Dataset":
@@ -500,6 +539,14 @@ def _to_matrix(data, pandas_categorical=None) -> np.ndarray:
 
 def _is_sparse(obj) -> bool:
     return hasattr(obj, "tocsc") and hasattr(obj, "nnz")
+
+
+def _is_row_shards(obj) -> bool:
+    """A table handed over as its row shards: a list of 2-D arrays.  (A
+    list of rows, which numpy reads as one matrix, is not.)"""
+    return (isinstance(obj, (list, tuple)) and len(obj) > 0
+            and all(isinstance(a, np.ndarray) and a.ndim == 2
+                    for a in obj))
 
 
 def _pandas_categories(data):

@@ -116,6 +116,17 @@ class GBDT:
             self.objective.repad_device_arrays(
                 lambda a: self.grower.policy.place_rows(
                     self.grower.pad_rows(a)))
+        elif self.objective is not None \
+                and self.grower._mesh_kernels:
+            # one host's mesh on the kernel path: the objective's
+            # per-row arrays live on the mesh, beside the rows they
+            # belong to (an array left on one device is sent to every
+            # other at each dispatch; at 2^26 rows that is 256 MB a
+            # chunk)
+            pol = self.grower.policy
+            self.objective.repad_device_arrays(
+                pol.place_rows if self.num_data % pol.num_shards == 0
+                else pol.replicate)
         self.models: List[Tree] = []
         self.device_trees: List[TreeArrays] = []   # kept for DART drops
         self.iter_ = 0

@@ -78,12 +78,36 @@ from ..tree import TreeRecordLayout
 NEG_INF = -jnp.inf
 
 
+def _rows_i32(count):
+    """A row count as the tree stores it: int32 (a float32 count is a
+    whole number already, exact to 2^24)."""
+    return jnp.round(count).astype(jnp.int32)
+
+
+def _pad_slots(hist, width):
+    """``hist`` (slots, G, B, 3), or its pair with the int32 counts,
+    cut or zero-padded to ``width`` slots."""
+    def fit(h):
+        if h.shape[0] >= width:
+            return h[:width]
+        pad = jnp.zeros((width - h.shape[0],) + h.shape[1:], h.dtype)
+        return jnp.concatenate([h, pad])
+    return jax.tree_util.tree_map(fit, hist)
+
+
+def _scaled(hist, scales):
+    """The dequantize multiply on ``hist``'s float32 part."""
+    if isinstance(hist, tuple):
+        return (hist[0] * scales[None, None, None, :],) + hist[1:]
+    return hist * scales[None, None, None, :]
+
+
 class TreeArrays(NamedTuple):
     """Device-side grown tree (fixed shapes; L leaf slots, M=L-1 nodes)."""
     num_leaves: jax.Array        # scalar int32 — actual leaves used
     leaf_value: jax.Array        # (L,) f32
     leaf_weight: jax.Array       # (L,) f32 (sum_hessian)
-    leaf_count: jax.Array        # (L,) f32
+    leaf_count: jax.Array        # (L,) int32 rows
     leaf_parent: jax.Array       # (L,) int32 — parent internal node (-1 root)
     leaf_depth: jax.Array        # (L,) int32
     node_feature: jax.Array      # (M,) int32 inner feature idx
@@ -94,7 +118,7 @@ class TreeArrays(NamedTuple):
     node_gain: jax.Array         # (M,) f32
     node_value: jax.Array        # (M,) f32 internal output
     node_weight: jax.Array       # (M,) f32
-    node_count: jax.Array        # (M,) f32
+    node_count: jax.Array        # (M,) int32 rows
     node_left: jax.Array         # (M,) int32 (neg = ~leaf)
     node_right: jax.Array        # (M,) int32
 
@@ -106,13 +130,15 @@ class GrowerState(NamedTuple):
     done: jax.Array
     leaf_sum_grad: jax.Array
     leaf_sum_hess: jax.Array
-    leaf_count: jax.Array
+    leaf_count: jax.Array        # (L,) f32, or int32 (_int_counts)
     leaf_min_c: jax.Array
     leaf_max_c: jax.Array
     leaf_is_left: jax.Array      # (L,) bool — side under its parent
     leaf_forced: jax.Array       # (L,) int32 forced-split spec idx (-1 none)
     tree: TreeArrays
     hist_cache: jax.Array        # (L, G, Bg, 3) f32 — per-leaf group hists
+    # (under _int_counts hist_cache, cand and forced_cand are each a pair
+    # of the array named here and its int32 row counts: (L, G, Bg), (L,))
     cand: jax.Array              # (L, CAND_COLS + Bf) f32 — the packed
     # best_split_per_leaf_ cache (reference serial_tree_learner.h +
     # SplitInfo, split_info.hpp:18-288); column layout in ops/split.py,
@@ -146,7 +172,11 @@ class TreeGrower:
     the ShardingPolicy: the bin matrix is placed sharded over the mesh
     and the histogram output constrained, after which XLA inserts the
     reduce-scatter/all-gather the reference's Network layer hand-codes
-    (see parallel/mesh.py)."""
+    (see parallel/mesh.py).  On a one-axis row mesh the data learner
+    runs the single-device kernel plan instead: the quantized fused
+    ladder once per row shard inside ``shard_map`` and an exact integer
+    sum of the shards' accumulators (``_on_row_shards``), so its trees
+    are the single device's."""
 
     def __init__(self, dataset: Dataset, config: Config, policy=None):
         # set-up stages (docs/OBSERVABILITY.md): "grower_init" is the
@@ -320,14 +350,29 @@ class TreeGrower:
                 # the stage ends when the matrix is on the device;
                 # nothing but Python overlaps the transfer today
                 jax.block_until_ready((self.bins, self._row_valid))
-        # the Pallas kernel path: single TPU device only (its sequential
-        # -grid accumulation is a Mosaic property); the XLA formulation
-        # stays for CPU simulation, GSPMD meshes (where the sharded
-        # contraction must lower to a reduce-scatter), and float32
-        # operand parity (the kernel runs bf16 operands, the analog of
-        # the reference GPU learner's single-precision default,
-        # gpu_tree_learner.cpp:73-77)
+        # the Pallas kernel path: one TPU device, or the row shards of
+        # a one-axis data mesh, each of which runs the single-device
+        # kernels on its own rows inside shard_map (_on_row_shards) and
+        # adds its int32 accumulators to the others' exactly.  The XLA
+        # formulation stays for CPU simulation, feature / voting /
+        # multi-axis / multi-host meshes (where the sharded contraction
+        # lowers to a reduce-scatter), and float32 operand parity (the
+        # kernel runs bf16 operands, the analog of the reference GPU
+        # learner's single-precision default, gpu_tree_learner.cpp:73-77)
         from ..utils.log import Log
+        mesh = self.policy.mesh
+        self._row_axis = None
+        if (mesh is not None and len(mesh.axis_names) == 1
+                and self.policy.row_spec is not None
+                and getattr(self.policy, "bins_spec", None) is None
+                and config.tree_learner in ("data", "serial")
+                and self._mh_local is None
+                and not self.policy.multihost):
+            self._row_axis = mesh.axis_names[0]
+        self.row_shards = mesh.size if self._row_axis is not None else 1
+        # every size rule of the kernels is a shard's: its rows bound
+        # the int32 accumulator and its rows are what a block divides
+        self.local_rows = self.n_padded // self.row_shards
         hk = getattr(config, "hist_kernel", "auto")
         if hk not in ("auto", "pallas", "paired", "xla"):
             Log.warning(f"unknown hist_kernel={hk!r}; using 'auto'")
@@ -338,17 +383,18 @@ class TreeGrower:
         self._interp = bool(getattr(config, "force_pallas_interpret",
                                     False))
         pallas_ok = (
-            self.policy.mesh is None
+            (mesh is None or self._row_axis is not None)
             and (on_tpu() or self._interp)
-            and self.n_padded % 1024 == 0)
+            and self.n_padded % (1024 * self.row_shards) == 0)
+        unhonourable = (
+            f"hist_kernel={hk} cannot run here: it needs a single TPU "
+            "device or a one-axis data mesh of them, and rows padded "
+            "to 1024 a shard — use hist_kernel=auto, or "
+            "force_pallas_interpret for the CPU test seam")
         if hk in ("pallas", "paired") and not pallas_ok:
             # an explicit kernel request that cannot be honoured is an
             # error, not a quiet XLA run under the Pallas kernel's name
-            raise ValueError(
-                f"hist_kernel={hk} cannot run here: it needs a single "
-                "TPU device (no mesh) and 1024-row padding — use "
-                "hist_kernel=auto, or force_pallas_interpret for the "
-                "CPU test seam")
+            raise ValueError(unhonourable)
         self.use_pallas = pallas_ok and (
             hk in ("pallas", "paired")
             or (hk == "auto" and config.hist_compute_dtype == "bfloat16"))
@@ -356,7 +402,7 @@ class TreeGrower:
         # slower than the expansion kernel on v5e; kept as an option
         self.pallas_paired = self.use_pallas and hk == "paired"
         blk = int(getattr(config, "pallas_hist_block", 2048))
-        self.pallas_block = blk if self.n_padded % blk == 0 else 1024
+        self.pallas_block = blk if self.local_rows % blk == 0 else 1024
         # tiled-iota kernels stream ~G bytes/row instead of the G*B-byte
         # one-hot, so their per-block fixed cost (route decode, iota
         # rebuild) wants much larger blocks than the streamed kernels'
@@ -375,7 +421,7 @@ class TreeGrower:
                 tblk *= 2
         self.pallas_block_tiled = 1024
         for cand in (tblk, 8192, 4096, 2048, 1024):
-            if cand <= self.n_padded and self.n_padded % cand == 0:
+            if cand <= self.local_rows and self.local_rows % cand == 0:
                 self.pallas_block_tiled = cand
                 break
         # precision tier (hist_precision): "tiered" forces the int32
@@ -395,7 +441,7 @@ class TreeGrower:
         self.hist_exchange = str(getattr(config, "hist_exchange",
                                          "f32")).lower()
         if self.hist_precision == "tiered":
-            check_quant_rows(self.n_padded, what="hist_precision=tiered")
+            check_quant_rows(self.local_rows, what="hist_precision=tiered")
         want_quant = (getattr(config, "quantized_grad", False)
                       or self.hist_precision == "tiered")
         if self.hist_precision == "f32":
@@ -405,19 +451,21 @@ class TreeGrower:
             want_quant = False
         # int8 quantized training (see _hist_kernel_body_q): histogram
         # matmuls on the int8 MXU with one grad/hess scale per tree.
-        # The int32 accumulator bounds rows at N*127 < 2^31.
+        # The int32 accumulator bounds a device's rows at N*127 < 2^31.
         self.use_quant = self.use_pallas and not self.pallas_paired \
-            and want_quant and quant_rows_ok(self.n_padded)
+            and want_quant and quant_rows_ok(self.local_rows)
         if want_quant and self.use_pallas \
                 and not self.use_quant and not self.pallas_paired:
             Log.warning("quantized_grad disabled: dataset exceeds the "
-                        "int32 histogram accumulator bound (~16.9M rows)")
+                        "int32 histogram accumulator bound (~16.9M rows "
+                        "a device)")
         if self.hist_precision == "tiered" and not self.use_quant:
             raise ValueError(
                 "hist_precision=tiered cannot run here: the quantized "
                 "accumulation tier needs the Pallas histogram path "
                 "(hist_compute_dtype=bfloat16 or hist_kernel=pallas on "
-                "a single TPU device); use hist_precision=auto or f32")
+                "a single TPU device or a one-axis row mesh); use "
+                "hist_precision=auto or f32")
         # quantized frontier kernels rebuild the bin one-hot in VMEM
         # from the packed bins (~G bytes/row of HBM traffic instead of
         # the G*B-byte streamed one-hot) — the cheapest formulation
@@ -478,14 +526,15 @@ class TreeGrower:
         # (route-free) tiled kernel — same deferred-route semantics,
         # different kernel decomposition (A/B knob; see ROOFLINE)
         self.split_route = (self.use_tiled and self.use_fused
+                            and mesh is None
                             and getattr(config, "hist_split_route",
                                         False))
         if getattr(config, "hist_split_route", False) \
                 and not self.split_route:
             raise ValueError(
                 "hist_split_route cannot run here: it needs the tiled "
-                "fused path (quantized_grad on a single TPU device, "
-                "frontier within the packed ladder)")
+                "fused path (quantized_grad on a single TPU device, no "
+                "mesh, frontier within the packed ladder)")
         # leaf-partitioned formulation (reference DataPartition insight,
         # data_partition.hpp:109-161, under static shapes): rows are
         # physically regrouped into block-aligned per-leaf segments each
@@ -498,7 +547,8 @@ class TreeGrower:
         # on-chip A/B and for a future Mosaic dynamic-lane-gather
         lp = str(getattr(config, "hist_leaf_partition", "auto")).lower()
         want_lp = lp in ("on", "true", "1")
-        self.leaf_part = want_lp and self.use_tiled and self.use_fused
+        self.leaf_part = (want_lp and self.use_tiled and self.use_fused
+                          and mesh is None)
         if want_lp and not self.leaf_part:
             raise ValueError(
                 "hist_leaf_partition=on cannot run here: it needs the "
@@ -552,6 +602,41 @@ class TreeGrower:
             self.pallas_paired = False
             self.use_quant = False
             self.use_quant_otf = False
+        if mesh is not None and self.use_pallas:
+            # a row mesh runs ONE kernel plan, the quantized fused tiled
+            # ladder: its accumulators are integers, so the shards' sum
+            # is exact and the trees are the single device's.  Every
+            # other Pallas formulation keeps to one device
+            explicit = hk in ("pallas", "paired") \
+                or self.hist_precision == "tiered"
+            ladder = self.use_quant and self.use_tiled and self.use_fused
+            if not ladder and explicit:
+                raise ValueError(
+                    unhonourable + "; under a mesh only the "
+                    "quantized fused ladder runs (quantized_grad, "
+                    "byte-wide bins, frontier_width <= "
+                    f"{3 * PACKED_STRIP})")
+            if ladder and self.hist_exchange != "f32" and explicit:
+                raise ValueError(
+                    f"hist_exchange={self.hist_exchange} cannot run "
+                    "here: the kernel path under a mesh sums int32 "
+                    "accumulators exactly and has no codec — drop it, "
+                    "or use hist_kernel=xla")
+            if not ladder or self.hist_exchange != "f32":
+                # hist_kernel=auto: what the ladder cannot honour runs
+                # on the XLA path, where the codec lives, as before
+                self.use_pallas = self.pallas_paired = False
+                self.use_quant = self.use_quant_otf = False
+                self.use_tiled = self.use_fused = False
+                self.use_pre_ohb = False
+        #: the fused ladder runs once per row shard, inside shard_map
+        self._mesh_kernels = mesh is not None and self.use_fused
+        #: row counts are int32 from the exact cross-shard sum to the
+        #: tree: float32 counts integers to 2^24, one device's rows, and
+        #: the shards of a mesh hold more between them (every histogram
+        #: then travels with its int32 count channel beside it, as a
+        #: pair; ``jax.tree_util.tree_map`` treats both alike)
+        self._int_counts = self._mesh_kernels
         self.ohb = None
         # transposed on DEVICE from the already-uploaded bins: a host
         # transpose + second upload of the (N, G) matrix doubles the
@@ -608,7 +693,7 @@ class TreeGrower:
         # the row block the strips cannot (v5e, 2^24 x 67 x 255 bins:
         # 4096 is 6-9% a pass under 2048, 8192 adds under 2%)
         self.pallas_block_factored = (
-            4096 if self.n_padded % 4096 == 0 else self.pallas_block_tiled)
+            4096 if self.local_rows % 4096 == 0 else self.pallas_block_tiled)
         self._train_tree = jax.jit(self._train_tree_impl)
         if TELEMETRY.on:
             # the grower's resolved kernel plan as gauges: the fused
@@ -651,6 +736,18 @@ class TreeGrower:
                             int(self.split_ladder))
             TELEMETRY.gauge("grower.frontier_width", int(self.frontier))
             TELEMETRY.gauge("grower.rows_padded", int(self.n_padded))
+            TELEMETRY.gauge("grower.row_shards", int(self.row_shards))
+            TELEMETRY.gauge("grower.local_rows", int(self.local_rows))
+            # what one shard puts into the cross-shard sum of the
+            # widest pass: (W, G, B, 3) int32, twice as two limbs
+            limbs = 1
+            if self._mesh_kernels:
+                from ..parallel.collectives import int_exchange_fits_int32
+                limbs = 1 if int_exchange_fits_int32(self.n_padded) else 2
+            TELEMETRY.gauge(
+                "grower.hist_exchange_bytes_widest",
+                int(self.frontier * self.num_groups * self.max_group_bin
+                    * 3 * 4 * limbs) if self._mesh_kernels else 0)
 
     # ------------------------------------------------------------------
     def _load_forced_splits(self, dataset: Dataset, config: Config) -> None:
@@ -790,8 +887,10 @@ class TreeGrower:
     # ------------------------------------------------------------------
     def _hist_kernel(self, grad, hess, counts, leaf_id, slots=None,
                      num_leaves=None, quant=None):
-        """Frontier histogram dispatch: Pallas on a real single chip,
-        XLA one-hot contraction under meshes / CPU simulation.  The
+        """Frontier histogram dispatch of the non-fused plans: Pallas on
+        one chip, XLA one-hot contraction under meshes / CPU simulation
+        (the fused ladder, which a row mesh runs too, is
+        ``_hist_kernel_fused``).  The
         ``tel.histogram`` scope (op metadata, at every telemetry mode)
         lets a profiler trace attribute the device events to it."""
         with TELEMETRY.phase("histogram"):
@@ -972,12 +1071,15 @@ class TreeGrower:
                 if self.use_tiled:
                     from ..ops.histogram import \
                         compute_group_histograms_fused_tiled
-                    h, leaf2 = compute_group_histograms_fused_tiled(
+                    h, leaf2 = self._on_row_shards(
+                        functools.partial(
+                            compute_group_histograms_fused_tiled,
+                            max_group_bin=B,
+                            block=self.pallas_block_tiled, strips=strips,
+                            interpret=self._interp,
+                            packed_groups=self.pack_P),
                         self.binsT, wT, in_scales, st.leaf_id,
-                        st.route_tab, rights, max_group_bin=B,
-                        block=self.pallas_block_tiled, strips=strips,
-                        interpret=self._interp,
-                        packed_groups=self.pack_P)
+                        st.route_tab, rights)
                 else:
                     # streamed-one-hot kernel: block=2048 measured
                     # fastest on v5e (4096 fits scoped VMEM for 1-strip
@@ -990,11 +1092,7 @@ class TreeGrower:
                         interpret=self._interp, pack=self.ohb_pack,
                         num_groups=self.num_groups,
                         packed_groups=self.pack_P)
-                cap = strips * PACKED_STRIP
-                if cap >= W:
-                    return h[:W], leaf2
-                pad = jnp.zeros((W - cap,) + h.shape[1:], h.dtype)
-                return jnp.concatenate([h, pad]), leaf2
+                return _pad_slots(h, W), leaf2
             return go
 
         def strips_ladder(_):
@@ -1013,13 +1111,15 @@ class TreeGrower:
             def go(_):
                 from ..ops.histogram import \
                     compute_group_histograms_fused_factored
-                h, leaf2 = compute_group_histograms_fused_factored(
+                h, leaf2 = self._on_row_shards(
+                    functools.partial(
+                        compute_group_histograms_fused_factored,
+                        max_group_bin=B, k_cap=k_cap, a=a,
+                        block=self.pallas_block_factored,
+                        interpret=self._interp),
                     self.binsT, wT, in_scales, st.leaf_id, st.route_tab,
-                    rights, max_group_bin=B, k_cap=k_cap, a=a,
-                    block=self.pallas_block_factored,
-                    interpret=self._interp)
-                pad = jnp.zeros((W - k_cap,) + h.shape[1:], h.dtype)
-                return jnp.concatenate([h, pad]), leaf2
+                    rights)
+                return _pad_slots(h, W), leaf2
             return go
 
         if not rungs:
@@ -1033,8 +1133,46 @@ class TreeGrower:
                 [run_factored(k_cap, a) for k_cap, a, _ in rungs], None),
             strips_ladder, None)
         if late_scale:
-            h = h * scales[None, None, None, :]
+            h = _scaled(h, scales)
         return h, leaf2
+
+    # ------------------------------------------------------------------
+    def _on_row_shards(self, kernel, binsT, wT, scales, leaf_id,
+                       route_tab, slots):
+        """One fused pass of the ladder.  On one device ``kernel`` (a
+        ``compute_group_histograms_fused_*`` with its static arguments
+        bound) is called as it always was.  Under a row mesh every
+        shard runs the same ``pallas_call`` on its own columns of
+        ``binsT`` / ``wT`` and its own leaf ids, and hands the int32
+        accumulators — not yet dequantized — to the exact cross-shard
+        sum; the dequantize multiply then meets a replicated histogram,
+        as does everything downstream of it, and the histogram comes
+        back as a pair with the sum's int32 row counts (_int_counts).
+        ``route_tab`` and ``slots`` are replicated, so every shard takes
+        the same rung of the ``lax.cond`` ladder this is called from."""
+        if not self._mesh_kernels:
+            return kernel(binsT, wT, scales, leaf_id, route_tab, slots)
+        from jax.sharding import PartitionSpec as P
+        from ..parallel import collectives
+        axis = self._row_axis
+        cols, rows, rep = P(None, axis), P(axis), P()
+
+        def shard(bT, w, lid, rt, sl):
+            acc, leaf2 = kernel(bT, w, None, lid, rt, sl,
+                                dequantize=False)
+            with TELEMETRY.phase("hist_exchange"):
+                # looked up on the module at trace time, so that a
+                # fault planted there (perfbench/tests) is in the sum
+                total, rows_i32 = collectives.exchange_int_histograms(
+                    acc, axis, global_rows=self.n_padded)
+            return total, rows_i32, leaf2
+
+        total, rows_i32, leaf2 = _get_shard_map()(
+            shard, mesh=self.policy.mesh,
+            in_specs=(cols, cols, rows, rep, rep),
+            out_specs=(rep, rep, rows))(binsT, wT, leaf_id, route_tab,
+                                        slots)
+        return (_scaled(total, scales), rows_i32), leaf2
 
     # ------------------------------------------------------------------
     def _hist_kernel_q_tiled(self, leaf_id, slots, quant):
@@ -1185,20 +1323,75 @@ class TreeGrower:
             return self.record_layout.pack_tree_record(tree)
 
     # ------------------------------------------------------------------
+    #: rows of one block of the root's float32 totals: a shard on the
+    #: kernel path holds whole blocks (its rows are padded to 1024)
+    ROOT_TOTAL_BLOCK = 1024
+
+    def _root_totals(self, grad, hess, counts):
+        """(1, 3) float32 root totals on the ladder's path, every
+        addition written out so that neither the number of shards nor
+        the compiler chooses the order: inside each fixed 1024-row block
+        and then across the blocks, halves are added elementwise until
+        one value is left.  A ``sum`` will not do: the TPU compiler
+        orders a reduction by its operand's shape, so the same rows
+        summed as one shard and as four give other last bits (measured
+        on a v5e, PR 28: 170 of 192 block sums differ), a float32
+        ``psum`` of per-shard sums likewise, and the trees with them."""
+        def halved(x):
+            # (..., 2^k) -> (...,): x[i] + x[i + half], level by level
+            while x.shape[-1] > 1:
+                half = x.shape[-1] // 2
+                x = x[..., :half] + x[..., half:]
+            return x[..., 0]
+
+        def block_sums(x):
+            # one row a block, the row its minor dimension: a (3, N)
+            # stack of the channels is laid out with the 3 minor-most
+            # under the mesh (3 padded to 128 lanes, a copy of every
+            # level: 51 ms a tree at 2^24 rows a chip, PERF.md PR 28)
+            return halved(x.reshape(-1, self.ROOT_TOTAL_BLOCK))
+
+        def total(x):
+            x = jnp.where(self._row_valid, x, 0.0)
+            if self._mesh_kernels:
+                from jax.sharding import PartitionSpec as P
+                axis = self._row_axis
+                part = _get_shard_map()(
+                    lambda x: jax.lax.all_gather(block_sums(x), axis,
+                                                 tiled=True),
+                    mesh=self.policy.mesh, in_specs=P(axis),
+                    out_specs=P())(x)
+            else:
+                part = block_sums(x)
+            blocks = part.shape[0]
+            pad = (1 << (blocks - 1).bit_length()) - blocks
+            return halved(jnp.pad(part, (0, pad)))
+
+        return jnp.stack([total(grad), total(hess), total(counts)])[None, :]
+
     def _init_state(self, grad, hess, counts) -> GrowerState:
         L = self.num_leaves
         M = L - 1
         B = self.max_feature_bin
         leaf_id = jnp.where(self._row_valid, 0, -1).astype(jnp.int32)
-        totals = compute_leaf_totals(grad, hess, counts, leaf_id, 1)
+        if self.use_fused and self.use_tiled:
+            totals = self._root_totals(grad, hess, counts)
+        else:
+            totals = compute_leaf_totals(grad, hess, counts, leaf_id, 1)
         leaf_sum_grad = jnp.zeros(L, jnp.float32).at[0].set(totals[0, 0])
         leaf_sum_hess = jnp.zeros(L, jnp.float32).at[0].set(totals[0, 1])
-        leaf_count = jnp.zeros(L, jnp.float32).at[0].set(totals[0, 2])
+        root_rows = totals[0, 2]
+        if self._int_counts:
+            # an integer sum has one value in any order, on any mesh
+            root_rows = jnp.sum(jnp.where(
+                self._row_valid, counts, 0.0).astype(jnp.int32))
+        leaf_count = jnp.zeros(L, root_rows.dtype).at[0].set(root_rows)
         tree = TreeArrays(
             num_leaves=jnp.int32(1),
             leaf_value=jnp.zeros(L, jnp.float32),
             leaf_weight=jnp.zeros(L, jnp.float32).at[0].set(totals[0, 1]),
-            leaf_count=jnp.zeros(L, jnp.float32).at[0].set(totals[0, 2]),
+            leaf_count=jnp.zeros(L, jnp.int32).at[0].set(
+                _rows_i32(root_rows)),
             leaf_parent=jnp.full(L, -1, jnp.int32),
             leaf_depth=jnp.zeros(L, jnp.int32),
             node_feature=jnp.zeros(M, jnp.int32),
@@ -1209,7 +1402,7 @@ class TreeGrower:
             node_gain=jnp.zeros(M, jnp.float32),
             node_value=jnp.zeros(M, jnp.float32),
             node_weight=jnp.zeros(M, jnp.float32),
-            node_count=jnp.zeros(M, jnp.float32),
+            node_count=jnp.zeros(M, jnp.int32),
             node_left=jnp.zeros(M, jnp.int32),
             node_right=jnp.zeros(M, jnp.int32),
         )
@@ -1220,6 +1413,14 @@ class TreeGrower:
             .at[:, CAND_GAIN].set(NEG_INF)
         forced_cand = jnp.zeros((L, FORCED_COLS), jnp.float32) \
             .at[:, FORCED_GAIN].set(NEG_INF)
+        hist_cache = jnp.zeros(
+            (L if self.use_hist_cache else 1, self.num_groups,
+             self.max_group_bin, 3), jnp.float32)
+        if self._int_counts:
+            hist_cache = (hist_cache,
+                          jnp.zeros(hist_cache.shape[:3], jnp.int32))
+            cand = (cand, jnp.zeros(L, jnp.int32))
+            forced_cand = (forced_cand, jnp.zeros(L, jnp.int32))
         W = self.frontier
         return GrowerState(
             route_tab=jnp.zeros((L, self._route_cols), jnp.float32),
@@ -1235,10 +1436,7 @@ class TreeGrower:
             leaf_is_left=jnp.zeros(L, bool),
             leaf_forced=leaf_forced,
             tree=tree,
-            hist_cache=jnp.zeros(
-                (L if self.use_hist_cache else 1, self.num_groups,
-                 self.max_group_bin, 3), jnp.float32),
-            cand=cand, forced_cand=forced_cand)
+            hist_cache=hist_cache, cand=cand, forced_cand=forced_cand)
 
     # ------------------------------------------------------------------
     def _train_tree_impl(self, grad, hess, counts, feature_mask,
@@ -1331,12 +1529,21 @@ class TreeGrower:
             # ms/tree at HIGGS scale)
             if self.use_tiled:
                 from ..ops.histogram import route_apply_tiled
-                leaf_id, row_val = route_apply_tiled(
+                route = functools.partial(
+                    route_apply_tiled, block=self.pallas_block_tiled,
+                    interpret=self._interp, packed_groups=self.pack_P)
+                if self._mesh_kernels:
+                    # per-row work on replicated tables: each shard
+                    # routes its own rows, nothing crosses
+                    from jax.sharding import PartitionSpec as P
+                    rows = P(self._row_axis)
+                    route = _get_shard_map()(
+                        route, mesh=self.policy.mesh,
+                        in_specs=(P(None, self._row_axis), rows, P(), P()),
+                        out_specs=(rows, rows))
+                leaf_id, row_val = route(
                     self.binsT, leaf_id, final.route_tab,
-                    final.tree.leaf_value,
-                    block=self.pallas_block_tiled,
-                    interpret=self._interp,
-                    packed_groups=self.pack_P)
+                    final.tree.leaf_value)
             else:
                 leaf_id, row_val = apply_route_table(
                     self.bins, leaf_id, final.route_tab,
@@ -1408,10 +1615,11 @@ class TreeGrower:
         else:
             right_hist = self._hist_kernel(grad, hess, counts, st.leaf_id,
                                            slots=rights, quant=quant)
-        right_hist = self.policy.constrain_hist(right_hist)
+        right_hist = self._constrain_hist(right_hist)
         safe_p = jnp.clip(parents, 0, L - 1)
+        tmap = jax.tree_util.tree_map     # a histogram, or its pair
         if self.use_hist_cache:
-            left_hist = cache[safe_p] - right_hist
+            left_hist = tmap(lambda c, r: c[safe_p] - r, cache, right_hist)
         elif self.use_fused and self.leaf_part:
             # the round's partition serves the parents pass too — the
             # parent slots host the LEFT children's (already-routed) rows
@@ -1426,18 +1634,20 @@ class TreeGrower:
             # idempotent), so a direct pass replaces the subtraction
             left_hist, _ = self._hist_kernel_fused(
                 st, parents, grad, hess, counts, quant)
-            left_hist = self.policy.constrain_hist(left_hist)
+            left_hist = self._constrain_hist(left_hist)
         else:
             left_hist = self._hist_kernel(grad, hess, counts, st.leaf_id,
                                           slots=parents, quant=quant)
             left_hist = self.policy.constrain_hist(left_hist)
         new_slots = jnp.concatenate([parents, rights])          # (2W,)
-        h_new = jnp.concatenate([left_hist, right_hist])        # (2W,G,B,3)
+        h_new = tmap(lambda l, r: jnp.concatenate([l, r]),
+                     left_hist, right_hist)                     # (2W,G,B,3)
         if self.use_hist_cache:
             # one combined scatter (parent and right slots are disjoint)
             # so XLA emits a single in-place update of the cache buffer
-            cache = cache.at[jnp.where(new_slots >= 0, new_slots, L)].set(
-                h_new, mode="drop")
+            at = jnp.where(new_slots >= 0, new_slots, L)
+            cache = tmap(lambda c, h: c.at[at].set(h, mode="drop"),
+                         cache, h_new)
         # ---- frontier-bounded candidate refresh (round 7): the finder
         # and the cache scatter run at the narrowest packed-strip width
         # covering the valid slots — a lax.cond ladder mirroring
@@ -1452,7 +1662,8 @@ class TreeGrower:
                         return self._refresh_cand(st, new_slots, h_new,
                                                   feature_mask)
                     slots_w = jnp.concatenate([parents[:w], rights[:w]])
-                    h_w = jnp.concatenate([left_hist[:w], right_hist[:w]])
+                    h_w = tmap(lambda l, r: jnp.concatenate([l[:w], r[:w]]),
+                               left_hist, right_hist)
                     return self._refresh_cand(st, slots_w, h_w, feature_mask)
                 return go
 
@@ -1473,6 +1684,17 @@ class TreeGrower:
                                                None), None)
         return st._replace(hist_cache=cache, cand=cand,
                            forced_cand=forced_cand)
+
+    # ------------------------------------------------------------------
+    def _constrain_hist(self, hist):
+        """The policy's feature-owned histogram constraint, except on
+        the mesh's kernel path: there the exact sum leaves the
+        histogram replicated and the split finder runs on every shard
+        alike (reduce-scatter with feature-owned finding is a later
+        optimisation: ROADMAP Speed 6)."""
+        if self._mesh_kernels:
+            return hist
+        return self.policy.constrain_hist(hist)
 
     # ------------------------------------------------------------------
     def _refresh_cand(self, st: GrowerState, slots_w, h_w, feature_mask):
@@ -1496,22 +1718,34 @@ class TreeGrower:
         sc = st.leaf_count[safe]
         mc = st.leaf_min_c[safe]
         xc = st.leaf_max_c[safe]
-        totals = jnp.stack([sg, sh, sc], axis=1)
+        feat_count = None
+        if self._int_counts:
+            # the counts' own FixHistogram, in integers
+            h_w, c_w = h_w
+            feat_count = expand_feature_histograms(
+                c_w[..., None], self.bin_map, self.fix_bin,
+                sc[:, None])[..., 0]
+        totals = jnp.stack([sg, sh, sc.astype(jnp.float32)], axis=1)
         feat_hist = expand_feature_histograms(h_w, self.bin_map,
                                               self.fix_bin, totals)
         block = find_best_split_block(
             feat_hist, sg, sh, sc, mc, xc, cfg, self.f_num_bin,
             self.f_missing, self.f_default_bin, self.f_monotone,
-            self.f_is_cat, feature_mask, self.has_categorical)
+            self.f_is_cat, feature_mask, self.has_categorical,
+            feat_count=feat_count)
         idx = jnp.where(slots_w >= 0, slots_w, L)
-        cand = st.cand.at[idx].set(block, mode="drop")
+        tmap = jax.tree_util.tree_map       # a block, or its pair
+        cand = tmap(lambda c, b: c.at[idx].set(b, mode="drop"),
+                    st.cand, block)
         forced_cand = st.forced_cand
         if self.forced_count:
             fblock = forced_split_block(
                 feat_hist, st.leaf_forced[safe], self.forced_feature,
                 self.forced_thr, sg, sh, sc, self.f_num_bin,
-                self.f_missing, self.f_default_bin, self.f_is_cat, cfg)
-            forced_cand = st.forced_cand.at[idx].set(fblock, mode="drop")
+                self.f_missing, self.f_default_bin, self.f_is_cat, cfg,
+                feat_count=feat_count)
+            forced_cand = tmap(lambda c, b: c.at[idx].set(b, mode="drop"),
+                               st.forced_cand, fblock)
         return cand, forced_cand
 
     # ------------------------------------------------------------------
@@ -1563,7 +1797,8 @@ class TreeGrower:
             node_value=t.node_value.at[nid].set(parent_out, mode="drop"),
             node_weight=t.node_weight.at[nid].set(st.leaf_sum_hess,
                                                   mode="drop"),
-            node_count=t.node_count.at[nid].set(st.leaf_count, mode="drop"),
+            node_count=t.node_count.at[nid].set(_rows_i32(st.leaf_count),
+                                                mode="drop"),
             node_left=t.node_left.at[nid].set(_encode_leaf(slot),
                                               mode="drop"),
             node_right=t.node_right.at[nid].set(_encode_leaf(right_slot),
@@ -1608,7 +1843,7 @@ class TreeGrower:
         tree = tree._replace(
             leaf_value=upd(t.leaf_value, lout, rout),
             leaf_weight=upd(t.leaf_weight, lsh, rsh),
-            leaf_count=upd(t.leaf_count, lsc, rsc),
+            leaf_count=upd(t.leaf_count, _rows_i32(lsc), _rows_i32(rsc)),
             leaf_parent=upd(t.leaf_parent, node_id, node_id),
             leaf_depth=upd(t.leaf_depth, new_depth, new_depth),
         )
@@ -1626,14 +1861,15 @@ class TreeGrower:
         else:
             leaf_forced = st.leaf_forced
 
-        # row re-labeling.  Fused path: only BUILD the route table —
-        # the next round's histogram kernel applies it in its own data
-        # stream (the loop exit applies the last pending table in
-        # _train_tree_inner).  Non-fused (CPU sim / GSPMD meshes): the
-        # XLA router runs now.  A Pallas VMEM-one-hot standalone router
-        # was benched on a v5e chip and lost to the XLA form (142 vs
-        # 96 ms/tree at 1M rows), which is what motivated fusing the
-        # routing into the histogram kernel instead.
+        # row re-labeling.  Fused path (one device, or the row shards
+        # of a data mesh): only BUILD the route table — the next
+        # round's histogram kernel applies it in its own data stream
+        # (the loop exit applies the last pending table in
+        # _train_tree_inner).  Non-fused (CPU sim / feature, voting and
+        # GSPMD meshes): the XLA router runs now.  A Pallas VMEM-one-hot
+        # standalone router was benched on a v5e chip and lost to the
+        # XLA form (142 vs 96 ms/tree at 1M rows), which is what
+        # motivated fusing the routing into the histogram kernel instead.
         route_args = (do_split, f_group_leaf,
                       self.f_gb_lo[best_f], self.f_gb_hi[best_f],
                       self.f_gb_shift[best_f], self.f_gb_oor[best_f],
@@ -1676,18 +1912,21 @@ class TreeGrower:
                                quant)
 
         with TELEMETRY.phase("split_finder"):
-            c = st.cand
+            c, lsc_i32 = st.cand if self._int_counts else (st.cand, None)
             best_gain = c[:, CAND_GAIN]
             best_f = c[:, CAND_FEATURE].astype(jnp.int32)
             thr = c[:, CAND_THRESHOLD].astype(jnp.int32)
             dleft = c[:, CAND_DEFAULT_LEFT] > 0.5
             lsg, lsh, lsc = c[:, CAND_LSG], c[:, CAND_LSH], c[:, CAND_LSC]
+            if self._int_counts:
+                lsc = lsc_i32
             lout, rout = c[:, CAND_LOUT], c[:, CAND_ROUT]
             cat_mask = c[:, CAND_COLS:] > 0.5
 
             forced_valid = None
             if self.forced_count:
-                fc = st.forced_cand
+                fc, flc = st.forced_cand if self._int_counts \
+                    else (st.forced_cand, st.forced_cand[:, FORCED_LSC])
                 fc_gain = fc[:, FORCED_GAIN]
                 fc_thr = fc[:, FORCED_THRESHOLD].astype(jnp.int32)
                 s_node = jnp.clip(st.leaf_forced, 0, self.forced_count - 1)
@@ -1700,7 +1939,7 @@ class TreeGrower:
                                   fc[:, FORCED_DEFAULT_LEFT] > 0.5, dleft)
                 lsg = jnp.where(forced_valid, fc[:, FORCED_LSG], lsg)
                 lsh = jnp.where(forced_valid, fc[:, FORCED_LSH], lsh)
-                lsc = jnp.where(forced_valid, fc[:, FORCED_LSC], lsc)
+                lsc = jnp.where(forced_valid, flc, lsc)
                 lout = jnp.where(forced_valid, fc[:, FORCED_LOUT], lout)
                 rout = jnp.where(forced_valid, fc[:, FORCED_ROUT], rout)
                 fmask = (jnp.arange(self.max_feature_bin,

@@ -1468,18 +1468,23 @@ def _tiled_out_to_hist(out: jax.Array, strips: int, num_groups: int,
 
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "strips",
-                              "interpret", "packed_groups"))
+                              "interpret", "packed_groups",
+                              "dequantize"))
 def compute_group_histograms_fused_tiled(
         binsT: jax.Array, wT: jax.Array, scales: jax.Array,
         leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
         max_group_bin: int, block: int = 2048, strips: int = 1,
-        interpret: bool = False, packed_groups: int = 0):
+        interpret: bool = False, packed_groups: int = 0,
+        dequantize: bool = True):
     """Fused route + tiled-iota int8 histogram: same contract as
     :func:`compute_group_histograms_fused` minus the ``ohb`` operand —
     the one-hot is rebuilt in VMEM from ``binsT``.  Quantized path only
     (wT is the (3, N) int32 quantized weights).  ``packed_groups`` > 0
     marks binsT as the (cols, N) nibble-packed storage — the HBM
-    stream halves and nibbles widen in-register per tile."""
+    stream halves and nibbles widen in-register per tile.
+    ``dequantize=False`` returns the int32 accumulators themselves
+    (``scales`` unread): what a row shard hands to the exact cross-shard
+    sum (parallel/collectives.py ``exchange_int_histograms``)."""
     num_groups = logical_groups(binsT.shape[0], packed_groups) \
         if packed_groups else binsT.shape[0]
     b = max_group_bin
@@ -1520,8 +1525,10 @@ def compute_group_histograms_fused_tiled(
         ],
         interpret=interpret, name="compute_group_histograms_fused_tiled",
     )(binsT, wT, leaf_id[None, :], routeT, slot_col)
-    hist = _tiled_out_to_hist(out, strips, num_groups, b).astype(
-        jnp.float32) * scales[None, None, None, :]
+    hist = _tiled_out_to_hist(out, strips, num_groups, b)
+    if not dequantize:
+        return hist, leaf_out[0]
+    hist = hist.astype(jnp.float32) * scales[None, None, None, :]
     return hist, leaf_out[0]
 
 
@@ -1657,19 +1664,20 @@ def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
 
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "k_cap", "a",
-                              "interpret"))
+                              "interpret", "dequantize"))
 def compute_group_histograms_fused_factored(
         binsT: jax.Array, wT: jax.Array, scales: jax.Array,
         leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
         max_group_bin: int, k_cap: int, a: int, block: int = 2048,
-        interpret: bool = False):
+        interpret: bool = False, dequantize: bool = True):
     """Fused route + factored int8 histogram, one rung of
     ``FACTORED_RUNGS``: the contract of
     :func:`compute_group_histograms_fused_tiled` for at most ``k_cap``
     active slots, which lead ``slots``.  Returns ``(hist, new_leaf)``
     with ``hist`` (k_cap, G, B, 3) following ``slots[:k_cap]``, equal to
     the tiled kernel's to the bit.  Byte-wide bins in a 256-lane tile
-    only (see :func:`factored_rungs`)."""
+    only (see :func:`factored_rungs`).  ``dequantize=False``: the int32
+    accumulators, as in the tiled kernel."""
     from jax.experimental.pallas import tpu as pltpu
 
     num_groups, n = binsT.shape
@@ -1720,7 +1728,10 @@ def compute_group_histograms_fused_factored(
     diag = jnp.stack([o[:, p, :, :, :, p, :] for p in range(pack)], axis=1)
     full = diag.reshape(num_tiles * pack, 3, k_cap,
                         tile_w)[:num_groups, :, :, :max_group_bin]
-    hist = jnp.transpose(full, (2, 0, 3, 1)).astype(jnp.float32)
+    hist = jnp.transpose(full, (2, 0, 3, 1))
+    if not dequantize:
+        return hist, leaf_out[0]
+    hist = hist.astype(jnp.float32)
     return hist * scales[None, None, None, :], leaf_out[0]
 
 
@@ -1982,7 +1993,7 @@ def expand_feature_histograms(group_hist: jax.Array, bin_map: jax.Array,
     Returns: (L, F, B_f, 3) float32
     """
     num_leaves = group_hist.shape[0]
-    flat = group_hist.reshape(num_leaves, -1, 3)
+    flat = group_hist.reshape(num_leaves, -1, group_hist.shape[-1])
     valid = (bin_map >= 0)
     safe = jnp.where(valid, bin_map, 0)
     feat = flat[:, safe, :] * valid[None, :, :, None]

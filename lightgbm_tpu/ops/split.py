@@ -19,7 +19,7 @@ remains of missing handling is exactly:
 """
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -131,7 +131,9 @@ def find_numerical_splits(hist: jax.Array, sum_grad: jax.Array,
                           num_bin: jax.Array, missing_type: jax.Array,
                           default_bin: jax.Array, monotone: jax.Array,
                           min_c: jax.Array, max_c: jax.Array,
-                          cfg: Dict[str, float]) -> SplitResult:
+                          cfg: Dict[str, float],
+                          hist_count: Optional[jax.Array] = None
+                          ) -> SplitResult:
     """Vectorized FindBestThresholdNumerical over every (leaf, feature).
 
     Args:
@@ -139,6 +141,11 @@ def find_numerical_splits(hist: jax.Array, sum_grad: jax.Array,
       sum_grad/sum_hess/num_data: (L,) leaf totals (raw; epsilon
         adjustments happen here, matching FindBestThreshold's
         ``sum_hessian + 2*kEpsilon``).
+      hist_count: (L, F, B) int32 row counts, with ``num_data`` int32
+        too: where a node can hold more rows than float32 counts
+        (2^24), every count here — prefix sums, parent minus side, the
+        ``min_data_in_leaf`` test, ``left_count`` — is integer
+        arithmetic and exact; ``hist``'s own count channel is unread.
       num_bin/missing_type/default_bin/monotone: (F,) metadata.
       min_c/max_c: (L,) monotone output constraints of the leaf.
       cfg: scalars — lambda_l1, lambda_l2, max_delta_step,
@@ -159,20 +166,28 @@ def find_numerical_splits(hist: jax.Array, sum_grad: jax.Array,
     bins = jnp.arange(B, dtype=jnp.int32)
     h_g, h_h, h_c = hist[..., 0], hist[..., 1], hist[..., 2]
 
+    def masked(h, mask_fb):
+        return h * (1.0 - mask_fb[None, :, :])
+
+    masked_c = masked
+    if hist_count is not None:
+        h_c = hist_count
+        min_data = jnp.ceil(min_data).astype(jnp.int32)
+
+        def masked_c(c, mask_fb):
+            return jnp.where(mask_fb[None, :, :], 0, c)
+
     is_default = bins[None, :] == default_bin[:, None]       # (F, B)
     is_nan_bin = bins[None, :] == (num_bin - 1)[:, None]     # (F, B)
     two_scan = (num_bin > 2) & (missing_type != MISSING_NONE)  # (F,)
     m_zero = missing_type == MISSING_ZERO
     m_nan = missing_type == MISSING_NAN
 
-    def masked(h, mask_fb):
-        return h * (1.0 - mask_fb[None, :, :])
-
     # ---- scan A: default-right (dir=+1); only for two-scan features ----
     excl_a = jnp.where(m_zero[:, None], is_default, jnp.zeros_like(is_default))
     left_g_a = jnp.cumsum(masked(h_g, excl_a), axis=2)
     left_h_a = jnp.cumsum(masked(h_h, excl_a), axis=2) + K_EPSILON
-    left_c_a = jnp.cumsum(masked(h_c, excl_a), axis=2)
+    left_c_a = jnp.cumsum(masked_c(h_c, excl_a), axis=2)
     # valid thresholds: t <= nb-2; Zero: t != default_bin
     t_ok_a = (bins[None, :] <= (num_bin - 2)[:, None])
     t_ok_a &= ~(m_zero[:, None] & is_default)
@@ -184,7 +199,7 @@ def find_numerical_splits(hist: jax.Array, sum_grad: jax.Array,
                                  jnp.zeros_like(is_default)))
     cum_g_b = jnp.cumsum(masked(h_g, excl_b), axis=2)
     cum_h_b = jnp.cumsum(masked(h_h, excl_b), axis=2)
-    cum_c_b = jnp.cumsum(masked(h_c, excl_b), axis=2)
+    cum_c_b = jnp.cumsum(masked_c(h_c, excl_b), axis=2)
     tot_g_b = cum_g_b[:, :, -1:]
     tot_h_b = cum_h_b[:, :, -1:]
     tot_c_b = cum_c_b[:, :, -1:]
@@ -237,7 +252,7 @@ def find_numerical_splits(hist: jax.Array, sum_grad: jax.Array,
 
     def pick(arr_a, arr_b):
         sel = jnp.where(from_b[:, :, None], arr_b, arr_a)
-        return jnp.sum(jnp.where(oh_thr, sel, 0.0), axis=2)
+        return jnp.sum(jnp.where(oh_thr, sel, jnp.zeros_like(sel)), axis=2)
 
     lg = pick(left_g_a, left_g_b)
     lh = pick(left_h_a, left_h_b)
@@ -443,7 +458,8 @@ def gather_split_at_threshold(hist_f: jax.Array, threshold: jax.Array,
                               num_data: jax.Array, num_bin: jax.Array,
                               missing_type: jax.Array, default_bin: jax.Array,
                               is_cat: jax.Array,
-                              cfg: Dict[str, float]):
+                              cfg: Dict[str, float],
+                              hist_count: Optional[jax.Array] = None):
     """Split info at a GIVEN (feature, threshold) per leaf — the forced
     -split evaluation (reference feature_histogram.hpp:273-413
     GatherInfoForThresholdNumerical/Categorical).
@@ -460,6 +476,8 @@ def gather_split_at_threshold(hist_f: jax.Array, threshold: jax.Array,
       threshold: (L,) int32 bin threshold (categorical: the bin).
       sum_grad/sum_hess/num_data: (L,) leaf totals (sum_hess raw).
       num_bin/missing_type/default_bin/is_cat: (L,) forced-feature meta.
+      hist_count: (L, B) int32 counts with ``num_data`` int32, as in
+        :func:`find_numerical_splits`; ``left_count`` is then int32.
 
     Returns: (gain, left_sum_grad, left_sum_hess(+eps removed),
               left_count, left_output, right_output, default_left) —
@@ -477,6 +495,8 @@ def gather_split_at_threshold(hist_f: jax.Array, threshold: jax.Array,
 
     bins = jnp.arange(B, dtype=jnp.int32)
     h_g, h_h, h_c = hist_f[..., 0], hist_f[..., 1], hist_f[..., 2]
+    if hist_count is not None:
+        h_c = hist_count
 
     # ---- numerical: right side = bins > threshold, minus skips ----
     m_zero = missing_type == MISSING_ZERO
@@ -522,20 +542,28 @@ def run_split_finders(hist: jax.Array, sum_grad: jax.Array,
                       f_missing: jax.Array, f_default_bin: jax.Array,
                       f_monotone: jax.Array, f_is_cat: jax.Array,
                       feature_mask: jax.Array,
-                      has_categorical: bool) -> Tuple[SplitResult,
-                                                      jax.Array]:
+                      has_categorical: bool,
+                      hist_count: Optional[jax.Array] = None
+                      ) -> Tuple[SplitResult, jax.Array]:
     """Per-(leaf-row, feature) finder pass shared by every best-split
     path: numerical finders, the categorical overlay where-merged by
     `f_is_cat`, and the feature-mask gain fill.  Leaf-shaped args are
     aligned with hist's first axis.  Returns (res, gains) with gains
-    masked to K_MIN_SCORE outside `feature_mask`."""
+    masked to K_MIN_SCORE outside `feature_mask`.  With ``hist_count``
+    (int32, ``count`` too) the numerical finder counts in integers and
+    ``res.left_count`` is int32; the categorical finder still reads
+    ``hist``'s float32 count channel (exact to 2^24 rows a node)."""
     num_res = find_numerical_splits(
         hist, sum_grad, sum_hess, count, f_num_bin, f_missing,
-        f_default_bin, f_monotone, min_c, max_c, cfg)
+        f_default_bin, f_monotone, min_c, max_c, cfg,
+        hist_count=hist_count)
     if has_categorical:
         cat_res = find_categorical_splits(
-            hist, sum_grad, sum_hess, count, f_num_bin, f_missing,
-            min_c, max_c, cfg)
+            hist, sum_grad, sum_hess, count.astype(jnp.float32),
+            f_num_bin, f_missing, min_c, max_c, cfg)
+        if hist_count is not None:
+            cat_res = cat_res._replace(left_count=jnp.round(
+                cat_res.left_count).astype(jnp.int32))
         icat = f_is_cat[None, :]
         res = SplitResult(*[jnp.where(icat, c, n) for c, n
                             in zip(cat_res, num_res)])
@@ -552,7 +580,8 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
                           f_missing: jax.Array, f_default_bin: jax.Array,
                           f_monotone: jax.Array, f_is_cat: jax.Array,
                           feature_mask: jax.Array,
-                          has_categorical: bool) -> jax.Array:
+                          has_categorical: bool,
+                          feat_count: Optional[jax.Array] = None):
     """Best split per FRONTIER leaf as one packed candidate block.
 
     Every shape here is bounded by the frontier width W' the caller
@@ -569,13 +598,17 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
       feat_hist: (W', F, B, 3) per-feature histograms of the frontier.
       sum_grad/sum_hess/count/min_c/max_c: (W',) leaf totals/bounds.
       f_*: (F,) feature metadata; feature_mask: (F,) bool.
-    Returns: (W', CAND_COLS + B) f32 packed candidate rows.
+      feat_count: (W', F, B) int32 counts, with ``count`` int32 (see
+        :func:`find_numerical_splits`).
+    Returns: (W', CAND_COLS + B) f32 packed candidate rows; with
+      ``feat_count`` a pair of them and the winners' (W',) int32 left
+      counts, which a float32 column would round above 2^24.
     """
     W, F, B, _ = feat_hist.shape
     res, gains = run_split_finders(
         feat_hist, sum_grad, sum_hess, count, min_c, max_c, cfg,
         f_num_bin, f_missing, f_default_bin, f_monotone, f_is_cat,
-        feature_mask, has_categorical)
+        feature_mask, has_categorical, hist_count=feat_count)
 
     best_fc = jnp.argmax(gains, axis=1).astype(jnp.int32)       # (W',)
     best_gain = jnp.max(gains, axis=1)     # == value at argmax
@@ -585,7 +618,8 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
     payload = jnp.stack(
         [res.threshold.astype(jnp.float32),
          res.default_left.astype(jnp.float32),
-         res.left_sum_grad, res.left_sum_hess, res.left_count,
+         res.left_sum_grad, res.left_sum_hess,
+         res.left_count.astype(jnp.float32),
          res.left_output, res.right_output,
          res.cat_dir.astype(jnp.float32)], axis=2)              # (W',F,8)
     oh = (jnp.arange(F, dtype=jnp.int32)[None, :]
@@ -601,9 +635,12 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
             f_missing[best_fc], cfg)
     else:
         cat_mask = jnp.zeros((W, B), bool)
-    return jnp.concatenate(
+    block = jnp.concatenate(
         [best_gain[:, None], best_fc.astype(jnp.float32)[:, None],
          sel, cat_mask.astype(jnp.float32)], axis=1)
+    if feat_count is None:
+        return block
+    return block, jnp.sum(jnp.where(oh, res.left_count, 0), axis=1)
 
 
 def forced_split_block(feat_hist: jax.Array, spec: jax.Array,
@@ -612,25 +649,32 @@ def forced_split_block(feat_hist: jax.Array, spec: jax.Array,
                        count: jax.Array, f_num_bin: jax.Array,
                        f_missing: jax.Array, f_default_bin: jax.Array,
                        f_is_cat: jax.Array,
-                       cfg: Dict[str, float]) -> jax.Array:
+                       cfg: Dict[str, float],
+                       feat_count: Optional[jax.Array] = None):
     """Forced-split evaluation of the frontier as one packed
     (W', FORCED_COLS) block (gather_split_at_threshold per leaf at its
     spec node's (feature, threshold); rows with no spec get -inf
     gain).  ``spec`` is the (W',) forced-spec index (-1 = none);
-    forced_feature/forced_thr the flat spec arrays."""
+    forced_feature/forced_thr the flat spec arrays.  With
+    ``feat_count`` (int32, ``count`` too): the block and the (W',)
+    int32 left counts, as :func:`find_best_split_block`."""
     n_spec = forced_feature.shape[0]
     s_node = jnp.clip(spec, 0, n_spec - 1)
     ff = forced_feature[s_node]
     ft = forced_thr[s_node]
     hist_ff = jnp.take_along_axis(
         feat_hist, ff[:, None, None, None], axis=1)[:, 0]
+    cnt_ff = None if feat_count is None else jnp.take_along_axis(
+        feat_count, ff[:, None, None], axis=1)[:, 0]
     (fgain, flg, flh, flc, flo, fro, fdl) = gather_split_at_threshold(
         hist_ff, ft, sum_grad, sum_hess, count, f_num_bin[ff],
-        f_missing[ff], f_default_bin[ff], f_is_cat[ff], cfg)
+        f_missing[ff], f_default_bin[ff], f_is_cat[ff], cfg,
+        hist_count=cnt_ff)
     fgain = jnp.where(spec >= 0, fgain, K_MIN_SCORE)
-    return jnp.stack(
+    fblock = jnp.stack(
         [fgain, ft.astype(jnp.float32), fdl.astype(jnp.float32),
-         flg, flh, flc, flo, fro], axis=1)
+         flg, flh, flc.astype(jnp.float32), flo, fro], axis=1)
+    return fblock if feat_count is None else (fblock, flc)
 
 
 def _shift_used(arr, n_used):

@@ -255,6 +255,51 @@ def exchange_histograms(hist, axis_name, mode: str = "f32",
     return jnp.cumsum(deq, axis=-2)
 
 
+def int_exchange_fits_int32(global_rows: int) -> bool:
+    """True where the cross-shard sum of int32 histogram accumulators
+    cannot leave int32: every shard's accumulator is bounded by its rows
+    times the int8 weight ceiling, so the sum is by ``global_rows``
+    (ops/histogram.py ``quant_rows_ok``, the same bound)."""
+    from ..ops.histogram import quant_rows_ok
+    return quant_rows_ok(global_rows)
+
+
+def exchange_int_histograms(acc, axis_name, *, global_rows: int):
+    """EXACT cross-shard sum of the quantized path's int32 histogram
+    accumulators ``(..., 3)``, taken before any dequantize.  Returns on
+    every shard ``(total, rows)``: the float32 nearest to each integer
+    total, which is a function of the total alone — so the trees do not
+    depend on how many shards hold the rows (the data-parallel
+    learner's ReduceScatter of histograms,
+    data_parallel_tree_learner.cpp:147-162, as integers) — and the
+    count channel's total as int32, which float32 would round above
+    2^24 rows (``global_rows`` < 2^31, so it fits).
+
+    Where ``global_rows * 127 < 2**31`` every total fits int32 and one
+    ``psum`` carries it.  Beyond that (4 shards x 2^24 rows x 127 =
+    2^33) each accumulator travels as two 16-bit limbs, ``lo`` in
+    [0, 65535] and ``hi`` the arithmetic shift: each limb's sum is
+    exact in int32 and, under 2^24 as it is for the shards of one host
+    (fewer than 256), in float32; ``hi_sum * 65536`` is exact, and the
+    one float32 addition that puts them together rounds the exact total
+    once."""
+    if str(acc.dtype) != "int32":
+        raise TypeError(f"exchange_int_histograms sums int32 "
+                        f"accumulators, got {acc.dtype}")
+    if axis_name is None:
+        return acc.astype(jnp.float32), acc[..., 2]
+    fits = int_exchange_fits_int32(global_rows)
+    payload = acc if fits else jnp.stack([acc & 0xFFFF, acc >> 16])
+    _note_collective("allreduce", payload)
+    _note_collective("hist_exchange", payload)
+    total = jax.lax.psum(payload, axis_name)
+    if fits:
+        return total.astype(jnp.float32), total[..., 2]
+    lo, hi = total
+    return (hi.astype(jnp.float32) * 65536.0 + lo.astype(jnp.float32),
+            hi[..., 2] * 65536 + lo[..., 2])
+
+
 def host_exchange_histograms(per_shard_hists, mode: str = "f32"):
     """Single-process analog of :func:`exchange_histograms` over
     caller-provided per-shard numpy histograms — the

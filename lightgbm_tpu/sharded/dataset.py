@@ -253,6 +253,120 @@ class ShardedDataset(CoreDataset):
         return self
 
     # ------------------------------------------------------------------
+    #: rows a worker converts to float64 and bins at a time on the
+    #: row-shard route: the float64 copy of the table never exists,
+    #: only one such block a worker (2^18 x 67 x 8 B = 141 MB)
+    ROW_BLOCK = 1 << 18
+
+    @classmethod
+    def from_row_shards(cls, shards, label=None, weight=None,
+                        init_score=None, config: Optional[Config] = None,
+                        categorical_features: Optional[Sequence[int]]
+                        = None,
+                        feature_names: Optional[Sequence[str]] = None
+                        ) -> "ShardedDataset":
+        """Build from a table that arrives AS row shards: a list of
+        (rows_i, F) float arrays of any float dtype, in row order (what
+        ``lgb.Dataset([X0, X1, ...], label=y)`` constructs).  For tables
+        a host cannot hold twice: neither the concatenated table, nor
+        its float64 copy (``basic._to_matrix``), nor a concatenated bin
+        matrix exists on this route — each shard is binned in
+        ``ROW_BLOCK``-row blocks into its own uint8 matrix, which
+        ``ShardingPolicy.place_row_shards`` puts straight on the mesh.
+
+        The bin mappers are fitted ONCE, from the rows the
+        single-matrix route would sample out of the concatenation (same
+        draw, same order), so mappers, bin matrix and trees are the
+        single-matrix route's to the byte."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from ..binning import resolve_construct_threads
+        from ..data_loader import split_sample_columns
+        config = config or Config()
+        shards = [np.asarray(a) for a in shards]
+        if not shards or any(a.ndim != 2 for a in shards) \
+                or len({a.shape[1] for a in shards}) != 1:
+            raise ValueError("row shards must be a non-empty list of "
+                             "2-dimensional arrays of one width")
+        starts = np.cumsum([0] + [a.shape[0] for a in shards])
+        num_data, num_features = int(starts[-1]), shards[0].shape[1]
+
+        self = cls()
+        self.config = config
+        self.num_data = num_data
+        self.num_total_features = num_features
+        self.max_bin = config.max_bin
+        self.world_size = len(shards)
+        self.shard_ranges = [(int(a), int(b))
+                             for a, b in zip(starts[:-1], starts[1:])]
+        self.feature_names = list(feature_names) if feature_names else [
+            f"Column_{i}" for i in range(num_features)]
+
+        # the sample dataset._sample_feature_values draws from one matrix
+        sample_cnt = config.bin_construct_sample_cnt
+        if num_data > sample_cnt:
+            idx = np.random.RandomState(config.data_random_seed).choice(
+                num_data, size=sample_cnt, replace=False)
+            idx.sort()
+        else:
+            idx = np.arange(num_data)
+        cuts = np.searchsorted(idx, starts)
+        sample = np.concatenate(
+            [np.asarray(a[idx[cuts[i]:cuts[i + 1]] - starts[i]],
+                        dtype=np.float64) for i, a in enumerate(shards)])
+        sample_vals, sample_rows = split_sample_columns(sample)
+        self.mappers = self._fit_mappers(sample_vals, sample.shape[0],
+                                         config,
+                                         set(categorical_features or []))
+        self.used_features = [i for i, m in enumerate(self.mappers)
+                              if not m.is_trivial]
+        if not self.used_features:
+            Log.warning("There are no meaningful features; "
+                        "all features are constant or filtered")
+        self._build_groups(reference=None, sample_nonzero=sample_rows,
+                           sample_cnt=sample.shape[0])
+        self._categorical_features = list(categorical_features or [])
+        self._resolve_monotone(config)
+        self.bin_fingerprint = binfind.mapper_fingerprint(
+            self.mappers, self._bundles, self.max_bin)
+
+        # block-wise binning, a few blocks in flight: the float64
+        # conversion and the native binner both release the GIL
+        sds = [CoreDataset.from_reference_for_push(self, a.shape[0])
+               for a in shards]
+        workers = max(1, min(4, resolve_construct_threads(config) // 4))
+        blocks = [(sd, a, lo) for sd, a in zip(sds, shards)
+                  for lo in range(0, a.shape[0], cls.ROW_BLOCK)]
+
+        def bin_block(job):
+            sd, a, lo = job
+            chunk = np.asarray(a[lo:lo + cls.ROW_BLOCK], dtype=np.float64)
+            out = sd.group_bins[lo:lo + chunk.shape[0]]
+            if sd.bin_layout is None:
+                sd._bin_rows_dense_into(chunk, out)
+            else:
+                scratch = np.zeros((chunk.shape[0], sd.num_groups),
+                                   dtype=np.uint8)
+                sd._bin_rows_dense_into(chunk, scratch)
+                sd.bin_layout.pack_rows(scratch, out=out,
+                                        lib=sd._native_lib())
+
+        with TELEMETRY.stage("bin", rows=num_data):
+            with ThreadPoolExecutor(max_workers=workers) as pool:
+                list(pool.map(bin_block, blocks))
+        self.shard_bins = [sd.group_bins for sd in sds]
+        if TELEMETRY.on:
+            TELEMETRY.add("sharded_rows_ingested", num_data)
+            TELEMETRY.gauge("sharded_world_size", len(shards))
+
+        self.metadata = Metadata(num_data)
+        if label is not None:
+            self.metadata.set_label(label)
+        self.metadata.set_weight(weight)
+        self.metadata.set_init_score(init_score)
+        return self
+
+    # ------------------------------------------------------------------
     @classmethod
     def _construct_degraded(cls, X, label, weight, init_score, config,
                             ranges, dead: List[int],

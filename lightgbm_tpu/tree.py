@@ -52,7 +52,7 @@ TREE_RECORD_SPEC = (
     ("num_leaves", "<i4", ()),
     ("leaf_value", "<f4", ("L",)),
     ("leaf_weight", "<f4", ("L",)),
-    ("leaf_count", "<f4", ("L",)),
+    ("leaf_count", "<i4", ("L",)),
     ("leaf_parent", "<i4", ("L",)),
     ("leaf_depth", "<i4", ("L",)),
     ("node_feature", "<i4", ("M",)),
@@ -63,7 +63,7 @@ TREE_RECORD_SPEC = (
     ("node_gain", "<f4", ("M",)),
     ("node_value", "<f4", ("M",)),
     ("node_weight", "<f4", ("M",)),
-    ("node_count", "<f4", ("M",)),
+    ("node_count", "<i4", ("M",)),
     ("node_left", "<i4", ("M",)),
     ("node_right", "<i4", ("M",)),
 )

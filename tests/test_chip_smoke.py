@@ -63,6 +63,26 @@ def test_legs_run_on_the_interpret_seam():
 
 
 @pytest.mark.fast
+def test_leg_d_runs_the_ladder_on_every_shard_of_the_seam():
+    """Leg D on four virtual devices: the kernel plan under the mesh,
+    the fused kernels in the chunk program, and the model text of one
+    device — the gates the chip run makes."""
+    import jax
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    X, y, w = make_data(4096, 6)
+    Xv, _, _ = make_data(1024, 6, seed=8, w=w)
+    d = chip_smoke.leg_multichip(
+        lgb, X, y, Xv, ROUNDS, n_chips=4,
+        extra={**TINY, "force_pallas_interpret": True}, interpret=True)
+    assert d["model_text_equal_one_chip"] and d["max_abs_vs_one_chip"] == 0
+    assert d["plan"]["use_fused"] and d["plan"]["use_quant"]
+    assert "compute_group_histograms_fused_tiled" in d["kernels"]
+    assert "route_apply_tiled" in d["kernels"]
+    assert len(d["devices"]) == 4
+
+
+@pytest.mark.fast
 def test_main_refuses_without_a_tpu(capsys):
     assert chip_smoke.main() != 0
     captured = capsys.readouterr()

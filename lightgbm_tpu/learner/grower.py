@@ -682,16 +682,19 @@ class TreeGrower:
             # packed byte straddles two logical groups, so packed
             # datasets take the constraint-sharded fallback instead
             and self.pack_P == 0)
-        # factored low rungs of the fused tiled ladder: in force only
+        # factored rungs of the fused tiled ladder: in force only
         # where a group fills a 256-lane tile (max_group_bin > 128)
         self.hist_factored_rungs = ()
         if self.use_tiled and self.use_fused:
             from ..ops.histogram import factored_rungs
             self.hist_factored_rungs = factored_rungs(self.max_group_bin,
                                                       self.pack_P)
-        # their accumulators are a fraction of a strip's, so they take
-        # the row block the strips cannot (v5e, 2^24 x 67 x 255 bins:
-        # 4096 is 6-9% a pass under 2048, 8192 adds under 2%)
+        # their accumulator is a whole-array output block, which XLA
+        # keeps in VMEM outside the kernel's scoped allocation (26 MB at
+        # 126 slots x 67 groups), so they take the row block the strips
+        # cannot (v5e, 2^24 x 67 x 255 bins: 4096 is 6-9% a pass under
+        # 2048 on the narrow rungs and 1-2% on the wide ones; 8192 adds
+        # under 2% up to 64 slots and loses 10% at 126)
         self.pallas_block_factored = (
             4096 if self.local_rows % 4096 == 0 else self.pallas_block_tiled)
         self._train_tree = jax.jit(self._train_tree_impl)
@@ -1053,9 +1056,10 @@ class TreeGrower:
             wT = jnp.stack([grad, hess, counts], axis=0)
             scales, q = None, False
 
-        # low rungs (256-lane tiles only): few active slots leave the
-        # strip's rows idle, so the bin index is factored across both
-        # sides of the dot (ops/histogram.py FACTORED_RUNGS).  The
+        # factored rungs (256-lane tiles only): the bin index is split
+        # across both sides of the dot, so a pass streams the rows its
+        # active slots need and not whole strips (ops/histogram.py
+        # FACTORED_RUNGS; they reach the widest frontier).  The
         # dequantize multiply stays where the strips ladder alone has it
         # — inside the branch when that ladder is a cond, in the open
         # when it is a plain call (W <= one strip) — so that the
@@ -1126,12 +1130,19 @@ class TreeGrower:
             return strips_ladder(None)
         k = jnp.sum(rights >= 0)
         caps = [r[0] for r in rungs]
-        h, leaf2 = jax.lax.cond(
-            k <= caps[-1],
-            lambda _: jax.lax.switch(
+
+        def rung_ladder(_):
+            return jax.lax.switch(
                 sum((k > cap).astype(jnp.int32) for cap in caps[:-1]),
-                [run_factored(k_cap, a) for k_cap, a, _ in rungs], None),
-            strips_ladder, None)
+                [run_factored(k_cap, a) for k_cap, a, _ in rungs], None)
+
+        if caps[-1] >= W:
+            # the rungs serve every count of active slots there can be:
+            # the strips are not traced
+            h, leaf2 = rung_ladder(None)
+        else:
+            h, leaf2 = jax.lax.cond(k <= caps[-1], rung_ladder,
+                                    strips_ladder, None)
         if late_scale:
             h = _scaled(h, scales)
         return h, leaf2

@@ -1539,9 +1539,12 @@ def compute_group_histograms_fused_tiled(
 #: ``b`` lanes a group instead of 256 and the other operand carries
 #: ``3 * k_cap * a`` rows a group instead of a 128-row strip.  A pass
 #: costs what that operand's rows cost the MXU, in steps of 32
-#: (``_factored_rows``), so the caps sit where a step ends.  Wider
-#: frontiers keep the strip ladder.  Settled on the chip: PERF.md, PR 27.
-FACTORED_RUNGS = ((2, 4, 64), (10, 2, 128), (16, 2, 128), (32, 2, 128))
+#: (``_factored_rows``), so the caps sit where a step ends.  The two
+#: wide rungs take the passes that two and three strips had, up to the
+#: widest frontier (3 x PACKED_STRIP).  Settled on the chip: PERF.md,
+#: PR 27 (the four narrow rungs) and PR 29 (the two wide ones).
+FACTORED_RUNGS = ((2, 4, 64), (10, 2, 128), (16, 2, 128), (32, 2, 128),
+                  (64, 2, 128), (126, 2, 128))
 
 #: feature tiles a trip of the factored kernel's loop (Mosaic schedules
 #: one trip's operand builds under the dots before them)
@@ -1570,7 +1573,7 @@ def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
                                   slots_ref, hist_ref, leaf_out_ref,
                                   key4_ref, ksh_ref, lo4_ref, bit_ref, *,
                                   k_cap, a, b, num_groups, nb):
-    """Fused route + FACTORED int8 histogram for narrow frontiers.
+    """Fused route + FACTORED int8 histogram, a rung of ``FACTORED_RUNGS``.
 
         hist[slot, ch, g, hi, lo] =
             sum_r (w[ch, r] [leaf_r = slot] [hi_g,r = hi]) [lo_g,r = lo]

@@ -67,27 +67,32 @@ def serial_trees(table):
 
 
 # -- (a) the shards' int32 histograms add up to the one-shard histogram --
-def _pass_inputs(rows, groups=5, bins=255, slots=6, leaves=8):
+def _pass_inputs(rows, groups=5, bins=255, slots=6, leaves=8, active=2):
     rng = np.random.RandomState(3)
     binsT = rng.randint(0, bins, size=(groups, rows)).astype(np.uint8)
     wT = np.stack([rng.randint(-127, 128, rows), rng.randint(0, 128, rows),
                    np.ones(rows)]).astype(np.int32)
     leaf = rng.randint(0, leaves, rows).astype(np.int32)
     route = np.zeros((leaves, ROUTE_FIXED_COLS + (bins + 7) // 8), np.float32)
-    active = np.full(slots, -1, np.int32)
-    active[:2] = [5, 2]
-    return binsT, wT, leaf, route, active
+    frontier = np.full(slots, -1, np.int32)
+    frontier[:active] = ([5, 2] + [i for i in range(leaves)
+                                   if i not in (5, 2)])[:active]
+    return binsT, wT, leaf, route, frontier
 
 
-@pytest.mark.parametrize("kernel", [
-    functools.partial(H.compute_group_histograms_fused_tiled, block=1024,
-                      strips=1),
-    functools.partial(H.compute_group_histograms_fused_factored, k_cap=2,
-                      a=4, block=1024),
-], ids=["tiled_pass", "factored_rung"])
-def test_shard_accumulators_add_up_to_the_whole(kernel):
+@pytest.mark.parametrize("kernel,shape", [
+    (functools.partial(H.compute_group_histograms_fused_tiled, block=1024,
+                       strips=1), {}),
+    (functools.partial(H.compute_group_histograms_fused_factored, k_cap=2,
+                       a=4, block=1024), {}),
+    # a wide rung: 50 slots live of the 64 it holds, the frontier's 126
+    (functools.partial(H.compute_group_histograms_fused_factored, k_cap=64,
+                       a=2, block=1024),
+     dict(slots=126, leaves=70, active=50)),
+], ids=["tiled_pass", "factored_rung", "factored_wide_rung"])
+def test_shard_accumulators_add_up_to_the_whole(kernel, shape):
     rows = 4096
-    binsT, wT, leaf, route, active = _pass_inputs(rows)
+    binsT, wT, leaf, route, active = _pass_inputs(rows, **shape)
     run = functools.partial(kernel, max_group_bin=255, interpret=True,
                             dequantize=False)
     whole, leaf_whole = run(binsT, wT, None, leaf, route, active)

@@ -2,6 +2,7 @@
 reference's GPU_DEBUG_COMPARE CPU-vs-GPU histogram comparator,
 gpu_tree_learner.cpp:1020-1044)."""
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -438,87 +439,119 @@ def test_split_route_no_cache_identical_trees():
 
 
 # ---------------------------------------------------------------------------
-# factored low rungs (ops/histogram.py FACTORED_RUNGS): same integers as
-# the one-strip tiled kernel, from a dot whose one-hot is b lanes a group
+# factored rungs (ops/histogram.py FACTORED_RUNGS): same integers as the
+# tiled kernel on the strips the pass had before, from a dot whose
+# one-hot is b lanes a group
 # ---------------------------------------------------------------------------
-_FACT_N, _FACT_G, _FACT_L = 512, 19, 40   # 19 groups: no pack divides it,
-#                                            and the kernel's loop makes a trip
+_FACT_N, _FACT_G = 512, 19    # 19 groups: no pack divides it, and the
+#                                kernel's loop makes a trip
 _FACT_SLOTS = [21, 3, -1, 9, 0, 14, 6, 11, 2, 17, 5, 8, 19, 1, 12, 4]
+#: the wide rungs' frontier: 126 slots over 140 leaves, two of them idle
+_FACT_SLOTS_WIDE = [int(v) for v in
+                    np.random.RandomState(7).permutation(140)[:126]]
+_FACT_SLOTS_WIDE[2] = _FACT_SLOTS_WIDE[70] = -1
+#: active slots a wide rung is tried at: the fewest it serves, the most,
+#: and one between
+_FACT_WIDE_KS = {64: (33, 64), 126: (65, 100, 126)}
 
 
 def _factored_rung_cases():
     from lightgbm_tpu.ops.histogram import FACTORED_RUNGS
-    return [(k_cap, a, b, k, B)
-            for k_cap, a, b in FACTORED_RUNGS
-            for k in (1, 2, 3, 4, 8, 16) if k <= k_cap
-            for B in (255, 256)]
+    narrow = [(k_cap, a, b, k, B, True)
+              for k_cap, a, b in FACTORED_RUNGS if k_cap <= 32
+              for k in (1, 2, 3, 4, 8, 16) if k <= k_cap
+              for B in (255, 256)]
+    wide = [(k_cap, a, b, k, B, dequantize)
+            for k_cap, a, b in FACTORED_RUNGS if k_cap > 32
+            for k in _FACT_WIDE_KS[k_cap]
+            for B in (255, 256)
+            for dequantize in (True, False)]
+    return narrow + wide
 
 
 @functools.lru_cache(maxsize=None)
-def _factored_inputs(B):
-    """One table a bin width: padded rows (leaf -1), a route table that
-    moves rows, quantized weights, and the one-strip tiled kernel's
-    answer for every slot of ``_FACT_SLOTS``."""
+def _factored_inputs(B, strips=1, dequantize=True):
+    """One table a bin width and frontier: padded rows (leaf -1), a route
+    table that moves rows, quantized weights, and the tiled kernel's
+    answer on ``strips`` strips for every slot of ``_FACT_SLOTS`` (one
+    strip) or of as many of ``_FACT_SLOTS_WIDE`` as the strips hold."""
     from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_fused_tiled, quantize_gradients)
+        PACKED_STRIP, compute_group_histograms_fused_tiled,
+        quantize_gradients)
     from lightgbm_tpu.ops.partition import (MISSING_NAN, MISSING_NONE,
                                             MISSING_ZERO,
                                             build_route_table)
     rng = np.random.RandomState(B)
-    N, G, L = _FACT_N, _FACT_G, _FACT_L
+    N, G = _FACT_N, _FACT_G
+    # leaves the rows start in, the four that split, and the table's rows
+    leaves, L = (20, 40) if strips == 1 else (140, 160)
+    slots = _FACT_SLOTS if strips == 1 \
+        else _FACT_SLOTS_WIDE[:strips * PACKED_STRIP]
     bins = rng.randint(0, B, (N, G)).astype(np.uint8)
-    leaf = rng.randint(-1, 20, N).astype(np.int32)
+    leaf = rng.randint(-1, leaves, N).astype(np.int32)
+    if strips > 1:
+        leaf[-8:] = -1
     wq, scales = quantize_gradients(
         jnp.asarray(rng.randn(N).astype(np.float32)),
         jnp.asarray(np.abs(rng.randn(N)).astype(np.float32)),
         jnp.asarray((rng.rand(N) > 0.2).astype(np.float32)))
-    pad = [0] * (L - 4)
+    split = [0, 1, 2, 3] if strips == 1 else [7, 31, 64, 120]
     sm = np.zeros(L, bool)
-    sm[:4] = True
+    sm[split] = True
+
+    def col(values, dtype=np.int32):
+        out = np.zeros(L, dtype)
+        out[split] = values
+        return jnp.asarray(out)
     tab = build_route_table(
-        jnp.asarray(sm),
-        jnp.asarray(np.array([0, 2, 5, 3] + pad, np.int32)),
+        jnp.asarray(sm), col([0, 2, 5, 3]),
         jnp.zeros(L, jnp.int32), jnp.full(L, B, jnp.int32),
         jnp.zeros(L, jnp.int32), jnp.full(L, B - 1, jnp.int32),
-        jnp.asarray(np.array([0, 0, 0, 1] + pad, bool)),
-        jnp.asarray(np.array([70, 30, 110, 50] + pad, np.int32)),
-        jnp.asarray(np.array([1, 0, 1, 0] + pad, bool)),
-        jnp.asarray(np.array([MISSING_NONE, MISSING_ZERO, MISSING_NAN, 0]
-                             + pad, np.int32)),
-        jnp.asarray(np.array([0, 2, 0, 0] + pad, np.int32)),
+        col([0, 0, 0, 1], dtype=bool), col([70, 30, 110, 50]),
+        col([1, 0, 1, 0], dtype=bool),
+        col([MISSING_NONE, MISSING_ZERO, MISSING_NAN, 0]),
+        col([0, 2, 0, 0]),
         jnp.full(L, B, jnp.int32),
         jnp.asarray(rng.rand(L, B) > 0.5),
-        jnp.asarray(np.array([20, 21, 22, 23] + pad, np.int32)))
+        col([leaves + i for i in range(4)]))
     args = (jnp.asarray(bins.T), wq.T, scales, jnp.asarray(leaf), tab)
     want_h, want_leaf = compute_group_histograms_fused_tiled(
-        *args, jnp.asarray(np.array(_FACT_SLOTS, np.int32)),
-        max_group_bin=B, block=256, strips=1, interpret=True)
+        *args, jnp.asarray(np.array(slots, np.int32)),
+        max_group_bin=B, block=256, strips=strips, interpret=True,
+        dequantize=dequantize)
     want_leaf = np.asarray(want_leaf)
-    assert (want_leaf != leaf).sum() > 20       # the route table routes
+    assert (want_leaf != leaf).sum() > (20 if strips == 1 else 5)
     assert (want_leaf == -1).sum() > 5          # padded rows stay out
-    return args, np.asarray(want_h), want_leaf
+    return args, slots, np.asarray(want_h), want_leaf
 
 
-@pytest.mark.parametrize("k_cap,a,b,k,B", _factored_rung_cases())
-def test_factored_rung_equals_one_strip_tiled_interpret(k_cap, a, b, k, B):
-    """Every rung, at every active-slot count it serves: the dequantized
-    histogram and the routed leaf ids equal the one-strip tiled kernel's
-    exactly (the int32 sums are the same integers)."""
-    from lightgbm_tpu.ops.histogram import \
-        compute_group_histograms_fused_factored
+@pytest.mark.parametrize("k_cap,a,b,k,B,dequantize", _factored_rung_cases())
+def test_factored_rung_equals_one_strip_tiled_interpret(k_cap, a, b, k, B,
+                                                        dequantize):
+    """Every rung, at every active-slot count it serves (the wide rungs:
+    the fewest, the most and one between): the histogram and the routed
+    leaf ids equal the tiled kernel's exactly, on the one strip, the two
+    or the three that the pass had before the rung (the int32 sums are
+    the same integers; ``dequantize=False`` hands them over as they
+    are, which is what a row shard gives the cross-chip sum)."""
+    from lightgbm_tpu.ops.histogram import (
+        PACKED_STRIP, compute_group_histograms_fused_factored)
     assert a * b == 256
-    args, want_h, want_leaf = _factored_inputs(B)
+    strips = -(-k_cap // PACKED_STRIP)          # the pass's strips before
+    args, all_slots, want_h, want_leaf = _factored_inputs(B, strips,
+                                                          dequantize)
     slots = np.full(126, -1, np.int32)
-    slots[:k] = _FACT_SLOTS[:k]
+    slots[:k] = all_slots[:k]
     got_h, got_leaf = compute_group_histograms_fused_factored(
         *args, jnp.asarray(slots), max_group_bin=B, block=256,
-        k_cap=k_cap, a=a, interpret=True)
+        k_cap=k_cap, a=a, interpret=True, dequantize=dequantize)
     got_h = np.asarray(got_h)
     assert got_h.shape == (k_cap, _FACT_G, B, 3)
+    assert got_h.dtype == (np.float32 if dequantize else np.int32)
     np.testing.assert_array_equal(np.asarray(got_leaf), want_leaf)
     np.testing.assert_array_equal(got_h[:k], want_h[:k])
     assert not got_h[k:].any()                  # invalid slots: zero rows
-    assert np.abs(got_h[0]).sum() > 0
+    assert got_h[:k].any(axis=(1, 2, 3)).sum() > k // 2    # rows came
 
 
 def _fast_255(leaves, **extra):
@@ -529,14 +562,31 @@ def _fast_255(leaves, **extra):
                  "force_pallas_interpret": True}, **extra)
 
 
+def _splits_by_depth(tree):
+    """Internal nodes at each depth of one tree of ``dump_model()``.  The
+    grower splits every leaf it can in a round, up to the frontier's
+    width, so while the levels above are full, the count at depth d is
+    the number of active slots of the tree's pass d + 1."""
+    counts = {}
+
+    def walk(node, depth):
+        if "left_child" in node:
+            counts[depth] = counts.get(depth, 0) + 1
+            walk(node["left_child"], depth + 1)
+            walk(node["right_child"], depth + 1)
+    walk(tree["tree_structure"], 0)
+    return [counts[d] for d in sorted(counts)]
+
+
 @pytest.mark.parametrize("leaves,extra", [
     (31, {}), (255, {}), (31, {"histogram_pool_size": 0.001})],
     ids=["31", "255", "31_no_cache"])
 def test_factored_rungs_grow_identical_trees(leaves, extra, monkeypatch):
-    """At max_bin=255 the factored rungs serve the narrow passes of every
-    tree (frontier one strip wide at 31 leaves, three at 255; with no
-    histogram cache the parents pass too) and the model is the model of
-    the strips ladder alone, byte for byte."""
+    """At max_bin=255 the factored rungs serve the passes of every tree
+    (frontier one strip wide at 31 leaves; three at 255, where the rungs
+    serve every pass and no strip is traced; with no histogram cache the
+    parents pass too) and the model is the model of the strips ladder
+    alone, byte for byte."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.ops import histogram as H
     from lightgbm_tpu.telemetry import TELEMETRY
@@ -552,43 +602,55 @@ def test_factored_rungs_grow_identical_trees(leaves, extra, monkeypatch):
         bst = lgb.train(_fast_255(leaves, telemetry="counters", **extra),
                         lgb.Dataset(X, label=y), 3, verbose_eval=False)
         gauge = TELEMETRY.gauges()["grower.hist_factored_rungs"]
-        return bst.model_to_string(), gauge
+        return bst, gauge
 
     try:
-        with_rungs, gauge = model(H.FACTORED_RUNGS)
+        bst, gauge = model(H.FACTORED_RUNGS)
+        with_rungs = bst.model_to_string()
         assert gauge == ",".join(f"{k}:{a}x{b}"
                                  for k, a, b in H.FACTORED_RUNGS) != ""
+        assert gauge.endswith(",32:2x128,64:2x128,126:2x128")
         without, gauge = model(())
         assert gauge == ""
     finally:
         TELEMETRY.configure("off")
         TELEMETRY.reset()
-    assert with_rungs == without
+    assert with_rungs == without.model_to_string()
+    if leaves == 255:
+        # the last tree's passes had 1, 2, 4, 8, 16, 32, then 33..64 and
+        # 65..126 active slots: all six rungs ran, the two wide ones too
+        splits = _splits_by_depth(bst.dump_model()["tree_info"][-1])
+        assert splits[:6] == [1, 2, 4, 8, 16, 32]
+        assert 33 <= splits[6] <= 64 and 65 <= splits[7] <= 126
 
 
-def test_factored_rungs_leave_narrow_tiles_alone():
-    """max_bin=63 (every tile 128 lanes): no rung in force, the gauge is
-    empty and the tree program has no factored kernel in it."""
+def test_factored_rungs_leave_narrow_tiles_alone(monkeypatch):
+    """max_bin=63 (every tile 128 lanes) and nibble-packed bins: no
+    rung in force, the gauge is empty and the lowered tree program is the
+    program of the strips ladder alone, letter for letter.  max_bin=255:
+    where the rungs reach the frontier's width no strip kernel is traced;
+    where they do not, the strips keep the passes above the last rung."""
     import jax
     import lightgbm_tpu as lgb
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config
-    from lightgbm_tpu.ops.histogram import FACTORED_RUNGS, factored_rungs
+    from lightgbm_tpu.ops import histogram as H
     from lightgbm_tpu.telemetry import TELEMETRY
 
-    assert factored_rungs(255) == factored_rungs(129) == FACTORED_RUNGS
-    assert factored_rungs(128) == factored_rungs(63) == ()
-    assert factored_rungs(255, packed_groups=3) == ()
+    assert H.factored_rungs(255) == H.factored_rungs(129) \
+        == H.FACTORED_RUNGS
+    assert H.factored_rungs(128) == H.factored_rungs(63) == ()
+    assert H.factored_rungs(255, packed_groups=3) == ()
 
     rng = np.random.RandomState(3)
     X = rng.randn(1024, 6)
     y = (X[:, 0] + 0.4 * X[:, 1] > 0).astype(float)
 
-    def tree_program(max_bin):
+    def tree_program(max_bin, leaves=255, **extra):
         TELEMETRY.configure("counters")
         TELEMETRY.reset()
-        cfg = Config.from_params(dict(_fast_255(255), max_bin=max_bin,
-                                      telemetry="counters"))
+        cfg = Config.from_params(dict(_fast_255(leaves), max_bin=max_bin,
+                                      telemetry="counters", **extra))
         gr = GBDT(cfg, lgb.Dataset(X, label=y).construct(cfg)).grower
         gauge = TELEMETRY.gauges()["grower.hist_factored_rungs"]
         n = gr.n_padded
@@ -596,14 +658,34 @@ def test_factored_rungs_leave_narrow_tiles_alone():
         text = jax.jit(gr._train_tree_impl).lower(
             f32, f32, f32, np.ones(gr.num_features, bool), None, gr.bins,
             gr.binsT, gr._row_valid, jax.random.PRNGKey(0)).as_text()
-        return gauge, text
+        return gauge, text, gr
 
+    def kernels(text):
+        """Fused histogram functions defined in the program, by kernel:
+        one a strip count, one a rung (interpret mode drops the pinned
+        ``name=``; tests/test_phase_trace.py holds those)."""
+        found = re.findall(r"func\.func private @compute_group_histograms_"
+                           r"fused_(tiled|factored)(?:_\d+)?\(", text)
+        return {name: found.count(name) for name in set(found)}
+
+    narrow = [(63, {}), (15, {"bin_packing": "4bit"})]
     try:
-        gauge, text = tree_program(63)
-        assert gauge == "" and "fused_tiled" in text
-        assert "factored" not in text
-        gauge, text = tree_program(255)
-        assert gauge != "" and "factored" in text
+        texts = []
+        for max_bin, extra in narrow:
+            gauge, text, gr = tree_program(max_bin, **extra)
+            assert gauge == "" and gr.use_tiled and gr.use_fused
+            assert bool(gr.pack_P) == bool(extra)
+            assert kernels(text) == {"tiled": 3}
+            texts.append(text)
+        gauge, text, _ = tree_program(255)
+        assert gauge != ""
+        assert kernels(text) == {"factored": len(H.FACTORED_RUNGS)}
+        _, text, _ = tree_program(255, leaves=100)      # frontier 99
+        assert kernels(text) == {"tiled": 3, "factored": 5}
+        # with no rung in the table at all, the narrow programs are the same
+        monkeypatch.setattr(H, "FACTORED_RUNGS", ())
+        for (max_bin, extra), text in zip(narrow, texts):
+            assert tree_program(max_bin, **extra)[1] == text
     finally:
         TELEMETRY.configure("off")
         TELEMETRY.reset()

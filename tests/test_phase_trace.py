@@ -163,7 +163,7 @@ def _kernel_cases():
         (H.compute_group_histograms_fused_factored,
          (binsT, wT, scales, leaf,
           _s((L, ROUTE_FIXED_COLS + 32), jnp.float32),
-          _s((32,), jnp.int32)),
+          _s((126,), jnp.int32)),
          dict(max_group_bin=255, block=256, interpret=True, k_cap=k_cap,
               a=a))
         for k_cap, a, _ in H.FACTORED_RUNGS]
@@ -197,6 +197,8 @@ KERNEL_NAMES = [          # what a device trace showed before they were pinned
     "compute_group_histograms_fused_factored_k10_a2",   # from the start
     "compute_group_histograms_fused_factored_k16_a2",
     "compute_group_histograms_fused_factored_k32_a2",
+    "compute_group_histograms_fused_factored_k64_a2",   # PR 29: the wide
+    "compute_group_histograms_fused_factored_k126_a2",  # passes' rungs
 ]
 
 

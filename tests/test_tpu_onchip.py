@@ -234,19 +234,13 @@ def test_onchip_route_apply_tiled(case):
                                   np.asarray(want_val))
 
 
-def test_onchip_fused_factored_kernel():
-    """Every factored rung (ops/histogram.py FACTORED_RUNGS) against the
-    one-strip fused tiled kernel, 255 bins: the same leaf ids and the
-    same histogram to the bit.  The rungs build their int8 operands four
-    rows to a 32-bit word (pltpu.bitcast), whose byte order only the
-    chip can pin."""
-    from lightgbm_tpu.ops.histogram import (
-        FACTORED_RUNGS, compute_group_histograms_fused_factored,
-        compute_group_histograms_fused_tiled)
+def _factored_onchip_inputs(N, G, L, leaves):
+    """A table, leaf ids below ``leaves``, and a route table that moves
+    six of them to leaves ``leaves``..: the fused kernels' inputs."""
     rng = np.random.RandomState(2)
-    N, G, B, L = 16384, 13, 255, 40
+    B = 255
     binsT = jnp.asarray(rng.randint(0, B, (G, N)).astype(np.uint8))
-    leaf = jnp.asarray(rng.randint(-1, 36, N).astype(np.int32))
+    leaf = jnp.asarray(rng.randint(-1, leaves, N).astype(np.int32))
     wq, scales = quantize_gradients(
         jnp.asarray(rng.randn(N).astype(np.float32)),
         jnp.asarray(np.abs(rng.randn(N)).astype(np.float32)),
@@ -258,30 +252,54 @@ def test_onchip_fused_factored_kernel():
         jnp.asarray(rng.randint(0, G, L).astype(np.int32)),
         jnp.zeros(L, jnp.int32), jnp.full(L, B, jnp.int32),
         jnp.zeros(L, jnp.int32), jnp.full(L, B - 1, jnp.int32),
-        jnp.asarray(np.array([0, 1] * 20, bool)),
+        jnp.asarray(np.array([0, 1] * (L // 2), bool)),
         jnp.asarray(rng.randint(0, B, L).astype(np.int32)),
         jnp.asarray(rng.rand(L) > 0.5),
         jnp.asarray(rng.randint(0, 3, L).astype(np.int32)),
         jnp.asarray(rng.randint(0, 4, L).astype(np.int32)),
         jnp.full(L, B, jnp.int32),
         jnp.asarray(rng.rand(L, B) > 0.5),
-        jnp.asarray((np.arange(L) + 36).astype(np.int32) % L))
-    order = rng.permutation(36).astype(np.int32)
+        jnp.asarray((np.arange(L) + leaves).astype(np.int32) % L))
+    order = rng.permutation(leaves).astype(np.int32)
+    return (binsT, wq.T, scales, leaf, tab), order
+
+
+def test_onchip_fused_factored_kernel():
+    """Every factored rung (ops/histogram.py FACTORED_RUNGS) against the
+    fused tiled kernel on the strips its pass had, 255 bins: the same
+    leaf ids and the same histogram to the bit.  The rungs build their
+    int8 operands four rows to a 32-bit word (pltpu.bitcast), whose byte
+    order only the chip can pin.  The two wide rungs run at the 67
+    groups and the row block of the benchmark's cells: their
+    accumulators (13 and 26 MB there) live in VMEM, and whether the
+    chip's compiler grants that no interpreter can say."""
+    from lightgbm_tpu.ops.histogram import (
+        FACTORED_RUNGS, PACKED_STRIP,
+        compute_group_histograms_fused_factored,
+        compute_group_histograms_fused_tiled)
+    narrow = _factored_onchip_inputs(16384, 13, 40, 36)
+    wide = _factored_onchip_inputs(16384, 67, 160, 140)
     for k_cap, a, _ in FACTORED_RUNGS:
+        args, order = wide if k_cap > 32 else narrow
         slots = np.full(126, -1, np.int32)
         slots[:k_cap] = order[:k_cap]
         if k_cap > 2:
             slots[1] = -1
         slots = jnp.asarray(slots)
         want, want_leaf = compute_group_histograms_fused_tiled(
-            binsT, wq.T, scales, leaf, tab, slots, max_group_bin=B,
-            block=2048, strips=1)
-        got, got_leaf = compute_group_histograms_fused_factored(
-            binsT, wq.T, scales, leaf, tab, slots, max_group_bin=B,
-            block=4096, k_cap=k_cap, a=a)
-        np.testing.assert_array_equal(np.asarray(got_leaf),
-                                      np.asarray(want_leaf), err_msg=str(k_cap))
-        np.testing.assert_array_equal(np.asarray(got),
-                                      np.asarray(want)[:k_cap],
-                                      err_msg=str(k_cap))
-        assert float(jnp.abs(got).sum()) > 0
+            *args, slots, max_group_bin=255, block=2048,
+            strips=-(-k_cap // PACKED_STRIP))
+        for dequantize in (True, False) if k_cap > 32 else (True,):
+            got, got_leaf = compute_group_histograms_fused_factored(
+                *args, slots, max_group_bin=255, block=4096, k_cap=k_cap,
+                a=a, dequantize=dequantize)
+            np.testing.assert_array_equal(
+                np.asarray(got_leaf), np.asarray(want_leaf),
+                err_msg=str(k_cap))
+            if not dequantize:
+                assert got.dtype == jnp.int32
+                got = got.astype(jnp.float32) * args[2]
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want)[:k_cap],
+                                          err_msg=str(k_cap))
+            assert float(jnp.abs(got).sum()) > 0

@@ -104,14 +104,15 @@ def check(ok, msg):
 
 def grower_plan(grower):
     """The kernel plan a built grower resolved to."""
-    return {k: bool(getattr(grower, k)) for k in
-            ("use_pallas", "use_quant", "use_tiled", "use_fused",
-             "_interp")} | {"block": int(grower.pallas_block_tiled),
-                            "rows_padded": int(grower.n_padded)}
+    plan = grower.plan
+    return {"tier": plan.tier, "quantized": plan.quantized,
+            "fused": plan.fused, "interpret": plan.interpret,
+            "row_shards": plan.row_shards, "block": plan.block_tiled,
+            "rows_padded": int(grower.n_padded)}
 
 
 def leg_fast(lgb, X, y, Xv, yv, rounds, extra=None, interpret=False):
-    """Leg A.  ``interpret`` is what ``grower._interp`` must equal:
+    """Leg A.  ``interpret`` is what the plan's ``interpret`` must equal:
     False everywhere except the CPU plumbing test."""
     from bench import auc_score
     from lightgbm_tpu.backend import on_tpu
@@ -121,9 +122,10 @@ def leg_fast(lgb, X, y, Xv, yv, rounds, extra=None, interpret=False):
                     verbose_eval=False, keep_training_booster=True)
     g = bst.gbdt.grower
     plan = grower_plan(g)
-    check(g.use_pallas and g.use_quant and g.use_tiled and g.use_fused,
+    check(g.plan.tier == "ladder",
           f"leg A ran a downgraded kernel plan: {plan}")
-    check(g._interp is interpret, f"interpret seam is {g._interp}")
+    check(g.plan.interpret is interpret,
+          f"interpret seam is {g.plan.interpret}")
     check(bst.num_trees() == rounds, f"{bst.num_trees()} trees")
     tel = TELEMETRY.counters()
     check(tel.get("oom_downshifts", 0) == 0, f"oom downshifts: {tel}")
@@ -247,8 +249,8 @@ def leg_multichip(lgb, X, y, Xv, rounds, n_chips=4, extra=None,
           f"mesh is {g.policy.mesh}")
     plan = grower_plan(g)
     gauges = TELEMETRY.gauges()
-    check(g.use_quant and g.use_tiled and g.use_fused
-          and g.row_shards == n_chips and g._interp is interpret,
+    check(g.plan.tier == "ladder" and g.plan.row_shards == n_chips
+          and g.plan.interpret is interpret,
           f"leg D ran a downgraded kernel plan under the mesh: {plan}")
     check(gauges.get("grower.quantized") == 1
           and gauges.get("grower.hist_kernel") == "fused_tiled",

@@ -330,7 +330,7 @@ class ProgramSet:
                                  hist_kernel="pallas",
                                  force_pallas_interpret=True,
                                  max_bin=15).grower
-            assert g.use_quant, (
+            assert g.plan.quantized, (
                 "tiered probe did not plan onto the quantized "
                 "kernels — HLO009 would be checking the wrong program")
             zeros = np.zeros(g.n_padded, np.float32)

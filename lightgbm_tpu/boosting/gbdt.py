@@ -117,7 +117,7 @@ class GBDT:
                 lambda a: self.grower.policy.place_rows(
                     self.grower.pad_rows(a)))
         elif self.objective is not None \
-                and self.grower._mesh_kernels:
+                and self.grower.plan.mesh_kernels:
             # one host's mesh on the kernel path: the objective's
             # per-row arrays live on the mesh, beside the rows they
             # belong to (an array left on one device is sent to every
@@ -477,7 +477,7 @@ class GBDT:
         recipe; REQUIRED by skewed-gradient objectives like lambdarank
         — see ops/histogram.py quantize_gradients).  Auto mode defers
         to the objective's need_stochastic_quant."""
-        if not self.grower.use_quant:
+        if not self.grower.plan.quantized:
             return False
         mode = int(getattr(self.config, "quant_stochastic_rounding",
                            -1))

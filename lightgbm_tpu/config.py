@@ -349,10 +349,10 @@ class Config:
     # device HBM and the histogram kernels' read stream all halve
     # (requires max_bin <= 16; trees are byte-identical to the 8-bit
     # path on every packed-capable kernel route — tiled/fused/
-    # streamed-one-hot/XLA, i.e. every default selection; the two
-    # Pallas formulations without a packed input path, paired and
-    # otf-int8, fall back to XLA with a loud warning and only
-    # f32-level parity); "auto" is adaptive precision — groups whose
+    # streamed-one-hot/XLA, i.e. every default selection; the one
+    # Pallas formulation without a packed input path, the float
+    # tier's expansion kernel, falls back to XLA with a loud warning
+    # and only f32-level parity); "auto" is adaptive precision — groups whose
     # fitted bin count fits 4 bits pack even when others don't, via a
     # two-section (packed + wide) layout, and <=2-bit groups tighten
     # further to crumbs; "2bit" crumb-packs four <=4-bin groups per
@@ -424,22 +424,12 @@ class Config:
     # binary bench shape but measurably hurts lambdarank NDCG at 255
     # leaves; growth order near the leaf cap is a documented,
     # quality-bounded deviation from one-split-at-a-time)
-    hist_kernel: str = "auto"       # auto | pallas | paired | xla
-    # (pallas/paired raise where the Pallas path cannot run: a mesh,
-    # or no TPU and no force_pallas_interpret)
-    hist_packed_dispatch: bool = True  # lax.cond to the channel-packed
-    # kernel on narrow frontiers (off: always the full-width kernel)
-    pallas_hist_block: int = 2048   # rows per Pallas histogram block
-    # (streamed-one-hot kernels; the 3.6 MB/block DMA prefers 2048)
-    pallas_hist_block_tiled: int = 0  # rows per block for the
-    # tiled-iota kernels, whose HBM stream is only the (G, N) packed
-    # bins (~0.2 MB/block): larger blocks amortize the in-VMEM one-hot
-    # rebuild, but the (m_pad, hist_width) int32 output block lives in
-    # scoped VMEM so wide-G shapes want smaller row blocks.  0 = auto:
-    # keep block*width near the measured 8192*1792 sweet spot, clamped
-    # to [2048, 8192] (8192 at the 28-feature bench shape: 25.9 vs
-    # 26.5 ms/tree; 2048 at 136 features: 288 vs 308), then the
-    # largest power-of-two block dividing the padded row count
+    hist_kernel: str = "auto"       # auto | pallas | xla
+    # (auto: what ops/hist_plan.py resolves from the backend, the mesh,
+    # hist_compute_dtype and quantized_grad; pallas raises where the
+    # Pallas kernels cannot run: a feature / voting / multi-axis mesh,
+    # no TPU and no force_pallas_interpret, rows not padded to 1024 a
+    # shard)
     quantized_grad: bool = False    # int8-MXU quantized histogram
     # construction (one grad/hess scale per tree; the TPU analog of
     # LightGBM v4 quantized training, arXiv 2207.09682) — TPU path only
@@ -478,44 +468,6 @@ class Config:
     # (num_leaves, G, B, 3) f32 cache exceeds the bound, the grower
     # drops histogram subtraction and computes BOTH children of every
     # split directly from the data (2x histogram passes, no cache).
-    hist_onehot_budget_mb: int = 6144  # HBM budget for the resident
-    # streamed bin one-hot; datasets over budget (at every pack) rebuild
-    # the one-hot in-kernel per round instead.  6 GB leaves ~9 GB of a
-    # 16 GB v5e for bins/scores/gradients/temps — HIGGS scale (10.5M
-    # rows) needs 5.4 GB at pack=4
-    hist_onehot_pack: int = 0       # one-hot columns per stored byte
-    # (planar sub-byte packing, widened in-VMEM by the kernels): 1, 2
-    # or 4; 0 = auto — the largest pack dividing G*B that fits the
-    # budget, which both cuts the per-pass HBM stream and lets
-    # HIGGS-scale (10.5M-row) one-hots stay resident on a 16 GB chip
-    hist_quant_onthefly: bool = True  # quantized path: rebuild the bin
-    # one-hot in-kernel (packed int8 lanes) instead of streaming the
-    # (N, G*B) one-hot from HBM — B x less HBM traffic per round
-    hist_fused_route: bool = True   # apply pending split routing inside
-    # the next round's histogram kernel (single chip, streamed one-hot)
-    # instead of a separate XLA routing pass per round
-    hist_split_route: bool = False  # tiled path: run the pending split
-    # routing as its own Pallas pass (route_only_tiled) and keep every
-    # histogram pass route-free, instead of fusing the route into the
-    # first histogram pass — same deferred-route semantics, different
-    # kernel decomposition (perf A/B; see docs/ROOFLINE.md)
-    hist_kernel_tiled: bool = True  # quantized path: tiled-iota in-VMEM
-    # one-hot rebuild (no resident one-hot at all — HBM stream is just
-    # the transposed packed bins).  Measured at the MXU floor
-    # (~1.6 ms/pass at 1M x 28 x 63 on v5e), faster than streaming a
-    # precomputed one-hot and pack-free; False restores the round-3
-    # streamed/packed kernel ladder
-    hist_leaf_partition: str = "auto"  # leaf-partitioned histogram
-    # formulation (the reference DataPartition insight under static
-    # shapes): per round, rows are physically regrouped so each
-    # frontier leaf's rows are contiguous block-aligned segments and
-    # the histogram kernel runs one (8, C) weight-strip dot per block
-    # — no leaf one-hot, no 128/3 wasted systolic rows.  "on" forces
-    # it (requires the tiled quantized single-chip path), "off"
-    # disables, "auto" currently resolves OFF: the per-round
-    # permutation maintenance costs more than the MXU rows it frees on
-    # this hardware generation (measured decomposition:
-    # docs/PARTITION_DESIGN.md round-6 record)
     dispatch_chunk: str = "auto"    # boosting iterations fused into ONE
     # device program (lax.scan) during headless training stretches: an
     # integer pins the chunk length; "auto" re-fits the per-iteration
@@ -980,10 +932,6 @@ class Config:
                                            or self.bagging_fraction >= 1.0):
             raise ValueError("RF must use bagging "
                              "(bagging_freq > 0, bagging_fraction < 1)")
-        if str(self.hist_leaf_partition).lower() not in (
-                "auto", "on", "off", "true", "false", "1", "0"):
-            raise ValueError("hist_leaf_partition must be auto/on/off, "
-                             f"got {self.hist_leaf_partition!r}")
         if str(self.packed_tree_carry).lower() not in (
                 "auto", "on", "off", "true", "false", "1", "0"):
             raise ValueError("packed_tree_carry must be auto/on/off, "

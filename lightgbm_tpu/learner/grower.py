@@ -49,19 +49,16 @@ import numpy as np
 from ..backend import on_tpu
 from ..config import Config
 from ..dataset import Dataset
-from ..ops.histogram import (PACKED_STRIP, check_quant_rows,
-                             compute_group_histograms,
+from ..ops.hist_plan import LADDER_WIDTH, resolve_hist_plan
+from ..ops.histogram import (PACKED_STRIP, compute_group_histograms,
                              compute_group_histograms_fused,
                              compute_group_histograms_pallas,
-                             compute_group_histograms_pallas_paired,
-                             compute_group_histograms_pallas_q,
                              compute_group_histograms_pre,
                              compute_group_histograms_pre_packed,
-                             compute_group_histograms_q_packed,
                              compute_leaf_totals, expand_feature_histograms,
                              precompute_bin_onehot,
                              precompute_bin_onehot_packed,
-                             quant_rows_ok, quantize_gradients)
+                             quantize_gradients)
 from ..ops.partition import (apply_route_table, apply_splits,
                              build_route_table)
 from ..ops.split import (CAND_CAT_DIR, CAND_COLS, CAND_DEFAULT_LEFT,
@@ -130,14 +127,14 @@ class GrowerState(NamedTuple):
     done: jax.Array
     leaf_sum_grad: jax.Array
     leaf_sum_hess: jax.Array
-    leaf_count: jax.Array        # (L,) f32, or int32 (_int_counts)
+    leaf_count: jax.Array        # (L,) f32, or int32 (plan.int_counts)
     leaf_min_c: jax.Array
     leaf_max_c: jax.Array
     leaf_is_left: jax.Array      # (L,) bool — side under its parent
     leaf_forced: jax.Array       # (L,) int32 forced-split spec idx (-1 none)
     tree: TreeArrays
     hist_cache: jax.Array        # (L, G, Bg, 3) f32 — per-leaf group hists
-    # (under _int_counts hist_cache, cand and forced_cand are each a pair
+    # (under plan.int_counts hist_cache, cand and forced_cand are each a pair
     # of the array named here and its int32 row counts: (L, G, Bg), (L,))
     cand: jax.Array              # (L, CAND_COLS + Bf) f32 — the packed
     # best_split_per_leaf_ cache (reference serial_tree_learner.h +
@@ -252,7 +249,7 @@ class TreeGrower:
         # default stays at the widest packed ladder and the knob is
         # left to users who know their task tolerates it.
         self.frontier = min(config.num_leaves - 1,
-                            config.frontier_width or 126)
+                            config.frontier_width or LADDER_WIDTH)
         # frontier ladder for the split finder (round 7, ROOFLINE
         # headroom #2): run the finder + candidate scatter at the
         # narrowest packed-strip width covering the ACTIVE frontier —
@@ -350,319 +347,52 @@ class TreeGrower:
                 # the stage ends when the matrix is on the device;
                 # nothing but Python overlaps the transfer today
                 jax.block_until_ready((self.bins, self._row_valid))
-        # the Pallas kernel path: one TPU device, or the row shards of
-        # a one-axis data mesh, each of which runs the single-device
-        # kernels on its own rows inside shard_map (_on_row_shards) and
-        # adds its int32 accumulators to the others' exactly.  The XLA
-        # formulation stays for CPU simulation, feature / voting /
-        # multi-axis / multi-host meshes (where the sharded contraction
-        # lowers to a reduce-scatter), and float32 operand parity (the
-        # kernel runs bf16 operands, the analog of the reference GPU
-        # learner's single-precision default, gpu_tree_learner.cpp:73-77)
+        # which histogram kernels run: decided once, from facts, by
+        # ops/hist_plan.py (the only reader of hist_kernel,
+        # hist_precision and hist_exchange)
         from ..utils.log import Log
         mesh = self.policy.mesh
-        self._row_axis = None
-        if (mesh is not None and len(mesh.axis_names) == 1
-                and self.policy.row_spec is not None
-                and getattr(self.policy, "bins_spec", None) is None
-                and config.tree_learner in ("data", "serial")
-                and self._mh_local is None
-                and not self.policy.multihost):
-            self._row_axis = mesh.axis_names[0]
-        self.row_shards = mesh.size if self._row_axis is not None else 1
-        # every size rule of the kernels is a shard's: its rows bound
-        # the int32 accumulator and its rows are what a block divides
-        self.local_rows = self.n_padded // self.row_shards
-        hk = getattr(config, "hist_kernel", "auto")
-        if hk not in ("auto", "pallas", "paired", "xla"):
-            Log.warning(f"unknown hist_kernel={hk!r}; using 'auto'")
-            hk = "auto"
-        # test seam: interpret-mode Pallas on CPU exercises the SAME
-        # grower wiring (fused route carry, quant transpose, exit-time
-        # route application) the real chip runs
-        self._interp = bool(getattr(config, "force_pallas_interpret",
-                                    False))
-        pallas_ok = (
-            (mesh is None or self._row_axis is not None)
-            and (on_tpu() or self._interp)
-            and self.n_padded % (1024 * self.row_shards) == 0)
-        unhonourable = (
-            f"hist_kernel={hk} cannot run here: it needs a single TPU "
-            "device or a one-axis data mesh of them, and rows padded "
-            "to 1024 a shard — use hist_kernel=auto, or "
-            "force_pallas_interpret for the CPU test seam")
-        if hk in ("pallas", "paired") and not pallas_ok:
-            # an explicit kernel request that cannot be honoured is an
-            # error, not a quiet XLA run under the Pallas kernel's name
-            raise ValueError(unhonourable)
-        self.use_pallas = pallas_ok and (
-            hk in ("pallas", "paired")
-            or (hk == "auto" and config.hist_compute_dtype == "bfloat16"))
-        # "paired" (per-group-pair dots, no expansion matmul) benched
-        # slower than the expansion kernel on v5e; kept as an option
-        self.pallas_paired = self.use_pallas and hk == "paired"
-        blk = int(getattr(config, "pallas_hist_block", 2048))
-        self.pallas_block = blk if self.local_rows % blk == 0 else 1024
-        # tiled-iota kernels stream ~G bytes/row instead of the G*B-byte
-        # one-hot, so their per-block fixed cost (route decode, iota
-        # rebuild) wants much larger blocks than the streamed kernels'
-        # DMA-tuned 2048 — but the (m_pad, hist_width) int32 output
-        # block lives in scoped VMEM, so wide-G shapes must shrink the
-        # row block again.  Measured on v5e: G*B_pad=1792 (28 feats,
-        # 63 bins) wants 8192 (25.9 vs 26.5 ms/tree); 8704 (136 feats)
-        # wants 2048 (288 vs 308 ms/tree).  Auto keeps block*width
-        # near the 8192*1792 sweet spot, clamped to [2048, 8192].
-        tblk = int(getattr(config, "pallas_hist_block_tiled", 0) or 0)
-        if not tblk:
-            from ..ops.histogram import tiled_hist_width
-            width = tiled_hist_width(self.num_groups, self.max_group_bin)
-            tblk = 2048
-            while tblk < 8192 and (2 * tblk) * width <= 8192 * 1792 * 2:
-                tblk *= 2
-        self.pallas_block_tiled = 1024
-        for cand in (tblk, 8192, 4096, 2048, 1024):
-            if cand <= self.local_rows and self.local_rows % cand == 0:
-                self.pallas_block_tiled = cand
-                break
-        # precision tier (hist_precision): "tiered" forces the int32
-        # quantized-weight accumulation path (narrow per-leaf
-        # accumulators + the f32 dequantize fix-up before split
-        # finding), "f32" forces full-precision accumulation, "auto"
-        # follows quantized_grad exactly as before (byte-identical
-        # trees by construction).  The overflow bound lives in ONE
-        # place — ops/histogram.check_quant_rows, next to the kernel
-        # it protects — and "tiered" turns it into a loud kernel-plan
-        # error instead of a silent fallback.
-        self.hist_precision = str(getattr(config, "hist_precision",
-                                          "auto")).lower()
-        # cross-shard histogram exchange codec (the _hist_xla_rowsharded
-        # psum window); resolved here so the compiled step's lowering is
-        # fixed at plan time
-        self.hist_exchange = str(getattr(config, "hist_exchange",
-                                         "f32")).lower()
-        if self.hist_precision == "tiered":
-            check_quant_rows(self.local_rows, what="hist_precision=tiered")
-        want_quant = (getattr(config, "quantized_grad", False)
-                      or self.hist_precision == "tiered")
-        if self.hist_precision == "f32":
-            if want_quant:
-                Log.warning("hist_precision=f32: quantized_grad ignored "
-                            "— histograms accumulate float32")
-            want_quant = False
-        # int8 quantized training (see _hist_kernel_body_q): histogram
-        # matmuls on the int8 MXU with one grad/hess scale per tree.
-        # The int32 accumulator bounds a device's rows at N*127 < 2^31.
-        self.use_quant = self.use_pallas and not self.pallas_paired \
-            and want_quant and quant_rows_ok(self.local_rows)
-        if want_quant and self.use_pallas \
-                and not self.use_quant and not self.pallas_paired:
-            Log.warning("quantized_grad disabled: dataset exceeds the "
-                        "int32 histogram accumulator bound (~16.9M rows "
-                        "a device)")
-        if self.hist_precision == "tiered" and not self.use_quant:
-            raise ValueError(
-                "hist_precision=tiered cannot run here: the quantized "
-                "accumulation tier needs the Pallas histogram path "
-                "(hist_compute_dtype=bfloat16 or hist_kernel=pallas on "
-                "a single TPU device or a one-axis row mesh); use "
-                "hist_precision=auto or f32")
-        # quantized frontier kernels rebuild the bin one-hot in VMEM
-        # from the packed bins (~G bytes/row of HBM traffic instead of
-        # the G*B-byte streamed one-hot) — the cheapest formulation
-        # measured on v5e
-        self.use_quant_otf = self.use_quant and getattr(
-            config, "hist_quant_onthefly", True) and not self.pack_P
-        # streamed-one-hot histogram path: materialize the (N, G*B)
-        # int8 bin one-hot once (it is constant for the whole training
-        # run) and stream it through the kernel instead of rebuilding
-        # it from the packed bins every round.  Gated on an HBM budget.
-        # Sub-byte packing (hist_onehot_pack) stores `pack` one-hot
-        # columns per byte (planar layout, widened in-VMEM): pack-x
-        # less HBM footprint AND per-pass stream — at 10.5M x 28 x 63
-        # the full one-hot is 17.2 GB (over a 16 GB v5e) while pack=4
-        # is 4.3 GB and stays resident.
-        gbtot = self.num_groups * self.max_group_bin
-        budget = int(getattr(config, "hist_onehot_budget_mb", 4096)) << 20
-
-        from ..ops.histogram import _round_up
-
-        def _ohb_bytes(p):
-            width = gbtot if p == 1 else _round_up(gbtot // p, 128)
-            return self.n_padded * width
-
-        pk_cfg = int(getattr(config, "hist_onehot_pack", 0) or 0)
-        if pk_cfg in (1, 2, 4) and gbtot % pk_cfg == 0:
-            self.ohb_pack = pk_cfg
-        else:
-            if pk_cfg:
-                Log.warning(f"hist_onehot_pack={pk_cfg} invalid for "
-                            f"G*B={gbtot}; auto-selecting")
-            # auto: the pack with the smallest resident/streamed bytes;
-            # ties break toward the SMALLER pack (less 128-lane plane
-            # padding waste — for small G*B packing is a pessimization
-            # and this reduces to pack=1)
-            self.ohb_pack = min(
-                (p for p in (1, 2, 4) if gbtot % p == 0),
-                key=lambda p: (_ohb_bytes(p), p))
-        ohb_bytes = _ohb_bytes(self.ohb_pack)
-        # tiled-iota kernel (quantized single chip, round 4): the bin
-        # one-hot is rebuilt in VMEM per 128-lane tile — measured at
-        # the MXU floor on v5e, so the resident streamed one-hot (and
-        # its precompute + HBM budget gating) is obsolete on this path
-        self.use_tiled = (self.use_quant and self.frontier
-                          <= 3 * PACKED_STRIP
-                          and getattr(config, "hist_kernel_tiled", True))
-        # fused route+histogram kernel (single chip): the pending split
-        # routing is applied INSIDE the next round's histogram pass, so
-        # the separate per-round apply_splits pass disappears.  Needs a
-        # frontier that fits the packed strip ladder, and (non-tiled)
-        # the streamed one-hot (HBM budget).
-        self.use_fused = (self.use_pallas and not self.pallas_paired
-                          and self.frontier <= 3 * PACKED_STRIP
-                          and (self.use_tiled or ohb_bytes <= budget)
-                          and getattr(config, "hist_fused_route", True))
-        # split-route variant of the tiled fused path: routing runs as
-        # its own Pallas pass and every histogram pass is the plain
-        # (route-free) tiled kernel — same deferred-route semantics,
-        # different kernel decomposition (A/B knob; see ROOFLINE)
-        self.split_route = (self.use_tiled and self.use_fused
-                            and mesh is None
-                            and getattr(config, "hist_split_route",
-                                        False))
-        if getattr(config, "hist_split_route", False) \
-                and not self.split_route:
-            raise ValueError(
-                "hist_split_route cannot run here: it needs the tiled "
-                "fused path (quantized_grad on a single TPU device, no "
-                "mesh, frontier within the packed ladder)")
-        # leaf-partitioned formulation (reference DataPartition insight,
-        # data_partition.hpp:109-161, under static shapes): rows are
-        # physically regrouped into block-aligned per-leaf segments each
-        # round and the histogram kernel runs an (8, C) weight-strip dot
-        # per block — no leaf one-hot, 16x less MXU work per streamed
-        # byte.  "auto" resolves OFF: the per-round permutation
-        # maintenance (XLA sort + row gathers) costs more than the MXU
-        # rows the segment dot frees — the measured decomposition is
-        # docs/PARTITION_DESIGN.md's round-6 record; the knob stays for
-        # on-chip A/B and for a future Mosaic dynamic-lane-gather
-        lp = str(getattr(config, "hist_leaf_partition", "auto")).lower()
-        want_lp = lp in ("on", "true", "1")
-        self.leaf_part = (want_lp and self.use_tiled and self.use_fused
-                          and mesh is None)
-        if want_lp and not self.leaf_part:
-            raise ValueError(
-                "hist_leaf_partition=on cannot run here: it needs the "
-                "tiled fused path (quantized_grad on a single TPU "
-                "device, frontier within the packed ladder)")
-        # partition granularity = segment alignment unit = seg-kernel
-        # row block: small blocks waste less alignment capacity
-        # (num_leaves+1 buckets each pad up to one block), large blocks
-        # amortize the per-block fixed costs.  512 always divides
-        # n_padded here — the tiled path this rides on requires
-        # n_padded % 1024 == 0 (pallas_ok above)
-        self.leaf_part_block = 512
-        self.use_quant_otf = (self.use_quant_otf and not self.use_fused
-                              and not self.use_tiled)
-        self.use_pre_ohb = (self.use_pallas and not self.pallas_paired
-                            and not self.use_quant_otf
-                            and not self.use_tiled
-                            and ohb_bytes <= budget)
-        if self.use_pallas and not self.use_tiled and ohb_bytes > budget:
-            Log.warning(
-                f"resident one-hot ({ohb_bytes >> 20} MB at pack="
-                f"{self.ohb_pack}) exceeds hist_onehot_budget_mb="
-                f"{budget >> 20}; using the slower on-the-fly rebuild "
-                "(see docs/ROOFLINE.md regime table)")
-        if self.pack_P and self.use_pallas and not (
-                self.use_tiled or self.use_fused or self.use_pre_ohb):
-            # the remaining Pallas formulations (expansion-matmul /
-            # paired / on-the-fly int8) rebuild their one-hots
-            # straight from byte-wide group columns; nibble-packed
-            # datasets route to the packed-capable kernels above or —
-            # here — the XLA formulation, which widens per chunk.
-            # NOTE: this changes the histogram formulation (and drops
-            # int8 quantization if it was selected), so trees on THIS
-            # config are not guaranteed byte-identical to the same
-            # config at bin_packing=8bit — the byte-identity guarantee
-            # is scoped to the packed-capable routes (tiled / fused /
-            # streamed-one-hot / XLA), which cover every default
-            # kernel selection
-            Log.warning(
-                "bin_packing: the selected Pallas histogram kernel "
-                "has no nibble-packed input path; using the XLA "
-                "histogram formulation for this packed dataset"
-                + (" (int8 quantized training disabled — expect "
-                   "f32-accumulation trees, not byte-identical to "
-                   "this config under bin_packing=8bit)"
-                   if self.use_quant else
-                   " (different f32 accumulation order than the "
-                   "selected kernel — trees may differ in ulps from "
-                   "this config under bin_packing=8bit)"))
-            self.use_pallas = False
-            self.pallas_paired = False
-            self.use_quant = False
-            self.use_quant_otf = False
-        if mesh is not None and self.use_pallas:
-            # a row mesh runs ONE kernel plan, the quantized fused tiled
-            # ladder: its accumulators are integers, so the shards' sum
-            # is exact and the trees are the single device's.  Every
-            # other Pallas formulation keeps to one device
-            explicit = hk in ("pallas", "paired") \
-                or self.hist_precision == "tiered"
-            ladder = self.use_quant and self.use_tiled and self.use_fused
-            if not ladder and explicit:
-                raise ValueError(
-                    unhonourable + "; under a mesh only the "
-                    "quantized fused ladder runs (quantized_grad, "
-                    "byte-wide bins, frontier_width <= "
-                    f"{3 * PACKED_STRIP})")
-            if ladder and self.hist_exchange != "f32" and explicit:
-                raise ValueError(
-                    f"hist_exchange={self.hist_exchange} cannot run "
-                    "here: the kernel path under a mesh sums int32 "
-                    "accumulators exactly and has no codec — drop it, "
-                    "or use hist_kernel=xla")
-            if not ladder or self.hist_exchange != "f32":
-                # hist_kernel=auto: what the ladder cannot honour runs
-                # on the XLA path, where the codec lives, as before
-                self.use_pallas = self.pallas_paired = False
-                self.use_quant = self.use_quant_otf = False
-                self.use_tiled = self.use_fused = False
-                self.use_pre_ohb = False
-        #: the fused ladder runs once per row shard, inside shard_map
-        self._mesh_kernels = mesh is not None and self.use_fused
-        #: row counts are int32 from the exact cross-shard sum to the
-        #: tree: float32 counts integers to 2^24, one device's rows, and
-        #: the shards of a mesh hold more between them (every histogram
-        #: then travels with its int32 count channel beside it, as a
-        #: pair; ``jax.tree_util.tree_map`` treats both alike)
-        self._int_counts = self._mesh_kernels
-        self.ohb = None
+        self.plan = resolve_hist_plan(
+            config, on_tpu=on_tpu(),
+            mesh_axes=None if mesh is None else tuple(
+                zip(mesh.axis_names, mesh.devices.shape)),
+            row_axis=(self.policy.row_spec[0]
+                      if mesh is not None
+                      and self.policy.row_spec is not None else None),
+            cols_sharded=getattr(self.policy, "bins_spec",
+                                 None) is not None,
+            multihost=(self._mh_local is not None
+                       or self.policy.multihost),
+            rows_padded=self.n_padded, num_groups=self.num_groups,
+            max_group_bin=self.max_group_bin, packed_groups=self.pack_P,
+            frontier=self.frontier)
+        for message in self.plan.warnings:
+            Log.warning(message)
         # transposed on DEVICE from the already-uploaded bins: a host
         # transpose + second upload of the (N, G) matrix doubles the
         # host->device traffic at the 10.5M scale
         self.binsT = None
-        if self.use_fused or self.use_tiled:
+        if self.plan.fused:
             with TELEMETRY.stage("binsT"):
                 self.binsT = jnp.transpose(self.bins)
                 if TELEMETRY.on:
                     jax.block_until_ready(self.binsT)
         self._route_cols = 15 + (self.max_feature_bin + 7) // 8
-        # trace-scoped override: callers thread the one-hot through
-        # their jit boundary as an ARGUMENT (a multi-hundred-MB closure
-        # constant sends XLA's constant-folding passes into minutes of
-        # compile time); _train_tree_impl pins the traced value here for
-        # the dynamic extent of its trace
+        # the float tier's resident one-hot.  Trace-scoped override:
+        # callers thread it through their jit boundary as an ARGUMENT (a
+        # multi-hundred-MB closure constant sends XLA's constant-folding
+        # passes into minutes of compile time); _train_tree_impl pins
+        # the traced value here for the dynamic extent of its trace
+        self.ohb = None
         self._ohb_arg = None
-        if self.use_pre_ohb:
-            if self.ohb_pack == 1:
-                self.ohb = precompute_bin_onehot(
-                    self.bins, max_group_bin=self.max_group_bin,
-                    packed_groups=self.pack_P)
-            else:
-                self.ohb = precompute_bin_onehot_packed(
-                    self.bins, max_group_bin=self.max_group_bin,
-                    pack=self.ohb_pack, packed_groups=self.pack_P)
+        if self.plan.onehot_pack == 1:
+            self.ohb = precompute_bin_onehot(
+                self.bins, max_group_bin=self.max_group_bin,
+                packed_groups=self.pack_P)
+        elif self.plan.onehot_pack:
+            self.ohb = precompute_bin_onehot_packed(
+                self.bins, max_group_bin=self.max_group_bin,
+                pack=self.plan.onehot_pack, packed_groups=self.pack_P)
         self._is_voting = (self.policy.mesh is not None
                            and config.tree_learner == "voting")
         # feature-parallel shard_map path: vertical partition with a
@@ -682,21 +412,6 @@ class TreeGrower:
             # packed byte straddles two logical groups, so packed
             # datasets take the constraint-sharded fallback instead
             and self.pack_P == 0)
-        # factored rungs of the fused tiled ladder: in force only
-        # where a group fills a 256-lane tile (max_group_bin > 128)
-        self.hist_factored_rungs = ()
-        if self.use_tiled and self.use_fused:
-            from ..ops.histogram import factored_rungs
-            self.hist_factored_rungs = factored_rungs(self.max_group_bin,
-                                                      self.pack_P)
-        # their accumulator is a whole-array output block, which XLA
-        # keeps in VMEM outside the kernel's scoped allocation (26 MB at
-        # 126 slots x 67 groups), so they take the row block the strips
-        # cannot (v5e, 2^24 x 67 x 255 bins: 4096 is 6-9% a pass under
-        # 2048 on the narrow rungs and 1-2% on the wide ones; 8192 adds
-        # under 2% up to 64 slots and loses 10% at 126)
-        self.pallas_block_factored = (
-            4096 if self.local_rows % 4096 == 0 else self.pallas_block_tiled)
         self._train_tree = jax.jit(self._train_tree_impl)
         if TELEMETRY.on:
             # the grower's resolved kernel plan as gauges: the fused
@@ -704,26 +419,13 @@ class TreeGrower:
             # compiled program), so telemetry records WHAT was selected
             # — device time per phase comes from a profiler trace and
             # the tel.<phase> scopes (docs/OBSERVABILITY.md)
-            if self.leaf_part:
-                hk = "seg_tiled(leaf_partition)"
-            elif self.use_tiled:
-                hk = "fused_tiled" if self.use_fused else "q_tiled"
-            elif self.use_fused:
-                hk = "fused_streamed"
-            elif self.use_quant_otf:
-                hk = "q_onthefly"
-            elif self.use_pre_ohb:
-                hk = "pre_onehot"
-            elif self.use_pallas:
-                hk = "pallas_paired" if self.pallas_paired else "pallas"
-            else:
-                hk = "xla"
-            TELEMETRY.gauge("grower.hist_kernel", hk)
+            plan = self.plan
+            TELEMETRY.gauge("grower.hist_kernel", plan.kernel)
             TELEMETRY.gauge("grower.hist_factored_rungs", ",".join(
-                f"{k}:{a}x{b}" for k, a, b in self.hist_factored_rungs))
-            TELEMETRY.gauge("grower.quantized", int(self.use_quant))
+                f"{k}:{a}x{b}" for k, a, b in plan.factored_rungs))
+            TELEMETRY.gauge("grower.quantized", int(plan.quantized))
             TELEMETRY.gauge("grower.hist_precision",
-                            "tiered" if self.use_quant else "f32")
+                            "tiered" if plan.quantized else "f32")
             # resolved device bin-matrix footprint: rows_padded x
             # storage byte columns — THE gauge the compact-bins
             # acceptance measures (<= 0.55x of 8-bit at max_bin=15,
@@ -739,18 +441,14 @@ class TreeGrower:
                             int(self.split_ladder))
             TELEMETRY.gauge("grower.frontier_width", int(self.frontier))
             TELEMETRY.gauge("grower.rows_padded", int(self.n_padded))
-            TELEMETRY.gauge("grower.row_shards", int(self.row_shards))
-            TELEMETRY.gauge("grower.local_rows", int(self.local_rows))
+            TELEMETRY.gauge("grower.row_shards", int(plan.row_shards))
+            TELEMETRY.gauge("grower.local_rows", int(plan.local_rows))
             # what one shard puts into the cross-shard sum of the
             # widest pass: (W, G, B, 3) int32, twice as two limbs
-            limbs = 1
-            if self._mesh_kernels:
-                from ..parallel.collectives import int_exchange_fits_int32
-                limbs = 1 if int_exchange_fits_int32(self.n_padded) else 2
             TELEMETRY.gauge(
                 "grower.hist_exchange_bytes_widest",
                 int(self.frontier * self.num_groups * self.max_group_bin
-                    * 3 * 4 * limbs) if self._mesh_kernels else 0)
+                    * 3 * 4 * plan.exchange_limbs))
 
     # ------------------------------------------------------------------
     def _load_forced_splits(self, dataset: Dataset, config: Config) -> None:
@@ -888,47 +586,23 @@ class TreeGrower:
                                 self._row_valid, qkey)
 
     # ------------------------------------------------------------------
-    def _hist_kernel(self, grad, hess, counts, leaf_id, slots=None,
-                     num_leaves=None, quant=None):
-        """Frontier histogram dispatch of the non-fused plans: Pallas on
-        one chip, XLA one-hot contraction under meshes / CPU simulation
-        (the fused ladder, which a row mesh runs too, is
-        ``_hist_kernel_fused``).  The
-        ``tel.histogram`` scope (op metadata, at every telemetry mode)
-        lets a profiler trace attribute the device events to it."""
+    def _hist_kernel(self, grad, hess, counts, leaf_id, slots):
+        """Frontier histogram dispatch of the plans whose route does not
+        ride the pass: the float tier's kernels on one chip, the XLA
+        one-hot contraction under meshes / CPU simulation (the fused
+        passes are ``_hist_kernel_fused``).  The ``tel.histogram`` scope
+        (op metadata, at every telemetry mode) lets a profiler trace
+        attribute the device events to it."""
         with TELEMETRY.phase("histogram"):
             return self._hist_kernel_impl(grad, hess, counts, leaf_id,
-                                          slots, num_leaves, quant)
+                                          slots)
 
-    def _hist_kernel_impl(self, grad, hess, counts, leaf_id, slots=None,
-                          num_leaves=None, quant=None):
-        L = self.num_leaves if num_leaves is None else num_leaves
-        if quant is not None and TELEMETRY.on:
-            # trace-time accounting (the _note_collective pattern):
-            # every quantized histogram pass ends in an f32 dequantize
-            # fix-up before split finding — inside jit this counts
-            # once per trace, i.e. "fix-up passes per compiled step"
-            TELEMETRY.add("hist_quant_fixup", 1)
-        if quant is not None and self.use_tiled:
-            return self._hist_kernel_q_tiled(leaf_id, slots, quant)
-        if quant is not None and self.use_quant_otf:
-            return self._hist_kernel_q_otf(leaf_id, slots, L, quant)
-        if self.use_pre_ohb:
-            return self._hist_kernel_pre(grad, hess, counts, leaf_id,
-                                         slots, L, quant)
-        if quant is not None:
-            wq, scales = quant
-            return compute_group_histograms_pallas_q(
-                self.bins, wq, scales, leaf_id,
-                num_leaves=L, max_group_bin=self.max_group_bin,
-                slots=slots)
-        if self.use_pallas:
-            if self.pallas_paired:
-                # lower VMEM footprint permits the larger row block
-                return compute_group_histograms_pallas_paired(
-                    self.bins, grad, hess, counts, leaf_id,
-                    num_leaves=L, max_group_bin=self.max_group_bin,
-                    slots=slots, block=self.pallas_block)
+    def _hist_kernel_impl(self, grad, hess, counts, leaf_id, slots):
+        L = self.num_leaves
+        if self.plan.tier == "float":
+            if self.plan.onehot_pack:
+                return self._hist_kernel_pre(grad, hess, counts, leaf_id,
+                                             slots)
             return compute_group_histograms_pallas(
                 self.bins, grad, hess, counts, leaf_id,
                 num_leaves=L, max_group_bin=self.max_group_bin,
@@ -989,11 +663,9 @@ class TreeGrower:
             # FixHistogram / parent-subtraction step downstream
             from ..parallel.collectives import exchange_histograms
             return exchange_histograms(local, axis,
-                                       mode=self.hist_exchange,
+                                       mode=self.plan.hist_exchange,
                                        world=int(nshards))
 
-        if slots is None:
-            slots = jnp.arange(L, dtype=jnp.int32)
         return inner(self.bins, grad, hess, counts, leaf_id, slots)
 
     # ------------------------------------------------------------------
@@ -1015,8 +687,6 @@ class TreeGrower:
                 return jnp.concatenate([h, pad])
             return run
 
-        if not getattr(self.config, "hist_packed_dispatch", True):
-            return full(None)
         if W <= PACKED_STRIP:
             return packed(1)(None)
 
@@ -1046,15 +716,20 @@ class TreeGrower:
                                 quant):
         B = self.max_group_bin
         W = rights.shape[0]
+        plan = self.plan
         ohb = self._ohb_arg if self._ohb_arg is not None else self.ohb
         if quant is not None:
             if TELEMETRY.on:
-                # per-trace fix-up accounting (see _hist_kernel_impl)
+                # trace-time accounting (the _note_collective pattern):
+                # every quantized histogram pass ends in an f32
+                # dequantize fix-up before split finding — inside jit
+                # this counts once per trace, i.e. "fix-up passes per
+                # compiled step"
                 TELEMETRY.add("hist_quant_fixup", 1)
-            wT, scales, q = quant[0], quant[1], True    # (3, N) int32
+            wT, scales = quant                          # (3, N) int32
         else:
             wT = jnp.stack([grad, hess, counts], axis=0)
-            scales, q = None, False
+            scales = None
 
         # factored rungs (256-lane tiles only): the bin index is split
         # across both sides of the dot, so a pass streams the rows its
@@ -1066,34 +741,30 @@ class TreeGrower:
         # compiler meets it in the same fusion with and without the
         # rungs and the floats are the same floats (XLA:CPU contracts it
         # with the parent-minus-right subtraction it is fused with)
-        rungs = [r for r in self.hist_factored_rungs if r[0] <= W]
+        rungs = [r for r in plan.factored_rungs if r[0] <= W]
         late_scale = bool(rungs) and W <= PACKED_STRIP
         in_scales = jnp.ones_like(scales) if late_scale else scales
 
         def run(strips):
             def go(_):
-                if self.use_tiled:
+                if plan.tier == "ladder":
                     from ..ops.histogram import \
                         compute_group_histograms_fused_tiled
                     h, leaf2 = self._on_row_shards(
                         functools.partial(
                             compute_group_histograms_fused_tiled,
                             max_group_bin=B,
-                            block=self.pallas_block_tiled, strips=strips,
-                            interpret=self._interp,
+                            block=plan.block_tiled, strips=strips,
+                            interpret=plan.interpret,
                             packed_groups=self.pack_P),
                         self.binsT, wT, in_scales, st.leaf_id,
                         st.route_tab, rights)
                 else:
-                    # streamed-one-hot kernel: block=2048 measured
-                    # fastest on v5e (4096 fits scoped VMEM for 1-strip
-                    # but benched 16% slower — its 3.6 MB/block DMA
-                    # pipeline prefers the finer granularity)
                     h, leaf2 = compute_group_histograms_fused(
-                        ohb, self.binsT, wT, scales, st.leaf_id,
+                        ohb, self.binsT, wT, st.leaf_id,
                         st.route_tab, rights, max_group_bin=B,
-                        block=self.pallas_block, strips=strips, quant=q,
-                        interpret=self._interp, pack=self.ohb_pack,
+                        block=plan.block_float, strips=strips,
+                        interpret=plan.interpret, pack=plan.onehot_pack,
                         num_groups=self.num_groups,
                         packed_groups=self.pack_P)
                 return _pad_slots(h, W), leaf2
@@ -1119,8 +790,8 @@ class TreeGrower:
                     functools.partial(
                         compute_group_histograms_fused_factored,
                         max_group_bin=B, k_cap=k_cap, a=a,
-                        block=self.pallas_block_factored,
-                        interpret=self._interp),
+                        block=plan.block_factored,
+                        interpret=plan.interpret),
                     self.binsT, wT, in_scales, st.leaf_id, st.route_tab,
                     rights)
                 return _pad_slots(h, W), leaf2
@@ -1158,14 +829,14 @@ class TreeGrower:
         accumulators — not yet dequantized — to the exact cross-shard
         sum; the dequantize multiply then meets a replicated histogram,
         as does everything downstream of it, and the histogram comes
-        back as a pair with the sum's int32 row counts (_int_counts).
+        back as a pair with the sum's int32 row counts (plan.int_counts).
         ``route_tab`` and ``slots`` are replicated, so every shard takes
         the same rung of the ``lax.cond`` ladder this is called from."""
-        if not self._mesh_kernels:
+        if not self.plan.mesh_kernels:
             return kernel(binsT, wT, scales, leaf_id, route_tab, slots)
         from jax.sharding import PartitionSpec as P
         from ..parallel import collectives
-        axis = self._row_axis
+        axis = self.plan.row_axis
         cols, rows, rep = P(None, axis), P(axis), P()
 
         def shard(bT, w, lid, rt, sl):
@@ -1186,139 +857,28 @@ class TreeGrower:
         return (_scaled(total, scales), rows_i32), leaf2
 
     # ------------------------------------------------------------------
-    def _hist_kernel_q_tiled(self, leaf_id, slots, quant):
-        """Tiled-iota dispatch (quant weights arrive TRANSPOSED (3, N)):
-        the one-hot is rebuilt in VMEM from the transposed packed bins
-        at the narrowest lane packing covering the frontier."""
-        from ..ops.histogram import compute_group_histograms_q_tiled
-        wT, scales = quant
-        B = self.max_group_bin
-
-        def full(_):  # pragma: no cover — frontier is capped at 126
-            return compute_group_histograms_pallas_q(
-                self.bins, wT.T, scales, leaf_id,
-                num_leaves=self.num_leaves, max_group_bin=B,
-                block=self.pallas_block, slots=slots)
-
-        def run_packed(strips):
-            return compute_group_histograms_q_tiled(
-                self.binsT, wT, scales, leaf_id, slots,
-                max_group_bin=B, block=self.pallas_block_tiled,
-                strips=strips, interpret=self._interp,
-                packed_groups=self.pack_P)
-
-        return self._packed_dispatch(full, run_packed, slots,
-                                     slots.shape[0])
-
-    # ------------------------------------------------------------------
-    def _build_partition(self, leaf_id, quant):
-        """One round's leaf partition: the stable block-aligned segment
-        permutation plus the PARTITIONED operand copies (transposed
-        bins, quantized weights) the segment kernel streams.  Built
-        once per round and shared by the rights and parents passes.
-        The two row gathers here are the formulation's dominant cost —
-        see the cost note on ops/partition.py build_leaf_partition."""
-        from ..ops.partition import apply_partition, build_leaf_partition
-        with TELEMETRY.phase("partition"):
-            wT, scales = quant                           # (3, N) int32
-            perm, blk_leaf, _ = build_leaf_partition(
-                leaf_id, num_slots=self.num_leaves,
-                block=self.leaf_part_block)
-            binsT_p = apply_partition(self.binsT, perm, axis=1)
-            wT_p = apply_partition(wT, perm, axis=1)
-            return binsT_p, wT_p, blk_leaf, scales
-
-    # ------------------------------------------------------------------
-    def _hist_kernel_seg(self, part, slots):
-        """Segment-addressed dispatch: map each partition block's
-        owning leaf to its frontier-slot position (tiny-table lookup)
-        and run the leaf-partitioned kernel at the narrowest output
-        width covering the valid slots (the seg kernel's VMEM
-        accumulator is 8 sublanes per slot, so wide frontiers ride the
-        same PACKED_STRIP ladder as the slot-packed kernels).  Valid
-        slots always occupy a PREFIX of ``slots`` (_round queues them
-        that way), so capping num_out at the ladder rung is safe.
-        Output follows ``slots`` order like every frontier kernel."""
-        from ..ops.histogram import compute_group_histograms_seg_tiled
-        binsT_p, wT_p, blk_leaf, scales = part
-        L1 = self.num_leaves + 1
-        W = slots.shape[0]
-        inv = jnp.full(L1, -1, jnp.int32).at[
-            jnp.where(slots >= 0, slots, L1)].set(
-            jnp.arange(W, dtype=jnp.int32), mode="drop")
-        blk_slot = jnp.where(blk_leaf >= 0,
-                             inv[jnp.clip(blk_leaf, 0, L1 - 1)], -1)
-
-        def run(num_out):
-            # positions >= num_out can only belong to invalid slots
-            # under the dispatch's count condition; mask them so the
-            # dynamic sublane write stays in bounds regardless
-            bs = jnp.where(blk_slot < num_out, blk_slot, -1)
-            return compute_group_histograms_seg_tiled(
-                binsT_p, wT_p, scales, bs, num_out=num_out,
-                max_group_bin=self.max_group_bin,
-                block=self.leaf_part_block, interpret=self._interp,
-                packed_groups=self.pack_P)
-
-        return self._packed_dispatch(
-            lambda _: run(W),
-            lambda strips: run(min(strips * PACKED_STRIP, W)),
-            slots, W)
-
-    # ------------------------------------------------------------------
-    def _hist_kernel_q_otf(self, leaf_id, slots, L, quant):
-        """Quantized on-the-fly dispatch: the packed-lane int8 kernel
-        rebuilds the bin one-hot in VMEM (HBM stream = the (N, G) packed
-        bins), at the narrowest lane packing covering the frontier."""
-        wq, scales = quant
-        B = self.max_group_bin
-
-        def full(_):
-            return compute_group_histograms_pallas_q(
-                self.bins, wq, scales, leaf_id, num_leaves=L,
-                max_group_bin=B, block=self.pallas_block, slots=slots)
-
-        if slots is None:
-            return full(None)
-
-        def run_packed(strips):
-            return compute_group_histograms_q_packed(
-                self.bins, wq, scales, leaf_id, slots,
-                max_group_bin=B, block=self.pallas_block, strips=strips)
-
-        return self._packed_dispatch(full, run_packed, slots,
-                                     slots.shape[0])
-
-    # ------------------------------------------------------------------
-    def _hist_kernel_pre(self, grad, hess, counts, leaf_id, slots, L,
-                         quant):
+    def _hist_kernel_pre(self, grad, hess, counts, leaf_id, slots):
         """Streamed-one-hot dispatch: channel-packed kernel when the
         frontier is narrow (3x fewer MXU rows), full kernel otherwise.
         The branch is a runtime lax.cond on the valid-slot count — the
         early rounds of EVERY tree have 1..PACKED_STRIP new leaves."""
         B = self.max_group_bin
+        plan = self.plan
         ohb = self._ohb_arg if self._ohb_arg is not None else self.ohb
-        if quant is not None:
-            w, scales, q = quant[0], quant[1], True
-        else:
-            w = jnp.stack([grad, hess, counts], axis=1)
-            scales, q = None, False
+        w = jnp.stack([grad, hess, counts], axis=1)
 
         def full(_):
             return compute_group_histograms_pre(
-                ohb, w, scales, leaf_id, num_leaves=L,
-                max_group_bin=B, block=self.pallas_block, quant=q,
-                slots=slots, pack=self.ohb_pack,
+                ohb, w, leaf_id, num_leaves=self.num_leaves,
+                max_group_bin=B, block=plan.block_float,
+                slots=slots, pack=plan.onehot_pack,
                 num_groups=self.num_groups)
-
-        if slots is None:
-            return full(None)
 
         def run_packed(strips):
             return compute_group_histograms_pre_packed(
-                ohb, w, scales, leaf_id, slots, max_group_bin=B,
-                block=self.pallas_block, strips=strips, quant=q,
-                pack=self.ohb_pack, num_groups=self.num_groups)
+                ohb, w, leaf_id, slots, max_group_bin=B,
+                block=plan.block_float, strips=strips,
+                pack=plan.onehot_pack, num_groups=self.num_groups)
 
         return self._packed_dispatch(full, run_packed, slots,
                                      slots.shape[0])
@@ -1364,9 +924,9 @@ class TreeGrower:
 
         def total(x):
             x = jnp.where(self._row_valid, x, 0.0)
-            if self._mesh_kernels:
+            if self.plan.mesh_kernels:
                 from jax.sharding import PartitionSpec as P
-                axis = self._row_axis
+                axis = self.plan.row_axis
                 part = _get_shard_map()(
                     lambda x: jax.lax.all_gather(block_sums(x), axis,
                                                  tiled=True),
@@ -1385,14 +945,14 @@ class TreeGrower:
         M = L - 1
         B = self.max_feature_bin
         leaf_id = jnp.where(self._row_valid, 0, -1).astype(jnp.int32)
-        if self.use_fused and self.use_tiled:
+        if self.plan.tier == "ladder":
             totals = self._root_totals(grad, hess, counts)
         else:
             totals = compute_leaf_totals(grad, hess, counts, leaf_id, 1)
         leaf_sum_grad = jnp.zeros(L, jnp.float32).at[0].set(totals[0, 0])
         leaf_sum_hess = jnp.zeros(L, jnp.float32).at[0].set(totals[0, 1])
         root_rows = totals[0, 2]
-        if self._int_counts:
+        if self.plan.int_counts:
             # an integer sum has one value in any order, on any mesh
             root_rows = jnp.sum(jnp.where(
                 self._row_valid, counts, 0.0).astype(jnp.int32))
@@ -1427,7 +987,7 @@ class TreeGrower:
         hist_cache = jnp.zeros(
             (L if self.use_hist_cache else 1, self.num_groups,
              self.max_group_bin, 3), jnp.float32)
-        if self._int_counts:
+        if self.plan.int_counts:
             hist_cache = (hist_cache,
                           jnp.zeros(hist_cache.shape[:3], jnp.int32))
             cand = (cand, jnp.zeros(L, jnp.int32))
@@ -1499,12 +1059,12 @@ class TreeGrower:
             # qkey enables the stochastic rounding the skewed-gradient
             # objectives need (see quantize_gradients)
             with TELEMETRY.phase("quantize"):
-                quant = (quantize_gradients(grad, hess, counts, key=qkey)
-                         if self.use_quant else None)
-                if quant is not None \
-                        and (self.use_fused or self.use_tiled):
-                    # the fused/tiled kernels stream weights lane-major
-                    quant = (quant[0].T, quant[1])          # (3, N)
+                quant = None
+                if self.plan.quantized:
+                    wq, scales = quantize_gradients(grad, hess, counts,
+                                                    key=qkey)
+                    # the ladder streams weights lane-major
+                    quant = (wq.T, scales)                  # (3, N)
 
             def body_fn(st):
                 return self._round(st, grad, hess, counts, feature_mask,
@@ -1528,7 +1088,7 @@ class TreeGrower:
         """(final leaf ids, per-row post-route leaf value or None)."""
         leaf_id = final.leaf_id
         row_val = None
-        if self.use_fused:
+        if self.plan.fused:
             # the last round's selected splits were never routed (the
             # loop exited before the next refresh) — apply them once,
             # and ride the per-row POST-route leaf value on the same
@@ -1538,19 +1098,21 @@ class TreeGrower:
             # in-VMEM Pallas broadcast; the XLA form materializes an
             # (N, L_pad) bf16 one-hot + (N, K) rows in HBM (~16
             # ms/tree at HIGGS scale)
-            if self.use_tiled:
+            if self.plan.tier == "ladder":
                 from ..ops.histogram import route_apply_tiled
                 route = functools.partial(
-                    route_apply_tiled, block=self.pallas_block_tiled,
-                    interpret=self._interp, packed_groups=self.pack_P)
-                if self._mesh_kernels:
+                    route_apply_tiled, block=self.plan.block_tiled,
+                    interpret=self.plan.interpret,
+                    packed_groups=self.pack_P)
+                if self.plan.mesh_kernels:
                     # per-row work on replicated tables: each shard
                     # routes its own rows, nothing crosses
                     from jax.sharding import PartitionSpec as P
-                    rows = P(self._row_axis)
+                    axis = self.plan.row_axis
+                    rows = P(axis)
                     route = _get_shard_map()(
                         route, mesh=self.policy.mesh,
-                        in_specs=(P(None, self._row_axis), rows, P(), P()),
+                        in_specs=(P(None, axis), rows, P(), P()),
                         out_specs=(rows, rows))
                 leaf_id, row_val = route(
                     self.binsT, leaf_id, final.route_tab,
@@ -1589,67 +1151,30 @@ class TreeGrower:
         cfg = self.cfg_scalars
         cache = st.hist_cache
 
-        part = None
-        if self.use_fused and self.leaf_part:
-            # leaf-partitioned round: apply the pending route in its own
-            # Pallas pass, regroup rows into per-leaf segments ONCE (the
-            # permutation is amortized across the rights and — in
-            # no-cache mode — parents passes), then run the segment-
-            # addressed kernel whose LHS carries no leaf one-hot
-            from ..ops.histogram import route_only_tiled
-            with TELEMETRY.phase("route"):
-                new_leaf = route_only_tiled(
-                    self.binsT, st.leaf_id, st.route_tab,
-                    block=self.pallas_block_tiled, interpret=self._interp,
-                    packed_groups=self.pack_P)
-            st = st._replace(leaf_id=new_leaf)
-            part = self._build_partition(new_leaf, quant)
-            right_hist = self._hist_kernel_seg(part, rights)
-        elif self.use_fused and self.split_route:
-            # split-route: apply the pending table in a dedicated
-            # Pallas pass, then histogram with the route-free kernel
-            from ..ops.histogram import route_only_tiled
-            with TELEMETRY.phase("route"):
-                new_leaf = route_only_tiled(
-                    self.binsT, st.leaf_id, st.route_tab,
-                    block=self.pallas_block_tiled, interpret=self._interp,
-                    packed_groups=self.pack_P)
-            st = st._replace(leaf_id=new_leaf)
-            right_hist = self._hist_kernel_q_tiled(new_leaf, rights,
-                                                   quant)
-        elif self.use_fused:
-            # the pending route (last round's splits) is applied INSIDE
-            # the histogram kernel just before each row contributes
-            right_hist, new_leaf = self._hist_kernel_fused(
-                st, rights, grad, hess, counts, quant)
-            st = st._replace(leaf_id=new_leaf)
-        else:
-            right_hist = self._hist_kernel(grad, hess, counts, st.leaf_id,
-                                           slots=rights, quant=quant)
+        def histogram(st, slots):
+            """(histogram of ``slots``, state after the pass).  Fused:
+            the pending route (last round's splits) is applied INSIDE
+            the kernel just before each row contributes; a second pass
+            re-applies it, which is idempotent."""
+            if self.plan.fused:
+                hist, new_leaf = self._hist_kernel_fused(
+                    st, slots, grad, hess, counts, quant)
+                return hist, st._replace(leaf_id=new_leaf)
+            return self._hist_kernel(grad, hess, counts, st.leaf_id,
+                                     slots), st
+
+        right_hist, st = histogram(st, rights)
         right_hist = self._constrain_hist(right_hist)
         safe_p = jnp.clip(parents, 0, L - 1)
         tmap = jax.tree_util.tree_map     # a histogram, or its pair
         if self.use_hist_cache:
             left_hist = tmap(lambda c, r: c[safe_p] - r, cache, right_hist)
-        elif self.use_fused and self.leaf_part:
-            # the round's partition serves the parents pass too — the
-            # parent slots host the LEFT children's (already-routed) rows
-            left_hist = self.policy.constrain_hist(
-                self._hist_kernel_seg(part, parents))
-        elif self.use_fused and self.split_route:
-            left_hist = self.policy.constrain_hist(
-                self._hist_kernel_q_tiled(st.leaf_id, parents, quant))
-        elif self.use_fused:
-            # no-cache mode: the parent slot now hosts the LEFT child's
-            # rows (routing already applied; re-application is
-            # idempotent), so a direct pass replaces the subtraction
-            left_hist, _ = self._hist_kernel_fused(
-                st, parents, grad, hess, counts, quant)
-            left_hist = self._constrain_hist(left_hist)
         else:
-            left_hist = self._hist_kernel(grad, hess, counts, st.leaf_id,
-                                          slots=parents, quant=quant)
-            left_hist = self.policy.constrain_hist(left_hist)
+            # no-cache mode: the parent slot now hosts the LEFT child's
+            # rows (routing already applied), so a direct pass replaces
+            # the subtraction
+            left_hist, _ = histogram(st, parents)
+            left_hist = self._constrain_hist(left_hist)
         new_slots = jnp.concatenate([parents, rights])          # (2W,)
         h_new = tmap(lambda l, r: jnp.concatenate([l, r]),
                      left_hist, right_hist)                     # (2W,G,B,3)
@@ -1703,7 +1228,7 @@ class TreeGrower:
         histogram replicated and the split finder runs on every shard
         alike (reduce-scatter with feature-owned finding is a later
         optimisation: ROADMAP Speed 6)."""
-        if self._mesh_kernels:
+        if self.plan.mesh_kernels:
             return hist
         return self.policy.constrain_hist(hist)
 
@@ -1730,7 +1255,7 @@ class TreeGrower:
         mc = st.leaf_min_c[safe]
         xc = st.leaf_max_c[safe]
         feat_count = None
-        if self._int_counts:
+        if self.plan.int_counts:
             # the counts' own FixHistogram, in integers
             h_w, c_w = h_w
             feat_count = expand_feature_histograms(
@@ -1886,7 +1411,7 @@ class TreeGrower:
                       self.f_gb_shift[best_f], self.f_gb_oor[best_f],
                       f_is_cat_leaf, thr, dleft, f_missing_leaf,
                       f_dbin_leaf, f_nb_leaf, cat_mask, right_slot)
-        if self.use_fused:
+        if self.plan.fused:
             leaf_id = st.leaf_id
             route_tab = build_route_table(*route_args)
         else:
@@ -1923,20 +1448,21 @@ class TreeGrower:
                                quant)
 
         with TELEMETRY.phase("split_finder"):
-            c, lsc_i32 = st.cand if self._int_counts else (st.cand, None)
+            c, lsc_i32 = st.cand if self.plan.int_counts \
+                else (st.cand, None)
             best_gain = c[:, CAND_GAIN]
             best_f = c[:, CAND_FEATURE].astype(jnp.int32)
             thr = c[:, CAND_THRESHOLD].astype(jnp.int32)
             dleft = c[:, CAND_DEFAULT_LEFT] > 0.5
             lsg, lsh, lsc = c[:, CAND_LSG], c[:, CAND_LSH], c[:, CAND_LSC]
-            if self._int_counts:
+            if self.plan.int_counts:
                 lsc = lsc_i32
             lout, rout = c[:, CAND_LOUT], c[:, CAND_ROUT]
             cat_mask = c[:, CAND_COLS:] > 0.5
 
             forced_valid = None
             if self.forced_count:
-                fc, flc = st.forced_cand if self._int_counts \
+                fc, flc = st.forced_cand if self.plan.int_counts \
                     else (st.forced_cand, st.forced_cand[:, FORCED_LSC])
                 fc_gain = fc[:, FORCED_GAIN]
                 fc_thr = fc[:, FORCED_THRESHOLD].astype(jnp.int32)

@@ -270,47 +270,6 @@ def _hist_kernel_body(bins_ref, w_ref, leaf_ref, emat_ref, bcol_ref,
         preferred_element_type=jnp.float32)
 
 
-def _hist_kernel_body_paired(bins_ref, w_ref, leaf_ref, slots_ref, out_ref,
-                             *, num_leaves, max_group_bin, m_pad):
-    """Alternative kernel body: no expansion matmul — per-group one-hots
-    are built directly and dotted in group PAIRS so every dot runs at
-    the full 128-lane width (B=64 pairs to 128).  Lower VMEM footprint
-    than the expansion variant permits larger row blocks."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    c = bins_ref.shape[0]
-    num_groups = bins_ref.shape[1]
-    b = max_group_bin
-    m_leaf = m_pad // 3
-
-    leaf = leaf_ref[:]                                   # (C, 1) int32
-    w = w_ref[:]                                         # (C, 3) f32
-    ohl = leaf == slots_ref[0:1, :]                      # (C, m_leaf)
-    zero = jnp.zeros((), jnp.float32)
-    lhs = jnp.concatenate(
-        [jnp.where(ohl, w[:, 0:1], zero),
-         jnp.where(ohl, w[:, 1:2], zero),
-         jnp.where(ohl, w[:, 2:3], zero)], axis=1).astype(jnp.bfloat16)
-
-    binb = bins_ref[:].astype(jnp.int32)                 # (C, G)
-    biota = jax.lax.broadcasted_iota(jnp.int32, (c, b), 1)
-    per_dot = max(1, 128 // b)
-    for g0 in range(0, num_groups, per_dot):
-        gs = range(g0, min(g0 + per_dot, num_groups))
-        parts = [(binb[:, g:g + 1] == biota).astype(jnp.bfloat16)
-                 for g in gs]
-        ohb = parts[0] if len(parts) == 1 else jnp.concatenate(parts,
-                                                               axis=1)
-        contrib = jax.lax.dot_general(
-            lhs, ohb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        out_ref[:, g0 * b:(g0 + len(parts)) * b] += contrib
-
-
 def _slot_prep(num_leaves: int, slots: Optional[jax.Array]):
     """Shared leaf-strip padding + slot-row encoding for every Pallas
     histogram wrapper.  The leaf axis pads to a 128-lane multiple so the
@@ -329,7 +288,7 @@ def _slot_prep(num_leaves: int, slots: Optional[jax.Array]):
 
 def _run_hist_kernel(kern, bins, w, leaf_id, const_inputs, *, name, block,
                      m_leaf, m_pad, num_leaves, max_group_bin, out_dtype,
-                     interpret, raw_out=False):
+                     interpret):
     """Shared pallas_call plumbing: row-blocked (bins, w, leaf) inputs,
     VMEM-resident constants, one (m_pad, G*B) accumulator; returns the
     (L, G, B, 3) histogram view.  ``name`` pins the kernel's name in
@@ -352,77 +311,9 @@ def _run_hist_kernel(kern, bins, w, leaf_id, const_inputs, *, name, block,
         out_shape=jax.ShapeDtypeStruct((m_pad, gb), out_dtype),
         interpret=interpret, name=name,
     )(bins, w, leaf_id[:, None], *consts)
-    if raw_out:
-        return out
     # (3*m_leaf, G*B) channel-major -> (L, G, B, 3)
     hist = out.reshape(3, m_leaf, num_groups, max_group_bin)[:, :num_leaves]
     return jnp.transpose(hist, (1, 2, 3, 0))
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_leaves", "max_group_bin", "block", "interpret"))
-def compute_group_histograms_pallas_paired(
-        bins: jax.Array, grad: jax.Array, hess: jax.Array,
-        counts: jax.Array, leaf_id: jax.Array, *, num_leaves: int,
-        max_group_bin: int, block: int = 2048, interpret: bool = False,
-        slots: Optional[jax.Array] = None) -> jax.Array:
-    """Paired-dot Pallas histogram (same contract as
-    :func:`compute_group_histograms_pallas`)."""
-    num_leaves, m_leaf, m_pad, slot_row = _slot_prep(num_leaves, slots)
-    w = jnp.stack([grad, hess, counts], axis=1).astype(jnp.float32)
-    kern = functools.partial(_hist_kernel_body_paired,
-                             num_leaves=num_leaves,
-                             max_group_bin=max_group_bin, m_pad=m_pad)
-    return _run_hist_kernel(
-        kern, bins, w, leaf_id, [slot_row], block=block, m_leaf=m_leaf,
-        name="compute_group_histograms_pallas_paired",
-        m_pad=m_pad, num_leaves=num_leaves, max_group_bin=max_group_bin,
-        out_dtype=jnp.float32, interpret=interpret)
-
-
-def _hist_kernel_body_q(bins_ref, wq_ref, leaf_ref, emat_ref, bcol_ref,
-                        slots_ref, out_ref, *, m_pad, int8_bins):
-    """int8-MXU histogram kernel: the TPU analog of LightGBM v4's
-    quantized training (arXiv 2207.09682) and the reference GPU
-    learner's single-precision default (gpu_tree_learner.cpp:73-77).
-    Gradient/hessian channels arrive pre-quantized to int8 (one global
-    scale per channel per tree); the histogram matmul runs
-    int8 x int8 -> int32 at twice the bf16 MXU rate and the one-hot
-    selects pack 4x denser in VPU registers.  Counts (0/1) are exact.
-    The bin-broadcast matmul also runs int8 when every bin index fits
-    int8 (``int8_bins``); wider bin spaces use the exact-bf16 route."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    m_leaf = m_pad // 3
-    leaf = leaf_ref[:]                                   # (C, 1) int32
-    wq = wq_ref[:]                                       # (C, 3) int32
-    ohl = leaf == slots_ref[0:1, :]                      # (C, m_leaf)
-    zero = jnp.zeros((), jnp.int32)
-    lhs = jnp.concatenate(
-        [jnp.where(ohl, wq[:, 0:1], zero),
-         jnp.where(ohl, wq[:, 1:2], zero),
-         jnp.where(ohl, wq[:, 2:3], zero)],
-        axis=1).astype(jnp.int8)
-    if int8_bins:
-        binb = bins_ref[:].astype(jnp.int32).astype(jnp.int8)
-        rep = jax.lax.dot_general(                       # (C, G*B) i32
-            binb, emat_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-    else:
-        # bin indices up to 255 are exact in bf16 but wrap in int8
-        binb = bins_ref[:].astype(jnp.int32).astype(jnp.bfloat16)
-        rep = jax.lax.dot_general(
-            binb, emat_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(jnp.int32)
-    ohb = (rep == bcol_ref[0:1, :]).astype(jnp.int8)
-    out_ref[:] += jax.lax.dot_general(
-        lhs, ohb, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
 
 
 #: int32 histogram-accumulator headroom: quantized weights are int8
@@ -441,9 +332,9 @@ def check_quant_rows(n_rows: int, what: str = "quantized histogram"
                      ) -> None:
     """Loud kernel-plan-time form of the :func:`quantize_gradients`
     caller contract: raises when ``n_rows`` could overflow the int32
-    accumulator.  Shared by the grower's ``use_quant`` gate and the
-    ``hist_precision`` tier selector so the bound lives in ONE place
-    next to the kernel it protects."""
+    accumulator.  What the kernel plan (ops/hist_plan.py) calls for
+    ``hist_precision=tiered``, so the bound lives in ONE place next to
+    the kernel it protects."""
     if not quant_rows_ok(n_rows):
         raise ValueError(
             f"{what}: {int(n_rows)} rows can overflow the int32 "
@@ -491,54 +382,15 @@ def quantize_gradients(grad: jax.Array, hess: jax.Array, counts: jax.Array,
     return wq, scales
 
 
-@functools.partial(
-    jax.jit, static_argnames=("num_leaves", "max_group_bin", "block",
-                              "interpret"))
-def compute_group_histograms_pallas_q(
-        bins: jax.Array, wq: jax.Array, scales: jax.Array,
-        leaf_id: jax.Array, *, num_leaves: int, max_group_bin: int,
-        block: int = 1024, interpret: bool = False,
-        slots: Optional[jax.Array] = None) -> jax.Array:
-    """Quantized-int8 Pallas histogram: same contract as
-    :func:`compute_group_histograms_pallas` but takes pre-quantized
-    weights from :func:`quantize_gradients` and dequantizes the int32
-    output with the per-channel scales.
-
-    Caller contract: N * 127 must stay below 2^31 (int32 accumulator;
-    ~16.9M rows) — checked loudly at kernel-plan time via
-    :func:`check_quant_rows`, which the grower's use_quant gate and
-    the hist_precision tier selector both call."""
-    num_groups = bins.shape[1]
-    num_leaves, m_leaf, m_pad, slot_row = _slot_prep(num_leaves, slots)
-    int8_bins = max_group_bin <= 127
-    kind = "i8" if int8_bins else "bf16_i32"
-    emat, bcol = _expansion_consts(num_groups, max_group_bin, kind)
-    kern = functools.partial(_hist_kernel_body_q, m_pad=m_pad,
-                             int8_bins=int8_bins)
-    hist = _run_hist_kernel(
-        kern, bins, wq, leaf_id, [emat, bcol, slot_row], block=block,
-        name="compute_group_histograms_pallas_q",
-        m_leaf=m_leaf, m_pad=m_pad, num_leaves=num_leaves,
-        max_group_bin=max_group_bin, out_dtype=jnp.int32,
-        interpret=interpret)
-    return hist.astype(jnp.float32) * scales[None, None, None, :]
-
-
 @functools.lru_cache(maxsize=None)
-def _expansion_consts(num_groups: int, max_group_bin: int,
-                      kind: str = "bf16"):
-    """Constant (G, G*B) 0/1 expansion matrix and (1, G*B) per-column
-    bin index.  kind selects the dtype pair: "bf16" (emat bf16 / bcol
-    f32), "i8" (int8 / int32), "bf16_i32" (bf16 / int32)."""
+def _expansion_consts(num_groups: int, max_group_bin: int):
+    """Constant (G, G*B) 0/1 expansion matrix (bf16) and (1, G*B)
+    per-column bin index (f32)."""
     g, b = num_groups, max_group_bin
     emat = np.zeros((g, g * b), dtype=np.float32)  # lint: disable=TRC001(static-shape constant table, never touches traced values)
     for gg in range(g):
         emat[gg, gg * b:(gg + 1) * b] = 1.0
     bcol = np.tile(np.arange(b, dtype=np.float32), g)[None, :]  # lint: disable=TRC001(static-shape constant table, never touches traced values)
-    if kind == "i8":
-        return emat.astype(np.int8), bcol.astype(np.int32)
-    if kind == "bf16_i32":
-        return emat.astype(jnp.bfloat16), bcol.astype(np.int32)
     return emat.astype(jnp.bfloat16), bcol
 
 
@@ -693,35 +545,26 @@ def _write_packed_chunk(out: jax.Array, part: jax.Array,
         out, part, (jnp.asarray(start, jnp.int32), jnp.int32(0)))
 
 
-def _unpack_ohb_planes(pk: jax.Array, pack: int, out_dtype):
-    """(C, GBp) planar-packed block -> list of ``pack`` (plane, shift)
-    pairs in ``out_dtype`` (int8 for the quantized dot, bfloat16
-    otherwise).  The plane holds values {0, 2^shift} — extraction is a
-    SINGLE int8 AND per element (the full 0/1 widen costs 3 VPU ops
-    per element: and, !=0, cast — measured as the pass bottleneck once
-    the stream is packed).  The caller divides the 2^shift factor out
-    of the post-dot (m_pad, GBp) result, ~4 orders of magnitude fewer
-    elements; the int32 quant descale is an exact arithmetic shift
-    (every accumulated value is a multiple of 2^shift)."""
+def _unpack_ohb_planes(pk: jax.Array, pack: int):
+    """(C, GBp) planar-packed int8 block -> list of ``pack`` (bf16
+    plane, shift) pairs.  The plane holds values {0, 2^shift} —
+    extraction is a SINGLE int8 AND per element (the full 0/1 widen
+    costs 3 VPU ops per element: and, !=0, cast — measured as the pass
+    bottleneck once the stream is packed).  The caller divides the
+    2^shift factor out of the post-dot (m_pad, GBp) result, ~4 orders of
+    magnitude fewer elements."""
     if pack == 1:
-        return [(pk if out_dtype == jnp.int8 else pk.astype(out_dtype),
-                 0)]
+        return [(pk.astype(jnp.bfloat16), 0)]
     bits = 8 // pack
-    out = []
-    for p in range(pack):
-        masked = pk & jnp.int8(1 << (p * bits))
-        out.append((masked if out_dtype == jnp.int8
-                    else masked.astype(out_dtype), p * bits))
-    return out
+    return [((pk & jnp.int8(1 << (p * bits))).astype(jnp.bfloat16),
+             p * bits) for p in range(pack)]
 
 
 def _descale_contrib(contrib: jax.Array, shift: int) -> jax.Array:
-    """Divide the 2^shift plane scaling out of a post-dot block (exact
-    for both the int32 arithmetic-shift and the f32 multiply)."""
+    """Divide the 2^shift plane scaling out of a post-dot block (an
+    exact f32 multiply)."""
     if shift == 0:
         return contrib
-    if contrib.dtype == jnp.int32:
-        return jax.lax.shift_right_arithmetic(contrib, shift)
     return contrib * jnp.float32(1.0 / (1 << shift))
 
 
@@ -730,7 +573,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 def _hist_kernel_body_pre(ohb_ref, w_ref, leaf_ref, slots_ref, out_ref, *,
-                          m_pad, quant, pack=1):
+                          m_pad, pack=1):
     """Streamed-one-hot kernel body: HBM traffic is the (C, G*B[/pack])
     one-hot block (prefetched by the Pallas pipeline while the MXU
     works), and the only compute is the lhs build + one dot per plane
@@ -743,28 +586,18 @@ def _hist_kernel_body_pre(ohb_ref, w_ref, leaf_ref, slots_ref, out_ref, *,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     leaf = leaf_ref[:]                                   # (C, 1) int32
-    w = w_ref[:]                                         # (C, 3)
+    w = w_ref[:]                                         # (C, 3) f32
     ohl = leaf == slots_ref[0:1, :]                      # (C, m_leaf)
-    if quant:
-        zero = jnp.zeros((), jnp.int32)
-        lhs = jnp.concatenate(
-            [jnp.where(ohl, w[:, 0:1], zero),
-             jnp.where(ohl, w[:, 1:2], zero),
-             jnp.where(ohl, w[:, 2:3], zero)], axis=1).astype(jnp.int8)
-        rdt, odt = jnp.int8, jnp.int32
-    else:
-        zero = jnp.zeros((), jnp.float32)
-        lhs = jnp.concatenate(
-            [jnp.where(ohl, w[:, 0:1], zero),
-             jnp.where(ohl, w[:, 1:2], zero),
-             jnp.where(ohl, w[:, 2:3], zero)], axis=1).astype(jnp.bfloat16)
-        rdt, odt = jnp.bfloat16, jnp.float32
+    zero = jnp.zeros((), jnp.float32)
+    lhs = jnp.concatenate(
+        [jnp.where(ohl, w[:, 0:1], zero),
+         jnp.where(ohl, w[:, 1:2], zero),
+         jnp.where(ohl, w[:, 2:3], zero)], axis=1).astype(jnp.bfloat16)
     gbp_pad = ohb_ref.shape[1]
-    for p, (plane, sh) in enumerate(
-            _unpack_ohb_planes(ohb_ref[:], pack, rdt)):
+    for p, (plane, sh) in enumerate(_unpack_ohb_planes(ohb_ref[:], pack)):
         contrib = _descale_contrib(jax.lax.dot_general(
             lhs, plane, (((0,), (0,)), ((), ())),
-            preferred_element_type=odt), sh)
+            preferred_element_type=jnp.float32), sh)
         if pack == 1:
             out_ref[:] += contrib
         else:
@@ -772,8 +605,7 @@ def _hist_kernel_body_pre(ohb_ref, w_ref, leaf_ref, slots_ref, out_ref, *,
 
 
 def _hist_kernel_body_pre_packed(ohb_ref, w_ref, leaf_ref, slots_ref,
-                                 out_ref, *, strip, strips, quant,
-                                 pack=1):
+                                 out_ref, *, strip, strips, pack=1):
     """Channel-packed kernel: the three weight channels share each
     128-lane tile (lane = c*strip + l within a tile) instead of
     occupying three separate tiles, cutting the dot's output rows — and
@@ -797,26 +629,21 @@ def _hist_kernel_body_pre_packed(ohb_ref, w_ref, leaf_ref, slots_ref,
     c = leaf_ref.shape[0]
     m_pad = 128 * strips
     leaf = leaf_ref[:]                                   # (C, 1) int32
-    w = w_ref[:]                                         # (C, 3)
+    w = w_ref[:]                                         # (C, 3) f32
     # slots_ref tiles each strip's slot ids three times per 128-lane
     # tile; lane -> channel is a boundary select on lane mod 128
     ohl = leaf == slots_ref[0:1, :]                      # (C, m_pad)
     lane = jax.lax.broadcasted_iota(jnp.int32, (c, m_pad), 1) % 128
     wl = jnp.where(lane < strip, w[:, 0:1],
                    jnp.where(lane < 2 * strip, w[:, 1:2], w[:, 2:3]))
-    if quant:
-        lhs = jnp.where(ohl, wl, jnp.zeros((), jnp.int32)).astype(jnp.int8)
-        rdt, odt = jnp.int8, jnp.int32
-    else:
-        lhs = jnp.where(ohl, wl,
-                        jnp.zeros((), jnp.float32)).astype(jnp.bfloat16)
-        rdt, odt = jnp.bfloat16, jnp.float32
+    lhs = jnp.where(ohl, wl,
+                    jnp.zeros((), jnp.float32)).astype(jnp.bfloat16)
     gbp_pad = ohb_ref.shape[1]
-    planes = _unpack_ohb_planes(ohb_ref[:], pack, rdt)
+    planes = _unpack_ohb_planes(ohb_ref[:], pack)
     for p, (plane, sh) in enumerate(planes):
         contrib = _descale_contrib(jax.lax.dot_general(
             lhs, plane, (((0,), (0,)), ((), ())),
-            preferred_element_type=odt), sh)
+            preferred_element_type=jnp.float32), sh)
         if pack == 1:
             out_ref[:] += contrib
         else:
@@ -824,7 +651,7 @@ def _hist_kernel_body_pre_packed(ohb_ref, w_ref, leaf_ref, slots_ref,
 
 
 def _run_hist_kernel_pre(kern, ohb, w, leaf_id, slot_row, *, name, block,
-                         m_pad, out_dtype, interpret, out_cols=None):
+                         m_pad, interpret, out_cols=None):
     """pallas_call plumbing for the streamed-one-hot bodies: the (N,
     G*B[/pack]) one-hot is row-blocked like the weights; output is the
     (m_pad, out_cols) VMEM accumulator (out_cols = pack * plane
@@ -845,7 +672,7 @@ def _run_hist_kernel_pre(kern, ohb, w, leaf_id, slot_row, *, name, block,
             pl.BlockSpec(slot_row.shape, lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((m_pad, out_cols), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, out_cols), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m_pad, out_cols), jnp.float32),
         interpret=interpret, name=name,
     )(ohb, w, leaf_id[:, None], slot_row)
     return out
@@ -866,17 +693,15 @@ def _departition_planes(out: jax.Array, pack: int, gb: int) -> jax.Array:
 
 @functools.partial(
     jax.jit, static_argnames=("num_leaves", "max_group_bin", "block",
-                              "quant", "interpret", "pack", "num_groups"))
+                              "interpret", "pack", "num_groups"))
 def compute_group_histograms_pre(
-        ohb: jax.Array, w: jax.Array, scales: Optional[jax.Array],
-        leaf_id: jax.Array, *, num_leaves: int, max_group_bin: int,
-        block: int = 1024, quant: bool = False, interpret: bool = False,
-        slots: Optional[jax.Array] = None, pack: int = 1,
-        num_groups: Optional[int] = None) -> jax.Array:
+        ohb: jax.Array, w: jax.Array, leaf_id: jax.Array, *,
+        num_leaves: int, max_group_bin: int, block: int = 1024,
+        interpret: bool = False, slots: Optional[jax.Array] = None,
+        pack: int = 1, num_groups: Optional[int] = None) -> jax.Array:
     """Histogram from a precomputed (N, G*B[/pack]) one-hot (same
     output contract as :func:`compute_group_histograms`).  ``w`` is the
-    (N, 3) weight matrix — float32 (grad, hess, cnt) or int32 quantized
-    (then ``scales`` dequantizes the int32 accumulator).  ``pack`` > 1
+    (N, 3) float32 weight matrix (grad, hess, cnt).  ``pack`` > 1
     requires ``num_groups``."""
     if pack == 1:
         num_groups = ohb.shape[1] // max_group_bin
@@ -885,91 +710,14 @@ def compute_group_histograms_pre(
     gb = num_groups * max_group_bin
     num_leaves, m_leaf, m_pad, slot_row = _slot_prep(num_leaves, slots)
     kern = functools.partial(_hist_kernel_body_pre, m_pad=m_pad,
-                             quant=quant, pack=pack)
+                             pack=pack)
     out = _run_hist_kernel_pre(
         kern, ohb, w, leaf_id, slot_row, block=block, m_pad=m_pad,
-        name="compute_group_histograms_pre",
-        out_dtype=jnp.int32 if quant else jnp.float32,
-        interpret=interpret,
+        name="compute_group_histograms_pre", interpret=interpret,
         out_cols=None if pack == 1 else pack * ohb.shape[1])
     out = _departition_planes(out, pack, gb)
     hist = out.reshape(3, m_leaf, num_groups, max_group_bin)[:, :num_leaves]
-    hist = jnp.transpose(hist, (1, 2, 3, 0))
-    if quant:
-        hist = hist.astype(jnp.float32) * scales[None, None, None, :]
-    return hist
-
-
-def _hist_kernel_body_q_packed(bins_ref, wq_ref, leaf_ref, emat_ref,
-                               bcol_ref, slots_ref, out_ref, *, strip,
-                               strips, int8_bins):
-    """On-the-fly packed kernel: the bin one-hot is rebuilt in VMEM per
-    block (HBM stream is just the ~G bytes/row packed bins) AND the
-    weight channels share each 128-lane tile (see
-    _hist_kernel_body_pre_packed).  Regime (docs/ROOFLINE.md table):
-    this is the FALLBACK for datasets whose resident one-hot exceeds
-    the HBM budget — its VMEM rebuild (expansion matmul + full-width
-    compare) makes it VPU-bound and ~3.5x slower per pass than
-    streaming a resident one-hot at the bench shape, but its HBM
-    footprint is O(N*G) instead of O(N*G*B)."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    c = bins_ref.shape[0]
-    m_pad = 128 * strips
-    leaf = leaf_ref[:]                                   # (C, 1) int32
-    wq = wq_ref[:]                                       # (C, 3) int32
-    ohl = leaf == slots_ref[0:1, :]                      # (C, m_pad)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (c, m_pad), 1) % 128
-    wl = jnp.where(lane < strip, wq[:, 0:1],
-                   jnp.where(lane < 2 * strip, wq[:, 1:2], wq[:, 2:3]))
-    lhs = jnp.where(ohl, wl, jnp.zeros((), jnp.int32)).astype(jnp.int8)
-    if int8_bins:
-        binb = bins_ref[:].astype(jnp.int32).astype(jnp.int8)
-        rep = jax.lax.dot_general(                       # (C, G*B) i32
-            binb, emat_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-    else:
-        binb = bins_ref[:].astype(jnp.int32).astype(jnp.bfloat16)
-        rep = jax.lax.dot_general(
-            binb, emat_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(jnp.int32)
-    ohb = (rep == bcol_ref[0:1, :]).astype(jnp.int8)
-    out_ref[:] += jax.lax.dot_general(
-        lhs, ohb, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("max_group_bin", "block", "strips",
-                              "interpret"))
-def compute_group_histograms_q_packed(
-        bins: jax.Array, wq: jax.Array, scales: jax.Array,
-        leaf_id: jax.Array, slots: jax.Array, *, max_group_bin: int,
-        block: int = 2048, strips: int = 1,
-        interpret: bool = False) -> jax.Array:
-    """Packed-lane on-the-fly int8 histogram: ``slots`` must hold at
-    most strips*PACKED_STRIP valid entries; returns
-    (strips*PACKED_STRIP, G, B, 3) following (padded) ``slots`` order."""
-    num_groups = bins.shape[1]
-    cap = PACKED_STRIP * strips
-    slot_row = _pack_slot_tiles(slots, strips)[None, :]  # (1, 128*strips)
-    int8_bins = max_group_bin <= 127
-    kind = "i8" if int8_bins else "bf16_i32"
-    emat, bcol = _expansion_consts(num_groups, max_group_bin, kind)
-    kern = functools.partial(_hist_kernel_body_q_packed, strip=PACKED_STRIP,
-                             strips=strips, int8_bins=int8_bins)
-    out = _run_hist_kernel(
-        kern, bins, wq, leaf_id, [emat, bcol, slot_row], block=block,
-        name="compute_group_histograms_q_packed",
-        m_leaf=128 * strips, m_pad=128 * strips, num_leaves=cap,
-        max_group_bin=max_group_bin, out_dtype=jnp.int32,
-        interpret=interpret, raw_out=True)
-    hist = _unpack_strip_channels(out, strips, num_groups, max_group_bin)
-    return hist.astype(jnp.float32) * scales[None, None, None, :]
+    return jnp.transpose(hist, (1, 2, 3, 0))
 
 
 PACKED_STRIP = 42  # 3 channels x 42 slots fit one 128-lane tile
@@ -1017,104 +765,21 @@ def _unpack_strip_channels(out: jax.Array, strips: int, num_groups: int,
 def tiled_hist_width(num_groups: int, max_group_bin: int) -> int:
     """Lane width of the tiled-iota kernels' output block: ``per_tile``
     groups packed per 128-lane tile (the layout contract shared by
-    _hist_kernel_body_q_tiled / _fused_kernel_body_q_tiled and the
-    grower's VMEM-aware block-size heuristic)."""
+    _fused_kernel_body_q_tiled and the kernel plan's VMEM-aware
+    block-size rule)."""
     b = max_group_bin
     per_tile = max(1, 128 // b)
     tile_w = 128 if b <= 128 else _round_up(b, 128)
     return ((num_groups + per_tile - 1) // per_tile) * tile_w
 
 
-def _hist_kernel_body_q_tiled(binsT_ref, wT_ref, leafT_ref, slots_ref,
-                              out_ref, *, strip, strips, max_group_bin,
-                              num_groups, packed_groups=0):
-    """Fast on-the-fly int8 kernel: the bin one-hot is rebuilt in VMEM
-    per 128-lane TILE by a single iota compare — no expansion matmul.
-
-    The old q_packed rebuild route (bins @ E with a (G, G*B) constant)
-    is MXU-hostile: K = G = 28 pads to 128 (4.6x wasted systolic rows)
-    and runs bf16, making the rebuild several times the cost of the
-    histogram dot itself.  Here everything is TRANSPOSED (the fused
-    kernel's Mosaic-friendly orientation: per-row scalars are (1, C)
-    lane vectors, one-hots are built (rows, C) by broadcasting an iota
-    COLUMN against (1, C) rows — sublane broadcasts, no cross-lane
-    shuffles).  Each one-hot tile packs ``per_tile = 128 // B`` groups
-    as SUBLANE ranges; the tile is ``target == sublane_iota`` with
-    ``target`` selecting the owning group's bins row offset by k*B —
-    ~3 VPU ops/element.  Output rows follow the tile layout; the
-    wrapper reshuffles to (slot, G, B, 3)."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    lhs = _tiled_lhs(leafT_ref[:], wT_ref[:], slots_ref[:], strip=strip,
-                     strips=strips)
-    binb = binsT_ref[:].astype(jnp.int32)                # (G|S, C)
-    _tiled_onehot_dots(lhs, binb, out_ref, max_group_bin=max_group_bin,
-                       num_groups=num_groups,
-                       packed_groups=packed_groups)
-
-
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "strips",
-                              "interpret", "packed_groups"))
-def compute_group_histograms_q_tiled(
-        binsT: jax.Array, wT: jax.Array, scales: jax.Array,
-        leaf_id: jax.Array, slots: jax.Array, *, max_group_bin: int,
-        block: int = 2048, strips: int = 1,
-        interpret: bool = False, packed_groups: int = 0) -> jax.Array:
-    """Tiled-iota on-the-fly int8 histogram: same contract as
-    :func:`compute_group_histograms_q_packed` but takes TRANSPOSED
-    inputs (binsT (G, N) uint8 — or the (cols, N) nibble-packed
-    storage when ``packed_groups`` > 0 — and wT (3, N) int32
-    quantized).  ``slots`` holds at most strips*PACKED_STRIP valid
-    entries; returns (strips*PACKED_STRIP, G, B, 3) following (padded)
-    ``slots`` order."""
-    num_groups = logical_groups(binsT.shape[0], packed_groups) \
-        if packed_groups else binsT.shape[0]
-    b = max_group_bin
-    per_tile = max(1, 128 // b)
-    tile_w = 128 if b <= 128 else _round_up(b, 128)
-    num_tiles = (num_groups + per_tile - 1) // per_tile
-    m_pad = 128 * strips
-    slot_col = _pack_slot_tiles(slots, strips)[:, None]  # (m_pad, 1)
-    kern = functools.partial(_hist_kernel_body_q_tiled, strip=PACKED_STRIP,
-                             strips=strips, max_group_bin=b,
-                             num_groups=num_groups,
-                             packed_groups=packed_groups)
-    n = binsT.shape[1]
-    if n % block != 0:
-        raise ValueError(f"N ({n}) must be a multiple of block ({block})")
-    s_rows = binsT.shape[0]              # storage rows (== G unpacked)
-    out = pl.pallas_call(
-        kern,
-        grid=(n // block,),
-        in_specs=[
-            pl.BlockSpec((s_rows, block), lambda i: (0, i)),
-            pl.BlockSpec((3, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec(slot_col.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((m_pad, num_tiles * tile_w),
-                               lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, num_tiles * tile_w),
-                                       jnp.int32),
-        interpret=interpret, name="compute_group_histograms_q_tiled",
-    )(binsT, wT, leaf_id[None, :], slot_col)
-    hist = _tiled_out_to_hist(out, strips, num_groups, b)
-    return hist.astype(jnp.float32) * scales[None, None, None, :]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("max_group_bin", "block", "strips", "quant",
                               "interpret", "pack", "num_groups"))
 def compute_group_histograms_pre_packed(
-        ohb: jax.Array, w: jax.Array, scales: Optional[jax.Array],
-        leaf_id: jax.Array, slots: jax.Array, *, max_group_bin: int,
-        block: int = 1024, strips: int = 1, quant: bool = False,
-        interpret: bool = False, pack: int = 1,
+        ohb: jax.Array, w: jax.Array, leaf_id: jax.Array,
+        slots: jax.Array, *, max_group_bin: int, block: int = 1024,
+        strips: int = 1, interpret: bool = False, pack: int = 1,
         num_groups: Optional[int] = None) -> jax.Array:
     """Channel-packed streamed-one-hot histogram: ``slots`` must hold
     at most strips*PACKED_STRIP valid entries; returns
@@ -1130,19 +795,13 @@ def compute_group_histograms_pre_packed(
     gb = num_groups * max_group_bin
     slot_row = _pack_slot_tiles(slots, strips)[None, :]  # (1, 128*strips)
     kern = functools.partial(_hist_kernel_body_pre_packed,
-                             strip=PACKED_STRIP, strips=strips,
-                             quant=quant, pack=pack)
+                             strip=PACKED_STRIP, strips=strips, pack=pack)
     out = _run_hist_kernel_pre(
         kern, ohb, w, leaf_id, slot_row, block=block, m_pad=128 * strips,
-        name="compute_group_histograms_pre_packed",
-        out_dtype=jnp.int32 if quant else jnp.float32,
-        interpret=interpret,
+        name="compute_group_histograms_pre_packed", interpret=interpret,
         out_cols=None if pack == 1 else pack * ohb.shape[1])
     out = _departition_planes(out, pack, gb)
-    hist = _unpack_strip_channels(out, strips, num_groups, max_group_bin)
-    if quant:
-        hist = hist.astype(jnp.float32) * scales[None, None, None, :]
-    return hist
+    return _unpack_strip_channels(out, strips, num_groups, max_group_bin)
 
 
 def _route_prologue_T(binb, leaf, routeT, *, num_groups, nb,
@@ -1238,14 +897,15 @@ def _tiled_lhs(leaf, w, slot_col, *, strip, strips):
 
 
 def _tiled_onehot_dots(lhs, binb, out_ref, *, max_group_bin, num_groups,
-                       row_start=None, packed_groups=0):
+                       packed_groups=0):
     """Shared tiled-iota histogram accumulate: rebuild the bin one-hot
     per 128-lane tile from the (G, C) int32 bins block and dot ``lhs``
-    ((m_pad, C) int8) into the tile's output slice.  See
-    _hist_kernel_body_q_tiled for the layout contract.  With
-    ``row_start`` (a traced scalar) the contribution lands in the
-    dynamic sublane window [row_start, row_start + lhs rows) — the
-    segment-addressed kernel's per-slot strip."""
+    ((m_pad, C) int8) into the tile's output slice.  Everything is
+    TRANSPOSED (per-row scalars are (1, C) lane vectors, one-hots are
+    built (rows, C) by broadcasting an iota COLUMN against (1, C) rows —
+    sublane broadcasts, no cross-lane shuffles).  A tile packs
+    ``per_tile = 128 // B`` groups as SUBLANE ranges (tiled_hist_width);
+    the wrapper reshuffles the tile layout to (slot, G, B, 3)."""
     b = max_group_bin
     c = binb.shape[1]
     per_tile = max(1, 128 // b)
@@ -1270,17 +930,12 @@ def _tiled_onehot_dots(lhs, binb, out_ref, *, max_group_bin, num_groups,
         contrib = jax.lax.dot_general(
             lhs, oh, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32)
-        if row_start is None:
-            out_ref[:, t * tile_w:(t + 1) * tile_w] += contrib
-        else:
-            out_ref[pl.ds(row_start, lhs.shape[0]),
-                    t * tile_w:(t + 1) * tile_w] += contrib
+        out_ref[:, t * tile_w:(t + 1) * tile_w] += contrib
 
 
 def _fused_kernel_body(ohb_ref, binsT_ref, wT_ref, leafT_ref, routeT_ref,
                        slots_ref, hist_ref, leaf_out_ref, *, strip,
-                       strips, quant, num_groups, nb, pack=1,
-                       packed_groups=0):
+                       strips, num_groups, nb, pack=1, packed_groups=0):
     """Route-then-histogram kernel: one row-block applies the PENDING
     per-leaf route table (the splits selected last round) to its rows,
     writes the new leaf ids, and accumulates the frontier histogram
@@ -1316,22 +971,16 @@ def _fused_kernel_body(ohb_ref, binsT_ref, wT_ref, leafT_ref, routeT_ref,
     slot_col = slots_ref[:]                              # (m_pad, 1)
     ohl = slot_col == new_leaf                           # (m_pad, C)
     riota = jax.lax.broadcasted_iota(jnp.int32, (m_pad, 1), 0) % 128
-    w = wT_ref[:]                                        # (3, C)
+    w = wT_ref[:]                                        # (3, C) f32
     wl = jnp.where(riota < strip, w[0:1, :],
                    jnp.where(riota < 2 * strip, w[1:2, :], w[2:3, :]))
-    if quant:
-        lhs = jnp.where(ohl, wl, jnp.zeros((), jnp.int32)).astype(jnp.int8)
-        rdt, odt = jnp.int8, jnp.int32
-    else:
-        lhs = jnp.where(ohl, wl,
-                        jnp.zeros((), jnp.float32)).astype(jnp.bfloat16)
-        rdt, odt = jnp.bfloat16, jnp.float32
+    lhs = jnp.where(ohl, wl,
+                    jnp.zeros((), jnp.float32)).astype(jnp.bfloat16)
     gbp_pad = ohb_ref.shape[1]
-    for p, (plane, sh) in enumerate(
-            _unpack_ohb_planes(ohb_ref[:], pack, rdt)):
+    for p, (plane, sh) in enumerate(_unpack_ohb_planes(ohb_ref[:], pack)):
         contrib = _descale_contrib(jax.lax.dot_general(
             lhs, plane, (((1,), (0,)), ((), ())),
-            preferred_element_type=odt), sh)
+            preferred_element_type=jnp.float32), sh)
         if pack == 1:
             hist_ref[:] += contrib
         else:
@@ -1339,14 +988,13 @@ def _fused_kernel_body(ohb_ref, binsT_ref, wT_ref, leafT_ref, routeT_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("max_group_bin", "block", "strips", "quant",
+    jax.jit, static_argnames=("max_group_bin", "block", "strips",
                               "interpret", "pack", "num_groups",
                               "packed_groups"))
 def compute_group_histograms_fused(
         ohb: jax.Array, binsT: jax.Array, wT: jax.Array,
-        scales: Optional[jax.Array], leaf_id: jax.Array,
-        route_tab: jax.Array, slots: jax.Array, *, max_group_bin: int,
-        block: int = 2048, strips: int = 1, quant: bool = False,
+        leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
+        max_group_bin: int, block: int = 2048, strips: int = 1,
         interpret: bool = False, pack: int = 1,
         num_groups: Optional[int] = None, packed_groups: int = 0):
     """Fused route+histogram: returns ``(hist, new_leaf)`` where
@@ -1359,8 +1007,7 @@ def compute_group_histograms_fused(
         then required).
       binsT: (G, N) uint8 TRANSPOSED packed bins (routing reads the
         chosen group's bin per row as a lane vector).
-      wT: (3, N) weight channels — float32 (grad, hess, cnt) or int32
-        quantized (then ``scales`` dequantizes).
+      wT: (3, N) float32 weight channels (grad, hess, cnt).
       leaf_id: (N,) int32 pre-route leaf ids.
       route_tab: (L, 15+ceil(B_f/8)) f32 route table from
         ops/partition.py build_route_table; an all-zero table routes
@@ -1383,7 +1030,7 @@ def compute_group_histograms_fused(
     m_pad = 128 * strips
 
     kern = functools.partial(_fused_kernel_body, strip=PACKED_STRIP,
-                             strips=strips, quant=quant,
+                             strips=strips,
                              num_groups=num_groups, nb=K - 15, pack=pack,
                              packed_groups=packed_groups)
     s_rows = binsT.shape[0]              # storage rows (== G unpacked)
@@ -1403,18 +1050,14 @@ def compute_group_histograms_fused(
             pl.BlockSpec((1, block), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m_pad, out_cols),
-                                 jnp.int32 if quant else jnp.float32),
+            jax.ShapeDtypeStruct((m_pad, out_cols), jnp.float32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         interpret=interpret, name="compute_group_histograms_fused",
     )(ohb, binsT, wT, leaf_id[None, :], routeT, slot_col)
     hist = _departition_planes(hist, pack, gb)
-    out = _unpack_strip_channels(hist, strips, num_groups,
-                                 max_group_bin).astype(jnp.float32)
-    if quant:
-        out = out * scales[None, None, None, :]
-    return out, leaf_out[0]
+    return _unpack_strip_channels(hist, strips, num_groups,
+                                  max_group_bin), leaf_out[0]
 
 
 def _fused_kernel_body_q_tiled(binsT_ref, wT_ref, leafT_ref, routeT_ref,
@@ -1738,109 +1381,6 @@ def compute_group_histograms_fused_factored(
     return hist * scales[None, None, None, :], leaf_out[0]
 
 
-def _hist_kernel_body_seg_tiled(blk_slot_ref, binsT_ref, wT_ref, out_ref,
-                                *, max_group_bin, num_groups,
-                                packed_groups=0):
-    """Segment-addressed tiled-iota kernel — the leaf-partitioned
-    formulation's histogram pass.  Rows arrive PHYSICALLY grouped by
-    leaf (ops/partition.py build_leaf_partition: block-aligned
-    segments), so each row block belongs to exactly ONE frontier slot
-    (``blk_slot_ref``, scalar-prefetched) and the LHS is the raw
-    (8, C) weight strip — rows 0..2 the quantized grad/hess/count
-    channels, rows 3..7 zero.  The leaf one-hot, its VPU build cost,
-    and the 128-row systolic dot (of which the slot-packed kernels use
-    3/128 per slot) all disappear: the dot runs 8 rows, 16x less MXU
-    work per streamed byte.  Dead blocks (slot -1: alignment gaps,
-    non-frontier segments, capacity tail) skip compute but still pay
-    their stream DMA — the formulation's floor is the stream, not the
-    dot (docs/PARTITION_DESIGN.md round-6 record has the full
-    decomposition)."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    k = blk_slot_ref[i]
-
-    @pl.when(k >= 0)
-    def _accum():
-        c = wT_ref.shape[1]
-        w = wT_ref[:]                                    # (3, C) int32
-        riota = jax.lax.broadcasted_iota(jnp.int32, (8, c), 0)
-        wl = jnp.where(riota == 0, w[0:1, :],
-                       jnp.where(riota == 1, w[1:2, :],
-                                 jnp.where(riota == 2, w[2:3, :],
-                                           jnp.zeros((), jnp.int32))))
-        lhs = wl.astype(jnp.int8)                        # (8, C)
-        binb = binsT_ref[:].astype(jnp.int32)            # (G|S, C)
-        _tiled_onehot_dots(lhs, binb, out_ref,
-                           max_group_bin=max_group_bin,
-                           num_groups=num_groups, row_start=8 * k,
-                           packed_groups=packed_groups)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("num_out", "max_group_bin", "block",
-                              "interpret", "packed_groups"))
-def compute_group_histograms_seg_tiled(
-        binsT_p: jax.Array, wT_p: jax.Array, scales: jax.Array,
-        blk_slot: jax.Array, *, num_out: int, max_group_bin: int,
-        block: int = 512, interpret: bool = False,
-        packed_groups: int = 0) -> jax.Array:
-    """Leaf-partitioned histogram: inputs are in PARTITIONED row order
-    (binsT_p (G, n_cap) uint8 and wT_p (3, n_cap) int32 gathered
-    through a build_leaf_partition permutation; gap rows carry zero
-    weight), ``blk_slot`` maps each row block to its output slot (-1 =
-    skip).  Returns (num_out, G, B, 3) f32 dequantized by ``scales`` —
-    same output contract as compute_group_histograms_q_tiled with
-    ``slots`` replaced by the block map.  VMEM note: the accumulator is
-    (8*num_out, hist_width) int32 — 7.2 MB at num_out=126 and the
-    bench shape, so wide frontiers want the caller to cap num_out the
-    way the slot-packed ladder does."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    num_groups = logical_groups(binsT_p.shape[0], packed_groups) \
-        if packed_groups else binsT_p.shape[0]
-    b = max_group_bin
-    per_tile = max(1, 128 // b)
-    tile_w = 128 if b <= 128 else _round_up(b, 128)
-    num_tiles = (num_groups + per_tile - 1) // per_tile
-    n_cap = binsT_p.shape[1]
-    if n_cap % block != 0:
-        raise ValueError(
-            f"n_cap ({n_cap}) must be a multiple of block ({block})")
-    m_out = 8 * num_out
-    kern = functools.partial(_hist_kernel_body_seg_tiled,
-                             max_group_bin=b, num_groups=num_groups,
-                             packed_groups=packed_groups)
-    s_rows = binsT_p.shape[0]            # storage rows (== G unpacked)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_cap // block,),
-        in_specs=[
-            pl.BlockSpec((s_rows, block), lambda i, bs: (0, i)),
-            pl.BlockSpec((3, block), lambda i, bs: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((m_out, num_tiles * tile_w),
-                               lambda i, bs: (0, 0)),
-    )
-    out = pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m_out, num_tiles * tile_w),
-                                       jnp.int32),
-        interpret=interpret, name="compute_group_histograms_seg_tiled",
-    )(blk_slot.astype(jnp.int32), binsT_p, wT_p)
-    # slot k's channels live in rows [8k, 8k+3); tile layout matches
-    # the tiled-iota kernels (per_tile groups per 128-lane tile)
-    tiles = out.reshape(num_out, 8, num_tiles,
-                        tile_w)[:, :3, :, :per_tile * b]
-    full = tiles.reshape(num_out, 3, num_tiles * per_tile,
-                         b)[:, :, :num_groups]
-    hist = jnp.transpose(full, (0, 2, 3, 1))
-    return hist.astype(jnp.float32) * scales[None, None, None, :]
-
-
 def _transpose_pad_route(table: jax.Array) -> jax.Array:
     """(L, K) route table -> (K, l_pad) transposed, zero-padded to a
     128-multiple leaf axis — the in-VMEM orientation every fused/route
@@ -1870,58 +1410,6 @@ def _route_value_kernel_body(binsT_ref, leafT_ref, routeT_ref,
     vr = scal[k0 + 3:k0 + 4] + scal[k0 + 4:k0 + 5] + scal[k0 + 5:k0 + 6]
     val = jnp.where(went_right, vr, vk)
     val_out_ref[:] = jnp.where(leaf >= 0, val, 0.0)
-
-
-def _route_only_kernel_body(binsT_ref, leafT_ref, routeT_ref,
-                            leaf_out_ref, *, num_groups, nb,
-                            packed_groups=0):
-    """Route-only kernel: the per-round split routing as its own
-    stream, leaving the histogram passes to the plain (route-free)
-    tiled kernel — the split-route alternative to fusing the route
-    into the histogram kernel's first pass."""
-    leaf_out_ref[:] = _route_prologue_T(
-        binsT_ref[:].astype(jnp.int32), leafT_ref[:], routeT_ref[:],
-        num_groups=num_groups, nb=nb, packed_groups=packed_groups)
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret",
-                                             "packed_groups"))
-def route_only_tiled(binsT: jax.Array, leaf_id: jax.Array,
-                     route_tab: jax.Array, *, block: int = 8192,
-                     interpret: bool = False,
-                     packed_groups: int = 0) -> jax.Array:
-    """Apply a pending route table to leaf ids via the in-VMEM
-    broadcast (no histogram, no values).  Returns the (N,) post-route
-    leaf ids."""
-    num_groups = logical_groups(binsT.shape[0], packed_groups) \
-        if packed_groups else binsT.shape[0]
-    if num_groups >= 65536:  # fg // 256 must stay bf16-exact
-        raise ValueError(
-            "route_only_tiled supports at most 65535 feature groups, "
-            f"got {num_groups} — the route table encodes the group "
-            "index as two bf16-exact bytes (hi/lo)")
-    n = binsT.shape[1]
-    if n % block != 0:
-        raise ValueError(f"N ({n}) must be a multiple of block ({block})")
-    routeT = _transpose_pad_route(route_tab)
-    kern = functools.partial(
-        _route_only_kernel_body, num_groups=num_groups,
-        nb=route_tab.shape[1] - ROUTE_FIXED_COLS,
-        packed_groups=packed_groups)
-    s_rows = binsT.shape[0]
-    leaf_out = pl.pallas_call(
-        kern,
-        grid=(n // block,),
-        in_specs=[
-            pl.BlockSpec((s_rows, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec(routeT.shape, lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        interpret=interpret, name="route_only_tiled",
-    )(binsT, leaf_id[None, :], routeT)
-    return leaf_out[0]
 
 
 @functools.partial(

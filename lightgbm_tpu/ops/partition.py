@@ -289,94 +289,6 @@ def apply_route_table(bins: jax.Array, leaf_id: jax.Array,
     return new_leaf, row_value
 
 
-def partition_capacity(n: int, num_slots: int, block: int) -> int:
-    """Static row capacity of a leaf partition: every one of the
-    ``num_slots + 1`` buckets (leaf slots plus the invalid bucket) can
-    waste up to one block of alignment padding."""
-    return n + (num_slots + 1) * block
-
-
-@functools.partial(jax.jit, static_argnames=("num_slots", "block"))
-def build_leaf_partition(leaf_id: jax.Array, *, num_slots: int,
-                         block: int):
-    """Stable leaf-segment permutation under static shapes — the
-    DataPartition index layout (reference data_partition.hpp:109-161)
-    re-expressed for the segment-addressed histogram kernel
-    (ops/histogram.py compute_group_histograms_seg_tiled).
-
-    Rows are stably ordered by leaf id (invalid rows — ``leaf_id < 0``
-    — go to a trailing bucket) and each leaf's segment start is aligned
-    UP to a ``block`` multiple, so every kernel row-block belongs to
-    exactly ONE leaf and the kernel's LHS needs no leaf one-hot at all.
-    Alignment gaps are -1 entries; gathers through the permutation use
-    mode="fill" so gap rows contribute zero weight.
-
-    Cost note (why this path is gated off by default): the sort is
-    XLA sort_key_val (~5 ms at 1M rows on v5e) and consumers pay one
-    row gather per permuted operand (~80M rows/s regardless of row
-    width) — see docs/PARTITION_DESIGN.md round-6 record.
-
-    Args:
-      leaf_id: (N,) int32; negative = padded/out-of-tree row.
-      num_slots: static L — leaf slots (ids in [0, L)).
-      block: static alignment granularity = the kernel row-block size.
-
-    Returns (perm, blk_leaf, seg_count):
-      perm: (partition_capacity(N),) int32 — source row per partitioned
-        position, -1 in alignment gaps.
-      blk_leaf: (capacity // block,) int32 — owning leaf per block, -1
-        for blocks holding no real rows (gap tails, the invalid
-        bucket, and the unused capacity tail).
-      seg_count: (num_slots + 1,) int32 — real rows per bucket (last =
-        invalid).
-    """
-    n = leaf_id.shape[0]
-    if n % block:
-        raise ValueError(f"N ({n}) must be a multiple of block ({block})")
-    num_buckets = num_slots + 1
-    lid = jnp.where(leaf_id >= 0, leaf_id, num_slots).astype(jnp.int32)
-    iota = jnp.arange(n, dtype=jnp.int32)
-    sorted_lid, order = jax.lax.sort_key_val(lid, iota, is_stable=True)
-    bucket_iota = jnp.arange(num_buckets, dtype=jnp.int32)
-    seg_first = jnp.searchsorted(sorted_lid, bucket_iota,
-                                 side="left").astype(jnp.int32)
-    seg_count = (jnp.searchsorted(sorted_lid, bucket_iota,
-                                  side="right").astype(jnp.int32)
-                 - seg_first)
-    aligned = ((seg_count + block - 1) // block) * block
-    astart = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(aligned)])[:num_buckets]
-    # partitioned position of sorted entry i: its bucket's aligned
-    # start plus its stable rank within the bucket
-    pos = astart[sorted_lid] + (iota - seg_first[sorted_lid])
-    n_cap = partition_capacity(n, num_slots, block)
-    perm = jnp.full(n_cap, -1, jnp.int32).at[pos].set(order)
-    nblk = n_cap // block
-    bstart = jnp.arange(nblk, dtype=jnp.int32) * block
-    blk_leaf = (jnp.searchsorted(astart, bstart, side="right")
-                .astype(jnp.int32) - 1)
-    safe = jnp.clip(blk_leaf, 0, num_buckets - 1)
-    live = (bstart < astart[safe] + seg_count[safe]) \
-        & (blk_leaf < num_slots)
-    return perm, jnp.where(live, blk_leaf, -1), seg_count
-
-
-def apply_partition(arr: jax.Array, perm: jax.Array,
-                    axis: int = 0) -> jax.Array:
-    """Gather ``arr`` rows into partitioned order (gap entries -> 0).
-    Gap indices (-1) are masked explicitly — jnp.take wraps negative
-    indices python-style even under mode="fill", which would alias the
-    LAST source row into every alignment gap.  This is the path's
-    dominant cost: an N-row XLA gather per operand per round (see
-    build_leaf_partition cost note)."""
-    taken = jnp.take(arr, jnp.clip(perm, 0, arr.shape[axis] - 1),
-                     axis=axis)
-    shape = [1] * arr.ndim
-    shape[axis] = perm.shape[0]
-    return jnp.where((perm >= 0).reshape(shape), taken,
-                     jnp.zeros((), arr.dtype))
-
-
 def apply_splits(bins: jax.Array, leaf_id: jax.Array,
                  split_mask: jax.Array, feat_group: jax.Array,
                  fb_lo: jax.Array, fb_hi: jax.Array, fb_shift: jax.Array,

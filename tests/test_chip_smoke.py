@@ -46,7 +46,7 @@ def test_legs_run_on_the_interpret_seam():
     seam = {**TINY, "force_pallas_interpret": True}
     bst_a, a = chip_smoke.leg_fast(lgb, X, y, Xv, yv, ROUNDS, extra=seam,
                                    interpret=True)
-    assert a["plan"]["use_fused"] and a["plan"]["_interp"]
+    assert a["plan"]["tier"] == "ladder" and a["plan"]["interpret"]
     assert a["hist_path"] == "fused_tiled"
     assert a["dispatch_chunk_auto"] is None     # the TPU-only branch
     bst_b, b = chip_smoke.leg_default(lgb, X, y, Xv, yv, ROUNDS, extra=TINY)
@@ -76,7 +76,7 @@ def test_leg_d_runs_the_ladder_on_every_shard_of_the_seam():
         lgb, X, y, Xv, ROUNDS, n_chips=4,
         extra={**TINY, "force_pallas_interpret": True}, interpret=True)
     assert d["model_text_equal_one_chip"] and d["max_abs_vs_one_chip"] == 0
-    assert d["plan"]["use_fused"] and d["plan"]["use_quant"]
+    assert d["plan"]["tier"] == "ladder" and d["plan"]["row_shards"] == 4
     assert "compute_group_histograms_fused_tiled" in d["kernels"]
     assert "route_apply_tiled" in d["kernels"]
     assert len(d["devices"]) == 4

@@ -121,8 +121,8 @@ def test_model_text_does_not_depend_on_the_shards(table, serial_trees,
     bst = lgb.train(mesh_params(shards), lgb.Dataset(X, label=y), 4,
                     verbose_eval=False, keep_training_booster=True)
     g = bst.gbdt.grower
-    assert g.use_quant and g.use_tiled and g.use_fused and g._mesh_kernels
-    assert g.hist_factored_rungs          # 255 bins: the rungs are in force
+    assert g.plan.tier == "ladder" and g.plan.mesh_kernels
+    assert g.plan.factored_rungs          # 255 bins: the rungs are in force
     gauges = TELEMETRY.gauges()
     assert gauges["grower.quantized"] == 1
     assert gauges["grower.hist_kernel"] == "fused_tiled"
@@ -254,7 +254,7 @@ def test_tree_counts_are_whole_rows_under_the_mesh(table, shards):
     X, y = table
     bst = lgb.train(mesh_params(shards), lgb.Dataset(X, label=y), 2,
                     verbose_eval=False, keep_training_booster=True)
-    assert bst.gbdt.grower._int_counts
+    assert bst.gbdt.grower.plan.int_counts
     leaves = bst.predict(X, pred_leaf=True)
     for t, tree in enumerate(bst.gbdt.models):
         rows = np.bincount(leaves[:, t], minlength=tree.num_leaves)
@@ -270,8 +270,7 @@ def test_tree_counts_are_whole_rows_under_the_mesh(table, shards):
      "hist_kernel=pallas cannot run here"),
     (dict(tree_learner="voting"), "hist_kernel=pallas cannot run here"),
     (dict(hist_exchange="q16"), "hist_exchange=q16 cannot run here"),
-    (dict(hist_split_route=True), "hist_split_route cannot run here"),
-], ids=["feature_mesh", "two_axis_mesh", "voting", "codec", "split_route"])
+], ids=["feature_mesh", "two_axis_mesh", "voting", "codec"])
 def test_unhonourable_requests_raise(table, extra, match):
     X, y = table
     with pytest.raises(ValueError, match=match):
@@ -288,7 +287,7 @@ def test_auto_keeps_the_xla_path_where_the_ladder_cannot_run(table):
     bst = lgb.train(params, lgb.Dataset(X, label=y), 1, verbose_eval=False,
                     keep_training_booster=True)
     g = bst.gbdt.grower
-    assert not g.use_pallas and not g._mesh_kernels
+    assert g.plan.tier == "xla" and not g.plan.mesh_kernels
     assert TELEMETRY.gauges()["grower.hist_kernel"] == "xla"
     assert TELEMETRY.gauges()["grower.hist_exchange_bytes_widest"] == 0
 
@@ -303,8 +302,8 @@ def test_auto_keeps_the_codec_on_the_xla_path(table):
     bst = lgb.train(params, lgb.Dataset(X, label=y), 1, verbose_eval=False,
                     keep_training_booster=True)
     g = bst.gbdt.grower
-    assert not g.use_pallas and not g._mesh_kernels and not g._int_counts
-    assert g.hist_exchange == "q16"
+    assert g.plan.tier == "xla" and not g.plan.int_counts
+    assert g.plan.hist_exchange == "q16"
     assert TELEMETRY.gauges()["grower.hist_kernel"] == "xla"
 
 

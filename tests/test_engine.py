@@ -3,6 +3,7 @@ tests/python_package_test/test_engine.py (binary :35, regression :82,
 missing-value matrix :101-213, categorical :214-281, multiclass :282,
 early stopping :330, continued training :361, cv :413, feature name
 :437, save/load/pickle :450, SHAP :533, monotone :603)."""
+import dataclasses
 import pickle
 
 import numpy as np
@@ -695,7 +696,8 @@ def test_lambdarank_quantized_stochastic():
                                                       true_sum)
 
     # auto mode resolves per objective: lambdarank needs it, binary
-    # does not (the grower's use_quant gate is forced on for the check)
+    # does not (the grower's plan is replaced by a quantized one for the
+    # check)
     import lightgbm_tpu as lgb
     from lightgbm_tpu.boosting.gbdt import GBDT
     from lightgbm_tpu.config import Config
@@ -710,7 +712,8 @@ def test_lambdarank_quantized_stochastic():
         cfg = Config.from_params(p)
         core = lgb.Dataset(X, **kw).construct(cfg)
         g = GBDT(cfg, core)
-        g.grower.use_quant = True          # CPU backend has it off
+        g.grower.plan = dataclasses.replace(   # the CPU backend's is xla
+            g.grower.plan, tier="ladder")
         assert g._quant_stochastic() is want, obj
         g.config.quant_stochastic_rounding = 1 - int(want)
         assert g._quant_stochastic() is (not want), obj

@@ -183,7 +183,7 @@ def test_tiered_rides_quantized_kernel_path():
     TELEMETRY.reset()
     mq, gq = _tier_trees(quantized_grad=True)
     mt, gt = _tier_trees(hist_precision="tiered")
-    assert gt.use_quant, "tiered did not engage the quantized kernels"
+    assert gt.plan.quantized, "tiered did not engage the quantized kernels"
     assert mt == mq
     assert TELEMETRY.gauges().get("grower.hist_precision") == "tiered"
     # the f32 fix-up pass is accounted once per compiled trace
@@ -192,7 +192,7 @@ def test_tiered_rides_quantized_kernel_path():
 
 def test_hist_precision_f32_disables_quant():
     m32, g32 = _tier_trees(hist_precision="f32", quantized_grad=True)
-    assert not g32.use_quant
+    assert not g32.plan.quantized
     mref, _ = _tier_trees()
     assert m32 == mref, "hist_precision=f32 must match the default path"
 

@@ -84,53 +84,14 @@ def test_fused_route_hist_matches_composition_interpret():
     wT = jnp.stack([jnp.asarray(grad), jnp.asarray(hess),
                     jnp.asarray(cnt)], axis=0)
     got_hist, got_leaf = compute_group_histograms_fused(
-        ohb, jnp.asarray(bins.T), wT, None, jnp.asarray(leaf), tab,
-        slots, max_group_bin=B, block=256, strips=1, quant=False,
-        interpret=True)
+        ohb, jnp.asarray(bins.T), wT, jnp.asarray(leaf), tab,
+        slots, max_group_bin=B, block=256, strips=1, interpret=True)
     np.testing.assert_array_equal(np.asarray(got_leaf), want_leaf)
     got = np.asarray(got_hist)[:slots.shape[0]]
     ref = np.asarray(want_hist)
     scale = np.abs(ref).max() + 1.0
     assert np.abs(ref - got).max() / scale < 5e-3
     assert np.abs(ref[..., 2] - got[..., 2]).max() == 0.0
-
-
-def test_fused_route_hist_quant_interpret():
-    """Quantized fused kernel: int8 weights accumulate exactly."""
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_fused, precompute_bin_onehot,
-        quantize_gradients)
-
-    rng = np.random.RandomState(2)
-    N, G, B, L = 512, 4, 8, 6
-    bins = rng.randint(0, B, (N, G)).astype(np.uint8)
-    grad = rng.randn(N).astype(np.float32)
-    hess = np.abs(rng.randn(N)).astype(np.float32)
-    cnt = np.ones(N, np.float32)
-    leaf = rng.randint(0, 4, N).astype(np.int32)
-    wq, scales = quantize_gradients(jnp.asarray(grad), jnp.asarray(hess),
-                                    jnp.asarray(cnt))
-    # no-op route table (active column zero)
-    tab = jnp.zeros((L, 15 + (B + 7) // 8), jnp.float32)
-    slots = jnp.asarray(np.arange(4, dtype=np.int32))
-    got_hist, got_leaf = compute_group_histograms_fused(
-        ohb=precompute_bin_onehot(jnp.asarray(bins), max_group_bin=B),
-        binsT=jnp.asarray(bins.T), wT=wq.T, scales=scales,
-        leaf_id=jnp.asarray(leaf), route_tab=tab, slots=slots,
-        max_group_bin=B, block=256, strips=1, quant=True, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got_leaf), leaf)
-    # compare against numpy quantized accumulation (exact int math)
-    wqn = np.asarray(wq)
-    sn = np.asarray(scales)
-    want = np.zeros((4, G, B, 3))
-    for r in range(N):
-        l = leaf[r]
-        if l < 4:
-            for g in range(G):
-                want[l, g, bins[r, g]] += wqn[r]
-    want = want * sn[None, None, None, :]
-    np.testing.assert_allclose(np.asarray(got_hist)[:4], want, rtol=1e-6)
 
 
 def test_subbyte_packed_onehot_matches_full():
@@ -159,9 +120,13 @@ def test_subbyte_packed_onehot_matches_full():
 
 def test_subbyte_streamed_kernels_match_pack1_interpret():
     """pre / pre_packed / fused kernels give identical histograms from
-    the sub-byte packed one-hot (quant path: exact int accumulation)."""
+    the sub-byte packed one-hot.  The weights are the int8 levels as
+    floats (exact in bf16, their sums exact in float32), so every
+    comparison is to the bit — the int8 tiled kernel's accumulators
+    among them."""
     from lightgbm_tpu.ops.histogram import (
-        PACKED_STRIP, compute_group_histograms_fused,
+        compute_group_histograms_fused,
+        compute_group_histograms_fused_tiled,
         compute_group_histograms_pre, compute_group_histograms_pre_packed,
         precompute_bin_onehot, precompute_bin_onehot_packed,
         quantize_gradients)
@@ -174,6 +139,7 @@ def test_subbyte_streamed_kernels_match_pack1_interpret():
     leaf = rng.randint(-1, 8, N).astype(np.int32)
     wq, scales = quantize_gradients(jnp.asarray(grad), jnp.asarray(hess),
                                     jnp.asarray(cnt))
+    w = wq.astype(jnp.float32)
     slots = jnp.asarray(np.array([0, 3, 5, -1, 7, 2], np.int32))
     tab = jnp.zeros((L, 15 + (B + 7) // 8), jnp.float32)
     ohb1 = precompute_bin_onehot(jnp.asarray(bins), max_group_bin=B)
@@ -184,16 +150,16 @@ def test_subbyte_streamed_kernels_match_pack1_interpret():
         ohb = (ohb1 if pack == 1 else precompute_bin_onehot_packed(
             jnp.asarray(bins), max_group_bin=B, pack=pack))
         h_pre = np.asarray(compute_group_histograms_pre(
-            ohb, wq, scales, jnp.asarray(leaf), num_leaves=L,
-            max_group_bin=B, block=256, quant=True, slots=slots,
+            ohb, w, jnp.asarray(leaf), num_leaves=L,
+            max_group_bin=B, block=256, slots=slots,
             interpret=True, pack=pack, num_groups=G))
         h_pp = np.asarray(compute_group_histograms_pre_packed(
-            ohb, wq, scales, jnp.asarray(leaf), slots, max_group_bin=B,
-            block=256, strips=1, quant=True, interpret=True, pack=pack,
+            ohb, w, jnp.asarray(leaf), slots, max_group_bin=B,
+            block=256, strips=1, interpret=True, pack=pack,
             num_groups=G))[:slots.shape[0]]
         h_fu, lf = compute_group_histograms_fused(
-            ohb, jnp.asarray(bins.T), wq.T, scales, jnp.asarray(leaf),
-            tab, slots, max_group_bin=B, block=256, strips=1, quant=True,
+            ohb, jnp.asarray(bins.T), w.T, jnp.asarray(leaf),
+            tab, slots, max_group_bin=B, block=256, strips=1,
             interpret=True, pack=pack, num_groups=G)
         h_fu = np.asarray(h_fu)[:slots.shape[0]]
         np.testing.assert_array_equal(np.asarray(lf), leaf)
@@ -205,22 +171,24 @@ def test_subbyte_streamed_kernels_match_pack1_interpret():
             np.testing.assert_array_equal(h_fu, ref_fu)
     # the three kernel families agree with each other (all outputs are
     # slot-ordered; negative slots are zero rows everywhere)
-    np.testing.assert_allclose(ref_pp, ref_pre, rtol=1e-6)
-    np.testing.assert_allclose(ref_fu, ref_pre, rtol=1e-6)
+    np.testing.assert_array_equal(ref_pp, ref_pre)
+    np.testing.assert_array_equal(ref_fu, ref_pre)
+    # ... and with the plain sum of the levels
+    want = np.zeros((slots.shape[0], G, B, 3))
+    wqn = np.asarray(wq)
+    for r in range(N):
+        for k, s_ in enumerate(np.asarray(slots)):
+            if s_ >= 0 and leaf[r] == s_:
+                for g in range(G):
+                    want[k, g, bins[r, g]] += wqn[r]
+    np.testing.assert_array_equal(ref_pre, want)
 
-    # round-4 tiled-iota kernels (no resident one-hot at all) join the
-    # family parity: both must reproduce the pack=1 streamed results
-    from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_fused_tiled,
-        compute_group_histograms_q_tiled)
-    binsT = jnp.asarray(bins.T)
-    h_qt = np.asarray(compute_group_histograms_q_tiled(
-        binsT, wq.T, scales, jnp.asarray(leaf), slots, max_group_bin=B,
-        block=256, strips=1, interpret=True))[:slots.shape[0]]
-    np.testing.assert_array_equal(h_qt, ref_pp)
+    # the tiled-iota kernel (no resident one-hot at all) joins the
+    # family: its int32 accumulators are the same integers
     h_ft, lf_t = compute_group_histograms_fused_tiled(
-        binsT, wq.T, scales, jnp.asarray(leaf), tab, slots,
-        max_group_bin=B, block=256, strips=1, interpret=True)
+        jnp.asarray(bins.T), wq.T, None, jnp.asarray(leaf), tab, slots,
+        max_group_bin=B, block=256, strips=1, interpret=True,
+        dequantize=False)
     np.testing.assert_array_equal(np.asarray(lf_t), leaf)
     np.testing.assert_array_equal(
         np.asarray(h_ft)[:slots.shape[0]], ref_fu)
@@ -298,144 +266,6 @@ def test_route_apply_tiled_matches_xla_interpret():
                                   np.asarray(want_leaf))
     np.testing.assert_array_equal(np.asarray(got_val),
                                   np.asarray(want_val))
-
-
-def test_seg_tiled_matches_q_tiled_interpret():
-    """Leaf-partitioned segment kernel == slot-packed tiled-iota kernel
-    (exact int accumulation) across bin widths incl. the bench shape's
-    B=63, with negative slots, empty leaves, and padded rows."""
-    from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_q_tiled,
-        compute_group_histograms_seg_tiled, quantize_gradients)
-    from lightgbm_tpu.ops.partition import (apply_partition,
-                                            build_leaf_partition)
-
-    for seed, (N, G, B, L, block) in ((7, (1024, 4, 8, 10, 128)),
-                                      (8, (2048, 5, 63, 20, 256))):
-        rng = np.random.RandomState(seed)
-        leaf = rng.randint(-1, L, N).astype(np.int32)
-        bins = rng.randint(0, B, (N, G)).astype(np.uint8)
-        grad = rng.randn(N).astype(np.float32)
-        hess = np.abs(rng.randn(N)).astype(np.float32)
-        cnt = np.ones(N, np.float32)
-        wq, scales = quantize_gradients(
-            jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(cnt))
-        wT = wq.T
-        binsT = jnp.asarray(bins.T)
-        slots_np = rng.permutation(L)[:6].astype(np.int32)
-        slots_np[3] = -1
-        slots = jnp.asarray(slots_np)
-        ref = np.asarray(compute_group_histograms_q_tiled(
-            binsT, wT, scales, jnp.asarray(leaf), slots,
-            max_group_bin=B, block=256, strips=1,
-            interpret=True))[:slots.shape[0]]
-
-        perm, blk_leaf, _ = build_leaf_partition(
-            jnp.asarray(leaf), num_slots=L, block=block)
-        binsT_p = apply_partition(binsT, perm, axis=1)
-        wT_p = apply_partition(wT, perm, axis=1)
-        inv = np.full(L + 1, -1, np.int32)
-        for i, s in enumerate(slots_np):
-            if s >= 0:
-                inv[s] = i
-        blk_np = np.asarray(blk_leaf)
-        blk_slot = np.where(blk_np >= 0, inv[np.clip(blk_np, 0, L)],
-                            -1).astype(np.int32)
-        got = np.asarray(compute_group_histograms_seg_tiled(
-            binsT_p, wT_p, scales, jnp.asarray(blk_slot),
-            num_out=slots.shape[0], max_group_bin=B, block=block,
-            interpret=True))
-        np.testing.assert_array_equal(got, ref)
-
-
-def test_leaf_partition_grows_identical_trees():
-    """hist_leaf_partition=on (per-round physical regrouping + the
-    segment-addressed kernel) must grow byte-identical models to the
-    default fused tiled decomposition — the formulation changes the
-    kernels, not the semantics.  Runs on the interpret-mode CPU seam
-    like the split-route A/B test above."""
-    import lightgbm_tpu as lgb
-
-    rng = np.random.RandomState(11)
-    X = rng.randn(1536, 8)
-    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(1536)
-         > 0).astype(float)
-    base = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-            "quantized_grad": True, "hist_compute_dtype": "bfloat16",
-            "force_pallas_interpret": True, "min_data_in_leaf": 5}
-    m0 = lgb.train(base, lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    m1 = lgb.train(dict(base, hist_leaf_partition="on"),
-                   lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    assert m0.model_to_string() == m1.model_to_string()
-
-
-# re-tiered slow (tier-1 wall budget): the no-cache arm doubles the
-# training cost of the A/B pin above; the partition route itself stays
-# pinned fast
-@pytest.mark.slow
-def test_leaf_partition_no_cache_identical_trees():
-    """No-cache mode histograms BOTH children through the partition —
-    the parents pass shares the round's permutation."""
-    import lightgbm_tpu as lgb
-
-    rng = np.random.RandomState(11)
-    X = rng.randn(1536, 8)
-    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(1536)
-         > 0).astype(float)
-    base = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-            "quantized_grad": True, "hist_compute_dtype": "bfloat16",
-            "force_pallas_interpret": True, "min_data_in_leaf": 5}
-    nc0 = lgb.train(dict(base, histogram_pool_size=0.001),
-                    lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    nc1 = lgb.train(dict(base, histogram_pool_size=0.001,
-                         hist_leaf_partition="on"),
-                    lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    assert nc0.model_to_string() == nc1.model_to_string()
-
-
-def test_split_route_grows_identical_trees():
-    """hist_split_route=True (dedicated route_only_tiled pass + plain
-    tiled histograms) must grow byte-identical models to the default
-    fused decomposition — the A/B knob changes kernels, not
-    semantics."""
-    import lightgbm_tpu as lgb
-
-    rng = np.random.RandomState(9)
-    X = rng.randn(1536, 8)
-    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(1536)
-         > 0).astype(float)
-    base = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-            "quantized_grad": True, "hist_compute_dtype": "bfloat16",
-            "force_pallas_interpret": True, "min_data_in_leaf": 5}
-    m0 = lgb.train(base, lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    m1 = lgb.train(dict(base, hist_split_route=True),
-                   lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    assert m0.model_to_string() == m1.model_to_string()
-
-
-# re-tiered slow (tier-1 wall budget): the no-cache arm doubles the
-# training cost of the A/B pin above; the split route itself stays
-# pinned fast
-@pytest.mark.slow
-def test_split_route_no_cache_identical_trees():
-    """No-cache mode (histogram_pool_size=0 drops subtraction and
-    histograms BOTH children directly) exercises the split-route
-    left-histogram branch too."""
-    import lightgbm_tpu as lgb
-
-    rng = np.random.RandomState(9)
-    X = rng.randn(1536, 8)
-    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] + 0.3 * rng.randn(1536)
-         > 0).astype(float)
-    base = {"objective": "binary", "num_leaves": 15, "verbose": -1,
-            "quantized_grad": True, "hist_compute_dtype": "bfloat16",
-            "force_pallas_interpret": True, "min_data_in_leaf": 5}
-    nc0 = lgb.train(dict(base, histogram_pool_size=0.001),
-                    lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    nc1 = lgb.train(dict(base, histogram_pool_size=0.001,
-                         hist_split_route=True),
-                    lgb.Dataset(X, label=y), 8, verbose_eval=False)
-    assert nc0.model_to_string() == nc1.model_to_string()
 
 
 # ---------------------------------------------------------------------------
@@ -673,7 +503,7 @@ def test_factored_rungs_leave_narrow_tiles_alone(monkeypatch):
         texts = []
         for max_bin, extra in narrow:
             gauge, text, gr = tree_program(max_bin, **extra)
-            assert gauge == "" and gr.use_tiled and gr.use_fused
+            assert gauge == "" and gr.plan.tier == "ladder"
             assert bool(gr.pack_P) == bool(extra)
             assert kernels(text) == {"tiled": 3}
             texts.append(text)

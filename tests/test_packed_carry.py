@@ -6,8 +6,8 @@ the protocol whose on-chip half is the chunk-90 A/B flag
 The packed carry changes the fused dispatch scan's OUTPUT layout (one
 uint8 record stack vs 18 per-field stacks) and the chunk length
 changes how many iterations share one device program; neither may
-change a single tree byte.  Extends the `hist_split_route` parity
-pattern (tests/test_histogram_kernel.py)."""
+change a single tree byte (the identical-trees pattern of
+tests/test_histogram_kernel.py)."""
 import numpy as np
 import pytest
 

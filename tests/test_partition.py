@@ -70,73 +70,3 @@ def test_apply_splits_matches_reference_over_256_groups(rng):
     want = _route_numpy(*args)
     got = np.asarray(apply_splits(*[jnp.asarray(a) for a in args]))
     np.testing.assert_array_equal(got, want)
-
-
-def test_leaf_partition_roundtrip_property(rng):
-    """build_leaf_partition invariants over random leaf layouts: the
-    permutation is a bijection onto the real rows, segments are stable
-    (source order preserved within a leaf), block-aligned, and every
-    block's ownership map matches the rows it actually holds; gathering
-    through the permutation reconstructs exactly the per-leaf row sets
-    (the round-trip the grower's segment kernel relies on)."""
-    from lightgbm_tpu.ops.partition import (apply_partition,
-                                            build_leaf_partition,
-                                            partition_capacity)
-
-    for n, L, block in ((256, 3, 64), (1024, 17, 128), (512, 255, 256)):
-        leaf = rng.randint(-1, L, n).astype(np.int32)
-        # exercise empty leaves and a dominant leaf too
-        leaf[rng.rand(n) < 0.3] = min(2, L - 1)
-        perm, blk_leaf, seg_count = build_leaf_partition(
-            jnp.asarray(leaf), num_slots=L, block=block)
-        perm_np = np.asarray(perm)
-        blk_np = np.asarray(blk_leaf)
-        cnt_np = np.asarray(seg_count)
-        assert perm_np.shape == (partition_capacity(n, L, block),)
-        real = perm_np[perm_np >= 0]
-        assert sorted(real.tolist()) == list(range(n))
-        lid = np.where(leaf >= 0, leaf, L)
-        assert cnt_np.sum() == n
-        np.testing.assert_array_equal(cnt_np, np.bincount(lid,
-                                                          minlength=L + 1))
-        pos_of = {int(r): i for i, r in enumerate(perm_np) if r >= 0}
-        for w in range(L + 1):
-            rows = np.flatnonzero(lid == w)
-            positions = [pos_of[int(r)] for r in rows]
-            # contiguity + stability: consecutive positions, source order
-            assert positions == sorted(positions)
-            if len(positions):
-                assert positions[-1] - positions[0] == len(positions) - 1
-                assert positions[0] % block == 0  # aligned segment start
-        for bi, w in enumerate(blk_np):
-            rows = perm_np[bi * block:(bi + 1) * block]
-            rows = rows[rows >= 0]
-            if w >= 0:
-                assert np.all(lid[rows] == w)
-            else:  # dead block: gap tail, invalid bucket, or capacity
-                assert len(rows) == 0 or np.all(lid[rows] == L)
-        # gather round-trip: partitioned leaf ids match block ownership
-        leaf_p = np.asarray(apply_partition(
-            jnp.asarray(np.where(leaf >= 0, leaf, -7)), perm))
-        for bi, w in enumerate(blk_np):
-            if w >= 0:
-                blk = leaf_p[bi * block:(bi + 1) * block]
-                assert set(blk[perm_np[bi * block:(bi + 1) * block] >= 0]
-                           .tolist()) <= {int(w)}
-
-
-def test_apply_partition_masks_gap_rows(rng):
-    """Gap entries (-1) must read as ZERO, not wrap to the last row —
-    jnp.take's python-style negative wrapping under mode="fill" aliased
-    the final source row into every alignment gap (caught by the
-    segment-kernel parity test during development; pinned here)."""
-    from lightgbm_tpu.ops.partition import apply_partition
-
-    arr = jnp.asarray(rng.randint(1, 9, (3, 16)).astype(np.int32))
-    perm = jnp.asarray(np.array([0, 15, -1, 7, -1], np.int32))
-    out = np.asarray(apply_partition(arr, perm, axis=1))
-    arr_np = np.asarray(arr)
-    np.testing.assert_array_equal(out[:, 0], arr_np[:, 0])
-    np.testing.assert_array_equal(out[:, 1], arr_np[:, 15])
-    np.testing.assert_array_equal(out[:, 3], arr_np[:, 7])
-    assert (out[:, 2] == 0).all() and (out[:, 4] == 0).all()

@@ -128,7 +128,8 @@ def _kernel_cases():
     from lightgbm_tpu.ops.partition import ROUTE_FIXED_COLS
     bins, binsT = _s((N, G), jnp.uint8), _s((G, N), jnp.uint8)
     f32n, leaf = _s((N,), jnp.float32), _s((N,), jnp.int32)
-    wq, wT = _s((N, 3), jnp.int32), _s((3, N), jnp.int32)
+    w, wT = _s((N, 3), jnp.float32), _s((3, N), jnp.int32)
+    wTf = _s((3, N), jnp.float32)
     scales, slots = _s((3,), jnp.float32), _s((W,), jnp.int32)
     ohb = _s((N, G * B), jnp.int8)
     tab = _s((L, ROUTE_FIXED_COLS + (B + 7) // 8), jnp.float32)
@@ -136,27 +137,14 @@ def _kernel_cases():
     return [
         (H.compute_group_histograms_pallas,
          (bins, f32n, f32n, f32n, leaf), dict(kw, num_leaves=L)),
-        (H.compute_group_histograms_pallas_paired,
-         (bins, f32n, f32n, f32n, leaf), dict(kw, num_leaves=L)),
-        (H.compute_group_histograms_pallas_q,
-         (bins, wq, scales, leaf), dict(kw, num_leaves=L)),
         (H.compute_group_histograms_pre,
-         (ohb, wq, scales, leaf), dict(kw, num_leaves=L, quant=True)),
-        (H.compute_group_histograms_q_packed,
-         (bins, wq, scales, leaf, slots), kw),
-        (H.compute_group_histograms_q_tiled,
-         (binsT, wT, scales, leaf, slots), kw),
+         (ohb, w, leaf), dict(kw, num_leaves=L)),
         (H.compute_group_histograms_pre_packed,
-         (ohb, wq, scales, leaf, slots), dict(kw, quant=True)),
+         (ohb, w, leaf, slots), kw),
         (H.compute_group_histograms_fused,
-         (ohb, binsT, wT, scales, leaf, tab, slots), dict(kw, quant=True)),
+         (ohb, binsT, wTf, leaf, tab, slots), kw),
         (H.compute_group_histograms_fused_tiled,
          (binsT, wT, scales, leaf, tab, slots), kw),
-        (H.compute_group_histograms_seg_tiled,
-         (binsT, wT, scales, _s((N // 256,), jnp.int32)),
-         dict(kw, num_out=W)),
-        (H.route_only_tiled, (binsT, leaf, tab),
-         dict(block=256, interpret=True)),
         (H.route_apply_tiled, (binsT, leaf, tab, _s((L,), jnp.float32)),
          dict(block=256, interpret=True)),
     ] + [   # the factored rungs: 256-lane tiles only, one kernel a rung
@@ -182,16 +170,10 @@ def _pallas_names(jaxpr):
 
 KERNEL_NAMES = [          # what a device trace showed before they were pinned
     "compute_group_histograms_pallas",
-    "compute_group_histograms_pallas_paired",
-    "compute_group_histograms_pallas_q",
     "compute_group_histograms_pre",
-    "compute_group_histograms_q_packed",
-    "compute_group_histograms_q_tiled",
     "compute_group_histograms_pre_packed",
     "compute_group_histograms_fused",
     "compute_group_histograms_fused_tiled",
-    "compute_group_histograms_seg_tiled",
-    "route_only_tiled",
     "route_apply_tiled",
     "compute_group_histograms_fused_factored_k2_a4",    # PR 27: pinned
     "compute_group_histograms_fused_factored_k10_a2",   # from the start
@@ -211,7 +193,7 @@ def test_pallas_call_carries_its_pinned_name(i):
     want = KERNEL_NAMES[i]
     jaxpr = jax.make_jaxpr(lambda *a: fn(*a, **kw))(*args)
     assert _pallas_names(jaxpr.jaxpr) == [want]
-    if i in (0, 5, 8, 11):        # and in the lowered text's locations
+    if i in (0, 3, 4, 5):         # and in the lowered text's locations
         text = jax.jit(lambda *a: fn(*a, **kw)).lower(*args).as_text(
             debug_info=True)
         assert f"{want}/" in text
@@ -229,7 +211,7 @@ def test_histogram_kernel_names_match_the_benchmarks_pattern():
         patterns = [re.compile(p) for p in json.load(f)["params"]["patterns"]]
     from lightgbm_tpu.ops.histogram import FACTORED_RUNGS
     hist = [n for n in KERNEL_NAMES if not n.startswith("route_")]
-    assert len(hist) == len(KERNEL_NAMES) - 2
+    assert len(hist) == len(KERNEL_NAMES) - 1
     assert sum("factored" in n for n in hist) == len(FACTORED_RUNGS) > 0
     for name in hist:
         # an event is named by the instruction's whole text (xplane.py)

@@ -20,8 +20,8 @@ if not on_tpu():
 
 from lightgbm_tpu.ops.histogram import (  # noqa: E402
     compute_group_histograms, compute_group_histograms_fused,
-    compute_group_histograms_pallas, compute_group_histograms_q_packed,
-    precompute_bin_onehot, quantize_gradients)
+    compute_group_histograms_pallas, precompute_bin_onehot,
+    quantize_gradients)
 from lightgbm_tpu.ops.partition import (apply_route_table,  # noqa: E402
                                         build_route_table)
 
@@ -56,17 +56,6 @@ def test_onchip_pallas_expansion_kernel(case):
     assert float(jnp.max(jnp.abs(ref[..., 2] - got[..., 2]))) == 0.0
 
 
-def test_onchip_quantized_packed_kernel(case):
-    bins, grad, hess, cnt, leaf, ref, (N, G, B, L) = case
-    wq, scales = quantize_gradients(grad, hess, cnt)
-    slots = jnp.arange(31, dtype=jnp.int32)
-    got = compute_group_histograms_q_packed(
-        bins, wq, scales, leaf, slots, max_group_bin=B, block=1024)
-    # int8 quantization: tolerance = quantization step * sqrt(rows/leaf)
-    assert _close(ref, got[:31], tol=2e-2)
-    assert float(jnp.max(jnp.abs(ref[..., 2] - got[:31, ..., 2]))) == 0.0
-
-
 def test_onchip_fused_route_hist(case):
     """Fused kernel on chip: routing BIT-IDENTICAL to the XLA router,
     histogram within bf16 operand tolerance."""
@@ -96,75 +85,21 @@ def test_onchip_fused_route_hist(case):
     wT = jnp.stack([grad, hess, cnt], axis=0)
     slots = jnp.arange(42, dtype=jnp.int32)
     got_hist, got_leaf = compute_group_histograms_fused(
-        ohb, jnp.asarray(np.asarray(bins).T), wT, None, leaf, tab,
-        slots, max_group_bin=B, block=1024, strips=1, quant=False)
+        ohb, jnp.asarray(np.asarray(bins).T), wT, leaf, tab,
+        slots, max_group_bin=B, block=1024, strips=1)
     np.testing.assert_array_equal(np.asarray(got_leaf),
                                   np.asarray(want_leaf))
     assert _close(want[:42], got_hist)
 
 
-def test_onchip_q_tiled_kernel(case):
-    """Tiled-iota kernel (the r4+ DEFAULT quantized path,
-    learner/grower.py _hist_kernel_q_tiled): int32 accumulation is
-    exact, so it must match the int8-quantized reference exactly."""
-    from lightgbm_tpu.ops.histogram import compute_group_histograms_q_tiled
-    bins, grad, hess, cnt, leaf, ref, (N, G, B, L) = case
-    wq, scales = quantize_gradients(grad, hess, cnt)
-    slots = jnp.arange(31, dtype=jnp.int32)
-    want = compute_group_histograms_q_packed(
-        bins, wq, scales, leaf, slots, max_group_bin=B, block=1024)
-    for block in (2048, 8192):
-        got = compute_group_histograms_q_tiled(
-            jnp.asarray(np.asarray(bins).T), wq.T, scales, leaf, slots,
-            max_group_bin=B, block=block, strips=1)
-        np.testing.assert_array_equal(np.asarray(want),
-                                      np.asarray(got), err_msg=str(block))
-    # quantized-vs-f32 tolerance against the float reference
-    assert _close(ref, got[:31], tol=2e-2)
-
-
-def test_onchip_seg_tiled_kernel(case):
-    """Leaf-partitioned segment kernel (r6, gated off by default):
-    Mosaic must accept the scalar-prefetched block map + dynamic
-    sublane accumulate, and the int32 accumulation must match the
-    slot-packed tiled kernel exactly.  This is the one-flag A/B the
-    r6 rejection record defers to chip-having sessions
-    (docs/PARTITION_DESIGN.md)."""
-    from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_q_tiled,
-        compute_group_histograms_seg_tiled)
-    from lightgbm_tpu.ops.partition import (apply_partition,
-                                            build_leaf_partition)
-    bins, grad, hess, cnt, leaf, ref, (N, G, B, L) = case
-    wq, scales = quantize_gradients(grad, hess, cnt)
-    slots = jnp.arange(31, dtype=jnp.int32)
-    binsT = jnp.asarray(np.asarray(bins).T)
-    want = compute_group_histograms_q_tiled(
-        binsT, wq.T, scales, leaf, slots, max_group_bin=B, block=1024,
-        strips=1)
-    perm, blk_leaf, _ = build_leaf_partition(leaf, num_slots=L,
-                                             block=512)
-    binsT_p = apply_partition(binsT, perm, axis=1)
-    wT_p = apply_partition(wq.T, perm, axis=1)
-    inv = jnp.full(L + 1, -1, jnp.int32).at[slots].set(
-        jnp.arange(slots.shape[0], dtype=jnp.int32))
-    blk_slot = jnp.where(blk_leaf >= 0,
-                         inv[jnp.clip(blk_leaf, 0, L)], -1)
-    got = compute_group_histograms_seg_tiled(
-        binsT_p, wT_p, scales, blk_slot, num_out=31, max_group_bin=B,
-        block=512)
-    np.testing.assert_array_equal(np.asarray(want)[:31],
-                                  np.asarray(got))
-
-
 def test_onchip_fused_tiled_kernel(case):
     """Fused route + tiled-iota kernel — the kernel the DEFAULT
-    training path actually executes every round (grower run():
-    use_tiled branch).  Routing bit-identical to the XLA router;
-    histogram identical to the non-fused tiled kernel after routing."""
-    from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_fused_tiled,
-        compute_group_histograms_q_tiled)
+    training path executes on 128-lane tiles (the ladder's strips).
+    Routing bit-identical to the XLA router; int32 accumulation is
+    exact, so the histogram is the XLA formulation's sum of the int8
+    levels after routing, to the bit."""
+    from lightgbm_tpu.ops.histogram import \
+        compute_group_histograms_fused_tiled
     bins, grad, hess, cnt, leaf, ref, (N, G, B, L) = case
     rng = np.random.RandomState(1)
     sm = np.zeros(L, bool)
@@ -185,9 +120,13 @@ def test_onchip_fused_tiled_kernel(case):
     want_leaf = apply_route_table(bins, leaf, tab)
     wq, scales = quantize_gradients(grad, hess, cnt)
     slots = jnp.arange(42, dtype=jnp.int32)
-    want = compute_group_histograms_q_tiled(
-        jnp.asarray(np.asarray(bins).T), wq.T, scales, want_leaf, slots,
-        max_group_bin=B, block=2048, strips=1)
+    # the levels (|q| <= 127) are exact in bf16 and their sums over
+    # 8192 rows in float32
+    wf = wq.astype(jnp.float32)
+    want = compute_group_histograms(
+        bins, wf[:, 0], wf[:, 1], wf[:, 2], want_leaf, num_leaves=128,
+        max_group_bin=B, compute_dtype="float32", chunk=8192,
+        slots=slots) * scales[None, None, None, :]
     for strips in (1, 2):
         s = jnp.arange(42 * strips, dtype=jnp.int32)
         got_hist, got_leaf = compute_group_histograms_fused_tiled(

@@ -28,8 +28,10 @@ class Dataset:
                  params: Optional[Dict[str, Any]] = None,
                  free_raw_data: bool = True):
         # free_raw_data defaults True like the reference python package
-        # (at bench scale the float64 matrix is 224 MB of dead weight
-        # next to the binned copy; 2.4 GB at HIGGS scale).  Continued
+        # (the raw matrix is dead weight next to the binned copy once
+        # construct has read it; construct keeps no copy of its own:
+        # with free_raw_data=False ``_raw_data`` is the caller's array,
+        # in the dtype it has).  Continued
         # training (init_model) needs the raw matrix to seed scores —
         # pass free_raw_data=False there, as in the reference.
         self.data = data
@@ -169,20 +171,29 @@ class Dataset:
             ref_core = self.reference.construct(config) \
                 if hasattr(self.reference, "construct") \
                 else self.reference
+        if _is_row_shards(data):
+            _note_input({a.dtype for a in data})
+            return self._construct_row_shards(data, label, config, ref_core)
         # validation frames must encode pandas categoricals against the
         # TRAIN-time category lists (the reference aligns valid frames
         # to the train categories and errors on mismatch)
-        if _is_row_shards(data):
-            return self._construct_row_shards(data, label, config, ref_core)
         train_cats = getattr(ref_core, "pandas_categorical", None)
         pandas_cats = (train_cats if train_cats is not None
                        else _pandas_categories(data))
-        data = _to_matrix(data, train_cats)
-        if _is_sparse(data) and not config.is_enable_sparse:
-            # reference is_enable_sparse=false: bypass the sparse-aware
-            # construction and bin the dense matrix
-            data = np.ascontiguousarray(
-                np.asarray(data.todense(), dtype=np.float64))
+        if isinstance(data, np.ndarray):
+            # the caller's buffer, in the dtype it has: the binner reads
+            # float32 and float64 as they are, so no copy of the table
+            # is made here (from_matrix widens what it samples, and
+            # ROW_BLOCK rows at a time of what the binner cannot read)
+            _note_input({data.dtype})
+        else:
+            data = _to_matrix(data, train_cats)
+            if _is_sparse(data) and not config.is_enable_sparse:
+                # reference is_enable_sparse=false: bypass the
+                # sparse-aware construction and bin the dense matrix
+                data = np.ascontiguousarray(
+                    np.asarray(data.todense(), dtype=np.float64))
+            _note_input((), 0 if _is_sparse(data) else data.nbytes)
         feature_names, cat_indices = self._resolve_columns(data)
 
         import time as _time
@@ -378,7 +389,7 @@ class Dataset:
             return d.shape[0]
         if _is_row_shards(d):
             return sum(a.shape[0] for a in d)
-        return _to_matrix(d).shape[0]
+        return _shape_of(d)[0]
 
     def num_feature(self) -> int:
         if self._core is not None:
@@ -387,7 +398,7 @@ class Dataset:
             return self.data.shape[1]
         if _is_row_shards(self.data):
             return self.data[0].shape[1]
-        return _to_matrix(self.data).shape[1]
+        return _shape_of(self.data)[1]
 
     def set_reference(self, reference: "Dataset") -> "Dataset":
         """reference basic.py Dataset.set_reference: align this
@@ -458,6 +469,8 @@ class Dataset:
                       "the Dataset with free_raw_data=False")
         if _is_sparse(self.data):
             data = self.data.tocsr()[used_indices]
+        elif isinstance(self.data, np.ndarray):
+            data = self.data[used_indices]      # in the dtype it has
         else:
             data = _to_matrix(self.data)[used_indices]
         label = (None if self.label is None
@@ -539,6 +552,32 @@ def _to_matrix(data, pandas_categorical=None) -> np.ndarray:
 
 def _is_sparse(obj) -> bool:
     return hasattr(obj, "tocsc") and hasattr(obj, "nnz")
+
+
+def _shape_of(data):
+    """(rows, columns) of raw in-memory input, read off the object where
+    it says (arrays, frames) — only what has no 2-D shape of its own (a
+    list of rows, a Series) is converted to find out."""
+    shape = getattr(data, "shape", None)
+    if shape is not None and len(shape) == 2:
+        return tuple(shape)
+    return _to_matrix(data).shape
+
+
+def _note_input(dtypes, converted_bytes: int = 0) -> None:
+    """What ``construct`` was handed, and what it cost to read
+    (docs/OBSERVABILITY.md): gauge ``construct_input_dtype`` is
+    ``float32`` / ``float64`` for an array (or row shards) of that
+    dtype, ``other`` for everything else; counter
+    ``construct_widened_mb`` starts at the float64 matrix that input
+    which is no array (a frame, a list of rows) was converted to, and
+    grows by every block the binner has to widen."""
+    from .telemetry import TELEMETRY
+    names = {str(d) for d in dtypes}
+    TELEMETRY.gauge("construct_input_dtype",
+                    names.pop() if names in ({"float32"}, {"float64"})
+                    else "other")
+    TELEMETRY.add("construct_widened_mb", converted_bytes / 1e6)
 
 
 def _is_row_shards(obj) -> bool:

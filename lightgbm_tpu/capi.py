@@ -94,7 +94,9 @@ def LGBM_DatasetCreateFromMat(data, parameters: str, reference=None,
     a one-element list receiving the handle (the C out-pointer)."""
     params = _parse_params(parameters)
     ref = _get(reference) if reference else None
-    ds = Dataset(np.asarray(data, dtype=np.float64), reference=ref,
+    # in the element type the caller passed (C_API_DTYPE_FLOAT32 /
+    # FLOAT64): construct bins both from the buffer as it is
+    ds = Dataset(np.asarray(data), reference=ref,
                  free_raw_data=False,
                  params=params)
     out[0] = _register(ds)

@@ -23,6 +23,7 @@ from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN,
                       MISSING_NONE, MISSING_ZERO, BinMapper,
                       find_bin_mappers, resolve_construct_threads)
 from .config import Config
+from .native import TABLE_DTYPES
 from .packing import (CRUMB_MAX_BIN, NIBBLE_MAX_BIN, BinLayout,
                       build_layout, resolve_bin_packing)
 from .utils.log import Log
@@ -140,6 +141,14 @@ class Dataset:
     gpu_tree_learner.cpp:234-556).
     """
 
+    #: rows of a dense table that are binned at a time, on every
+    #: construction route.  A C-contiguous float32 or float64 table is
+    #: read by the native binner from the buffer it arrived in; whatever
+    #: else has to be made contiguous or widened to float64 is, one such
+    #: block at a time (2^18 x 67 x 8 B = 141 MB) — the float64 copy of
+    #: a table never exists
+    ROW_BLOCK = 1 << 18
+
     def __init__(self):
         self.num_data = 0
         self.num_total_features = 0
@@ -218,7 +227,9 @@ class Dataset:
             data = data.tocsc()
             data.sort_indices()
         else:
-            data = np.asarray(data, dtype=np.float64)
+            # whatever dtype it has: the sampler widens the sampled rows
+            # and the binner reads, or widens, ROW_BLOCK rows at a time
+            data = np.asarray(data)
             if data.ndim != 2:
                 raise ValueError("data must be 2-dimensional")
         num_data, num_features = data.shape
@@ -360,7 +371,7 @@ class Dataset:
     def push_rows(self, chunk: np.ndarray, row_start: int) -> None:
         """Streaming construction, step 2: bin one dense float chunk
         (reference LGBM_DatasetPushRows, c_api.h:100-120)."""
-        chunk = np.asarray(chunk, dtype=np.float64)
+        chunk = np.asarray(chunk)
         if chunk.ndim == 1:
             chunk = chunk[None, :]
         self._bin_rows_dense(chunk, row_start)
@@ -561,28 +572,24 @@ class Dataset:
         library can't take.
 
         Nibble-packed datasets bin through a bounded LOGICAL scratch
-        chunk and pack it straight into the storage matrix
+        block and pack it straight into the storage matrix
         (``ltpu_pack_nibbles`` / the numpy fallback): the full-width
         8-bit matrix never exists — peak extra memory is one scratch
-        chunk, regardless of N."""
+        block a worker, regardless of N."""
         from .telemetry import TELEMETRY
         out = self.group_bins[row_start:row_start + data.shape[0]]
         with TELEMETRY.stage("bin", rows=int(data.shape[0])):
-            if self.bin_layout is None:
-                self._bin_rows_dense_into(data, out)
-                return
-            lay = self.bin_layout
-            lib = self._native_lib()
-            step = max(1, int(getattr(self.config,
-                                      "streaming_chunk_rows", 65536)
-                              or 65536))
-            for i in range(0, data.shape[0], step):
-                chunk = np.asarray(data[i:i + step])
-                scratch = np.zeros((chunk.shape[0], self.num_groups),
-                                   dtype=np.uint8)
-                self._bin_rows_dense_into(chunk, scratch)
-                lay.pack_rows(scratch, out=out[i:i + chunk.shape[0]],
-                              lib=lib)
+            bin_row_blocks([(self, data, out)], self.config)
+
+    def _bin_block(self, chunk: np.ndarray, out) -> None:
+        """Bin at most ``ROW_BLOCK`` rows into their storage rows."""
+        if self.bin_layout is None:
+            self._bin_rows_dense_into(chunk, out)
+            return
+        scratch = np.zeros((chunk.shape[0], self.num_groups),
+                           dtype=np.uint8)
+        self._bin_rows_dense_into(chunk, scratch)
+        self.bin_layout.pack_rows(scratch, out=out, lib=self._native_lib())
 
     def _bin_rows_dense_into(self, data: np.ndarray, out) -> None:
         native_feats = [f for f in self.features
@@ -591,7 +598,7 @@ class Dataset:
         lib = self._native_lib()
         xc = None
         if lib is not None and data.shape[0]:
-            xc = np.ascontiguousarray(data, dtype=np.float64)
+            xc = _native_table(data)
         if native_feats and xc is not None \
                 and self._try_native_bin_dense(xc, out, native_feats, lib):
             pass
@@ -601,8 +608,12 @@ class Dataset:
             if xc is not None \
                     and self._try_native_bin_rest(xc, out, f, lib):
                 continue
-            col = self.mappers[f.feature_idx].value_to_bin(
-                data[:, f.feature_idx])
+            col = data[:, f.feature_idx]
+            if col.dtype != np.float64:
+                # the Python mapper searches float64: it widens the
+                # block's column (value_to_bin's own np.asarray)
+                _count_widened(col.size)
+            col = self.mappers[f.feature_idx].value_to_bin(col)
             if not f.collapsed_default:
                 out[:, f.group] = col.astype(np.uint8)
             else:
@@ -636,15 +647,18 @@ class Dataset:
         compare-count loop in native/src/bin_dense.cpp is BIT-IDENTICAL
         (same float64 'left'-side search as the reference's ValueToBin,
         bin.h:450-486) and ~10x faster, and ``ltpu_bin_dense_mt`` fans
-        the row blocks over ``construct_threads`` host threads.
+        the row blocks over ``construct_threads`` host threads.  ``xc``
+        is float32 or float64 (``_native_table``): the float32 entry
+        points widen each value in a register, which is exact, so a
+        float32 table gives the bins of its float64 copy.
         ``feats`` is the numerical non-bundled subset of features this
         call handles.  Disable with ``native_binning=false``.  The old
         4096-row cutoff is gone: streaming chunks of any size take the
         native path now.
 
         (An accelerator-side compare-count formulation would have to
-        upload the raw float64 matrix first; its cost on a directly
-        attached chip: not measured on the chip.)
+        upload the raw matrix first; its cost on a directly attached
+        chip: not measured on the chip.)
         """
         import ctypes
         if self.group_bins is None or xc.shape[0] == 0:
@@ -675,9 +689,11 @@ class Dataset:
             return a.ctypes.data_as(ctypes.POINTER(t))
 
         # threaded over disjoint row ranges — byte-identical to the
-        # serial walk at every thread count (no accumulation)
-        lib.ltpu_bin_dense_mt(
-            p(xc, ctypes.c_double), n, f_total,
+        # serial walk at every thread count (no accumulation); the
+        # entry point is the one built for the table's element type
+        sfx, c_elem = TABLE_DTYPES[xc.dtype]
+        getattr(lib, f"ltpu_bin_dense{sfx}_mt")(
+            p(xc, c_elem), n, f_total,
             p(fidx, ctypes.c_long), nfu,
             p(bounds_flat, ctypes.c_double), p(boff, ctypes.c_long),
             p(use_nan, ctypes.c_ubyte), p(nan_bin, ctypes.c_long),
@@ -723,21 +739,22 @@ class Dataset:
         def p(a, t):
             return a.ctypes.data_as(ctypes.POINTER(t))
 
+        sfx, c_elem = TABLE_DTYPES[xc.dtype]
         if f.is_categorical:
-            fn_cat = lib.ltpu_bin_cat
+            fn_cat = getattr(lib, f"ltpu_bin_cat{sfx}")
             if not m.categorical_2_bin:
                 return False
             if getattr(m, "_cat_lut", None) is None:
                 m._build_cat_cache()
             lut = np.ascontiguousarray(m._cat_lut, dtype=np.int32)
             if not f.collapsed_default:
-                fn_cat(p(xc, ctypes.c_double), n, xc.shape[1],
+                fn_cat(p(xc, c_elem), n, xc.shape[1],
                        f.feature_idx, p(lut, ctypes.c_int32), len(lut),
                        m.num_bin - 1, out_col, stride)
                 return True
             fn_bundle = lib.ltpu_bin_bundle
             tmp = np.empty(n, np.uint8)
-            fn_cat(p(xc, ctypes.c_double), n, xc.shape[1],
+            fn_cat(p(xc, c_elem), n, xc.shape[1],
                    f.feature_idx, p(lut, ctypes.c_int32), len(lut),
                    m.num_bin - 1, p(tmp, ctypes.c_ubyte), 1)
             fn_bundle(p(tmp, ctypes.c_ubyte), n, f.offset,
@@ -746,7 +763,7 @@ class Dataset:
         # numerical feature inside a multi-feature bundle: bin through
         # the shared dense kernel into a scratch row, then apply the
         # bundle write
-        fn = lib.ltpu_bin_dense
+        fn = getattr(lib, f"ltpu_bin_dense{sfx}")
         fn_bundle = lib.ltpu_bin_bundle
         n_search = m.num_bin - (1 if m.missing_type == MISSING_NAN else 0)
         bounds = np.ascontiguousarray(
@@ -761,7 +778,7 @@ class Dataset:
         nan_bin = np.asarray([m.num_bin - 1], np.int64)
         fidx = np.asarray([f.feature_idx], np.int64)
         tmp = np.empty(n, np.uint8)
-        fn(p(xc, ctypes.c_double), n, xc.shape[1],
+        fn(p(xc, c_elem), n, xc.shape[1],
            p(fidx, ctypes.c_long), 1, p(bounds, ctypes.c_double),
            p(boff, ctypes.c_long), p(use_nan, ctypes.c_ubyte),
            p(nan_bin, ctypes.c_long), p(tmp, ctypes.c_ubyte))
@@ -922,6 +939,52 @@ class Dataset:
 
 
 # ---------------------------------------------------------------------------
+def _count_widened(values: int) -> None:
+    """``values`` table values were copied to float64 for binning:
+    counter ``construct_widened_mb`` (docs/OBSERVABILITY.md).  The rows
+    sampled for the mapper fit are not the table and are not counted."""
+    from .telemetry import TELEMETRY
+    TELEMETRY.add("construct_widened_mb", values * 8 / 1e6)
+
+
+def _native_table(block: np.ndarray) -> np.ndarray:
+    """``block`` as the native kernels read it: itself where it is
+    C-contiguous float32 or float64 (any row range of such a table is),
+    else a C-contiguous float64 copy — of a block, never of a table."""
+    if block.flags.c_contiguous and block.dtype in TABLE_DTYPES:
+        return block
+    _count_widened(block.size)
+    return np.ascontiguousarray(block, dtype=np.float64)
+
+
+def bin_row_blocks(tables, config) -> None:
+    """Bin dense tables ``ROW_BLOCK`` rows at a time, a few blocks in
+    flight (the native binner, and numpy where a block is widened,
+    release the GIL).  ``tables`` holds ``(dataset, rows, out)``, ``out``
+    the storage rows of ``dataset`` that ``rows`` bin into: one matrix
+    (``Dataset._bin_rows_dense``) or a table's row shards, each into its
+    own (``ShardedDataset.from_row_shards``).  Blocks write disjoint
+    rows, so the result is the serial walk's to the byte."""
+    step = Dataset.ROW_BLOCK
+    jobs = [(ds, rows[lo:lo + step], out[lo:lo + step])
+            for ds, rows, out in tables
+            for lo in range(0, rows.shape[0], step)]
+
+    def bin_block(job):
+        ds, rows, out = job
+        ds._bin_block(rows, out)
+
+    workers = min(4, resolve_construct_threads(config) // 4, len(jobs))
+    if workers <= 1:
+        for job in jobs:
+            bin_block(job)
+        return
+    from concurrent.futures import ThreadPoolExecutor
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        # consume the iterator so a worker's exception propagates
+        list(pool.map(bin_block, jobs))
+
+
 def _bundle_num_bin(ds: "Dataset", bundle: List[int]) -> int:
     """A bundle's group bin count — the same arithmetic the
     `_build_groups_impl` packing loop applies (shared default slot +
@@ -950,6 +1013,9 @@ def _sample_feature_values(data: np.ndarray, sample_cnt: int, seed: int
         sample = data[idx]
     else:
         sample = data
+    # the mappers are fitted on float64, whatever the table holds: only
+    # the sampled rows are widened
+    sample = np.asarray(sample, dtype=np.float64)
     from .data_loader import split_sample_columns
     out, rows = split_sample_columns(sample)
     return out, sample.shape[0], rows

@@ -29,6 +29,12 @@ _SRC_DIR = os.path.join(os.path.dirname(__file__), "src")
 _LIB_PATH = os.path.join(os.path.dirname(__file__), "libltpu.so")
 _FLAGS = ("-O3", "-march=native", "-funroll-loops", "-std=c++17",
           "-shared", "-fPIC", "-pthread")
+#: element types the table-reading kernels (``ltpu_bin_dense[_mt]``,
+#: ``ltpu_bin_cat``) are built for: numpy dtype -> (entry-point suffix,
+#: ctypes element type).  A C-contiguous table of one of these dtypes is
+#: binned from the buffer it arrived in (native/README.md).
+TABLE_DTYPES = {np.dtype(np.float64): ("", ctypes.c_double),
+                np.dtype(np.float32): ("_f32", ctypes.c_float)}
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 #: why the library is unavailable in this process (None while it is
@@ -141,20 +147,23 @@ def get_lib() -> Optional[ctypes.CDLL]:
         # construction-pipeline entry points.  ONE home for every
         # binner signature — dataset.py must not carry its own copies
         # that could drift from the C side.
-        lib.ltpu_bin_dense.restype = None
-        lib.ltpu_bin_dense.argtypes = [
-            _ptr(c_d), c_l, c_l, _ptr(c_l), c_l, _ptr(c_d), _ptr(c_l),
-            _ptr(c_ub), _ptr(c_l), _ptr(c_ub)]
-        lib.ltpu_bin_dense_mt.restype = None
-        lib.ltpu_bin_dense_mt.argtypes = \
-            lib.ltpu_bin_dense.argtypes + [c_l]
+        for sfx, c_t in TABLE_DTYPES.values():
+            dense = getattr(lib, f"ltpu_bin_dense{sfx}")
+            dense.restype = None
+            dense.argtypes = [
+                _ptr(c_t), c_l, c_l, _ptr(c_l), c_l, _ptr(c_d), _ptr(c_l),
+                _ptr(c_ub), _ptr(c_l), _ptr(c_ub)]
+            dense_mt = getattr(lib, f"ltpu_bin_dense{sfx}_mt")
+            dense_mt.restype = None
+            dense_mt.argtypes = dense.argtypes + [c_l]
+            cat = getattr(lib, f"ltpu_bin_cat{sfx}")
+            cat.restype = None
+            cat.argtypes = [
+                _ptr(c_t), c_l, c_l, c_l, _ptr(ctypes.c_int32), c_l, c_l,
+                _ptr(c_ub), c_l]
         lib.ltpu_scatter_cols.restype = None
         lib.ltpu_scatter_cols.argtypes = [
             _ptr(c_ub), c_l, c_l, _ptr(c_l), _ptr(c_ub), c_l]
-        lib.ltpu_bin_cat.restype = None
-        lib.ltpu_bin_cat.argtypes = [
-            _ptr(c_d), c_l, c_l, c_l, _ptr(ctypes.c_int32), c_l, c_l,
-            _ptr(c_ub), c_l]
         lib.ltpu_pack_nibbles.restype = None
         lib.ltpu_pack_nibbles.argtypes = [
             _ptr(c_ub), c_l, c_l, c_l, _ptr(c_ub), c_l]

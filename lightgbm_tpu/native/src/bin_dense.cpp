@@ -31,8 +31,9 @@ constexpr long BKMAX = 512;
 // 28-feature HIGGS).  With the row block held in cache, only the first
 // feature's gather touches DRAM; the rest hit L2.  BK shrinks for very
 // wide rows so the block (BK * f_total * 8B) stays cache-resident.
+template <typename T>
 void bin_dense_range(
-    const double* X, long i0_lo, long i0_hi, long n, long f_total,
+    const T* X, long i0_lo, long i0_hi, long n, long f_total,
     const long* feat_idx, long n_used,
     const double* bounds_flat, const long* bounds_off,
     const unsigned char* use_nan, const long* nan_bin,
@@ -47,11 +48,11 @@ void bin_dense_range(
   unsigned char nanv[BKMAX];
   for (long i0 = i0_lo; i0 < i0_hi; i0 += bk) {
     const long m = (i0_hi - i0 < bk) ? (i0_hi - i0) : bk;
-    const double* xb = X + i0 * f_total;
+    const T* xb = X + i0 * f_total;
     for (long j = 0; j < n_used; ++j) {
       const double* ub = bounds_flat + bounds_off[j];
       const long len = bounds_off[j + 1] - bounds_off[j];
-      const double* col = xb + feat_idx[j];
+      const T* col = xb + feat_idx[j];
       const bool un = use_nan[j] != 0;
       const unsigned char nb = (unsigned char)nan_bin[j];
       unsigned char* o = out + j * n + i0;
@@ -60,7 +61,7 @@ void bin_dense_range(
       // search costs ~6 dependent mispredicting branches on random
       // data; this form runs at SIMD compare throughput
       for (long i = 0; i < m; ++i) {
-        double v = col[i * f_total];
+        const double v = (double)col[i * f_total];  // exact for float
         const bool is_nan = std::isnan(v);
         nanv[i] = is_nan ? 1 : 0;
         buf[i] = is_nan ? 0.0 : v;
@@ -76,30 +77,19 @@ void bin_dense_range(
   }
 }
 
-}  // namespace
-
-extern "C" void ltpu_bin_dense(
-    const double* X, long n, long f_total,
-    const long* feat_idx, long n_used,
-    const double* bounds_flat, const long* bounds_off,
-    const unsigned char* use_nan, const long* nan_bin,
-    unsigned char* out /* (n_used, n) feature-major */) {
-  bin_dense_range(X, 0, n, n, f_total, feat_idx, n_used, bounds_flat,
-                  bounds_off, use_nan, nan_bin, out);
-}
-
 // Threaded form: contiguous block-aligned row ranges per thread.  Each
 // range writes a disjoint slice of every output row, so the packed
 // result is byte-identical at any thread count.
-extern "C" void ltpu_bin_dense_mt(
-    const double* X, long n, long f_total,
+template <typename T>
+void bin_dense_mt(
+    const T* X, long n, long f_total,
     const long* feat_idx, long n_used,
     const double* bounds_flat, const long* bounds_off,
     const unsigned char* use_nan, const long* nan_bin,
-    unsigned char* out, long n_threads) {
+    unsigned char* out /* (n_used, n) feature-major */, long n_threads) {
   if (n_threads <= 1 || n < 2 * BKMAX) {
-    bin_dense_range(X, 0, n, n, f_total, feat_idx, n_used, bounds_flat,
-                    bounds_off, use_nan, nan_bin, out);
+    bin_dense_range<T>(X, 0, n, n, f_total, feat_idx, n_used, bounds_flat,
+                       bounds_off, use_nan, nan_bin, out);
     return;
   }
   const long max_t = (n + BKMAX - 1) / BKMAX;
@@ -112,7 +102,7 @@ extern "C" void ltpu_bin_dense_mt(
     const long lo = t * per;
     if (lo >= n) break;
     const long hi = std::min(n, lo + per);
-    ts.emplace_back(bin_dense_range, X, lo, hi, n, f_total, feat_idx,
+    ts.emplace_back(bin_dense_range<T>, X, lo, hi, n, f_total, feat_idx,
                     n_used, bounds_flat, bounds_off, use_nan, nan_bin,
                     out);
   }
@@ -126,13 +116,14 @@ extern "C" void ltpu_bin_dense_mt(
 // path's iv = -1.  out_stride lets the caller write a packed-matrix
 // column in place (stride = num_groups) or a contiguous scratch row
 // (stride = 1, feeding ltpu_bin_bundle).
-extern "C" void ltpu_bin_cat(
-    const double* X, long n, long f_total, long col,
+template <typename T>
+void bin_cat(
+    const T* X, long n, long f_total, long col,
     const int* lut, long lut_len, long unseen_bin,
     unsigned char* out, long out_stride) {
-  const double* c = X + col;
+  const T* c = X + col;
   for (long i = 0; i < n; ++i) {
-    const double v = c[i * f_total];
+    const double v = (double)c[i * f_total];
     // (long)v truncates toward zero exactly like numpy's
     // astype(int64); out-of-range doubles land outside [0, lut_len)
     // on both paths and take the unseen bin
@@ -141,6 +132,38 @@ extern "C" void ltpu_bin_cat(
     out[i * out_stride] = (unsigned char)b;
   }
 }
+
+}  // namespace
+
+// One signature per kernel, once per element type: `name` reads
+// double, `name_f32` reads float.
+#define LTPU_BIN_ENTRY_POINTS(T, SUFFIX)                                  \
+  extern "C" void ltpu_bin_dense##SUFFIX(                                 \
+      const T* X, long n, long f_total, const long* feat_idx,             \
+      long n_used, const double* bounds_flat, const long* bounds_off,     \
+      const unsigned char* use_nan, const long* nan_bin,                  \
+      unsigned char* out) {                                               \
+    bin_dense_range<T>(X, 0, n, n, f_total, feat_idx, n_used,             \
+                       bounds_flat, bounds_off, use_nan, nan_bin, out);   \
+  }                                                                       \
+  extern "C" void ltpu_bin_dense##SUFFIX##_mt(                            \
+      const T* X, long n, long f_total, const long* feat_idx,             \
+      long n_used, const double* bounds_flat, const long* bounds_off,     \
+      const unsigned char* use_nan, const long* nan_bin,                  \
+      unsigned char* out, long n_threads) {                               \
+    bin_dense_mt<T>(X, n, f_total, feat_idx, n_used, bounds_flat,         \
+                    bounds_off, use_nan, nan_bin, out, n_threads);        \
+  }                                                                       \
+  extern "C" void ltpu_bin_cat##SUFFIX(                                   \
+      const T* X, long n, long f_total, long col, const int* lut,         \
+      long lut_len, long unseen_bin, unsigned char* out,                  \
+      long out_stride) {                                                  \
+    bin_cat<T>(X, n, f_total, col, lut, lut_len, unseen_bin, out,         \
+               out_stride);                                               \
+  }
+
+LTPU_BIN_ENTRY_POINTS(double, )
+LTPU_BIN_ENTRY_POINTS(float, _f32)
 
 // EFB bundle column write (reference feature_group.h:128-136): a
 // feature inside a multi-feature bundle stores non-default bins at

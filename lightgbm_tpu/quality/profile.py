@@ -445,7 +445,8 @@ def _build_profile_impl(booster, core, config) -> QualityProfile:
     sample = None
     if raw is not None and not (hasattr(raw, "tocsc")
                                 and hasattr(raw, "nnz")):
-        sample = strided_rows(np.asarray(raw, dtype=np.float64), cap)
+        # the rows first, then float64: never a copy of the raw table
+        sample = strided_rows(raw, cap).astype(np.float64, copy=False)
     if sample is not None and len(sample):
         # same predict path the serving monitors observe — no
         # f32-cache-vs-f64-walk tie skew at the quantile edges

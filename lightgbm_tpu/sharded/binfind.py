@@ -81,7 +81,7 @@ def collect_candidates(shard: np.ndarray, config: Optional[Config],
     ``distributed.sample_local_rows`` idiom)."""
     FAULTS.fault_point("sharded.binfind")
     cfg = config or Config()
-    shard = np.asarray(shard, dtype=np.float64)
+    shard = np.asarray(shard)
     n = shard.shape[0]
     quota = shard_sample_quota(cfg, world)
     if n > quota:
@@ -91,6 +91,8 @@ def collect_candidates(shard: np.ndarray, config: Optional[Config],
         sample = shard[idx]
     else:
         sample = shard
+    # only the sampled rows are widened, never the shard
+    sample = np.asarray(sample, dtype=np.float64)
     vals, rows = split_sample_columns(sample)
     return BoundaryCandidates(rank, n, sample.shape[0], vals, rows)
 

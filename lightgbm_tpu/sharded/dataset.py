@@ -30,7 +30,7 @@ import numpy as np
 
 from ..config import Config
 from ..dataset import Dataset as CoreDataset
-from ..dataset import Metadata
+from ..dataset import Metadata, bin_row_blocks
 from ..reliability.faults import FAULTS
 from ..reliability.watchdog import run_with_deadline
 from ..telemetry import TELEMETRY
@@ -124,7 +124,9 @@ class ShardedDataset(CoreDataset):
             Log.fatal("sharded construction does not support query "
                       "groups yet — queries must not span shards "
                       "(same bound as multi-host ranking)")
-        X = np.asarray(data, dtype=np.float64)
+        # in the dtype it arrived in: bin finding widens the rows it
+        # samples, push_rows bins (or widens) ROW_BLOCK rows at a time
+        X = np.asarray(data)
         if X.ndim != 2:
             raise ValueError("data must be 2-dimensional")
         num_data, num_features = X.shape
@@ -253,11 +255,6 @@ class ShardedDataset(CoreDataset):
         return self
 
     # ------------------------------------------------------------------
-    #: rows a worker converts to float64 and bins at a time on the
-    #: row-shard route: the float64 copy of the table never exists,
-    #: only one such block a worker (2^18 x 67 x 8 B = 141 MB)
-    ROW_BLOCK = 1 << 18
-
     @classmethod
     def from_row_shards(cls, shards, label=None, weight=None,
                         init_score=None, config: Optional[Config] = None,
@@ -266,21 +263,18 @@ class ShardedDataset(CoreDataset):
                         feature_names: Optional[Sequence[str]] = None
                         ) -> "ShardedDataset":
         """Build from a table that arrives AS row shards: a list of
-        (rows_i, F) float arrays of any float dtype, in row order (what
-        ``lgb.Dataset([X0, X1, ...], label=y)`` constructs).  For tables
-        a host cannot hold twice: neither the concatenated table, nor
-        its float64 copy (``basic._to_matrix``), nor a concatenated bin
+        (rows_i, F) arrays, in row order (what ``lgb.Dataset([X0, X1,
+        ...], label=y)`` constructs).  For tables a host cannot hold
+        twice: neither the concatenated table nor a concatenated bin
         matrix exists on this route — each shard is binned in
-        ``ROW_BLOCK``-row blocks into its own uint8 matrix, which
+        ``ROW_BLOCK``-row blocks (``dataset.bin_row_blocks``, the
+        one-matrix route's binner) into its own uint8 matrix, which
         ``ShardingPolicy.place_row_shards`` puts straight on the mesh.
 
         The bin mappers are fitted ONCE, from the rows the
         single-matrix route would sample out of the concatenation (same
         draw, same order), so mappers, bin matrix and trees are the
         single-matrix route's to the byte."""
-        from concurrent.futures import ThreadPoolExecutor
-
-        from ..binning import resolve_construct_threads
         from ..data_loader import split_sample_columns
         config = config or Config()
         shards = [np.asarray(a) for a in shards]
@@ -330,30 +324,11 @@ class ShardedDataset(CoreDataset):
         self.bin_fingerprint = binfind.mapper_fingerprint(
             self.mappers, self._bundles, self.max_bin)
 
-        # block-wise binning, a few blocks in flight: the float64
-        # conversion and the native binner both release the GIL
         sds = [CoreDataset.from_reference_for_push(self, a.shape[0])
                for a in shards]
-        workers = max(1, min(4, resolve_construct_threads(config) // 4))
-        blocks = [(sd, a, lo) for sd, a in zip(sds, shards)
-                  for lo in range(0, a.shape[0], cls.ROW_BLOCK)]
-
-        def bin_block(job):
-            sd, a, lo = job
-            chunk = np.asarray(a[lo:lo + cls.ROW_BLOCK], dtype=np.float64)
-            out = sd.group_bins[lo:lo + chunk.shape[0]]
-            if sd.bin_layout is None:
-                sd._bin_rows_dense_into(chunk, out)
-            else:
-                scratch = np.zeros((chunk.shape[0], sd.num_groups),
-                                   dtype=np.uint8)
-                sd._bin_rows_dense_into(chunk, scratch)
-                sd.bin_layout.pack_rows(scratch, out=out,
-                                        lib=sd._native_lib())
-
         with TELEMETRY.stage("bin", rows=num_data):
-            with ThreadPoolExecutor(max_workers=workers) as pool:
-                list(pool.map(bin_block, blocks))
+            bin_row_blocks([(sd, a, sd.group_bins)
+                            for sd, a in zip(sds, shards)], config)
         self.shard_bins = [sd.group_bins for sd in sds]
         if TELEMETRY.on:
             TELEMETRY.add("sharded_rows_ingested", num_data)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..backend import on_tpu
 from ..config import Config
 from ..dataset import Dataset
 from ..learner.grower import TreeGrower, TreeArrays
@@ -193,6 +194,9 @@ class GBDT:
         self._fused_step = None
         self._fused_chunk = None
         self._fused_chunk_n = 0
+        # the per-leaf histogram cache the chunk program grows its trees
+        # in, kept from chunk to chunk (grower.new_hist_pool)
+        self._hist_pool = None
         # packed tree carry (round 7): the fused chunk stacks each
         # tree as ONE byte-packed record (tree.TreeRecordLayout) so
         # the scan carries 2 output buffers instead of 18 — the
@@ -451,7 +455,7 @@ class GBDT:
             with self._bound_captives(cap):
                 return self._boost_one(scores, vscores, bag_mask, key,
                                        fmask, shrinkage, fresh_bag,
-                                       vb, ohb)
+                                       vb, ohb)[:5]
 
         # no donation here either: the same heap corruption bisected on
         # the fused chunk (see _build_fused_chunk) reproduces on this
@@ -495,10 +499,13 @@ class GBDT:
         return type(self).__name__ == "GBDT"
 
     def _boost_one(self, scores, vscores, bag_mask, key, fmask,
-                   shrinkage, fresh_bag, vbins, ohb=None):
+                   shrinkage, fresh_bag, vbins, ohb=None, hist_pool=None):
         """One boosting iteration's device body — shared by the
         per-iteration fused step and the multi-iteration chunk
         (``fresh_bag`` may be a python bool or a traced scalar).
+        ``hist_pool`` (grower.new_hist_pool) is the histogram cache the
+        iteration's trees grow in, one after the other; it is returned
+        last, as the last tree left it.
         Every op here lies under a ``tel.<phase>`` scope (the grower
         scopes its own), so a device trace splits by phase
         (docs/OBSERVABILITY.md, device phases)."""
@@ -533,8 +540,10 @@ class GBDT:
                 g_k, h_k, fmask_k = g[k], h[k], fmask[k]
             with TELEMETRY.phase("quantize"):
                 qkey = None if kq is None else jax.random.fold_in(kq, k)
-            tree, leaf_id, row_val = self.grower._train_tree_impl(
-                g_k, h_k, counts, fmask_k, ohb, qkey=qkey)
+            tree, leaf_id, row_val, hist_pool = \
+                self.grower._train_tree_impl(
+                    g_k, h_k, counts, fmask_k, ohb, qkey=qkey,
+                    hist_pool=hist_pool)
             with TELEMETRY.phase("finalize_tree"):
                 tree = self._finalize_tree(tree, leaf_id, k, scores,
                                            counts)
@@ -561,7 +570,8 @@ class GBDT:
                         pv * shrinkage)
                 nl = jnp.maximum(nl, tree.num_leaves)
             trees.append(tree)
-        return scores, tuple(new_vscores), bag_mask, tuple(trees), nl
+        return (scores, tuple(new_vscores), bag_mask, tuple(trees), nl,
+                hist_pool)
 
     def _build_fused_chunk(self, n_iters: int):
         """n_iters boosting iterations as ONE jitted lax.scan — one
@@ -583,28 +593,29 @@ class GBDT:
         packed = self._packed_carry
 
         def chunk(scores, vscores, bag_mask, keys, fmasks, fresh_flags,
-                  ohb=None, cap=None):
+                  ohb=None, cap=None, hist_pool=None):
             TELEMETRY.note_trace("gbdt.fused_chunk",
                                  (keys.shape[0], scores.shape))
             vb = vbins if cap is None else cap["vbins"]
 
             def one_iter(carry, xs):
-                scores, vscores, bag_mask = carry
+                scores, vscores, bag_mask, pool = carry
                 key, fmask, fresh_bag = xs
-                scores, vscores, bag_mask, trees, nl = self._boost_one(
-                    scores, vscores, bag_mask, key, fmask, shrinkage,
-                    fresh_bag, vb, ohb)
+                scores, vscores, bag_mask, trees, nl, pool = \
+                    self._boost_one(scores, vscores, bag_mask, key, fmask,
+                                    shrinkage, fresh_bag, vb, ohb, pool)
                 if packed:
                     with TELEMETRY.phase("tree_record"):
                         trees = jnp.stack([self.grower.emit_tree_record(t)
                                            for t in trees])
-                return (scores, vscores, bag_mask), (trees, nl)
+                return (scores, vscores, bag_mask, pool), (trees, nl)
 
             with self._bound_captives(cap):
-                (scores, vscores, bag_mask), (trees, nls) = jax.lax.scan(
-                    one_iter, (scores, vscores, bag_mask),
-                    (keys, fmasks, fresh_flags))
-            return scores, vscores, bag_mask, trees, nls
+                (scores, vscores, bag_mask, hist_pool), (trees, nls) = \
+                    jax.lax.scan(one_iter,
+                                 (scores, vscores, bag_mask, hist_pool),
+                                 (keys, fmasks, fresh_flags))
+            return scores, vscores, bag_mask, trees, nls, hist_pool
 
         # score donation is DISABLED on the fused chunk: donating the
         # scores buffer into the chunk program intermittently corrupted
@@ -621,7 +632,21 @@ class GBDT:
         # the C-API suite's long-flaky mid-suite SIGABRT/SIGSEGV (many
         # booster shapes jitted per process) stopped reproducing (0/8)
         # once its donation was dropped too.
-        return jax.jit(chunk)
+        # The histogram pool IS donated, on the chip: the job's one
+        # per-leaf cache (1.5 GB at 2,000 groups) goes in and comes
+        # back in the same buffer.  On the CPU backend it is copied,
+        # for the reason above.
+        return jax.jit(chunk, donate_argnames=(
+            ("hist_pool",) if on_tpu() else ()))
+
+    def _take_hist_pool(self):
+        """The histogram pool for the next chunk, out of this object's
+        hands: the chunk program donates it, so a dispatch that fails
+        leaves none behind and the next one starts a new pool."""
+        pool, self._hist_pool = self._hist_pool, None
+        if pool is None:
+            pool = self.grower.new_hist_pool()
+        return pool
 
     def train_chunk(self, n_iters: int) -> bool:
         """Run n_iters boosting iterations in one device program.
@@ -713,14 +738,16 @@ class GBDT:
                 self._bag_state, keys, fmasks,
                 fresh if isinstance(fresh, jax.Array)
                 else jnp.asarray(fresh),
-                self.grower.ohb, self._build_captives())
+                self.grower.ohb, self._build_captives(),
+                self._take_hist_pool())
 
         try:
             with tm.span("host_dispatch"):
-                scores, vscores, bag, trees, nls = retry_call(
-                    self._dispatch_guard(_enqueue, "gbdt.train_chunk"),
-                    policy=self._retry_policy(),
-                    seam="gbdt.train_chunk")
+                scores, vscores, bag, trees, nls, self._hist_pool = \
+                    retry_call(
+                        self._dispatch_guard(_enqueue, "gbdt.train_chunk"),
+                        policy=self._retry_policy(),
+                        seam="gbdt.train_chunk")
             if tm.on:
                 # the r7 bench split, now first-class counters: time-
                 # to-return is the host/dispatch cost (the async

@@ -424,6 +424,16 @@ class TreeGrower:
             TELEMETRY.gauge("grower.hist_factored_rungs", ",".join(
                 f"{k}:{a}x{b}" for k, a, b in plan.factored_rungs))
             TELEMETRY.gauge("grower.quantized", int(plan.quantized))
+            # the factored kernel's group axis: one chunk (every group a
+            # grid step), or a grid axis of chunks
+            TELEMETRY.gauge("grower.num_groups", int(self.num_groups))
+            TELEMETRY.gauge("grower.hist_group_chunks",
+                            int(plan.group_chunks))
+            TELEMETRY.gauge("grower.hist_group_chunk",
+                            int(plan.group_chunk))
+            TELEMETRY.gauge("grower.hist_cache_mb", round(
+                cache_mb if self.use_hist_cache else cache_mb
+                / self.num_leaves, 3))
             TELEMETRY.gauge("grower.hist_precision",
                             "tiered" if plan.quantized else "f32")
             # resolved device bin-matrix footprint: rows_padded x
@@ -583,7 +593,7 @@ class TreeGrower:
         see _train_tree_inner)."""
         return self._train_tree(grad, hess, counts, feature_mask,
                                 self.ohb, self.bins, self.binsT,
-                                self._row_valid, qkey)
+                                self._row_valid, qkey)[:3]
 
     # ------------------------------------------------------------------
     def _hist_kernel(self, grad, hess, counts, leaf_id, slots):
@@ -791,7 +801,8 @@ class TreeGrower:
                         compute_group_histograms_fused_factored,
                         max_group_bin=B, k_cap=k_cap, a=a,
                         block=plan.block_factored,
-                        interpret=plan.interpret),
+                        interpret=plan.interpret,
+                        group_chunk=plan.group_chunk),
                     self.binsT, wT, in_scales, st.leaf_id, st.route_tab,
                     rights)
                 return _pad_slots(h, W), leaf2
@@ -940,7 +951,33 @@ class TreeGrower:
 
         return jnp.stack([total(grad), total(hess), total(counts)])[None, :]
 
-    def _init_state(self, grad, hess, counts) -> GrowerState:
+    def new_hist_pool(self):
+        """The per-leaf histogram cache as an array of its own, zeroed
+        (the reference's HistogramPool): ``_train_tree_impl`` grows a
+        tree in the one it is handed and hands it back, so a caller that
+        keeps it from tree to tree — the chunk program, which donates it
+        — holds ONE cache for the job and no tree zeroes it (a tree reads
+        only the slots it has written).  The kernel ladder's only, where
+        the cache is one device's or replicated; None elsewhere: a
+        partitioned program chooses its cache's sharding itself."""
+        if self.plan.tier != "ladder":
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+        mesh = self.policy.mesh
+        return jax.jit(self._zero_hist_cache, out_shardings=(
+            None if mesh is None
+            else NamedSharding(mesh, PartitionSpec())))()
+
+    def _zero_hist_cache(self):
+        hist_cache = jnp.zeros(
+            (self.num_leaves if self.use_hist_cache else 1,
+             self.num_groups, self.max_group_bin, 3), jnp.float32)
+        if self.plan.int_counts:
+            return (hist_cache, jnp.zeros(hist_cache.shape[:3], jnp.int32))
+        return hist_cache
+
+    def _init_state(self, grad, hess, counts, hist_pool=None
+                    ) -> GrowerState:
         L = self.num_leaves
         M = L - 1
         B = self.max_feature_bin
@@ -984,12 +1021,9 @@ class TreeGrower:
             .at[:, CAND_GAIN].set(NEG_INF)
         forced_cand = jnp.zeros((L, FORCED_COLS), jnp.float32) \
             .at[:, FORCED_GAIN].set(NEG_INF)
-        hist_cache = jnp.zeros(
-            (L if self.use_hist_cache else 1, self.num_groups,
-             self.max_group_bin, 3), jnp.float32)
+        hist_cache = self._zero_hist_cache() if hist_pool is None \
+            else hist_pool
         if self.plan.int_counts:
-            hist_cache = (hist_cache,
-                          jnp.zeros(hist_cache.shape[:3], jnp.int32))
             cand = (cand, jnp.zeros(L, jnp.int32))
             forced_cand = (forced_cand, jnp.zeros(L, jnp.int32))
         W = self.frontier
@@ -1012,8 +1046,10 @@ class TreeGrower:
     # ------------------------------------------------------------------
     def _train_tree_impl(self, grad, hess, counts, feature_mask,
                          ohb=None, bins=None, binsT=None,
-                         row_valid=None, qkey=None):
-        """``ohb``/``bins``/``binsT``/``row_valid`` are the O(N) device
+                         row_valid=None, qkey=None, hist_pool=None):
+        """(tree, final leaf ids, per-row leaf value or None,
+        ``hist_pool`` as the tree left it: ``new_hist_pool``).
+        ``ohb``/``bins``/``binsT``/``row_valid`` are the O(N) device
         arrays, threaded through the caller's jit boundary as ARGUMENTS
         and bound to their attributes for the dynamic extent of the
         trace.  Closing over them instead would inline each one as an
@@ -1031,17 +1067,18 @@ class TreeGrower:
             self._row_valid = row_valid
         try:
             return self._train_tree_inner(grad, hess, counts,
-                                          feature_mask, qkey=qkey)
+                                          feature_mask, qkey=qkey,
+                                          hist_pool=hist_pool)
         finally:
             self._ohb_arg = None
             self.bins, self.binsT, self._row_valid = saved
 
     def _train_tree_inner(self, grad, hess, counts, feature_mask,
-                          qkey=None):
+                          qkey=None, hist_pool=None):
         # every op of a tree lies under a tel.<phase> scope (innermost
         # wins where they nest), so a device trace splits by phase
         with TELEMETRY.phase("init_state"):
-            state = self._init_state(grad, hess, counts)
+            state = self._init_state(grad, hess, counts, hist_pool)
         if self._is_voting:
             def body_fn(st):
                 # histograms and the vote run inside one shard_map
@@ -1082,7 +1119,8 @@ class TreeGrower:
         with TELEMETRY.phase("route"):
             leaf_id, row_val = self._exit_route(final)
         tree = final.tree._replace(num_leaves=final.num_leaves)
-        return tree, leaf_id, row_val
+        return (tree, leaf_id, row_val,
+                None if hist_pool is None else final.hist_cache)
 
     def _exit_route(self, final: GrowerState):
         """(final leaf ids, per-row post-route leaf value or None)."""
@@ -1099,11 +1137,19 @@ class TreeGrower:
             # (N, L_pad) bf16 one-hot + (N, K) rows in HBM (~16
             # ms/tree at HIGGS scale)
             if self.plan.tier == "ladder":
-                from ..ops.histogram import route_apply_tiled
-                route = functools.partial(
+                from ..ops.histogram import (gather_split_rows,
+                                             route_apply_tiled)
+                kernel = functools.partial(
                     route_apply_tiled, block=self.plan.block_tiled,
                     interpret=self.plan.interpret,
                     packed_groups=self.pack_P)
+                route = kernel
+                if self.plan.group_chunks > 1:
+                    # a wide table's route reads its split rows, as its
+                    # histogram passes do
+                    def route(binsT, leaf_id, route_tab, values):
+                        rowsT, tab = gather_split_rows(binsT, route_tab)
+                        return kernel(rowsT, leaf_id, tab, values)
                 if self.plan.mesh_kernels:
                     # per-row work on replicated tables: each shard
                     # routes its own rows, nothing crosses

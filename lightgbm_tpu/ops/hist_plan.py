@@ -32,7 +32,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-from .histogram import (PACKED_STRIP, _round_up, check_quant_rows,
+from .histogram import (CHUNK_VMEM_LIMIT, PACKED_STRIP, ROUTE_ROWS,
+                        _factored_rows, _round_up, check_quant_rows,
                         factored_rungs, quant_rows_ok, tiled_hist_width)
 
 #: frontier slots the fused kernels serve: three packed strips, and the
@@ -43,6 +44,11 @@ LADDER_WIDTH = 3 * PACKED_STRIP
 #: a 16 GB v5e for bins, scores, gradients and temporaries; HIGGS scale
 #: (10.5M x 28 x 63) needs 5.4 GB at pack=4
 ONEHOT_BUDGET_MB = 6144
+
+#: VMEM a pass of the factored kernel is planned into: what the chunked
+#: kernel asks of the compiler, less room for what Mosaic keeps besides
+#: the blocks counted in ``factored_vmem_bytes``
+CHUNK_VMEM_BUDGET = CHUNK_VMEM_LIMIT - (12 << 20)
 
 #: rows a block of the streamed-one-hot kernels (their 3.6 MB/block DMA
 #: pipeline prefers 2048 on a v5e: 4096 benched 16% slower)
@@ -69,6 +75,11 @@ class HistPlan:
     block_float: int              # rows a block: _pre, _pre_packed, _fused
     block_tiled: int              # ... _fused_tiled, route_apply_tiled
     block_factored: int           # ... _fused_factored
+    group_chunk: int              # groups a grid step of _fused_factored
+    # holds: every group (one chunk, the kernel's one-axis grid), or a
+    # multiple of 32 under them (the group axis is a grid axis, and the
+    # routes read the table's split rows: histogram.gather_split_rows)
+    num_groups: int               # the table's groups
     factored_rungs: Tuple[Tuple[int, int, int], ...]  # rungs in force
     warnings: Tuple[str, ...]     # for the caller to log, in order
 
@@ -83,6 +94,11 @@ class HistPlan:
         tree: float32 counts integers to 2^24, one device's rows, and a
         mesh's shards hold more between them."""
         return self.mesh_kernels
+
+    @property
+    def group_chunks(self) -> int:
+        """Group chunks a pass of the factored kernel sweeps."""
+        return -(-self.num_groups // self.group_chunk)
 
     @property
     def kernel(self) -> str:
@@ -115,6 +131,44 @@ def _tiled_block(num_groups: int, max_group_bin: int,
         if cand <= local_rows and local_rows % cand == 0:
             return cand
     return 1024
+
+
+def factored_vmem_bytes(rung: Tuple[int, int, int], groups: int,
+                        block: int, chunked: bool) -> int:
+    """VMEM a grid step of a factored rung holds for ``groups`` groups
+    of a ``block``-row block: the accumulator tiles (a pipelined block
+    where the group axis is chunked, so twice; the whole array, once,
+    where it is not), the four scratch rows a group, the uint8 block in
+    its two buffers and its int32 copies (bins, key, lo), and a chunked
+    pass's split rows."""
+    k_cap, a, b = rung
+    pack = 128 // b
+    acc = -(-groups // pack) * pack * 4 * _factored_rows(k_cap, a)[1] \
+        * 128 * 4
+    per_block = groups * (4 * 4 + 2 + 3 * 4)
+    if chunked:
+        acc *= 2
+        per_block += ROUTE_ROWS * (2 + 4)
+    return acc + per_block * block
+
+
+def _group_chunk(rungs, num_groups: int, block: int) -> int:
+    """Groups a grid step of the factored kernel holds: all of them
+    where the widest rung in force then fits ``CHUNK_VMEM_BUDGET`` (67
+    groups take 35 MB of it), else the most whole tiles of uint8
+    sublanes (32 groups) that fit as a chunk, and one at the least."""
+    if not rungs:
+        return num_groups
+    widest = max(rungs, key=lambda r: _factored_rows(r[0], r[1])[1]
+                 * (128 // r[2]))
+    if factored_vmem_bytes(widest, num_groups, block,
+                           False) <= CHUNK_VMEM_BUDGET:
+        return num_groups
+    chunk = 32
+    while chunk + 32 < num_groups and factored_vmem_bytes(
+            widest, chunk + 32, block, True) <= CHUNK_VMEM_BUDGET:
+        chunk += 32
+    return chunk
 
 
 def _onehot_pack(rows: int, gb: int) -> Tuple[int, int]:
@@ -281,7 +335,20 @@ def resolve_hist_plan(config, *, on_tpu: bool,
                 tier = "xla"
 
     mesh_kernels = on_mesh and tier == "ladder"
+    rungs = (factored_rungs(max_group_bin, packed_groups)
+             if tier == "ladder" else ())
+    # the factored kernel's accumulator is a whole-array output block,
+    # which XLA keeps in VMEM outside the kernel's scoped allocation
+    # (26 MB at 126 slots x 67 groups), so it takes the row block the
+    # strips cannot (v5e, 2^24 x 67 x 255 bins: 4096 is 6-9% a pass
+    # under 2048 on the narrow rungs and 1-2% on the wide ones; 8192
+    # adds under 2% up to 64 slots and loses 10% at 126)
     block_tiled = _tiled_block(num_groups, max_group_bin, local_rows)
+    block_factored = 4096 if local_rows % 4096 == 0 else block_tiled
+    group_chunk = _group_chunk(rungs, num_groups, block_factored)
+    if group_chunk < num_groups:
+        # the route kernel's block holds the split rows, not every group
+        block_tiled = _tiled_block(ROUTE_ROWS, max_group_bin, local_rows)
     return HistPlan(
         tier=tier, interpret=interpret, row_axis=kernel_axis,
         row_shards=row_shards, local_rows=local_rows,
@@ -294,15 +361,8 @@ def resolve_hist_plan(config, *, on_tpu: bool,
         block_float=(FLOAT_BLOCK if local_rows % FLOAT_BLOCK == 0
                      else 1024),
         block_tiled=block_tiled,
-        # the factored kernel's accumulator is a whole-array output
-        # block, which XLA keeps in VMEM outside the kernel's scoped
-        # allocation (26 MB at 126 slots x 67 groups), so it takes the
-        # row block the strips cannot (v5e, 2^24 x 67 x 255 bins: 4096
-        # is 6-9% a pass under 2048 on the narrow rungs and 1-2% on the
-        # wide ones; 8192 adds under 2% up to 64 slots and loses 10% at
-        # 126)
-        block_factored=4096 if local_rows % 4096 == 0 else block_tiled,
+        block_factored=block_factored,
+        group_chunk=group_chunk, num_groups=num_groups,
         # in force only where a group fills a 256-lane tile
-        factored_rungs=(factored_rungs(max_group_bin, packed_groups)
-                        if tier == "ladder" else ()),
+        factored_rungs=rungs,
         warnings=tuple(warnings))

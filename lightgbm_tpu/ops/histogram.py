@@ -1189,6 +1189,15 @@ def compute_group_histograms_fused_tiled(
 FACTORED_RUNGS = ((2, 4, 64), (10, 2, 128), (16, 2, 128), (32, 2, 128),
                   (64, 2, 128), (126, 2, 128))
 
+#: rows of the split-row input of the chunked kernels: the widest
+#: frontier the ladder serves (3 x PACKED_STRIP), padded to a tile of
+#: uint8 sublanes
+ROUTE_ROWS = 128
+
+#: scoped VMEM a chunked factored pass asks of the compiler (of a v5e
+#: core's 128 MiB); ops/hist_plan.py sizes the group chunk under it
+CHUNK_VMEM_LIMIT = 100 << 20
+
 #: feature tiles a trip of the factored kernel's loop (Mosaic schedules
 #: one trip's operand builds under the dots before them)
 _FACTORED_UNROLL = 8
@@ -1212,10 +1221,8 @@ def _factored_rows(k_cap: int, a: int):
     return per_channel, _round_up(3 * per_channel, 8)
 
 
-def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
-                                  slots_ref, hist_ref, leaf_out_ref,
-                                  key4_ref, ksh_ref, lo4_ref, bit_ref, *,
-                                  k_cap, a, b, num_groups, nb):
+def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
+                                  num_groups, nb, route_rows=0):
     """Fused route + FACTORED int8 histogram, a rung of ``FACTORED_RUNGS``.
 
         hist[slot, ch, g, hi, lo] =
@@ -1234,10 +1241,22 @@ def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
     key names ONE word row and ONE byte of it, so an operand costs a
     compare and a select a word and nothing is converted to int8.  The
     per-group rows (word row, byte shift, for both operands) are made
-    for the whole block at once and read back a row at a time."""
+    for the whole block at once and read back a row at a time.
+
+    ``route_rows`` > 0 is the wide table's form (:func:`gather_split_rows`):
+    the grid is (group chunks, row blocks), ``binsT_ref`` holds one
+    chunk's ``num_groups`` groups of the block — past the table's last
+    group a chunk holds stale rows, whose tiles lie outside the output
+    and are dropped — the accumulator block is the chunk's, resident
+    while its row blocks sweep, and the route reads the ``route_rows``
+    split rows (a second, narrow input) and never another chunk's."""
     from jax.experimental.pallas import tpu as pltpu
 
-    i = pl.program_id(0)
+    if route_rows:
+        rowsT_ref, *refs = refs
+    (wT_ref, leafT_ref, routeT_ref, slots_ref, hist_ref, leaf_out_ref,
+     key4_ref, ksh_ref, lo4_ref, bit_ref) = refs
+    i = pl.program_id(1 if route_rows else 0)
 
     @pl.when(i == 0)
     def _init():
@@ -1245,8 +1264,13 @@ def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
 
     leaf = leafT_ref[:]                                  # (1, C) int32
     binb = binsT_ref[:].astype(jnp.int32)                # (G, C)
-    new_leaf = _route_prologue_T(binb, leaf, routeT_ref[:],
-                                 num_groups=num_groups, nb=nb)
+    if route_rows:
+        new_leaf = _route_prologue_T(
+            rowsT_ref[:].astype(jnp.int32), leaf, routeT_ref[:],
+            num_groups=route_rows, nb=nb)
+    else:
+        new_leaf = _route_prologue_T(binb, leaf, routeT_ref[:],
+                                     num_groups=num_groups, nb=nb)
     leaf_out_ref[:] = new_leaf
 
     pack = 128 // b
@@ -1310,12 +1334,13 @@ def _fused_kernel_body_q_factored(binsT_ref, wT_ref, leafT_ref, routeT_ref,
 
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "k_cap", "a",
-                              "interpret", "dequantize"))
+                              "interpret", "dequantize", "group_chunk"))
 def compute_group_histograms_fused_factored(
         binsT: jax.Array, wT: jax.Array, scales: jax.Array,
         leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
         max_group_bin: int, k_cap: int, a: int, block: int = 2048,
-        interpret: bool = False, dequantize: bool = True):
+        interpret: bool = False, dequantize: bool = True,
+        group_chunk: int = 0):
     """Fused route + factored int8 histogram, one rung of
     ``FACTORED_RUNGS``: the contract of
     :func:`compute_group_histograms_fused_tiled` for at most ``k_cap``
@@ -1323,7 +1348,14 @@ def compute_group_histograms_fused_factored(
     with ``hist`` (k_cap, G, B, 3) following ``slots[:k_cap]``, equal to
     the tiled kernel's to the bit.  Byte-wide bins in a 256-lane tile
     only (see :func:`factored_rungs`).  ``dequantize=False``: the int32
-    accumulators, as in the tiled kernel."""
+    accumulators, as in the tiled kernel.
+
+    ``group_chunk`` (0, or at least the table's groups: one chunk, the
+    kernel as it always lowered) makes the group axis a grid axis: a
+    chunk of that many groups keeps its accumulator tiles in VMEM while
+    the row blocks sweep under it, and the pending route reads the
+    table's split rows (:func:`gather_split_rows`) and not the block's
+    whole column.  The same integers and the same ``new_leaf``."""
     from jax.experimental.pallas import tpu as pltpu
 
     num_groups, n = binsT.shape
@@ -1332,40 +1364,82 @@ def compute_group_histograms_fused_factored(
     pack = 128 // b
     if n % block != 0:
         raise ValueError(f"N ({n}) must be a multiple of block ({block})")
+    chunked = 0 < group_chunk < num_groups
+    if chunked and group_chunk % 32:
+        raise ValueError(f"group_chunk ({group_chunk}) must be a multiple "
+                         "of 32, a tile of uint8 sublanes")
+    chunk = group_chunk if chunked else num_groups
     kp = _round_up(k_cap, 8)
     slot_col = jnp.full(kp, -2, jnp.int32).at[:k_cap].set(
         jnp.where(slots[:k_cap] >= 0, slots[:k_cap], -2))[:, None]
+    split_rows = []
+    if chunked:
+        rowsT, route_tab = gather_split_rows(binsT, route_tab)
+        split_rows = [rowsT]
     routeT = _transpose_pad_route(route_tab)
     num_tiles = (num_groups + pack - 1) // pack
     ch_w, rows_w = _factored_rows(k_cap, a)
     rows = 4 * rows_w                          # int8 rows a group, padded
     kern = functools.partial(_fused_kernel_body_q_factored, k_cap=k_cap,
-                             a=a, b=b, num_groups=num_groups,
-                             nb=route_tab.shape[1] - ROUTE_FIXED_COLS)
+                             a=a, b=b, num_groups=chunk,
+                             nb=route_tab.shape[1] - ROUTE_FIXED_COLS,
+                             route_rows=ROUTE_ROWS if chunked else 0)
+
+    def at(by_chunk, by_rows):
+        """Index map of a two-axis block that follows the grid's group
+        chunk, its row block, both or neither: over ``(c, i)`` on the
+        chunked grid, over ``(i,)`` on the one it always had."""
+        if chunked:
+            return lambda c, i: (c if by_chunk else 0, i if by_rows else 0)
+        return lambda i: (0, i if by_rows else 0)
+
     out, leaf_out = pl.pallas_call(
         kern,
-        grid=(n // block,),
-        in_specs=[
-            pl.BlockSpec((num_groups, block), lambda i: (0, i)),
-            pl.BlockSpec((3, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec(routeT.shape, lambda i: (0, 0)),
-            pl.BlockSpec(slot_col.shape, lambda i: (0, 0)),
+        grid=((-(-num_groups // chunk),) if chunked else ()) + (n // block,),
+        in_specs=[pl.BlockSpec((chunk, block), at(True, True))] + [
+            pl.BlockSpec((ROUTE_ROWS, block), at(False, True))
+            for _ in split_rows] + [
+            pl.BlockSpec((3, block), at(False, True)),
+            pl.BlockSpec((1, block), at(False, True)),
+            pl.BlockSpec(routeT.shape, at(False, False)),
+            pl.BlockSpec(slot_col.shape, at(False, False)),
         ],
         out_specs=[
-            pl.BlockSpec((num_tiles, pack * rows, 128),
-                         lambda i: (0, 0, 0)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec((-(-chunk // pack), pack * rows, 128),
+                         (lambda c, i: (c, 0, 0)) if chunked
+                         else (lambda i: (0, 0, 0))),
+            pl.BlockSpec((1, block), at(False, True)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((num_tiles, pack * rows, 128), jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((num_groups, block), jnp.int32)
+        scratch_shapes=[pltpu.VMEM((chunk, block), jnp.int32)
                         for _ in range(4)],
+        # one chunk: the whole-array accumulator, which XLA keeps in
+        # VMEM outside the kernel's scoped allocation; chunks: theirs is
+        # a pipelined block inside it
+        **({"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=CHUNK_VMEM_LIMIT)} if chunked else {}),
         interpret=interpret,
         name=f"compute_group_histograms_fused_factored_k{k_cap}_a{a}",
-    )(binsT, wT, leaf_id[None, :], routeT, slot_col)
+    )(binsT, *split_rows, wT, leaf_id[None, :], routeT, slot_col)
+    return _factored_out_to_hist(
+        out, scales if dequantize else None, num_groups=num_groups,
+        max_group_bin=max_group_bin, k_cap=k_cap, a=a), leaf_out[0]
+
+
+def _factored_out_to_hist(out, scales, *, num_groups, max_group_bin,
+                          k_cap, a):
+    """The factored kernel's ``(tiles, pack * rows, 128)`` int32
+    accumulator -> ``(k_cap, G, B, 3)``: int32 as it is, or float32
+    times ``scales``."""
+    tile_w = tiled_hist_width(1, max_group_bin)
+    b = tile_w // a
+    pack = 128 // b
+    num_tiles = out.shape[0]
+    ch_w, rows_w = _factored_rows(k_cap, a)
+    rows = 4 * rows_w
     # rows (group-in-tile, channel, slot, hi) x lanes (group-in-tile, lo):
     # the histogram is where the two groups agree
     o = out.reshape(num_tiles, pack, rows, 128)[:, :, :12 * ch_w]
@@ -1375,10 +1449,32 @@ def compute_group_histograms_fused_factored(
     full = diag.reshape(num_tiles * pack, 3, k_cap,
                         tile_w)[:num_groups, :, :, :max_group_bin]
     hist = jnp.transpose(full, (2, 0, 3, 1))
-    if not dequantize:
-        return hist, leaf_out[0]
-    hist = hist.astype(jnp.float32)
-    return hist * scales[None, None, None, :], leaf_out[0]
+    if scales is None:
+        return hist
+    return hist.astype(jnp.float32) * scales[None, None, None, :]
+
+
+def gather_split_rows(binsT: jax.Array, route_tab: jax.Array):
+    """What a pending route reads of a wide table: the bin rows of the
+    groups its active leaves split on, ``(ROUTE_ROWS, N)`` — active leaf
+    number ``r`` (in slot order) gets row ``r`` — and the route table
+    with that row in the place of the leaf's group, so that the kernels'
+    route prologue selects among ``ROUTE_ROWS`` rows and not among every
+    group of the table.  At most ``ROUTE_ROWS`` leaves are active: the
+    ladder's frontier is narrower."""
+    with jax.named_scope("tel.route"):
+        active = route_tab[:, 10] > 0.5
+        grp = (route_tab[:, 0] * 256 + route_tab[:, 1]).astype(jnp.int32)
+        rank = jnp.cumsum(active.astype(jnp.int32)) - 1
+        row_group = jnp.zeros(ROUTE_ROWS, jnp.int32).at[
+            jnp.where(active, rank, ROUTE_ROWS)].set(grp, mode="drop")
+        # a row at a time: XLA's gather of whole rows copies the table
+        rowsT = jax.lax.map(
+            lambda g: jax.lax.dynamic_index_in_dim(binsT, g, 0, False),
+            row_group)
+        tab = route_tab.at[:, 0].set(0.0).at[:, 1].set(
+            jnp.where(active, rank, 0).astype(jnp.float32))
+        return rowsT, tab
 
 
 def _transpose_pad_route(table: jax.Array) -> jax.Array:

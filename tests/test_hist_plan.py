@@ -1,12 +1,16 @@
 """The histogram kernel plan (lightgbm_tpu/ops/hist_plan.py): every
 decision ``resolve_hist_plan`` makes, from plain facts — no Dataset, no
 device array, no training run."""
+import dataclasses
+
 import pytest
 
 from lightgbm_tpu.config import Config
+from lightgbm_tpu.ops import hist_plan
 from lightgbm_tpu.ops.hist_plan import (LADDER_WIDTH, ONEHOT_BUDGET_MB,
+                                        factored_vmem_bytes,
                                         resolve_hist_plan)
-from lightgbm_tpu.ops.histogram import FACTORED_RUNGS
+from lightgbm_tpu.ops.histogram import CHUNK_VMEM_LIMIT, FACTORED_RUNGS
 
 FAST = {"hist_compute_dtype": "bfloat16", "quantized_grad": True}
 BF16 = {"hist_compute_dtype": "bfloat16"}
@@ -14,6 +18,8 @@ SEAM = {"force_pallas_interpret": True}
 TPU = {"on_tpu": True}
 HIGGS = {"num_groups": 28, "max_group_bin": 63, "rows_padded": 1 << 20}
 CRITEO = {"num_groups": 67, "max_group_bin": 255, "rows_padded": 1 << 24}
+EPSILON = {"num_groups": 2000, "max_group_bin": 255,
+           "rows_padded": 98 * 4096}
 ROW_MESH = {"mesh_axes": (("data", 4),), "row_axis": "data"}
 DATA = {"tree_learner": "data"}
 
@@ -34,7 +40,19 @@ CASES = [
     ("tpu_quant_cell_shape", FAST, {**TPU, **CRITEO},
      dict(tier="ladder", interpret=False, block_tiled=2048,
           block_factored=4096, factored_rungs=FACTORED_RUNGS,
-          local_rows=1 << 24, silent=True)),
+          local_rows=1 << 24, group_chunk=67, group_chunks=1,
+          silent=True)),
+    # 2,000 groups: the group axis is a grid axis, in whole tiles of
+    # uint8 sublanes, and the route kernel's block holds the split rows
+    ("tpu_quant_wide_table", FAST, {**TPU, **EPSILON},
+     dict(tier="ladder", block_factored=4096, block_tiled=2048,
+          factored_rungs=FACTORED_RUNGS, group_chunk=96, group_chunks=21,
+          num_groups=2000, silent=True)),
+    # narrower tiles have no rung, so no chunk: A12's mechanism
+    ("tpu_quant_wide_table_63_bins", FAST,
+     {**TPU, **EPSILON, "max_group_bin": 63},
+     dict(tier="ladder", factored_rungs=(), group_chunk=2000,
+          group_chunks=1)),
     # rows * 127 < 2^31: 16513 blocks of 1024 rows fit, 16514 do not
     ("tpu_quant_last_block_inside_int32", FAST,
      {**TPU, **CRITEO, "rows_padded": 16513 * 1024},
@@ -75,7 +93,8 @@ CASES = [
      {**TPU, **ROW_MESH, **CRITEO, "rows_padded": 1 << 26},
      dict(tier="ladder", row_axis="data", row_shards=4,
           local_rows=1 << 24, mesh_kernels=True, int_counts=True,
-          exchange_limbs=2, block_factored=4096, silent=True)),
+          exchange_limbs=2, block_factored=4096, group_chunks=1,
+          silent=True)),
     ("row_mesh_quant_one_limb", {**FAST, **DATA},
      {**TPU, **ROW_MESH, **CRITEO},
      dict(tier="ladder", local_rows=1 << 22, exchange_limbs=1)),
@@ -172,5 +191,55 @@ def test_resolve_hist_plan(params, facts, want):
     assert plan.fused or plan.tier != "ladder"
     assert bool(plan.onehot_pack) <= (plan.tier == "float")
     assert plan.local_rows * plan.row_shards == facts["rows_padded"]
+    assert plan.num_groups == facts["num_groups"]
+    assert plan.group_chunk * plan.group_chunks >= plan.num_groups \
+        > plan.group_chunk * (plan.group_chunks - 1)
+    assert plan.group_chunks == 1 or plan.group_chunk % 32 == 0
     with pytest.raises(AttributeError):     # immutable
         plan.tier = "xla"
+
+
+def _plan(**facts):
+    return resolve_hist_plan(Config.from_params({"verbose": -1, **FAST}),
+                             **{**FACTS, **TPU, **facts})
+
+
+@pytest.mark.fast
+def test_criteo_plan_is_the_one_before_the_group_chunk():
+    """67 groups x 2^24 rows resolve to ONE chunk and, the group chunk's
+    two fields apart, to the plan the cells had before it, field for
+    field."""
+    got = dataclasses.asdict(_plan(**CRITEO))
+    assert (got.pop("group_chunk"), got.pop("num_groups")) == (67, 67)
+    assert got == dict(
+        tier="ladder", interpret=False, row_axis=None, row_shards=1,
+        local_rows=1 << 24, mesh_kernels=False, exchange_limbs=0,
+        hist_exchange="f32", fused=True, onehot_pack=0, block_float=2048,
+        block_tiled=2048, block_factored=4096,
+        factored_rungs=FACTORED_RUNGS, warnings=())
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("groups", [200, 2000, 5000])
+def test_group_chunk_fits_the_budget_the_plan_states(groups, monkeypatch):
+    """More than one chunk where the widest rung's whole accumulator
+    passes the budget; a chunk's own VMEM bytes lie under the budget,
+    the budget under what the kernel asks of the compiler, and another
+    32 groups would not fit.  The budget is the module's constant: a
+    smaller one gives smaller chunks, down to one tile of sublanes."""
+    widest = FACTORED_RUNGS[-1]
+    plan = _plan(**{**EPSILON, "num_groups": groups})
+    assert plan.group_chunks > 1
+    assert factored_vmem_bytes(widest, groups, plan.block_factored,
+                               False) > hist_plan.CHUNK_VMEM_BUDGET
+
+    def cost(chunk):
+        return factored_vmem_bytes(widest, chunk, plan.block_factored, True)
+    assert cost(plan.group_chunk) <= hist_plan.CHUNK_VMEM_BUDGET \
+        < cost(plan.group_chunk + 32)
+    assert hist_plan.CHUNK_VMEM_BUDGET < CHUNK_VMEM_LIMIT <= 128 << 20
+    monkeypatch.setattr(hist_plan, "CHUNK_VMEM_BUDGET", 40 << 20)
+    assert _plan(**{**EPSILON, "num_groups": groups}).group_chunk == 32
+    monkeypatch.setattr(hist_plan, "CHUNK_VMEM_BUDGET", 1 << 20)
+    assert _plan(**{**EPSILON, "num_groups": groups}).group_chunk == 32
+    assert _plan(**CRITEO).group_chunk == 32

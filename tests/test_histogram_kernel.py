@@ -300,11 +300,13 @@ def _factored_rung_cases():
 
 
 @functools.lru_cache(maxsize=None)
-def _factored_inputs(B, strips=1, dequantize=True):
+def _factored_inputs(B, strips=1, dequantize=True, G=_FACT_G,
+                     split_groups=(0, 2, 5, 3)):
     """One table a bin width and frontier: padded rows (leaf -1), a route
-    table that moves rows, quantized weights, and the tiled kernel's
-    answer on ``strips`` strips for every slot of ``_FACT_SLOTS`` (one
-    strip) or of as many of ``_FACT_SLOTS_WIDE`` as the strips hold."""
+    table that moves rows by ``split_groups``' bins, quantized weights,
+    and the tiled kernel's answer on ``strips`` strips for every slot of
+    ``_FACT_SLOTS`` (one strip) or of as many of ``_FACT_SLOTS_WIDE`` as
+    the strips hold."""
     from lightgbm_tpu.ops.histogram import (
         PACKED_STRIP, compute_group_histograms_fused_tiled,
         quantize_gradients)
@@ -312,7 +314,7 @@ def _factored_inputs(B, strips=1, dequantize=True):
                                             MISSING_ZERO,
                                             build_route_table)
     rng = np.random.RandomState(B)
-    N, G = _FACT_N, _FACT_G
+    N = _FACT_N
     # leaves the rows start in, the four that split, and the table's rows
     leaves, L = (20, 40) if strips == 1 else (140, 160)
     slots = _FACT_SLOTS if strips == 1 \
@@ -334,7 +336,7 @@ def _factored_inputs(B, strips=1, dequantize=True):
         out[split] = values
         return jnp.asarray(out)
     tab = build_route_table(
-        jnp.asarray(sm), col([0, 2, 5, 3]),
+        jnp.asarray(sm), col(list(split_groups)),
         jnp.zeros(L, jnp.int32), jnp.full(L, B, jnp.int32),
         jnp.zeros(L, jnp.int32), jnp.full(L, B - 1, jnp.int32),
         col([0, 0, 0, 1], dtype=bool), col([70, 30, 110, 50]),
@@ -382,6 +384,84 @@ def test_factored_rung_equals_one_strip_tiled_interpret(k_cap, a, b, k, B,
     np.testing.assert_array_equal(got_h[:k], want_h[:k])
     assert not got_h[k:].any()                  # invalid slots: zero rows
     assert got_h[:k].any(axis=(1, 2, 3)).sum() > k // 2    # rows came
+
+
+# ---------------------------------------------------------------------------
+# the group axis as a grid axis (group_chunk): a table wider than one
+# chunk of the factored kernel
+# ---------------------------------------------------------------------------
+_CHUNK_G, _CHUNK_B = 72, 255                   # 72 = 32 + 32 + 8 groups
+#: the four pending splits read a group of the first chunk of 32, of
+#: the second (twice) and of the third: every chunk routes rows by
+#: groups it does not hold
+_CHUNK_SPLITS = (3, 40, 70, 33)
+
+
+def _chunk_inputs():
+    return _factored_inputs(_CHUNK_B, 1, False, _CHUNK_G, _CHUNK_SPLITS)
+
+
+@pytest.mark.parametrize("k_cap,a,k", [(2, 4, 2), (16, 2, 11)],
+                         ids=["pack2", "pack1"])
+def test_factored_group_chunks_equal_one_chunk_interpret(k_cap, a, k):
+    """Two rungs (two groups to a 128-row tile, and one) over 72 groups
+    in chunks of 32 — the last chunk holds 8, the rest of its block is
+    stale — with every pending split's group in another chunk than two
+    of the three being accumulated: the int32 sums and the routed leaf
+    ids are the one-chunk call's, the tiled kernel's, and the XLA
+    contraction's integers."""
+    from lightgbm_tpu.ops.histogram import (
+        compute_group_histograms, compute_group_histograms_fused_factored)
+    args, all_slots, want_h, want_leaf = _chunk_inputs()
+    binsT, wqT = args[0], args[1]
+    slots = np.full(126, -1, np.int32)
+    slots[:k] = all_slots[:k]           # slot 0 is a right child: leaf 21
+
+    def run(group_chunk):
+        return compute_group_histograms_fused_factored(
+            *args, jnp.asarray(slots), max_group_bin=_CHUNK_B, block=256,
+            k_cap=k_cap, a=a, interpret=True, dequantize=False,
+            group_chunk=group_chunk)
+    one_h, one_leaf = run(0)
+    got_h, got_leaf = (np.asarray(v) for v in run(32))
+    assert got_h.shape == (k_cap, _CHUNK_G, _CHUNK_B, 3)
+    assert got_h.dtype == np.int32
+    np.testing.assert_array_equal(got_leaf, np.asarray(one_leaf))
+    np.testing.assert_array_equal(got_leaf, want_leaf)
+    np.testing.assert_array_equal(got_h, np.asarray(one_h))
+    np.testing.assert_array_equal(got_h[:k], want_h[:k])
+    # a chunk as wide as the table, or wider, is the one-chunk call
+    np.testing.assert_array_equal(np.asarray(run(96)[0]), got_h)
+    want = compute_group_histograms(
+        binsT.T, *(wqT[c].astype(jnp.float32) for c in range(3)),
+        jnp.asarray(got_leaf), num_leaves=40, max_group_bin=_CHUNK_B,
+        chunk=_FACT_N, slots=jnp.asarray(slots[:k]))
+    np.testing.assert_array_equal(got_h[:k],
+                                  np.asarray(want).astype(np.int32))
+    assert got_h[0].any()               # the routed-to child has rows
+
+
+def test_route_apply_split_rows_equal_whole_table_interpret():
+    """The exit route of a chunked table: ``route_apply_tiled`` over the
+    split rows of ``gather_split_rows`` gives the leaf ids and the row
+    values of the kernel over every group's rows."""
+    from lightgbm_tpu.ops.histogram import (ROUTE_ROWS, gather_split_rows,
+                                            route_apply_tiled)
+    (binsT, _, _, leaf, tab), _, _, want_leaf = _chunk_inputs()
+    values = jnp.asarray(np.random.RandomState(5).randn(
+        tab.shape[0]).astype(np.float32))
+    want = route_apply_tiled(binsT, leaf, tab, values, block=256,
+                             interpret=True)
+    rowsT, tab_rows = gather_split_rows(binsT, tab)
+    assert rowsT.shape == (ROUTE_ROWS, _FACT_N)
+    # the active leaves 0..3 read their split groups, in slot order
+    np.testing.assert_array_equal(
+        np.asarray(rowsT[:4]), np.asarray(binsT)[list(_CHUNK_SPLITS)])
+    got = route_apply_tiled(rowsT, leaf, tab_rows, values, block=256,
+                            interpret=True)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got[0]), want_leaf)
 
 
 def _fast_255(leaves, **extra):

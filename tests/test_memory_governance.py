@@ -2,6 +2,7 @@
 HistogramPool, feature_histogram.hpp:653-823): over-budget configs drop
 histogram subtraction and compute both children directly."""
 import numpy as np
+import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.config import Config
@@ -60,3 +61,46 @@ def test_wide_config_trains_with_bounded_cache():
     bst = lgb.train(params, lgb.Dataset(X, label=y), 3,
                     verbose_eval=False)
     assert (((bst.predict(X) > 0.5) == y).mean()) > 0.95
+
+
+def _fast(**extra):
+    return dict({"objective": "binary", "verbose": -1, "num_leaves": 15,
+                 "max_bin": 255, "min_data_in_leaf": 5,
+                 "quantized_grad": True, "hist_compute_dtype": "bfloat16",
+                 "quant_stochastic_rounding": 1,
+                 "force_pallas_interpret": True}, **extra)
+
+
+@pytest.mark.parametrize("extra,shape", [
+    ({}, (15, 12, 255, 3)), ({"histogram_pool_size": 0.001}, (1, 12, 255, 3))],
+    ids=["cache", "no_cache"])
+def test_hist_pool_is_kept_from_chunk_to_chunk(extra, shape):
+    """On the kernel ladder the chunk program grows its trees in ONE
+    histogram cache, handed from tree to tree and from chunk to chunk and
+    never zeroed in between (a tree reads only the slots it has written):
+    the model is the model of trees that each start from a zeroed
+    cache."""
+    X, y = _task()
+    X = np.exp(X).astype(np.float32)
+
+    def grow(chunk, keep):
+        bst = lgb.train(_fast(dispatch_chunk=chunk, **extra),
+                        lgb.Dataset(X, label=y), chunk, verbose_eval=False,
+                        keep_training_booster=True)
+        for _ in range(6 // chunk - 1):
+            if not keep:
+                bst.gbdt._hist_pool = None      # the next chunk zeroes one
+            bst.gbdt.train_chunk(chunk)
+        return bst.gbdt._hist_pool, bst.model_to_string()
+    pool, text = grow(2, keep=True)
+    assert pool.shape == shape and pool.dtype == np.float32
+    assert np.asarray(pool).any() == (not extra)   # the last tree's sums
+    assert text == grow(1, keep=False)[1]
+    assert text.count("Tree=") == 6
+
+
+def test_no_hist_pool_off_the_kernel_ladder():
+    X, y = _task()
+    cfg = Config.from_params({"objective": "binary", "verbose": -1})
+    g = TreeGrower(lgb.Dataset(X, label=y).construct(cfg), cfg)
+    assert g.plan.tier == "xla" and g.new_hist_pool() is None

@@ -1,0 +1,85 @@
+"""The wide table's kernels compiled for a v5e chip that is described,
+not attached (nothing runs, no time is read): the TPU's own compiler says
+whether a group chunk of the plan fits VMEM at the Epsilon cell's shape,
+which the interpret seam cannot.  One file, so that one worker loads the
+TPU's library; the topology is described inside a fixture."""
+import os
+
+import pytest
+
+ROWS, GROUPS, BINS, LEAVES = 98 * 4096, 2000, 255, 255
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def plan():
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops.hist_plan import LADDER_WIDTH, resolve_hist_plan
+    return resolve_hist_plan(
+        Config.from_params({"verbose": -1, "hist_compute_dtype": "bfloat16",
+                            "quantized_grad": True}),
+        on_tpu=True, mesh_axes=None, row_axis=None, cols_sharded=False,
+        multihost=False, rows_padded=ROWS, num_groups=GROUPS,
+        max_group_bin=BINS, packed_groups=0, frontier=LADDER_WIDTH)
+
+
+def _shapes(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    route_cols = 15 + (BINS + 7) // 8
+    return dict(binsT=s((GROUPS, ROWS), jnp.uint8),
+                wT=s((3, ROWS), jnp.int32), scales=s((3,), jnp.float32),
+                leaf=s((ROWS,), jnp.int32),
+                route=s((LEAVES, route_cols), jnp.float32),
+                slots=s((126,), jnp.int32), values=s((LEAVES,), jnp.float32))
+
+
+@pytest.mark.parametrize("k_cap,a", [(126, 2), (2, 4)])
+def test_factored_rung_compiles_in_group_chunks(one_chip, plan, k_cap, a):
+    """The widest rung and the narrowest (two groups a tile) at 2,000
+    groups x 401,408 rows, in the plan's chunks, under the VMEM limit the
+    kernel asks for."""
+    from lightgbm_tpu.ops.histogram import (
+        compute_group_histograms_fused_factored)
+    sh = _shapes(one_chip)
+    assert plan.group_chunks > 1
+    compiled = compute_group_histograms_fused_factored.lower(
+        sh["binsT"], sh["wT"], sh["scales"], sh["leaf"], sh["route"],
+        sh["slots"], max_group_bin=BINS, k_cap=k_cap, a=a,
+        block=plan.block_factored, group_chunk=plan.group_chunk).compile()
+    text = compiled.as_text()
+    assert f"compute_group_histograms_fused_factored_k{k_cap}_a{a}" in text
+    # the route's split rows, and no copy of the table for them
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * GROUPS * ROWS
+
+
+def test_exit_route_compiles_over_split_rows(one_chip, plan):
+    import jax
+    from lightgbm_tpu.ops.histogram import (gather_split_rows,
+                                            route_apply_tiled)
+    sh = _shapes(one_chip)
+
+    def route(binsT, leaf, tab, values):
+        rowsT, tab = gather_split_rows(binsT, tab)
+        return route_apply_tiled(rowsT, leaf, tab, values,
+                                 block=plan.block_tiled)
+    compiled = jax.jit(route).lower(sh["binsT"], sh["leaf"], sh["route"],
+                                    sh["values"]).compile()
+    assert "route_apply_tiled" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20

@@ -49,7 +49,8 @@ import numpy as np
 from ..backend import on_tpu
 from ..config import Config
 from ..dataset import Dataset
-from ..ops.hist_plan import LADDER_WIDTH, resolve_hist_plan
+from ..ops.hist_plan import (LADDER_WIDTH, finder_block, finder_identity_map,
+                              resolve_hist_plan)
 from ..ops.histogram import (PACKED_STRIP, compute_group_histograms,
                              compute_group_histograms_fused,
                              compute_group_histograms_pallas,
@@ -69,6 +70,7 @@ from ..ops.split import (CAND_CAT_DIR, CAND_COLS, CAND_DEFAULT_LEFT,
                          FORCED_ROUT, FORCED_THRESHOLD,
                          build_cat_bitset, find_best_split_block,
                          forced_split_block, run_split_finders)
+from ..ops.split_kernel import Finder, finder_scans
 from ..telemetry import TELEMETRY
 from ..tree import TreeRecordLayout
 
@@ -368,6 +370,16 @@ class TreeGrower:
             frontier=self.frontier)
         for message in self.plan.warnings:
             Log.warning(message)
+        # the numerical split finder's form, the plan's, with the host
+        # facts its fused form is specialised on; and whether it may
+        # read the group histogram itself (a property of this table)
+        self.finder = Finder(
+            self.plan.finder,
+            finder_scans(meta["num_bin"], meta["missing_type"]),
+            self.plan.interpret)
+        self.finder_identity = finder_identity_map(
+            bin_map, fix_bin, self.num_groups, self.max_group_bin,
+            self.has_categorical, bool(self.forced_count))
         # transposed on DEVICE from the already-uploaded bins: a host
         # transpose + second upload of the (N, G) matrix doubles the
         # host->device traffic at the 10.5M scale
@@ -424,6 +436,17 @@ class TreeGrower:
             TELEMETRY.gauge("grower.hist_factored_rungs", ",".join(
                 f"{k}:{a}x{b}" for k, a, b in plan.factored_rungs))
             TELEMETRY.gauge("grower.quantized", int(plan.quantized))
+            # the split finder: its form, whether it reads the group
+            # histogram itself, the scans it traces, and the fused
+            # form's (leaf rows x features) block at the widest refresh
+            TELEMETRY.gauge("grower.split_finder", plan.finder)
+            TELEMETRY.gauge("grower.finder_identity_map",
+                            int(self.finder_identity))
+            TELEMETRY.gauge("grower.finder_scans", self.finder.scans)
+            TELEMETRY.gauge("grower.finder_block", "x".join(map(
+                str, finder_block(
+                    2 * self.frontier, self.max_feature_bin,
+                    self.finder.scans, plan.int_counts))))
             # the factored kernel's group axis: one chunk (every group a
             # grid step), or a grid axis of chunks
             TELEMETRY.gauge("grower.num_groups", int(self.num_groups))
@@ -1179,7 +1202,7 @@ class TreeGrower:
         return run_split_finders(
             hist, sum_grad, sum_hess, count, min_c, max_c, cfg,
             f_num_bin, f_missing, f_default_bin, f_monotone, f_is_cat,
-            feature_mask, self.has_categorical)
+            feature_mask, self.has_categorical, finder=self.finder)
 
     # ------------------------------------------------------------------
     def _refresh(self, st: GrowerState, parents, rights, grad, hess,
@@ -1302,19 +1325,41 @@ class TreeGrower:
         xc = st.leaf_max_c[safe]
         feat_count = None
         if self.plan.int_counts:
-            # the counts' own FixHistogram, in integers
-            h_w, c_w = h_w
-            feat_count = expand_feature_histograms(
-                c_w[..., None], self.bin_map, self.fix_bin,
-                sc[:, None])[..., 0]
-        totals = jnp.stack([sg, sh, sc.astype(jnp.float32)], axis=1)
-        feat_hist = expand_feature_histograms(h_w, self.bin_map,
-                                              self.fix_bin, totals)
-        block = find_best_split_block(
-            feat_hist, sg, sh, sc, mc, xc, cfg, self.f_num_bin,
-            self.f_missing, self.f_default_bin, self.f_monotone,
-            self.f_is_cat, feature_mask, self.has_categorical,
-            feat_count=feat_count)
+            h_w, feat_count = h_w
+        if self.finder_identity:
+            # every feature is its own group, bin for bin: the finder
+            # reads the group histogram itself
+            Bf = self.max_feature_bin
+            feat_hist = h_w[:, :, :Bf]
+            if feat_count is not None:
+                feat_count = feat_count[:, :, :Bf]
+        else:
+            if feat_count is not None:
+                # the counts' own FixHistogram, in integers
+                feat_count = expand_feature_histograms(
+                    feat_count[..., None], self.bin_map, self.fix_bin,
+                    sc[:, None])[..., 0]
+            totals = jnp.stack([sg, sh, sc.astype(jnp.float32)], axis=1)
+            feat_hist = expand_feature_histograms(h_w, self.bin_map,
+                                                  self.fix_bin, totals)
+
+        def best_block(feat_hist, sg, sh, sc, mc, xc, feature_mask,
+                       feat_count):
+            return find_best_split_block(
+                feat_hist, sg, sh, sc, mc, xc, cfg, self.f_num_bin,
+                self.f_missing, self.f_default_bin, self.f_monotone,
+                self.f_is_cat, feature_mask, self.has_categorical,
+                feat_count=feat_count, finder=self.finder)
+
+        if self.plan.mesh_kernels and self.finder.form == "fused":
+            # the exact sum left the histogram replicated: every shard
+            # runs the finder's kernel on its own copy
+            from jax.sharding import PartitionSpec as P
+            best_block = _get_shard_map()(
+                best_block, mesh=self.policy.mesh, in_specs=P(),
+                out_specs=P())
+        block = best_block(feat_hist, sg, sh, sc, mc, xc, feature_mask,
+                           feat_count)
         idx = jnp.where(slots_w >= 0, slots_w, L)
         tmap = jax.tree_util.tree_map       # a block, or its pair
         cand = tmap(lambda c, b: c.at[idx].set(b, mode="drop"),

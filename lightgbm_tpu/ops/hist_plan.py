@@ -50,6 +50,16 @@ ONEHOT_BUDGET_MB = 6144
 #: the blocks counted in ``factored_vmem_bytes``
 CHUNK_VMEM_BUDGET = CHUNK_VMEM_LIMIT - (12 << 20)
 
+#: VMEM the fused split finder asks of the compiler, and what a grid
+#: step of it is planned into (``finder_vmem_bytes``), the rest left to
+#: what Mosaic keeps besides
+FINDER_VMEM_LIMIT = 96 << 20
+FINDER_VMEM_BUDGET = FINDER_VMEM_LIMIT - (24 << 20)
+
+#: leaf rows a grid step of the fused finder holds: they are the lanes
+#: of its (features, leaf rows) outputs
+FINDER_ROWS = 128
+
 #: rows a block of the streamed-one-hot kernels (their 3.6 MB/block DMA
 #: pipeline prefers 2048 on a v5e: 4096 benched 16% slower)
 FLOAT_BLOCK = 2048
@@ -81,6 +91,8 @@ class HistPlan:
     # routes read the table's split rows: histogram.gather_split_rows)
     num_groups: int               # the table's groups
     factored_rungs: Tuple[Tuple[int, int, int], ...]  # rungs in force
+    finder: str                   # "fused" | "xla": the numerical split
+    # finder's form (ops/split_kernel.py), fused with the Pallas tiers
     warnings: Tuple[str, ...]     # for the caller to log, in order
 
     @property
@@ -169,6 +181,62 @@ def _group_chunk(rungs, num_groups: int, block: int) -> int:
             widest, chunk + 32, block, True) <= CHUNK_VMEM_BUDGET:
         chunk += 32
     return chunk
+
+
+def finder_vmem_bytes(r_blk: int, f_blk: int, lanes: int, scans: int,
+                      int_counts: bool) -> int:
+    """VMEM a grid step of the fused split finder
+    (``ops/split_kernel.py``) holds for a block of ``r_blk`` leaf rows x
+    ``f_blk`` features x ``lanes`` bins: the three planes in their two
+    buffers, the three prefix sums of each scan, one prefix product's
+    operand and result (twice where int32 counts go as two limbs), and
+    the triangular matrix."""
+    plane = r_blk * f_blk * lanes * 4
+    return (6 + 3 * scans + (4 if int_counts else 2)) * plane \
+        + lanes * lanes * 4
+
+
+def finder_lanes(bins: int) -> int:
+    """Lanes a row of ``bins`` bins takes in the fused finder's blocks:
+    whole 128-lane tiles (255 bins read as 256)."""
+    return _round_up(bins, 128)
+
+
+def finder_block(rows: int, bins: int, scans: int,
+                 int_counts: bool) -> Tuple[int, int]:
+    """``(r_blk, f_blk)`` of the fused split finder for ``rows`` leaf
+    rows of ``bins`` bins: every row up to ``FINDER_ROWS`` of them, and
+    the most features (whole sublane tiles of 8, at most 32: a block of
+    a few MB already hides a grid step's fixed cost) that fit
+    ``FINDER_VMEM_BUDGET``."""
+    r_blk = min(rows, FINDER_ROWS)
+    lanes = finder_lanes(bins)
+    f_blk = 8
+    while f_blk < 32 and finder_vmem_bytes(
+            r_blk, 2 * f_blk, lanes, scans,
+            int_counts) <= FINDER_VMEM_BUDGET:
+        f_blk *= 2
+    return r_blk, f_blk
+
+
+def finder_identity_map(bin_map, fix_bin, num_groups: int,
+                        max_group_bin: int, has_categorical: bool,
+                        forced_splits: bool) -> bool:
+    """The finder may read the group histogram itself: every feature
+    alone in its own group with no collapsed default (``bin_map[f, b] ==
+    f * max_group_bin + b`` wherever it is set, no ``fix_bin``), no
+    categorical feature and no forced split.  ``bin_map`` / ``fix_bin``:
+    numpy, ``Dataset.feature_bin_maps``.  A bin past a feature's
+    ``num_bin`` holds no row, and the finder's threshold test excludes
+    it."""
+    import numpy as np
+    features, feature_bins = bin_map.shape
+    if (has_categorical or forced_splits or features != num_groups
+            or feature_bins > max_group_bin or (fix_bin >= 0).any()):
+        return False
+    own = (np.arange(features, dtype=np.int64)[:, None] * max_group_bin
+           + np.arange(feature_bins)[None, :])
+    return bool(((bin_map < 0) | (bin_map == own)).all())
 
 
 def _onehot_pack(rows: int, gb: int) -> Tuple[int, int]:
@@ -365,4 +433,5 @@ def resolve_hist_plan(config, *, on_tpu: bool,
         group_chunk=group_chunk, num_groups=num_groups,
         # in force only where a group fills a 256-lane tile
         factored_rungs=rungs,
+        finder="xla" if tier == "xla" else "fused",
         warnings=tuple(warnings))

@@ -19,6 +19,7 @@ remains of missing handling is exactly:
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -543,8 +544,8 @@ def run_split_finders(hist: jax.Array, sum_grad: jax.Array,
                       f_monotone: jax.Array, f_is_cat: jax.Array,
                       feature_mask: jax.Array,
                       has_categorical: bool,
-                      hist_count: Optional[jax.Array] = None
-                      ) -> Tuple[SplitResult, jax.Array]:
+                      hist_count: Optional[jax.Array] = None,
+                      finder=None) -> Tuple[SplitResult, jax.Array]:
     """Per-(leaf-row, feature) finder pass shared by every best-split
     path: numerical finders, the categorical overlay where-merged by
     `f_is_cat`, and the feature-mask gain fill.  Leaf-shaped args are
@@ -552,8 +553,17 @@ def run_split_finders(hist: jax.Array, sum_grad: jax.Array,
     masked to K_MIN_SCORE outside `feature_mask`.  With ``hist_count``
     (int32, ``count`` too) the numerical finder counts in integers and
     ``res.left_count`` is int32; the categorical finder still reads
-    ``hist``'s float32 count channel (exact to 2^24 rows a node)."""
-    num_res = find_numerical_splits(
+    ``hist``'s float32 count channel (exact to 2^24 rows a node).
+    ``finder``: the numerical finder's form, a
+    ``split_kernel.Finder`` (``HistPlan.finder``; None is the XLA
+    form)."""
+    numerical = find_numerical_splits
+    if finder is not None and finder.form == "fused":
+        from .split_kernel import find_numerical_splits_fused
+        numerical = functools.partial(
+            find_numerical_splits_fused, scans=finder.scans,
+            interpret=finder.interpret)
+    num_res = numerical(
         hist, sum_grad, sum_hess, count, f_num_bin, f_missing,
         f_default_bin, f_monotone, min_c, max_c, cfg,
         hist_count=hist_count)
@@ -581,7 +591,8 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
                           f_monotone: jax.Array, f_is_cat: jax.Array,
                           feature_mask: jax.Array,
                           has_categorical: bool,
-                          feat_count: Optional[jax.Array] = None):
+                          feat_count: Optional[jax.Array] = None,
+                          finder=None):
     """Best split per FRONTIER leaf as one packed candidate block.
 
     Every shape here is bounded by the frontier width W' the caller
@@ -600,6 +611,7 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
       f_*: (F,) feature metadata; feature_mask: (F,) bool.
       feat_count: (W', F, B) int32 counts, with ``count`` int32 (see
         :func:`find_numerical_splits`).
+      finder: as :func:`run_split_finders`.
     Returns: (W', CAND_COLS + B) f32 packed candidate rows; with
       ``feat_count`` a pair of them and the winners' (W',) int32 left
       counts, which a float32 column would round above 2^24.
@@ -608,7 +620,8 @@ def find_best_split_block(feat_hist: jax.Array, sum_grad: jax.Array,
     res, gains = run_split_finders(
         feat_hist, sum_grad, sum_hess, count, min_c, max_c, cfg,
         f_num_bin, f_missing, f_default_bin, f_monotone, f_is_cat,
-        feature_mask, has_categorical, hist_count=feat_count)
+        feature_mask, has_categorical, hist_count=feat_count,
+        finder=finder)
 
     best_fc = jnp.argmax(gains, axis=1).astype(jnp.int32)       # (W',)
     best_gain = jnp.max(gains, axis=1)     # == value at argmax

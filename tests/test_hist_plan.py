@@ -195,6 +195,7 @@ def test_resolve_hist_plan(params, facts, want):
     assert plan.group_chunk * plan.group_chunks >= plan.num_groups \
         > plan.group_chunk * (plan.group_chunks - 1)
     assert plan.group_chunks == 1 or plan.group_chunk % 32 == 0
+    assert plan.finder == ("xla" if plan.tier == "xla" else "fused")
     with pytest.raises(AttributeError):     # immutable
         plan.tier = "xla"
 
@@ -211,6 +212,7 @@ def test_criteo_plan_is_the_one_before_the_group_chunk():
     field."""
     got = dataclasses.asdict(_plan(**CRITEO))
     assert (got.pop("group_chunk"), got.pop("num_groups")) == (67, 67)
+    assert got.pop("finder") == "fused"
     assert got == dict(
         tier="ladder", interpret=False, row_axis=None, row_shards=1,
         local_rows=1 << 24, mesh_kernels=False, exchange_limbs=0,
@@ -243,3 +245,198 @@ def test_group_chunk_fits_the_budget_the_plan_states(groups, monkeypatch):
     monkeypatch.setattr(hist_plan, "CHUNK_VMEM_BUDGET", 1 << 20)
     assert _plan(**{**EPSILON, "num_groups": groups}).group_chunk == 32
     assert _plan(**CRITEO).group_chunk == 32
+
+
+# -- the split finder's form: decided here, once ------------------------
+@pytest.mark.fast
+@pytest.mark.parametrize("params,facts,finder,interpret", [
+    ({}, {}, "xla", False),
+    ({**FAST, **SEAM}, {}, "fused", True),
+    (FAST, {**TPU, **CRITEO}, "fused", False),
+    (FAST, {**TPU, **EPSILON}, "fused", False),
+    (BF16, {**TPU}, "fused", False),
+    ({**FAST, **DATA}, {**TPU, **ROW_MESH, **CRITEO}, "fused", False),
+    ({**FAST, "hist_kernel": "xla"}, {**TPU}, "xla", False),
+    ({**FAST, "tree_learner": "voting"}, {**TPU, **ROW_MESH}, "xla", False),
+    ({**FAST, "tree_learner": "feature"},
+     {**TPU, "mesh_axes": (("feature", 4),), "cols_sharded": True},
+     "xla", False),
+], ids=["cpu_defaults", "seam", "criteo_cell", "epsilon_cell", "float_tier",
+        "row_mesh", "hist_kernel_xla", "voting_mesh", "feature_mesh"])
+def test_finder_form_follows_the_tier(params, facts, finder, interpret):
+    """Fused wherever the plan runs Pallas kernels, with their interpret
+    seam; the XLA form on the ``xla`` tier.  No option names it."""
+    plan = resolve_hist_plan(Config.from_params({"verbose": -1, **params}),
+                             **{**FACTS, **facts})
+    assert (plan.finder, plan.interpret) == (finder, interpret)
+    assert not [f for f in Config.__dataclass_fields__ if "finder" in f
+                and f != "split_finder_ladder"]
+
+
+def _grower(X, y, categorical_feature="auto", **params):
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.learner.grower import TreeGrower
+    config = Config.from_params({"objective": "binary", "verbose": -1,
+                                 "min_data_in_leaf": 5, **params})
+    return TreeGrower(lgb.Dataset(
+        X, label=y, params=params,
+        categorical_feature=categorical_feature).construct(config), config)
+
+
+def _table(rows=600, features=6, seed=0):
+    import numpy as np
+    rng = np.random.RandomState(seed)
+    X = rng.randn(rows, features).astype(np.float32)
+    return X, (X[:, 0] - X[:, 1] > 0).astype(np.float32)
+
+
+def _identity_case(name, tmp_path):
+    """(grower, whether its finder may read the group histogram)."""
+    import json
+    import numpy as np
+    X, y = _table()
+    if name == "plain_dense_table":
+        return _grower(X, y), True
+    if name == "efb_bundles":
+        # mutually exclusive sparse columns share a group, their
+        # defaults collapsed into its slot 0
+        rng = np.random.RandomState(1)
+        X = np.zeros((600, 6), np.float32)
+        X[np.arange(600), rng.randint(0, 6, 600)] = rng.rand(600) + 1.0
+        grower = _grower(X, y, enable_bundle=True)
+        assert grower.num_groups < grower.num_features
+        assert (np.asarray(grower.fix_bin) >= 0).any()
+        return grower, False
+    if name == "categorical_feature":
+        X[:, 2] = np.random.RandomState(2).randint(0, 5, 600)
+        grower = _grower(X, y, categorical_feature=[2])
+        assert grower.has_categorical
+        return grower, False
+    assert name == "forced_splits"
+    fn = str(tmp_path / "forced.json")
+    with open(fn, "w") as f:
+        json.dump({"feature": 0, "threshold": 0.0}, f)
+    grower = _grower(X, y, forcedsplits_filename=fn)
+    assert grower.forced_count == 1
+    return grower, False
+
+
+@pytest.mark.parametrize("name", ["plain_dense_table", "efb_bundles",
+                                  "categorical_feature", "forced_splits"])
+def test_finder_identity_map_is_read_off_the_table(name, tmp_path,
+                                                   monkeypatch):
+    """Where every feature is its own group, bin for bin, the finder
+    reads the group histogram and ``expand_feature_histograms`` is not
+    traced: a property of the table, found at set-up."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.telemetry import TELEMETRY
+    TELEMETRY.configure("counters")
+    try:
+        grower, want = _identity_case(name, tmp_path)
+        gauges = TELEMETRY.gauges()
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    assert grower.finder_identity is want
+    assert gauges["grower.finder_identity_map"] == int(want)
+    assert gauges["grower.split_finder"] == "xla"
+    assert gauges["grower.finder_scans"] in (1, 2)
+    from lightgbm_tpu.learner import grower as grower_module
+    expands = []
+
+    def expand(*args):
+        expands.append(args[0].shape)
+        return grower_module.histogram_expand(*args)
+    monkeypatch.setattr(grower_module, "histogram_expand",
+                        grower_module.expand_feature_histograms,
+                        raising=False)
+    monkeypatch.setattr(grower_module, "expand_feature_histograms", expand)
+    ones = jnp.ones(grower.n_padded, jnp.float32)
+    jax.make_jaxpr(grower._train_tree_impl)(
+        ones * 0.5, ones, ones, jnp.ones(grower.num_features, bool))
+    assert bool(expands) != want
+
+
+@pytest.mark.fast
+def test_finder_identity_map_cases_of_the_map_itself():
+    import numpy as np
+    from lightgbm_tpu.ops.hist_plan import finder_identity_map
+    own = np.arange(4)[:, None] * 10 + np.arange(8)[None, :]
+    none = np.full(4, -1)
+    ragged = np.where(np.arange(8)[None, :] < np.array([8, 3, 5, 2])[:, None],
+                      own, -1)
+    ok = dict(num_groups=4, max_group_bin=10, has_categorical=False,
+              forced_splits=False)
+    assert finder_identity_map(own, none, **ok)
+    assert finder_identity_map(ragged, none, **ok)
+    # a collapsed default: its bin is rebuilt from the leaf's totals
+    assert not finder_identity_map(ragged, np.array([-1, 0, -1, -1]), **ok)
+    # a bundle member: its bins sit at an offset in another's group
+    shifted = own.copy()
+    shifted[2] = own[1] + 3
+    assert not finder_identity_map(shifted, none, **ok)
+    assert not finder_identity_map(own, none, **{**ok, "num_groups": 3})
+    assert not finder_identity_map(own, none, **{**ok, "max_group_bin": 7})
+    assert not finder_identity_map(own, none,
+                                   **{**ok, "has_categorical": True})
+    assert not finder_identity_map(own, none, **{**ok, "forced_splits": True})
+
+
+LEARNERS = {
+    "serial": {**FAST, **SEAM},
+    "data_parallel_kernels": {**FAST, **SEAM, **DATA, "mesh_shape": [4],
+                              "mesh_axes": ["data"],
+                              "hist_kernel": "pallas"},
+    "voting": {"tree_learner": "voting", "top_k": 4},
+    "feature_parallel": {"tree_learner": "feature"},
+}
+
+
+@pytest.mark.parametrize("learner", list(LEARNERS))
+def test_every_learner_grows_the_same_trees_with_either_finder(
+        learner, monkeypatch):
+    """One small job a learner, its finder's form forced each way in the
+    plan (no option does that): the same split features and thresholds
+    on a table whose gains are well apart."""
+    import re
+    import jax
+    import numpy as np
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.learner import grower as grower_module
+    if learner != "serial" and len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    rng = np.random.RandomState(5)
+    X = np.exp(rng.randn(4096, 8)).astype(np.float32)
+    y = (np.log(X[:, 0]) * 2 - np.log(X[:, 1]) + np.log(X[:, 2]) * 0.5
+         + 0.05 * rng.randn(4096) > 0).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": 8, "max_bin": 63,
+              "verbose": -1, "min_data_in_leaf": 20,
+              "quant_stochastic_rounding": 1, **LEARNERS[learner]}
+    seen = []
+
+    def plan_with(form):
+        def resolve(*args, **kwargs):
+            plan = resolve_hist_plan(*args, **kwargs)
+            seen.append((learner, plan.tier, form))
+            return dataclasses.replace(
+                plan, finder=form, interpret=plan.interpret
+                or form == "fused")
+        return resolve
+
+    def splits(form):
+        monkeypatch.setattr(grower_module, "resolve_hist_plan",
+                            plan_with(form))
+        bst = lgb.train(params, lgb.Dataset(X, label=y), 2,
+                        keep_training_booster=True)
+        assert bst.gbdt.grower.finder.form == form
+        text = bst.model_to_string()
+        return (re.findall(r"^split_feature=.*$", text, re.M),
+                [np.array(t.split("=")[1].split(), float) for t in
+                 re.findall(r"^threshold=.*$", text, re.M)])
+
+    (feat_x, thr_x), (feat_f, thr_f) = splits("xla"), splits("fused")
+    assert feat_x == feat_f and len(feat_x) == 2
+    for a, b in zip(thr_x, thr_f):
+        np.testing.assert_array_equal(a, b)
+    assert {s[2] for s in seen} == {"xla", "fused"}

@@ -83,3 +83,40 @@ def test_exit_route_compiles_over_split_rows(one_chip, plan):
                                     sh["values"]).compile()
     assert "route_apply_tiled" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("rows,features,scans,int_counts", [
+    (252, GROUPS, 1, False), (252, 67, 2, True)],
+    ids=["epsilon_widest_refresh", "two_scans_int32_counts"])
+def test_fused_split_finder_compiles_in_its_block(one_chip, rows, features,
+                                                  scans, int_counts):
+    """The fused finder at the widest refresh of the cells (252 leaf rows
+    x 255 bins): the block ``hist_plan.finder_block`` sizes fits the VMEM
+    the kernel asks for (two scans with int32 counts hold the most), and
+    at 2,000 features the channel-major view of the ``(R, F, B, 3)``
+    operand is no copy (no temporary the size of the histogram)."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.split_kernel import find_numerical_splits_fused
+    cfg = dict(lambda_l1=0.0, lambda_l2=0.0, max_delta_step=0.0,
+               min_data_in_leaf=1.0, min_sum_hessian_in_leaf=100.0,
+               min_gain_to_split=0.0)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    leaf, meta = s((rows,), jnp.float32), s((features,), jnp.int32)
+    count = jnp.int32 if int_counts else jnp.float32
+
+    def finder(hist, sg, sh, nd, nb, ms, db, mo, mc, xc, hc=None):
+        return find_numerical_splits_fused(hist, sg, sh, nd, nb, ms, db, mo,
+                                           mc, xc, cfg, hist_count=hc,
+                                           scans=scans)
+    args = [s((rows, features, BINS, 3), jnp.float32), leaf, leaf,
+            s((rows,), count), meta, meta, meta, meta, leaf, leaf]
+    if int_counts:
+        args.append(s((rows, features, BINS), jnp.int32))
+    compiled = jax.jit(finder).lower(*args).compile()
+    assert f"find_numerical_splits_fused_r{rows}" in compiled.as_text()
+    if features == GROUPS:
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < rows * features * BINS * 4

@@ -242,3 +242,40 @@ def test_onchip_fused_factored_kernel():
                                           np.asarray(want)[:k_cap],
                                           err_msg=str(k_cap))
             assert float(jnp.abs(got).sum()) > 0
+
+
+@pytest.mark.parametrize("case", [
+    dict(R=252, F=40, B=255, seed=21, missing=0),
+    dict(R=168, F=67, B=255, seed=22, int_counts=True, count_scale=2),
+    dict(R=84, F=24, B=255, seed=23, monotone=True, tight=True),
+    dict(R=31, F=28, B=63, seed=24, exact=True)],
+    ids=["one_scan_r252", "int32_counts_above_2_to_24", "two_scans_monotone",
+         "exact_sums"])
+def test_onchip_fused_split_finder(case):
+    """The fused finder (ops/split_kernel.py) compiled by Mosaic against
+    the XLA form on the chip, at the leaf rows and the 255 bins of the
+    benchmark's cells: its prefix sums are float32 products with a 0/1
+    matrix on the MXU (``Precision.HIGHEST``) and its int32 counts two
+    16-bit limbs, and only the chip says whether those are the sums
+    (tests/test_split_kernel.py pins the rest in interpret mode).  The
+    tolerance is the XLA form's own distance from float64 here: its
+    two-level ``reduce-window`` prefix sums read 3x further from it than
+    the kernel's (7.6e-4 against 2.5e-4 at sums of 2,770: my chip run,
+    PR 34), and a gain divides by a small hessian sum."""
+    from test_split_kernel import (CFG, assert_same_splits, make_case,
+                                   run_both)
+    exact = case.pop("exact", False)
+    args = make_case(exact=exact, **case)
+    cfg = CFG
+    if "hist_count" in args:
+        args["hist_count"] = args["hist_count"] * ((1 << 24) + 1)
+        args["num_data"] = args["hist_count"][:, 0].sum(1).astype(np.int32)
+        args["hist_count"][:, :, 0] += args["num_data"][:, None] \
+            - args["hist_count"].sum(2)
+        assert args["hist_count"].max() > 1 << 26
+        cfg = {**CFG, "min_data_in_leaf": 5.0 * (1 << 24)}
+    ref, got = run_both(args, cfg=cfg, interpret=False)
+    assert_same_splits(ref, got, exact_choice=exact, tol=3e-4, min_same=0.85)
+    if "hist_count" in args:
+        assert np.array_equal(np.asarray(ref.left_count),
+                              np.asarray(got.left_count))

@@ -432,7 +432,10 @@ class Config:
     # shard)
     quantized_grad: bool = False    # int8-MXU quantized histogram
     # construction (one grad/hess scale per tree; the TPU analog of
-    # LightGBM v4 quantized training, arXiv 2207.09682) — TPU path only
+    # LightGBM v4 quantized training, arXiv 2207.09682) — TPU path
+    # only.  Any number of rows a device: an int32 accumulator sums a
+    # row segment of at most 2^24 rows (rows * 127 < 2^31) and the
+    # segments are added exactly, as a mesh's shards are
     quant_stochastic_rounding: int = -1  # round the quantized
     # gradients stochastically (the v4 recipe, unbiased in
     # expectation): -1 = auto (the objective decides — lambdarank
@@ -446,11 +449,12 @@ class Config:
     # recipe, arXiv 2011.02022): "f32" always accumulates float32
     # (quantized_grad is ignored); "tiered" forces the int32
     # quantized-weight kernel path with its f32 fix-up (dequantize)
-    # pass before split finding — a loud kernel-plan error when the
-    # row count could overflow the int32 accumulator (rows * 127 >=
-    # 2^31) or no quantized kernel route exists; "auto" selects from
-    # the row count exactly like quantized_grad alone does today, so
-    # trees stay byte-identical to the pre-tier behavior.  The chosen
+    # pass before split finding — a loud kernel-plan error when no
+    # quantized kernel route exists (the row count is no cause: past
+    # 2^24 rows a device the accumulation runs in row segments, each
+    # inside the int32 bound rows * 127 < 2^31, folded exactly);
+    # "auto" follows quantized_grad alone, so trees stay byte-identical
+    # to the pre-tier behavior.  The chosen
     # tier is the grower.hist_precision telemetry gauge; fix-up passes
     # count in hist_quant_fixup
     hist_exchange: str = "f32"  # cross-shard histogram exchange codec

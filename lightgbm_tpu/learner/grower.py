@@ -476,6 +476,13 @@ class TreeGrower:
             TELEMETRY.gauge("grower.rows_padded", int(self.n_padded))
             TELEMETRY.gauge("grower.row_shards", int(plan.row_shards))
             TELEMETRY.gauge("grower.local_rows", int(plan.local_rows))
+            # int32 accumulators a pass writes on a device and the rows
+            # of one (1: no fold), and whether row counts are int32
+            TELEMETRY.gauge("grower.hist_row_segments",
+                            int(plan.row_segments))
+            TELEMETRY.gauge("grower.hist_segment_rows",
+                            int(plan.segment_rows))
+            TELEMETRY.gauge("grower.int_counts", int(plan.int_counts))
             # what one shard puts into the cross-shard sum of the
             # widest pass: (W, G, B, 3) int32, twice as two limbs
             TELEMETRY.gauge(
@@ -855,39 +862,49 @@ class TreeGrower:
     # ------------------------------------------------------------------
     def _on_row_shards(self, kernel, binsT, wT, scales, leaf_id,
                        route_tab, slots):
-        """One fused pass of the ladder.  On one device ``kernel`` (a
-        ``compute_group_histograms_fused_*`` with its static arguments
-        bound) is called as it always was.  Under a row mesh every
-        shard runs the same ``pallas_call`` on its own columns of
-        ``binsT`` / ``wT`` and its own leaf ids, and hands the int32
-        accumulators — not yet dequantized — to the exact cross-shard
-        sum; the dequantize multiply then meets a replicated histogram,
-        as does everything downstream of it, and the histogram comes
-        back as a pair with the sum's int32 row counts (plan.int_counts).
-        ``route_tab`` and ``slots`` are replicated, so every shard takes
-        the same rung of the ``lax.cond`` ladder this is called from."""
-        if not self.plan.mesh_kernels:
+        """One fused pass of the ladder.  On one device of one row
+        segment ``kernel`` (a ``compute_group_histograms_fused_*`` with
+        its static arguments bound) is called as it always was.  Under
+        a row mesh every shard runs the same ``pallas_call`` on its own
+        columns of ``binsT`` / ``wT`` and its own leaf ids, and hands
+        the int32 accumulators — not yet dequantized — to the exact
+        cross-shard sum; a device that holds more rows than one int32
+        accumulator sums (``plan.row_segments`` > 1, under a mesh or
+        not) gets one accumulator a segment from the kernel and the
+        same exact sum folds them.  The dequantize multiply then meets
+        one histogram, replicated under a mesh, as does everything
+        downstream of it, and the histogram comes back as a pair with
+        the sum's int32 row counts (plan.int_counts).  ``route_tab`` and
+        ``slots`` are replicated, so every shard takes the same rung of
+        the ``lax.cond`` ladder this is called from."""
+        plan = self.plan
+        if not plan.int_counts:
             return kernel(binsT, wT, scales, leaf_id, route_tab, slots)
         from jax.sharding import PartitionSpec as P
         from ..parallel import collectives
-        axis = self.plan.row_axis
-        cols, rows, rep = P(None, axis), P(axis), P()
+        axis = plan.row_axis if plan.mesh_kernels else None
 
         def shard(bT, w, lid, rt, sl):
             acc, leaf2 = kernel(bT, w, None, lid, rt, sl,
-                                dequantize=False)
-            with TELEMETRY.phase("hist_exchange"):
+                                dequantize=False,
+                                segment_rows=plan.segment_rows)
+            with (TELEMETRY.phase("hist_fold") if axis is None
+                  else TELEMETRY.phase("hist_exchange")):
                 # looked up on the module at trace time, so that a
                 # fault planted there (perfbench/tests) is in the sum
                 total, rows_i32 = collectives.exchange_int_histograms(
-                    acc, axis, global_rows=self.n_padded)
+                    acc, axis, global_rows=self.n_padded,
+                    segments=plan.row_segments)
             return total, rows_i32, leaf2
 
-        total, rows_i32, leaf2 = _get_shard_map()(
-            shard, mesh=self.policy.mesh,
-            in_specs=(cols, cols, rows, rep, rep),
-            out_specs=(rep, rep, rows))(binsT, wT, leaf_id, route_tab,
-                                        slots)
+        if axis is not None:
+            cols, rows, rep = P(None, axis), P(axis), P()
+            shard = _get_shard_map()(
+                shard, mesh=self.policy.mesh,
+                in_specs=(cols, cols, rows, rep, rep),
+                out_specs=(rep, rep, rows))
+        total, rows_i32, leaf2 = shard(binsT, wT, leaf_id, route_tab,
+                                       slots)
         return (_scaled(total, scales), rows_i32), leaf2
 
     # ------------------------------------------------------------------

@@ -21,7 +21,9 @@ immutable :class:`HistPlan`; the grower, ``boosting/gbdt.py`` and
     the int8 fused ladder (``_fused_tiled`` strips, ``_fused_factored``
     rungs, ``route_apply_tiled`` at the tree's end) on one device or on
     every row shard of a one-axis data mesh, whose int32 accumulators
-    are summed exactly.
+    are summed exactly — as are those of a device's row segments, where
+    it holds more rows than one int32 accumulator sums
+    (``histogram.QUANT_SEGMENT_ROWS``).
 
 An explicit request that cannot be honoured (``hist_kernel=pallas``,
 ``hist_precision=tiered``) raises; under ``hist_kernel=auto`` the XLA
@@ -29,12 +31,14 @@ formulation runs instead.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
 from .histogram import (CHUNK_VMEM_LIMIT, PACKED_STRIP, ROUTE_ROWS,
-                        _factored_rows, _round_up, check_quant_rows,
-                        factored_rungs, quant_rows_ok, tiled_hist_width)
+                        _factored_rows, _round_up, factored_rungs,
+                        quant_row_segments, quant_rows_ok,
+                        tiled_hist_width)
 
 #: frontier slots the fused kernels serve: three packed strips, and the
 #: widest factored rung
@@ -78,6 +82,10 @@ class HistPlan:
     mesh_kernels: bool            # the ladder runs once per row shard
     # inside shard_map, and the shards' int32 accumulators are summed
     exchange_limbs: int           # int32 limbs of that sum (0: no sum)
+    row_segments: int             # int32 accumulators a pass of the
+    # ladder writes on a device, one a row segment, folded by that same
+    # exact sum (1: the pass as it always was, and no fold)
+    segment_rows: int             # rows a segment (the last: the rest)
     hist_exchange: str            # codec of the XLA row-sharded psum
     fused: bool                   # the pending route rides the pass
     onehot_pack: int              # float tier: one-hot columns a stored
@@ -102,10 +110,10 @@ class HistPlan:
 
     @property
     def int_counts(self) -> bool:
-        """Row counts are int32 from the exact cross-shard sum to the
-        tree: float32 counts integers to 2^24, one device's rows, and a
-        mesh's shards hold more between them."""
-        return self.mesh_kernels
+        """Row counts are int32 from the exact sum to the tree: float32
+        counts integers to 2^24, one segment's rows, and a mesh's
+        shards or a device's segments hold more between them."""
+        return self.mesh_kernels or self.row_segments > 1
 
     @property
     def group_chunks(self) -> int:
@@ -134,7 +142,8 @@ def _tiled_block(num_groups: int, max_group_bin: int,
     Measured on v5e: G*B_pad=1792 (28 feats, 63 bins) wants 8192 (25.9 vs
     26.5 ms/tree); 8704 (136 feats) wants 2048 (288 vs 308 ms/tree).
     block*width stays near the 8192*1792 sweet spot, clamped to [2048,
-    8192], then the largest power of two dividing the shard's rows."""
+    8192], then the largest power of two dividing ``local_rows``: the
+    shard's rows, or what divides them and their segment."""
     width = tiled_hist_width(num_groups, max_group_bin)
     want = 2048
     while want < 8192 and (2 * want) * width <= 8192 * 1792 * 2:
@@ -146,35 +155,39 @@ def _tiled_block(num_groups: int, max_group_bin: int,
 
 
 def factored_vmem_bytes(rung: Tuple[int, int, int], groups: int,
-                        block: int, chunked: bool) -> int:
+                        block: int, chunked: bool,
+                        segmented: bool = False) -> int:
     """VMEM a grid step of a factored rung holds for ``groups`` groups
     of a ``block``-row block: the accumulator tiles (a pipelined block
-    where the group axis is chunked, so twice; the whole array, once,
-    where it is not), the four scratch rows a group, the uint8 block in
-    its two buffers and its int32 copies (bins, key, lo), and a chunked
-    pass's split rows."""
+    where the group axis is chunked or the rows are segmented, so
+    twice; the whole array, once, where neither), the four scratch rows
+    a group, the uint8 block in its two buffers and its int32 copies
+    (bins, key, lo), and a chunked pass's split rows."""
     k_cap, a, b = rung
     pack = 128 // b
     acc = -(-groups // pack) * pack * 4 * _factored_rows(k_cap, a)[1] \
         * 128 * 4
     per_block = groups * (4 * 4 + 2 + 3 * 4)
     if chunked:
-        acc *= 2
         per_block += ROUTE_ROWS * (2 + 4)
+    if chunked or segmented:
+        acc *= 2
     return acc + per_block * block
 
 
-def _group_chunk(rungs, num_groups: int, block: int) -> int:
+def _group_chunk(rungs, num_groups: int, block: int,
+                 segmented: bool) -> int:
     """Groups a grid step of the factored kernel holds: all of them
     where the widest rung in force then fits ``CHUNK_VMEM_BUDGET`` (67
-    groups take 35 MB of it), else the most whole tiles of uint8
-    sublanes (32 groups) that fit as a chunk, and one at the least."""
+    groups take 35 MB of it, 61 MB in row segments), else the most
+    whole tiles of uint8 sublanes (32 groups) that fit as a chunk, and
+    one at the least."""
     if not rungs:
         return num_groups
     widest = max(rungs, key=lambda r: _factored_rows(r[0], r[1])[1]
                  * (128 // r[2]))
-    if factored_vmem_bytes(widest, num_groups, block,
-                           False) <= CHUNK_VMEM_BUDGET:
+    if factored_vmem_bytes(widest, num_groups, block, False,
+                           segmented) <= CHUNK_VMEM_BUDGET:
         return num_groups
     chunk = 32
     while chunk + 32 < num_groups and factored_vmem_bytes(
@@ -309,23 +322,18 @@ def resolve_hist_plan(config, *, on_tpu: bool,
     # accumulation (and is a loud error where it cannot run), "f32"
     # forces float32 accumulation, "auto" follows quantized_grad.  The
     # overflow bound lives in ONE place, check_quant_rows, next to the
-    # kernel it protects
+    # kernel it protects: it bounds a row segment, and a shard of more
+    # rows is accumulated in as many segments as it takes
     precision = str(config.hist_precision).lower()
     exchange = str(config.hist_exchange).lower()
-    if precision == "tiered":
-        check_quant_rows(local_rows, what="hist_precision=tiered")
+    segments, segment_rows = quant_row_segments(local_rows)
     want_quant = bool(config.quantized_grad) or precision == "tiered"
     if precision == "f32":
         if want_quant:
             warnings.append("hist_precision=f32: quantized_grad ignored "
                             "— histograms accumulate float32")
         want_quant = False
-    # the int32 accumulator bounds a device's rows at N*127 < 2^31
-    quant = use_pallas and want_quant and quant_rows_ok(local_rows)
-    if want_quant and use_pallas and not quant:
-        warnings.append("quantized_grad disabled: dataset exceeds the "
-                        "int32 histogram accumulator bound (~16.9M rows "
-                        "a device)")
+    quant = use_pallas and want_quant
     if precision == "tiered" and not quant:
         raise ValueError(
             "hist_precision=tiered cannot run here: the quantized "
@@ -403,6 +411,11 @@ def resolve_hist_plan(config, *, on_tpu: bool,
                 tier = "xla"
 
     mesh_kernels = on_mesh and tier == "ladder"
+    if tier != "ladder":
+        segments, segment_rows = 1, local_rows
+    # a row block lies in one segment: it divides the segment as it
+    # divides the shard (2^24 rows a segment: any block does)
+    block_rows = math.gcd(local_rows, segment_rows)
     rungs = (factored_rungs(max_group_bin, packed_groups)
              if tier == "ladder" else ())
     # the factored kernel's accumulator is a whole-array output block,
@@ -411,12 +424,13 @@ def resolve_hist_plan(config, *, on_tpu: bool,
     # strips cannot (v5e, 2^24 x 67 x 255 bins: 4096 is 6-9% a pass
     # under 2048 on the narrow rungs and 1-2% on the wide ones; 8192
     # adds under 2% up to 64 slots and loses 10% at 126)
-    block_tiled = _tiled_block(num_groups, max_group_bin, local_rows)
-    block_factored = 4096 if local_rows % 4096 == 0 else block_tiled
-    group_chunk = _group_chunk(rungs, num_groups, block_factored)
+    block_tiled = _tiled_block(num_groups, max_group_bin, block_rows)
+    block_factored = 4096 if block_rows % 4096 == 0 else block_tiled
+    group_chunk = _group_chunk(rungs, num_groups, block_factored,
+                               segments > 1)
     if group_chunk < num_groups:
         # the route kernel's block holds the split rows, not every group
-        block_tiled = _tiled_block(ROUTE_ROWS, max_group_bin, local_rows)
+        block_tiled = _tiled_block(ROUTE_ROWS, max_group_bin, block_rows)
     return HistPlan(
         tier=tier, interpret=interpret, row_axis=kernel_axis,
         row_shards=row_shards, local_rows=local_rows,
@@ -424,7 +438,9 @@ def resolve_hist_plan(config, *, on_tpu: bool,
         # what one shard puts into the cross-shard sum: int32, twice as
         # two limbs where the global rows could leave int32
         exchange_limbs=(0 if not mesh_kernels
-                        else 1 if quant_rows_ok(rows_padded) else 2),
+                        else 1 if quant_rows_ok(rows_padded)
+                        and segments == 1 else 2),
+        row_segments=segments, segment_rows=segment_rows,
         hist_exchange=exchange, fused=fused, onehot_pack=onehot_pack,
         block_float=(FLOAT_BLOCK if local_rows % FLOAT_BLOCK == 0
                      else 1024),

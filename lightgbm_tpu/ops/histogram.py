@@ -321,10 +321,19 @@ def _run_hist_kernel(kern, bins, w, leaf_id, const_inputs, *, name, block,
 #: N * 127 — the bound every quantized-path selector shares.
 QUANT_WEIGHT_MAX = 127
 
+#: rows a segment: the kernels accumulate int32 over at most this many
+#: rows and write one accumulator a segment (the grower folds them
+#: exactly, parallel/collectives.py ``exchange_int_histograms``).  The
+#: most rows ``quant_rows_ok`` admits, as a power of two, so that every
+#: row block of the kernels divides it: 2^24
+QUANT_SEGMENT_ROWS = 1 << (
+    ((2 ** 31 - 1) // QUANT_WEIGHT_MAX).bit_length() - 1)
+
 
 def quant_rows_ok(n_rows: int) -> bool:
-    """True when ``n_rows`` rows can NEVER overflow the int32 quantized
-    histogram accumulator (``n_rows * 127 < 2^31``, ~16.9M rows)."""
+    """True when ``n_rows`` rows can NEVER overflow one int32 quantized
+    histogram accumulator (``n_rows * 127 < 2^31``, ~16.9M rows): asked
+    of a row segment, and of a mesh's global rows by the exact sum."""
     return int(n_rows) * QUANT_WEIGHT_MAX < 2 ** 31
 
 
@@ -332,16 +341,54 @@ def check_quant_rows(n_rows: int, what: str = "quantized histogram"
                      ) -> None:
     """Loud kernel-plan-time form of the :func:`quantize_gradients`
     caller contract: raises when ``n_rows`` could overflow the int32
-    accumulator.  What the kernel plan (ops/hist_plan.py) calls for
-    ``hist_precision=tiered``, so the bound lives in ONE place next to
-    the kernel it protects."""
+    accumulator.  What the kernel plan (ops/hist_plan.py) asks of the
+    segment it plans, so the bound lives in ONE place next to the
+    kernel it protects."""
     if not quant_rows_ok(n_rows):
         raise ValueError(
             f"{what}: {int(n_rows)} rows can overflow the int32 "
             f"histogram accumulator (requires rows * "
             f"{QUANT_WEIGHT_MAX} < 2^31, i.e. <= "
-            f"{(2 ** 31 - 1) // QUANT_WEIGHT_MAX} rows); use "
-            "hist_precision=f32 or shard the rows")
+            f"{(2 ** 31 - 1) // QUANT_WEIGHT_MAX} rows a segment); "
+            "use hist_precision=f32 or shard the rows")
+
+
+def quant_row_segments(n_rows: int):
+    """``(segments, rows a segment)`` of a device's ``n_rows`` rows on
+    the int8 path: ``QUANT_SEGMENT_ROWS`` rows each (the last holds what
+    is left), one segment of every row up to that many."""
+    seg = min(int(n_rows), QUANT_SEGMENT_ROWS)
+    check_quant_rows(seg, what="a row segment of the int8 histogram")
+    return max(1, -(-int(n_rows) // QUANT_SEGMENT_ROWS)), seg
+
+
+def _segment_blocks(n: int, block: int, segment_rows: int,
+                    dequantize: bool):
+    """``(segments, row blocks a segment)`` of a kernel's ``n`` rows;
+    ``segment_rows`` 0, or at least ``n``, is one segment: ``(1, 0)``,
+    the kernel as it always lowered."""
+    if not 0 < segment_rows < n:
+        return 1, 0
+    if segment_rows % block:
+        raise ValueError(f"segment_rows ({segment_rows}) must be a "
+                         f"multiple of block ({block})")
+    if dequantize:
+        raise ValueError("row segments come back as int32 accumulators: "
+                         "dequantize=False")
+    return -(-n // segment_rows), segment_rows // block
+
+
+def _pipelined_acc_params(pipelined: bool) -> dict:
+    """``pallas_call`` keywords of a pass whose accumulator block
+    changes along the grid (with the group chunk, with the row segment):
+    it is then a pipelined block of the kernel's scoped VMEM, in its two
+    buffers, and no longer the whole-array output that XLA keeps
+    outside it."""
+    if not pipelined:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=CHUNK_VMEM_LIMIT)}
 
 
 def quantize_gradients(grad: jax.Array, hess: jax.Array, counts: jax.Array,
@@ -1063,7 +1110,8 @@ def compute_group_histograms_fused(
 def _fused_kernel_body_q_tiled(binsT_ref, wT_ref, leafT_ref, routeT_ref,
                                slots_ref, hist_ref, leaf_out_ref, *,
                                strip, strips, num_groups, nb,
-                               max_group_bin, packed_groups=0):
+                               max_group_bin, packed_groups=0,
+                               segment_blocks=0):
     """Fused route + tiled-iota histogram: the pending route table is
     applied to the block's rows, then the histogram accumulates from a
     one-hot rebuilt per 128-lane tile in VMEM — HBM traffic is just the
@@ -1073,10 +1121,12 @@ def _fused_kernel_body_q_tiled(binsT_ref, wT_ref, leafT_ref, routeT_ref,
     one-hot, no precompute, and no HBM budget gating.
 
     Routing prologue is the _fused_kernel_body one (see
-    ops/partition.py route_rows for the semantics contract)."""
+    ops/partition.py route_rows for the semantics contract).
+    ``segment_blocks`` > 0: ``hist_ref`` is the accumulator of the row
+    segment the block lies in, begun every that many blocks."""
     i = pl.program_id(0)
 
-    @pl.when(i == 0)
+    @pl.when((i % segment_blocks if segment_blocks else i) == 0)
     def _init():
         hist_ref[:] = jnp.zeros_like(hist_ref)
 
@@ -1112,13 +1162,13 @@ def _tiled_out_to_hist(out: jax.Array, strips: int, num_groups: int,
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "strips",
                               "interpret", "packed_groups",
-                              "dequantize"))
+                              "dequantize", "segment_rows"))
 def compute_group_histograms_fused_tiled(
         binsT: jax.Array, wT: jax.Array, scales: jax.Array,
         leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
         max_group_bin: int, block: int = 2048, strips: int = 1,
         interpret: bool = False, packed_groups: int = 0,
-        dequantize: bool = True):
+        dequantize: bool = True, segment_rows: int = 0):
     """Fused route + tiled-iota int8 histogram: same contract as
     :func:`compute_group_histograms_fused` minus the ``ohb`` operand —
     the one-hot is rebuilt in VMEM from ``binsT``.  Quantized path only
@@ -1127,7 +1177,14 @@ def compute_group_histograms_fused_tiled(
     stream halves and nibbles widen in-register per tile.
     ``dequantize=False`` returns the int32 accumulators themselves
     (``scales`` unread): what a row shard hands to the exact cross-shard
-    sum (parallel/collectives.py ``exchange_int_histograms``)."""
+    sum (parallel/collectives.py ``exchange_int_histograms``).
+
+    ``segment_rows`` (0, or at least the rows: one segment, the kernel
+    as it always lowered) bounds what one int32 accumulator sums: the
+    rows are taken that many at a time, each segment into an accumulator
+    of its own, and the histogram comes back with the segments as its
+    leading axis, ``(segments, slots, G, B, 3)`` int32, for that same
+    exact sum to fold (``dequantize=False`` only)."""
     num_groups = logical_groups(binsT.shape[0], packed_groups) \
         if packed_groups else binsT.shape[0]
     b = max_group_bin
@@ -1137,6 +1194,8 @@ def compute_group_histograms_fused_tiled(
     n = binsT.shape[1]
     if n % block != 0:
         raise ValueError(f"N ({n}) must be a multiple of block ({block})")
+    segments, seg_blocks = _segment_blocks(n, block, segment_rows,
+                                           dequantize)
     slot_col = _pack_slot_tiles(slots, strips)[:, None]  # (m_pad, 1)
 
     routeT = _transpose_pad_route(route_tab)
@@ -1146,8 +1205,10 @@ def compute_group_histograms_fused_tiled(
     kern = functools.partial(_fused_kernel_body_q_tiled, strip=PACKED_STRIP,
                              strips=strips, num_groups=num_groups,
                              nb=K - 15, max_group_bin=b,
-                             packed_groups=packed_groups)
+                             packed_groups=packed_groups,
+                             segment_blocks=seg_blocks)
     s_rows = binsT.shape[0]              # storage rows (== G unpacked)
+    acc = (m_pad, num_tiles * tile_w)
     out, leaf_out = pl.pallas_call(
         kern,
         grid=(n // block,),
@@ -1159,16 +1220,24 @@ def compute_group_histograms_fused_tiled(
             pl.BlockSpec(slot_col.shape, lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((m_pad, num_tiles * tile_w), lambda i: (0, 0)),
+            pl.BlockSpec(acc, lambda i: (0, 0)) if segments == 1
+            else pl.BlockSpec((None,) + acc,
+                              lambda i: (i // seg_blocks, 0, 0)),
             pl.BlockSpec((1, block), lambda i: (0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m_pad, num_tiles * tile_w), jnp.int32),
+            jax.ShapeDtypeStruct(acc if segments == 1
+                                 else (segments,) + acc, jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
+        **_pipelined_acc_params(segments > 1),
         interpret=interpret, name="compute_group_histograms_fused_tiled",
     )(binsT, wT, leaf_id[None, :], routeT, slot_col)
-    hist = _tiled_out_to_hist(out, strips, num_groups, b)
+    unpack = functools.partial(_tiled_out_to_hist, strips=strips,
+                               num_groups=num_groups, max_group_bin=b)
+    if segments > 1:
+        return jax.vmap(unpack)(out), leaf_out[0]
+    hist = unpack(out)
     if not dequantize:
         return hist, leaf_out[0]
     hist = hist.astype(jnp.float32) * scales[None, None, None, :]
@@ -1222,7 +1291,8 @@ def _factored_rows(k_cap: int, a: int):
 
 
 def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
-                                  num_groups, nb, route_rows=0):
+                                  num_groups, nb, route_rows=0,
+                                  segment_blocks=0):
     """Fused route + FACTORED int8 histogram, a rung of ``FACTORED_RUNGS``.
 
         hist[slot, ch, g, hi, lo] =
@@ -1249,7 +1319,10 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
     group a chunk holds stale rows, whose tiles lie outside the output
     and are dropped — the accumulator block is the chunk's, resident
     while its row blocks sweep, and the route reads the ``route_rows``
-    split rows (a second, narrow input) and never another chunk's."""
+    split rows (a second, narrow input) and never another chunk's.
+
+    ``segment_blocks`` > 0: ``hist_ref`` is the accumulator of the row
+    segment the block lies in, begun every that many row blocks."""
     from jax.experimental.pallas import tpu as pltpu
 
     if route_rows:
@@ -1258,7 +1331,7 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
      key4_ref, ksh_ref, lo4_ref, bit_ref) = refs
     i = pl.program_id(1 if route_rows else 0)
 
-    @pl.when(i == 0)
+    @pl.when((i % segment_blocks if segment_blocks else i) == 0)
     def _init():
         hist_ref[:] = jnp.zeros_like(hist_ref)
 
@@ -1334,13 +1407,14 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
 
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "k_cap", "a",
-                              "interpret", "dequantize", "group_chunk"))
+                              "interpret", "dequantize", "group_chunk",
+                              "segment_rows"))
 def compute_group_histograms_fused_factored(
         binsT: jax.Array, wT: jax.Array, scales: jax.Array,
         leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
         max_group_bin: int, k_cap: int, a: int, block: int = 2048,
         interpret: bool = False, dequantize: bool = True,
-        group_chunk: int = 0):
+        group_chunk: int = 0, segment_rows: int = 0):
     """Fused route + factored int8 histogram, one rung of
     ``FACTORED_RUNGS``: the contract of
     :func:`compute_group_histograms_fused_tiled` for at most ``k_cap``
@@ -1355,7 +1429,12 @@ def compute_group_histograms_fused_factored(
     chunk of that many groups keeps its accumulator tiles in VMEM while
     the row blocks sweep under it, and the pending route reads the
     table's split rows (:func:`gather_split_rows`) and not the block's
-    whole column.  The same integers and the same ``new_leaf``."""
+    whole column.  The same integers and the same ``new_leaf``.
+
+    ``segment_rows``: as in the tiled kernel — past that many rows the
+    pass writes one accumulator a row segment (the row blocks of a
+    segment follow each other, under a chunk too) and returns
+    ``(segments, k_cap, G, B, 3)`` int32."""
     from jax.experimental.pallas import tpu as pltpu
 
     num_groups, n = binsT.shape
@@ -1369,6 +1448,8 @@ def compute_group_histograms_fused_factored(
         raise ValueError(f"group_chunk ({group_chunk}) must be a multiple "
                          "of 32, a tile of uint8 sublanes")
     chunk = group_chunk if chunked else num_groups
+    segments, seg_blocks = _segment_blocks(n, block, segment_rows,
+                                           dequantize)
     kp = _round_up(k_cap, 8)
     slot_col = jnp.full(kp, -2, jnp.int32).at[:k_cap].set(
         jnp.where(slots[:k_cap] >= 0, slots[:k_cap], -2))[:, None]
@@ -1383,7 +1464,8 @@ def compute_group_histograms_fused_factored(
     kern = functools.partial(_fused_kernel_body_q_factored, k_cap=k_cap,
                              a=a, b=b, num_groups=chunk,
                              nb=route_tab.shape[1] - ROUTE_FIXED_COLS,
-                             route_rows=ROUTE_ROWS if chunked else 0)
+                             route_rows=ROUTE_ROWS if chunked else 0,
+                             segment_blocks=seg_blocks)
 
     def at(by_chunk, by_rows):
         """Index map of a two-axis block that follows the grid's group
@@ -1393,6 +1475,16 @@ def compute_group_histograms_fused_factored(
             return lambda c, i: (c if by_chunk else 0, i if by_rows else 0)
         return lambda i: (0, i if by_rows else 0)
 
+    acc = (-(-chunk // pack), pack * rows, 128)
+    if segments > 1:
+        # the segment of row block i, then the chunk's tiles
+        acc_spec = pl.BlockSpec(
+            (None,) + acc,
+            (lambda c, i: (i // seg_blocks, c, 0, 0)) if chunked
+            else (lambda i: (i // seg_blocks, 0, 0, 0)))
+    else:
+        acc_spec = pl.BlockSpec(acc, (lambda c, i: (c, 0, 0)) if chunked
+                                else (lambda i: (0, 0, 0)))
     out, leaf_out = pl.pallas_call(
         kern,
         grid=((-(-num_groups // chunk),) if chunked else ()) + (n // block,),
@@ -1405,28 +1497,31 @@ def compute_group_histograms_fused_factored(
             pl.BlockSpec(slot_col.shape, at(False, False)),
         ],
         out_specs=[
-            pl.BlockSpec((-(-chunk // pack), pack * rows, 128),
-                         (lambda c, i: (c, 0, 0)) if chunked
-                         else (lambda i: (0, 0, 0))),
+            acc_spec,
             pl.BlockSpec((1, block), at(False, True)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((num_tiles, pack * rows, 128), jnp.int32),
+            jax.ShapeDtypeStruct(
+                ((segments,) if segments > 1 else ())
+                + (num_tiles, pack * rows, 128), jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((chunk, block), jnp.int32)
                         for _ in range(4)],
-        # one chunk: the whole-array accumulator, which XLA keeps in
-        # VMEM outside the kernel's scoped allocation; chunks: theirs is
-        # a pipelined block inside it
-        **({"compiler_params": pltpu.CompilerParams(
-            vmem_limit_bytes=CHUNK_VMEM_LIMIT)} if chunked else {}),
+        # one chunk, one segment: the whole-array accumulator, which XLA
+        # keeps in VMEM outside the kernel's scoped allocation; chunks
+        # or segments: theirs is a pipelined block inside it
+        **_pipelined_acc_params(chunked or segments > 1),
         interpret=interpret,
         name=f"compute_group_histograms_fused_factored_k{k_cap}_a{a}",
     )(binsT, *split_rows, wT, leaf_id[None, :], routeT, slot_col)
-    return _factored_out_to_hist(
-        out, scales if dequantize else None, num_groups=num_groups,
-        max_group_bin=max_group_bin, k_cap=k_cap, a=a), leaf_out[0]
+    unpack = functools.partial(
+        _factored_out_to_hist, scales=scales if dequantize else None,
+        num_groups=num_groups, max_group_bin=max_group_bin, k_cap=k_cap,
+        a=a)
+    if segments > 1:
+        return jax.vmap(unpack)(out), leaf_out[0]
+    return unpack(out), leaf_out[0]
 
 
 def _factored_out_to_hist(out, scales, *, num_groups, max_group_bin,

@@ -264,12 +264,16 @@ def int_exchange_fits_int32(global_rows: int) -> bool:
     return quant_rows_ok(global_rows)
 
 
-def exchange_int_histograms(acc, axis_name, *, global_rows: int):
-    """EXACT cross-shard sum of the quantized path's int32 histogram
-    accumulators ``(..., 3)``, taken before any dequantize.  Returns on
-    every shard ``(total, rows)``: the float32 nearest to each integer
-    total, which is a function of the total alone — so the trees do not
-    depend on how many shards hold the rows (the data-parallel
+def exchange_int_histograms(acc, axis_name, *, global_rows: int,
+                            segments: int = 1):
+    """EXACT sum of the quantized path's int32 histogram accumulators
+    ``(..., 3)`` — across the shards of ``axis_name``, and first across
+    a shard's own row segments where ``segments`` > 1 (``acc`` is then
+    ``(segments, ..., 3)``; ``axis_name`` None: one device's segments
+    alone) — taken before any dequantize.  Returns on every shard
+    ``(total, rows)``: the float32 nearest to each integer total, which
+    is a function of the total alone — so the trees do not depend on
+    how many shards or segments hold the rows (the data-parallel
     learner's ReduceScatter of histograms,
     data_parallel_tree_learner.cpp:147-162, as integers) — and the
     count channel's total as int32, which float32 would round above
@@ -277,25 +281,28 @@ def exchange_int_histograms(acc, axis_name, *, global_rows: int):
 
     Where ``global_rows * 127 < 2**31`` every total fits int32 and one
     ``psum`` carries it.  Beyond that (4 shards x 2^24 rows x 127 =
-    2^33) each accumulator travels as two 16-bit limbs, ``lo`` in
-    [0, 65535] and ``hi`` the arithmetic shift: each limb's sum is
-    exact in int32 and, under 2^24 as it is for the shards of one host
-    (fewer than 256), in float32; ``hi_sum * 65536`` is exact, and the
-    one float32 addition that puts them together rounds the exact total
-    once."""
+    2^33; any device in segments) each accumulator travels as two
+    16-bit limbs, ``lo`` in [0, 65535] and ``hi`` the arithmetic shift:
+    each limb's sum is exact in int32 and, under 2^24 as it is for the
+    shards of one host and their segments (fewer than 256), in float32;
+    ``hi_sum * 65536`` is exact, and the one float32 addition that puts
+    them together rounds the exact total once."""
     if str(acc.dtype) != "int32":
         raise TypeError(f"exchange_int_histograms sums int32 "
                         f"accumulators, got {acc.dtype}")
-    if axis_name is None:
+    if axis_name is None and segments == 1:
         return acc.astype(jnp.float32), acc[..., 2]
-    fits = int_exchange_fits_int32(global_rows)
+    fits = segments == 1 and int_exchange_fits_int32(global_rows)
     payload = acc if fits else jnp.stack([acc & 0xFFFF, acc >> 16])
-    _note_collective("allreduce", payload)
-    _note_collective("hist_exchange", payload)
-    total = jax.lax.psum(payload, axis_name)
+    if segments > 1:
+        payload = jnp.sum(payload, axis=1)
+    if axis_name is not None:
+        _note_collective("allreduce", payload)
+        _note_collective("hist_exchange", payload)
+        payload = jax.lax.psum(payload, axis_name)
     if fits:
-        return total.astype(jnp.float32), total[..., 2]
-    lo, hi = total
+        return payload.astype(jnp.float32), payload[..., 2]
+    lo, hi = payload
     return (hi.astype(jnp.float32) * 65536.0 + lo.astype(jnp.float32),
             hi[..., 2] * 65536 + lo[..., 2])
 

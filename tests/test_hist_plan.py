@@ -53,14 +53,18 @@ CASES = [
      {**TPU, **EPSILON, "max_group_bin": 63},
      dict(tier="ladder", factored_rungs=(), group_chunk=2000,
           group_chunks=1)),
-    # rows * 127 < 2^31: 16513 blocks of 1024 rows fit, 16514 do not
+    # rows * 127 < 2^31: 16513 blocks of 1024 rows fit one int32
+    # accumulator, 16514 do not; past 2^24 rows either is summed in two
+    # segments of at most 2^24, and the ladder stays (PR 35)
     ("tpu_quant_last_block_inside_int32", FAST,
      {**TPU, **CRITEO, "rows_padded": 16513 * 1024},
-     dict(tier="ladder", block_factored=1024, silent=True)),
+     dict(tier="ladder", block_factored=1024, row_segments=2,
+          silent=True)),
     ("tpu_quant_one_block_past_int32", FAST,
      {**TPU, **CRITEO, "rows_padded": 16514 * 1024},
-     dict(tier="float", quantized=False,
-          warns=["quantized_grad disabled: dataset exceeds the int32"])),
+     dict(tier="ladder", quantized=True, row_segments=2,
+          segment_rows=1 << 24, int_counts=True, block_factored=2048,
+          silent=True)),
     ("tpu_bf16_onehot_inside_budget", BF16, {**TPU},
      dict(tier="float", kernel="fused_streamed", fused=True,
           onehot_pack=4, block_float=2048, factored_rungs=(),
@@ -138,7 +142,9 @@ CASES = [
      dict(tier="ladder", quantized=True, silent=True)),
     ("tiered_past_int32", {**BF16, "hist_precision": "tiered"},
      {**TPU, "rows_padded": 1 << 25},
-     dict(raises="can overflow the int32 histogram accumulator")),
+     dict(tier="ladder", quantized=True, row_segments=2,
+          segment_rows=1 << 24, int_counts=True, mesh_kernels=False,
+          exchange_limbs=0, silent=True)),
     ("f32_over_quantized_grad", {**FAST, "hist_precision": "f32"}, {**TPU},
      dict(tier="float", quantized=False,
           warns=["hist_precision=f32: quantized_grad ignored"])),
@@ -187,7 +193,10 @@ def test_resolve_hist_plan(params, facts, want):
     # what holds for every plan
     assert plan.tier in ("xla", "float", "ladder")
     assert plan.quantized == (plan.tier == "ladder")
-    assert plan.int_counts == plan.mesh_kernels
+    assert plan.int_counts == (plan.mesh_kernels or plan.row_segments > 1)
+    assert plan.row_segments == 1 or plan.tier == "ladder"
+    assert (plan.row_segments - 1) * plan.segment_rows < plan.local_rows \
+        <= plan.row_segments * plan.segment_rows
     assert plan.fused or plan.tier != "ladder"
     assert bool(plan.onehot_pack) <= (plan.tier == "float")
     assert plan.local_rows * plan.row_shards == facts["rows_padded"]
@@ -207,11 +216,13 @@ def _plan(**facts):
 
 @pytest.mark.fast
 def test_criteo_plan_is_the_one_before_the_group_chunk():
-    """67 groups x 2^24 rows resolve to ONE chunk and, the group chunk's
-    two fields apart, to the plan the cells had before it, field for
-    field."""
+    """67 groups x 2^24 rows resolve to ONE chunk of ONE row segment
+    and, the group chunk's and the segment's two fields each apart, to
+    the plan the cells had before them, field for field."""
     got = dataclasses.asdict(_plan(**CRITEO))
     assert (got.pop("group_chunk"), got.pop("num_groups")) == (67, 67)
+    assert (got.pop("row_segments"), got.pop("segment_rows")) \
+        == (1, 1 << 24)
     assert got.pop("finder") == "fused"
     assert got == dict(
         tier="ladder", interpret=False, row_axis=None, row_shards=1,

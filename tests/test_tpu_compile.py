@@ -1,8 +1,10 @@
 """The wide table's kernels compiled for a v5e chip that is described,
 not attached (nothing runs, no time is read): the TPU's own compiler says
 whether a group chunk of the plan fits VMEM at the Epsilon cell's shape,
-which the interpret seam cannot.  One file, so that one worker loads the
+and a row segment's accumulator at the 2^25-row cell's, which the
+interpret seam cannot.  One file, so that one worker loads the
 TPU's library; the topology is described inside a fixture."""
+import functools
 import os
 
 import pytest
@@ -120,3 +122,44 @@ def test_fused_split_finder_compiles_in_its_block(one_chip, rows, features,
     if features == GROUPS:
         assert compiled.memory_analysis().temp_size_in_bytes \
             < rows * features * BINS * 4
+
+
+@pytest.mark.parametrize("k_cap,a", [(126, 2), (2, 4)])
+def test_factored_rung_compiles_in_row_segments(one_chip, k_cap, a):
+    """The widest rung and the narrowest at the cell past the int8
+    ceiling (67 groups x 2^25 rows, two row segments): a segment's
+    accumulator is a pipelined block of the kernel's scoped VMEM, in
+    its two buffers (2 x 26 MB at 126 slots), inside the limit the
+    kernel then asks for; one accumulator a segment comes back."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.ops.hist_plan import LADDER_WIDTH, resolve_hist_plan
+    from lightgbm_tpu.ops.histogram import (
+        compute_group_histograms_fused_factored)
+    rows, groups = 1 << 25, 67
+    plan = resolve_hist_plan(
+        Config.from_params({"verbose": -1, "hist_compute_dtype": "bfloat16",
+                            "quantized_grad": True}),
+        on_tpu=True, mesh_axes=None, row_axis=None, cols_sharded=False,
+        multihost=False, rows_padded=rows, num_groups=groups,
+        max_group_bin=BINS, packed_groups=0, frontier=LADDER_WIDTH)
+    assert (plan.row_segments, plan.group_chunks) == (2, 1)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (s((groups, rows), jnp.uint8), s((3, rows), jnp.int32), None,
+            s((rows,), jnp.int32),
+            s((LEAVES, 15 + (BINS + 7) // 8), jnp.float32),
+            s((126,), jnp.int32))
+    static = dict(max_group_bin=BINS, k_cap=k_cap, a=a,
+                  block=plan.block_factored, group_chunk=plan.group_chunk,
+                  dequantize=False, segment_rows=plan.segment_rows)
+    compiled = compute_group_histograms_fused_factored.lower(
+        *args, **static).compile()
+    assert f"compute_group_histograms_fused_factored_k{k_cap}_a{a}" \
+        in compiled.as_text()
+    out = jax.eval_shape(functools.partial(
+        compute_group_histograms_fused_factored, **static), *args)
+    assert out[0].shape == (2, k_cap, groups, BINS, 3)
+    assert out[0].dtype == jnp.int32

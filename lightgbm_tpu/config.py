@@ -489,13 +489,15 @@ class Config:
     # penalty to the TPU backend's handling of the 18 O(chunk) loop-
     # carried output stacks.  auto = on; "off" restores the legacy
     # 18-array carry (byte-identical trees either way, pinned by test)
-    split_finder_ladder: bool = True  # run the best-split finder and
-    # the candidate-cache scatter at the narrowest packed-strip width
-    # covering the ACTIVE frontier (lax.cond ladder, like the
-    # histogram kernels) instead of always the full frontier cap —
-    # early rounds of every tree have 1-2 new leaves, and the finder's
-    # (2W, F, B) threshold sweep was the last frontier-capped cost
-    # (ROOFLINE headroom #2).  False restores the full-width finder
+    split_finder_ladder: bool = True  # run a round's whole refresh —
+    # parent-minus-right, the histogram cache's update, the best-split
+    # finder and the candidate-cache scatter — at the width of the rung
+    # that served its histogram pass (ONE ladder: the factored rungs'
+    # slot caps, or the packed strips') instead of the full frontier
+    # cap — early rounds of every tree have 1-2 new leaves, and the
+    # glue and the finder's (2W, F, B) threshold sweep were the last
+    # frontier-capped costs (ROOFLINE headroom #2; PERF.md PR 36).
+    # False keeps the pass's rungs and does the rest at the cap
     predict_kernel: str = "auto"    # device predictor implementation:
     # "level" (default for auto) is the ensemble-vectorized
     # level-synchronous descent — all trees advance together over the

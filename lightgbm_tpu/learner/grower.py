@@ -83,7 +83,7 @@ def _rows_i32(count):
     return jnp.round(count).astype(jnp.int32)
 
 
-def _pad_slots(hist, width):
+def _fit_slots(hist, width):
     """``hist`` (slots, G, B, 3), or its pair with the int32 counts,
     cut or zero-padded to ``width`` slots."""
     def fit(h):
@@ -92,6 +92,28 @@ def _pad_slots(hist, width):
         pad = jnp.zeros((width - h.shape[0],) + h.shape[1:], h.dtype)
         return jnp.concatenate([h, pad])
     return jax.tree_util.tree_map(fit, hist)
+
+
+def _strip_widths(width):
+    """Slots of one, two, ... packed strips, the last cut (or, past
+    three strips, stretched) to the frontier's ``width``."""
+    return [s for s in (PACKED_STRIP, 2 * PACKED_STRIP)
+            if s < width] + [width]
+
+
+def _get_row(hist, i):
+    """Row ``i`` of ``hist`` (or of each of its pair), as (1, ...)."""
+    return jax.tree_util.tree_map(
+        lambda h: jax.lax.dynamic_index_in_dim(h, i, 0, keepdims=True),
+        hist)
+
+
+def _set_rows(hist, rows, i):
+    """``hist`` with ``rows`` (of ``_get_row``'s shape, or more of them)
+    written from row ``i`` on."""
+    return jax.tree_util.tree_map(
+        lambda h, r: jax.lax.dynamic_update_index_in_dim(h, r, i, 0),
+        hist, rows)
 
 
 def _scaled(hist, scales):
@@ -136,8 +158,12 @@ class GrowerState(NamedTuple):
     leaf_forced: jax.Array       # (L,) int32 forced-split spec idx (-1 none)
     tree: TreeArrays
     hist_cache: jax.Array        # (L, G, Bg, 3) f32 — per-leaf group hists
-    # (under plan.int_counts hist_cache, cand and forced_cand are each a pair
-    # of the array named here and its int32 row counts: (L, G, Bg), (L,))
+    # (under plan.int_counts hist_cache, each of hist_stage, cand and
+    # forced_cand are a pair of the array named here and its int32 row
+    # counts: (L, G, Bg), (W, G, Bg), (L,))
+    hist_stage: Tuple[jax.Array, jax.Array]   # 2 x (W, G, Bg, 3) f32 — the
+    # rows a round's refresh hands the cache: the left children's, the
+    # right children's (_refresh)
     cand: jax.Array              # (L, CAND_COLS + Bf) f32 — the packed
     # best_split_per_leaf_ cache (reference serial_tree_learner.h +
     # SplitInfo, split_info.hpp:18-288); column layout in ops/split.py,
@@ -252,11 +278,8 @@ class TreeGrower:
         # left to users who know their task tolerates it.
         self.frontier = min(config.num_leaves - 1,
                             config.frontier_width or LADDER_WIDTH)
-        # frontier ladder for the split finder (round 7, ROOFLINE
-        # headroom #2): run the finder + candidate scatter at the
-        # narrowest packed-strip width covering the ACTIVE frontier —
-        # the early rounds of every tree have 1-2 new leaves while the
-        # (2W, F, B) threshold sweep was always paying the full cap
+        # a round's whole refresh at the width of the rung that served
+        # its histogram pass (_refresh; False: at the frontier cap)
         self.split_ladder = bool(getattr(config, "split_finder_ladder",
                                          True))
         # packed tree-record carry (round 7): fixed-offset byte layout
@@ -627,22 +650,13 @@ class TreeGrower:
 
     # ------------------------------------------------------------------
     def _hist_kernel(self, grad, hess, counts, leaf_id, slots):
-        """Frontier histogram dispatch of the plans whose route does not
-        ride the pass: the float tier's kernels on one chip, the XLA
-        one-hot contraction under meshes / CPU simulation (the fused
-        passes are ``_hist_kernel_fused``).  The ``tel.histogram`` scope
-        (op metadata, at every telemetry mode) lets a profiler trace
-        attribute the device events to it."""
-        with TELEMETRY.phase("histogram"):
-            return self._hist_kernel_impl(grad, hess, counts, leaf_id,
-                                          slots)
-
-    def _hist_kernel_impl(self, grad, hess, counts, leaf_id, slots):
+        """The histogram pass of the plans that have no ladder of their
+        own: the float tier's plain kernel on one chip, the XLA one-hot
+        contraction under meshes / CPU simulation.  ``slots`` wide; it
+        routes no row (``_pass_ladder`` holds the passes that do, and
+        the packed ones)."""
         L = self.num_leaves
         if self.plan.tier == "float":
-            if self.plan.onehot_pack:
-                return self._hist_kernel_pre(grad, hess, counts, leaf_id,
-                                             slots)
             return compute_group_histograms_pallas(
                 self.bins, grad, hess, counts, leaf_id,
                 num_leaves=L, max_group_bin=self.max_group_bin,
@@ -709,55 +723,64 @@ class TreeGrower:
         return inner(self.bins, grad, hess, counts, leaf_id, slots)
 
     # ------------------------------------------------------------------
-    def _packed_dispatch(self, full, run_packed, slots, W):
-        """Shared narrow-frontier ladder: run at the narrowest lane
-        packing covering the valid slots.  ``full`` is a thunk for the
-        full-width kernel; ``run_packed(strips)`` runs the packed
-        kernel and returns its (strips*PACKED_STRIP, ...) output, which
-        is padded/truncated to W here.  The branch is a runtime
-        lax.cond on the valid-slot count — the early rounds of EVERY
-        tree have 1..PACKED_STRIP new leaves."""
-        def packed(strips):
-            def run(_):
-                h = run_packed(strips)
-                cap = strips * PACKED_STRIP
-                if cap >= W:
-                    return h[:W]
-                pad = jnp.zeros((W - cap,) + h.shape[1:], h.dtype)
-                return jnp.concatenate([h, pad])
-            return run
+    def _pass_ladder(self, st: "GrowerState", W, grad, hess, counts,
+                     quant):
+        """The histogram pass of a round as a ladder: ``[(w, kernel)]``,
+        the widths ascending to ``W``.  ``kernel(leaf_id, slots)`` is one
+        pass over the rows, ``(histogram of the first w slots — (w, G, B,
+        3), or its pair with the int32 counts — , leaf ids after it)``;
+        ``_refresh`` takes the narrowest width that covers a round's
+        valid slots (the early rounds of EVERY tree have 1-2 new leaves)
+        and does everything else of the round at that width too.
 
-        if W <= PACKED_STRIP:
-            return packed(1)(None)
+        Fused plans: the pending route (last round's splits) is applied
+        INSIDE the kernel just before each row contributes, so the leaf
+        ids come back re-labelled (a second pass re-applies the route,
+        which is idempotent).  Their widths are the factored rungs'
+        slot caps (256-lane tiles only: the bin index is split across
+        both sides of the dot, so a pass streams the rows its active
+        slots need and not whole strips; ops/histogram.py
+        FACTORED_RUNGS) and, past the widest rung that fits the
+        frontier, the packed strips'.  The float tier's streamed
+        one-hot: the strips' widths, channel-packed while the frontier
+        is narrow (3x fewer MXU rows), and its plain kernel past three
+        strips.
 
-        k = jnp.sum(slots >= 0)
-        if W <= 2 * PACKED_STRIP:
-            return jax.lax.cond(k <= PACKED_STRIP, packed(1), packed(2),
-                                None)
-        wide = packed(3) if W <= 3 * PACKED_STRIP else full
-        return jax.lax.cond(
-            k <= PACKED_STRIP, packed(1),
-            lambda _: jax.lax.cond(k <= 2 * PACKED_STRIP, packed(2),
-                                   wide, None), None)
-
-    # ------------------------------------------------------------------
-    def _hist_kernel_fused(self, st: "GrowerState", rights, grad, hess,
-                           counts, quant):
-        """Fused route+histogram ladder: one Pallas pass both re-labels
-        every row by the pending route table and accumulates the new
-        right children's histograms, at the narrowest strip packing
-        covering the frontier.  Returns (hist (W, G, B, 3), new
-        leaf_id)."""
-        with TELEMETRY.phase("histogram"):
-            return self._hist_kernel_fused_impl(st, rights, grad, hess,
-                                                counts, quant)
-
-    def _hist_kernel_fused_impl(self, st, rights, grad, hess, counts,
-                                quant):
+        Returned with the ladder: the scales of a dequantize multiply
+        that ``_refresh`` is to make itself, row by row beside
+        parent - right, or None.  XLA:CPU contracts a multiply with the
+        subtraction it is fused with; a frontier of one strip or less
+        never had a ladder between the two, and its floats stay those."""
         B = self.max_group_bin
-        W = rights.shape[0]
         plan = self.plan
         ohb = self._ohb_arg if self._ohb_arg is not None else self.ohb
+        late = None
+        strips = list(enumerate(_strip_widths(W), 1))   # (count, slots)
+
+        if not plan.fused:
+            w = jnp.stack([grad, hess, counts], axis=1)
+
+            def packed(strips):
+                def go(leaf_id, slots):
+                    return compute_group_histograms_pre_packed(
+                        ohb, w, leaf_id, slots, max_group_bin=B,
+                        block=plan.block_float, strips=strips,
+                        pack=plan.onehot_pack,
+                        num_groups=self.num_groups), leaf_id
+                return go
+
+            def full(leaf_id, slots):
+                return compute_group_histograms_pre(
+                    ohb, w, leaf_id, num_leaves=self.num_leaves,
+                    max_group_bin=B, block=plan.block_float,
+                    slots=slots, pack=plan.onehot_pack,
+                    num_groups=self.num_groups), leaf_id
+
+            ladder = [(w, packed(n)) for n, w in strips]
+            if W > LADDER_WIDTH:
+                ladder[-1] = (W, full)
+            return ladder, None
+
         if quant is not None:
             if TELEMETRY.on:
                 # trace-time accounting (the _note_collective pattern):
@@ -767,97 +790,57 @@ class TreeGrower:
                 # compiled step"
                 TELEMETRY.add("hist_quant_fixup", 1)
             wT, scales = quant                          # (3, N) int32
+            if W <= PACKED_STRIP:
+                late, scales = scales, jnp.ones_like(scales)
         else:
             wT = jnp.stack([grad, hess, counts], axis=0)
             scales = None
 
-        # factored rungs (256-lane tiles only): the bin index is split
-        # across both sides of the dot, so a pass streams the rows its
-        # active slots need and not whole strips (ops/histogram.py
-        # FACTORED_RUNGS; they reach the widest frontier).  The
-        # dequantize multiply stays where the strips ladder alone has it
-        # — inside the branch when that ladder is a cond, in the open
-        # when it is a plain call (W <= one strip) — so that the
-        # compiler meets it in the same fusion with and without the
-        # rungs and the floats are the same floats (XLA:CPU contracts it
-        # with the parent-minus-right subtraction it is fused with)
-        rungs = [r for r in plan.factored_rungs if r[0] <= W]
-        late_scale = bool(rungs) and W <= PACKED_STRIP
-        in_scales = jnp.ones_like(scales) if late_scale else scales
-
-        def run(strips):
-            def go(_):
+        def strips_pass(strips):
+            def go(leaf_id, slots):
                 if plan.tier == "ladder":
                     from ..ops.histogram import \
                         compute_group_histograms_fused_tiled
-                    h, leaf2 = self._on_row_shards(
+                    return self._on_row_shards(
                         functools.partial(
                             compute_group_histograms_fused_tiled,
                             max_group_bin=B,
                             block=plan.block_tiled, strips=strips,
                             interpret=plan.interpret,
                             packed_groups=self.pack_P),
-                        self.binsT, wT, in_scales, st.leaf_id,
-                        st.route_tab, rights)
-                else:
-                    h, leaf2 = compute_group_histograms_fused(
-                        ohb, self.binsT, wT, st.leaf_id,
-                        st.route_tab, rights, max_group_bin=B,
-                        block=plan.block_float, strips=strips,
-                        interpret=plan.interpret, pack=plan.onehot_pack,
-                        num_groups=self.num_groups,
-                        packed_groups=self.pack_P)
-                return _pad_slots(h, W), leaf2
+                        self.binsT, wT, scales, leaf_id,
+                        st.route_tab, slots)
+                return compute_group_histograms_fused(
+                    ohb, self.binsT, wT, leaf_id,
+                    st.route_tab, slots, max_group_bin=B,
+                    block=plan.block_float, strips=strips,
+                    interpret=plan.interpret, pack=plan.onehot_pack,
+                    num_groups=self.num_groups,
+                    packed_groups=self.pack_P)
             return go
 
-        def strips_ladder(_):
-            if W <= PACKED_STRIP:
-                return run(1)(None)
-            k = jnp.sum(rights >= 0)
-            if W <= 2 * PACKED_STRIP:
-                return jax.lax.cond(k <= PACKED_STRIP, run(1), run(2),
-                                    None)
-            return jax.lax.cond(
-                k <= PACKED_STRIP, run(1),
-                lambda _: jax.lax.cond(k <= 2 * PACKED_STRIP, run(2),
-                                       run(3), None), None)
-
-        def run_factored(k_cap, a):
-            def go(_):
+        def factored_pass(k_cap, a):
+            def go(leaf_id, slots):
                 from ..ops.histogram import \
                     compute_group_histograms_fused_factored
-                h, leaf2 = self._on_row_shards(
+                return self._on_row_shards(
                     functools.partial(
                         compute_group_histograms_fused_factored,
                         max_group_bin=B, k_cap=k_cap, a=a,
                         block=plan.block_factored,
                         interpret=plan.interpret,
                         group_chunk=plan.group_chunk),
-                    self.binsT, wT, in_scales, st.leaf_id, st.route_tab,
-                    rights)
-                return _pad_slots(h, W), leaf2
+                    self.binsT, wT, scales, leaf_id, st.route_tab,
+                    slots)
             return go
 
-        if not rungs:
-            return strips_ladder(None)
-        k = jnp.sum(rights >= 0)
-        caps = [r[0] for r in rungs]
-
-        def rung_ladder(_):
-            return jax.lax.switch(
-                sum((k > cap).astype(jnp.int32) for cap in caps[:-1]),
-                [run_factored(k_cap, a) for k_cap, a, _ in rungs], None)
-
-        if caps[-1] >= W:
-            # the rungs serve every count of active slots there can be:
-            # the strips are not traced
-            h, leaf2 = rung_ladder(None)
-        else:
-            h, leaf2 = jax.lax.cond(k <= caps[-1], rung_ladder,
-                                    strips_ladder, None)
-        if late_scale:
-            h = _scaled(h, scales)
-        return h, leaf2
+        ladder = [(k_cap, factored_pass(k_cap, a))
+                  for k_cap, a, _ in plan.factored_rungs if k_cap <= W]
+        widest = ladder[-1][0] if ladder else 0
+        # where the rungs serve every count of active slots there can
+        # be, the strips are not traced
+        return ladder + [(w, strips_pass(n)) for n, w in strips
+                         if widest < w], late
 
     # ------------------------------------------------------------------
     def _on_row_shards(self, kernel, binsT, wT, scales, leaf_id,
@@ -906,33 +889,6 @@ class TreeGrower:
         total, rows_i32, leaf2 = shard(binsT, wT, leaf_id, route_tab,
                                        slots)
         return (_scaled(total, scales), rows_i32), leaf2
-
-    # ------------------------------------------------------------------
-    def _hist_kernel_pre(self, grad, hess, counts, leaf_id, slots):
-        """Streamed-one-hot dispatch: channel-packed kernel when the
-        frontier is narrow (3x fewer MXU rows), full kernel otherwise.
-        The branch is a runtime lax.cond on the valid-slot count — the
-        early rounds of EVERY tree have 1..PACKED_STRIP new leaves."""
-        B = self.max_group_bin
-        plan = self.plan
-        ohb = self._ohb_arg if self._ohb_arg is not None else self.ohb
-        w = jnp.stack([grad, hess, counts], axis=1)
-
-        def full(_):
-            return compute_group_histograms_pre(
-                ohb, w, leaf_id, num_leaves=self.num_leaves,
-                max_group_bin=B, block=plan.block_float,
-                slots=slots, pack=plan.onehot_pack,
-                num_groups=self.num_groups)
-
-        def run_packed(strips):
-            return compute_group_histograms_pre_packed(
-                ohb, w, leaf_id, slots, max_group_bin=B,
-                block=plan.block_float, strips=strips,
-                pack=plan.onehot_pack, num_groups=self.num_groups)
-
-        return self._packed_dispatch(full, run_packed, slots,
-                                     slots.shape[0])
 
     # ------------------------------------------------------------------
     def emit_tree_record(self, tree: TreeArrays) -> jax.Array:
@@ -1081,7 +1037,11 @@ class TreeGrower:
             leaf_is_left=jnp.zeros(L, bool),
             leaf_forced=leaf_forced,
             tree=tree,
-            hist_cache=hist_cache, cand=cand, forced_cand=forced_cand)
+            hist_cache=hist_cache,
+            hist_stage=(jax.tree_util.tree_map(lambda c: jnp.zeros(
+                (W if self.use_hist_cache else 1,) + c.shape[1:],
+                c.dtype), hist_cache),) * 2,
+            cand=cand, forced_cand=forced_cand)
 
     # ------------------------------------------------------------------
     def _train_tree_impl(self, grad, hess, counts, feature_mask,
@@ -1224,88 +1184,126 @@ class TreeGrower:
     # ------------------------------------------------------------------
     def _refresh(self, st: GrowerState, parents, rights, grad, hess,
                  counts, feature_mask, quant=None) -> GrowerState:
-        """Histogram + split-finder pass over the new leaves of a round.
+        """Histogram + split-finder pass over the new leaves of a round,
+        all of it at the width of the rung that serves the round's valid
+        slots (``_pass_ladder``): ONE ladder, a branch a width.
 
-        ``rights`` are histogrammed directly from the data (one
-        frontier-restricted MXU pass); each ``parents`` slot (which the
-        left child inherited) becomes parent-minus-right.  The finder
-        then runs on the 2W new leaves only and its results are
-        scattered into the per-leaf candidate cache.  Negative slot
-        entries are inert (their writes drop, their lanes match no row).
-        """
-        L = self.num_leaves
-        cfg = self.cfg_scalars
-        cache = st.hist_cache
-
-        def histogram(st, slots):
-            """(histogram of ``slots``, state after the pass).  Fused:
-            the pending route (last round's splits) is applied INSIDE
-            the kernel just before each row contributes; a second pass
-            re-applies it, which is idempotent."""
-            if self.plan.fused:
-                hist, new_leaf = self._hist_kernel_fused(
-                    st, slots, grad, hess, counts, quant)
-                return hist, st._replace(leaf_id=new_leaf)
-            return self._hist_kernel(grad, hess, counts, st.leaf_id,
-                                     slots), st
-
-        right_hist, st = histogram(st, rights)
-        right_hist = self._constrain_hist(right_hist)
-        safe_p = jnp.clip(parents, 0, L - 1)
+        A branch of width ``w`` histograms ``rights[:w]`` directly from
+        the data (one frontier-restricted MXU pass), takes
+        parent-minus-right for each valid one of ``parents[:w]`` (the
+        slot the left child inherited) a row of the per-leaf cache at a
+        time, runs the finder on those 2w rows and scatters its results
+        into the per-leaf candidate cache.  The histogram does not leave
+        the branch, so nothing pads it to the frontier cap, re-lays it
+        or cuts it back: the branch writes its halves over the heads of
+        ``st.hist_stage``, and the one thing done after the switch is to
+        move the valid rows from there into the cache.  (The TPU
+        compiler keeps a buffer that a conditional hands on in place
+        only where the branches do not read it: a cache updated inside
+        them was copied whole on every pass, 1.6 GB at 2,000 groups; and
+        it cuts the WHOLE operand of a gather or scatter into slabs
+        first, so the cache is read and written a row at a time.)
+        Negative slot entries are inert (their results drop, their lanes
+        match no row), and valid slots lead both halves (``_round``
+        queues them so).  ``split_finder_ladder=False``: every branch
+        pads its pass to the frontier cap and runs the finder there (the
+        parity tests' reference)."""
+        W = parents.shape[0]
         tmap = jax.tree_util.tree_map     # a histogram, or its pair
-        if self.use_hist_cache:
-            left_hist = tmap(lambda c, r: c[safe_p] - r, cache, right_hist)
-        else:
+        k = jnp.sum(rights >= 0)
+        late = None                       # _pass_ladder: a late multiply
+
+        def both(kernel):
+            """(histogram of ``rights``, of ``parents`` where there is
+            no cache to subtract from, leaf ids after the pass)."""
+            right, leaf_id = kernel(st.leaf_id, rights)
             # no-cache mode: the parent slot now hosts the LEFT child's
             # rows (routing already applied), so a direct pass replaces
             # the subtraction
-            left_hist, _ = histogram(st, parents)
-            left_hist = self._constrain_hist(left_hist)
-        new_slots = jnp.concatenate([parents, rights])          # (2W,)
-        h_new = tmap(lambda l, r: jnp.concatenate([l, r]),
-                     left_hist, right_hist)                     # (2W,G,B,3)
-        if self.use_hist_cache:
-            # one combined scatter (parent and right slots are disjoint)
-            # so XLA emits a single in-place update of the cache buffer
-            at = jnp.where(new_slots >= 0, new_slots, L)
-            cache = tmap(lambda c, h: c.at[at].set(h, mode="drop"),
-                         cache, h_new)
-        # ---- frontier-bounded candidate refresh (round 7): the finder
-        # and the cache scatter run at the narrowest packed-strip width
-        # covering the valid slots — a lax.cond ladder mirroring
-        # _packed_dispatch, so the (2W, F, B) threshold sweep stops
-        # paying the full frontier cap on the 1-2-leaf early rounds
-        with TELEMETRY.phase("split_finder"):
-            W = parents.shape[0]
+            left = None if self.use_hist_cache \
+                else kernel(leaf_id, parents)[0]
+            return right, left, leaf_id
 
-            def refresh_at(w):
-                def go(_):
-                    if w >= W:
-                        return self._refresh_cand(st, new_slots, h_new,
-                                                  feature_mask)
-                    slots_w = jnp.concatenate([parents[:w], rights[:w]])
-                    h_w = tmap(lambda l, r: jnp.concatenate([l[:w], r[:w]]),
-                               left_hist, right_hist)
-                    return self._refresh_cand(st, slots_w, h_w, feature_mask)
-                return go
+        if self.plan.fused or (self.plan.tier == "float"
+                               and self.plan.onehot_pack):
+            ladder, late = self._pass_ladder(st, W, grad, hess, counts,
+                                             quant)
+            ladder = [(w, functools.partial(both, kernel))
+                      for w, kernel in ladder]
+        else:
+            # a pass with no ladder is made once, in the open, and each
+            # width (the packed strips') cuts its rows out of it
+            done = both(lambda leaf_id, slots: (self._hist_kernel(
+                grad, hess, counts, leaf_id, slots), leaf_id))
+            ladder = [(w, lambda: done) for w in _strip_widths(W)]
+        # a rung is taken by its pass's width; the rest of its refresh
+        # runs there too, or at the frontier cap with the ladder off
+        widths = [w if self.split_ladder else W for w, _ in ladder]
+        if TELEMETRY.on:
+            TELEMETRY.gauge("grower.refresh_widths",      # "2,10,16"
+                            str(widths)[1:-1].replace(" ", ""))
 
-            rungs = [s for s in (PACKED_STRIP, 2 * PACKED_STRIP) if s < W]
-            if not self.split_ladder or not rungs:
-                cand, forced_cand = refresh_at(W)(None)
-            else:
-                kv = jnp.sum(rights >= 0)
-                wide = refresh_at(W)
-                if len(rungs) == 1:
-                    cand, forced_cand = jax.lax.cond(
-                        kv <= rungs[0], refresh_at(rungs[0]), wide, None)
+        def at(w, passes):
+            def go(_):
+                right, left, leaf_id = passes()
+                right = self._constrain_hist(_fit_slots(right, w))
+                p_w = parents[:w]
+                slots_w = jnp.concatenate([p_w, rights[:w]])    # (2w,)
+                stage = st.hist_stage
+                if left is None:
+                    # parent - right a valid row at a time (the
+                    # dequantize multiply was rounded to memory before
+                    # the loop, on any backend: no compiler contracts
+                    # the two), then both halves over the stage's heads
+                    def minus(i, left):
+                        r = _get_row(right, i)
+                        if late is not None:
+                            r = _scaled(r, late)
+                        parent = _get_row(st.hist_cache,
+                                          jnp.maximum(p_w[i], 0))
+                        return _set_rows(
+                            left, tmap(jnp.subtract, parent, r), i)
+                    left = jax.lax.fori_loop(0, k, minus,
+                                             tmap(jnp.zeros_like, right))
+                    if late is not None:
+                        right = _scaled(right, late)
+                    stage = tuple(_set_rows(s, h, 0) for s, h
+                                  in zip(stage, (left, right)))
                 else:
-                    cand, forced_cand = jax.lax.cond(
-                        kv <= rungs[0], refresh_at(rungs[0]),
-                        lambda _: jax.lax.cond(kv <= rungs[1],
-                                               refresh_at(rungs[1]), wide,
-                                               None), None)
-        return st._replace(hist_cache=cache, cand=cand,
-                           forced_cand=forced_cand)
+                    left = self._constrain_hist(_fit_slots(left, w))
+                    if late is not None:
+                        left, right = (_scaled(h, late)
+                                       for h in (left, right))
+                h_w = tmap(lambda l, r: jnp.concatenate([l, r]),
+                           left, right)
+                cand, forced_cand = self._refresh_cand(
+                    st, slots_w, h_w, feature_mask)
+                return stage, cand, forced_cand, leaf_id
+            return go
+
+        branches = [at(w, passes)
+                    for w, (_, passes) in zip(widths, ladder)]
+        if len(branches) == 1:
+            out = branches[0](None)
+        else:
+            out = jax.lax.switch(
+                sum((k > w).astype(jnp.int32) for w, _ in ladder[:-1]),
+                branches, None)
+        stage, cand, forced_cand, leaf_id = out
+        cache = st.hist_cache
+        if self.use_hist_cache:
+            def move(i, cache):
+                left, right = (_get_row(s, i) for s in stage)
+                # (the root is a right slot with no parent)
+                child = parents[i] >= 0
+                cache = _set_rows(
+                    cache, tmap(lambda l, r: jnp.where(child, l, r),
+                                left, right),
+                    jnp.where(child, parents[i], rights[i]))
+                return _set_rows(cache, right, rights[i])
+            cache = jax.lax.fori_loop(0, k, move, cache)
+        return st._replace(hist_cache=cache, hist_stage=stage, cand=cand,
+                           forced_cand=forced_cand, leaf_id=leaf_id)
 
     # ------------------------------------------------------------------
     def _constrain_hist(self, hist):
@@ -1536,7 +1534,8 @@ class TreeGrower:
             leaf_sum_hess=leaf_sum_hess, leaf_count=leaf_count,
             leaf_min_c=leaf_min_c, leaf_max_c=leaf_max_c,
             leaf_is_left=leaf_is_left, leaf_forced=leaf_forced, tree=tree,
-            hist_cache=st.hist_cache, cand=st.cand,
+            hist_cache=st.hist_cache, hist_stage=st.hist_stage,
+            cand=st.cand,
             forced_cand=st.forced_cand, route_tab=route_tab,
             pend_parents=st.pend_parents, pend_rights=st.pend_rights)
 

@@ -369,6 +369,48 @@ def test_finder_identity_map_is_read_off_the_table(name, tmp_path,
     assert bool(expands) != want
 
 
+@pytest.mark.parametrize("params,leaves,want", [
+    ({"max_bin": 255}, 255, None),              # the rungs' caps
+    ({"max_bin": 255}, 100, "2,10,16,32,64,84,99"),
+    ({"max_bin": 63}, 255, "42,84,126"),        # strips alone
+    ({"max_bin": 255, "split_finder_ladder": False}, 255,
+     ",".join(["126"] * 6)),
+    ({"max_bin": 255, "quantized_grad": False,
+      "force_pallas_interpret": False}, 255, "42,84,126"),
+], ids=["rungs", "rungs_then_strips", "strips", "ladder_off", "xla_tier"])
+def test_refresh_widths_are_the_widths_of_the_pass(params, leaves, want):
+    """One ladder (PR 36): the widths a round's refresh can take are the
+    slot caps of the passes the plan has — the factored rungs', then the
+    strips' — and the gauge ``grower.refresh_widths`` says so when the
+    tree program is traced; with the ladder off every rung refreshes at
+    the frontier cap."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.telemetry import TELEMETRY
+    X, y = _table(rows=1024)
+    TELEMETRY.configure("counters")
+    try:
+        grower = _grower(X, y, **{
+            "num_leaves": leaves, "quantized_grad": True,
+            "hist_compute_dtype": "bfloat16",
+            "force_pallas_interpret": True, **params})
+        ones = jnp.ones(grower.n_padded, jnp.float32)
+        jax.make_jaxpr(grower._train_tree_impl)(
+            ones * 0.5, ones, ones, jnp.ones(grower.num_features, bool),
+            qkey=jax.random.PRNGKey(0))
+        gauges = TELEMETRY.gauges()
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    if want is None:
+        assert grower.plan.factored_rungs == FACTORED_RUNGS
+        want = ",".join(str(k) for k, _, _ in FACTORED_RUNGS)
+        assert gauges["grower.hist_factored_rungs"].split(",")[-1] \
+            .startswith(f"{grower.frontier}:")
+    assert gauges["grower.refresh_widths"] == want
+    assert int(want.split(",")[-1]) == grower.frontier
+
+
 @pytest.mark.fast
 def test_finder_identity_map_cases_of_the_map_itself():
     import numpy as np

@@ -103,15 +103,82 @@ def test_packed_record_roundtrip_is_exact():
     assert int(host["num_leaves"]) > 1
 
 
-def test_split_finder_ladder_parity(ref_model):
-    """The frontier-bounded split finder (lax.cond ladder over packed-
-    strip widths) must pick identical splits to the full-width finder —
-    the knob changes shapes, not semantics.  Compared against the
+# the rung ladder (PR 36): at max_bin=255 a frontier of 47 slots is served
+# by the factored rungs of 2, 10, 16 and 32 slots and the strips of 42 and
+# 47, and the round's whole refresh (pass, parent - right, the cache's
+# update, the finder) runs at the width of the rung
+RUNGS = dict(BASE, max_bin=255, num_leaves=48, quant_stochastic_rounding=1,
+             dispatch_chunk=2, telemetry="counters")
+RUNG_WIDTHS = "2,10,16,32,42,47"
+FORCED = {"feature": 0, "threshold": 0.1,
+          "left": {"feature": 1, "threshold": -0.2}}
+LADDER_CASES = {
+    "strips": None,                       # the module's 7-leaf model
+    "rungs": {},
+    "rungs_two_row_segments": {},         # int32 counts, QUANT_SEGMENT_ROWS
+    "rungs_row_mesh": {"tree_learner": "data", "mesh_shape": [4],
+                       "mesh_axes": ["data"], "hist_kernel": "pallas"},
+    "rungs_no_cache": {"histogram_pool_size": 0.001},
+    "rungs_forced_split": {"forcedsplits_filename": FORCED},
+}
+
+
+@pytest.mark.parametrize("case", list(LADDER_CASES))
+def test_split_finder_ladder_parity(case, request, tmp_path, monkeypatch):
+    """One ladder for the pass, the glue and the finder must pick
+    identical splits to the refresh at the frontier cap
+    (``split_finder_ladder=False``, the parent's behaviour) — the knob
+    changes shapes, not semantics.  ``strips``: compared against the
     shared chunk-1 reference (the ladder-ON chunk-10 model is byte-
-    identical to it by the test above)."""
-    X, y, ref = ref_model
-    assert _model(X, y, dispatch_chunk=10,
-                  split_finder_ladder=False) == ref
+    identical to it by the test above).  The others on a rung-ladder
+    shape: one device, two row segments with int32 counts, a four-shard
+    row mesh, no histogram cache, a forced split."""
+    if case == "strips":
+        X, y, ref = request.getfixturevalue("ref_model")
+        assert _model(X, y, dispatch_chunk=10,
+                      split_finder_ladder=False) == ref
+        return
+    import json
+
+    import jax
+    from lightgbm_tpu.ops import histogram as H
+    from lightgbm_tpu.telemetry import TELEMETRY
+    if case == "rungs_row_mesh" and len(jax.devices()) < 4:
+        pytest.skip("needs four (virtual) devices")
+    params = dict(RUNGS, **LADDER_CASES[case])
+    if case == "rungs_forced_split":
+        fn = tmp_path / "forced.json"
+        fn.write_text(json.dumps(FORCED))
+        params["forcedsplits_filename"] = str(fn)
+    if case == "rungs_two_row_segments":
+        monkeypatch.setattr(H, "QUANT_SEGMENT_ROWS", 2048)
+    rng = np.random.RandomState(5)
+    X = rng.lognormal(size=(4096, 6)).astype(np.float32)   # 1024 a shard
+    y = (X[:, 0] * X[:, 1] - X[:, 2] + 0.3 * rng.randn(4096)
+         > 0.5).astype(float)
+
+    def grow(**over):
+        TELEMETRY.reset()
+        bst = lgb.train(dict(params, **over), lgb.Dataset(X, label=y), 2,
+                        verbose_eval=False, keep_training_booster=True)
+        return (bst.model_to_string(), bst.gbdt.grower,
+                TELEMETRY.gauges()["grower.refresh_widths"])
+    try:
+        on, grower, widths = grow()
+        off, _, capped = grow(split_finder_ladder=False)
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    plan = grower.plan
+    assert plan.tier == "ladder" and grower.frontier == 47
+    assert widths == RUNG_WIDTHS
+    assert capped == ",".join(["47"] * 6)
+    assert (plan.row_segments == 2 and plan.int_counts) \
+        == (case == "rungs_two_row_segments")
+    assert plan.mesh_kernels == (case == "rungs_row_mesh")
+    assert grower.use_hist_cache == (case != "rungs_no_cache")
+    assert bool(grower.forced_count) == (case == "rungs_forced_split")
+    assert on == off
 
 
 def test_dispatch_chunk_param_validation():

@@ -88,8 +88,11 @@ def test_exit_route_compiles_over_split_rows(one_chip, plan):
 
 
 @pytest.mark.parametrize("rows,features,scans,int_counts", [
-    (252, GROUPS, 1, False), (252, 67, 2, True)],
-    ids=["epsilon_widest_refresh", "two_scans_int32_counts"])
+    (252, GROUPS, 1, False), (252, 67, 2, True), (4, GROUPS, 1, False),
+    (20, GROUPS, 1, False), (32, GROUPS, 1, False), (4, 67, 2, True)],
+    ids=["epsilon_widest_refresh", "two_scans_int32_counts",
+         "epsilon_rung_2", "epsilon_rung_10", "epsilon_rung_16",
+         "rung_2_int32_counts"])
 def test_fused_split_finder_compiles_in_its_block(one_chip, rows, features,
                                                   scans, int_counts):
     """The fused finder at the widest refresh of the cells (252 leaf rows
@@ -163,3 +166,62 @@ def test_factored_rung_compiles_in_row_segments(one_chip, k_cap, a):
         compute_group_histograms_fused_factored, **static), *args)
     assert out[0].shape == (2, k_cap, groups, BINS, 3)
     assert out[0].dtype == jnp.int32
+
+
+def test_tree_program_refreshes_at_the_width_of_its_rung(one_chip,
+                                                         monkeypatch):
+    """One tree's program at a wide shape, lowered for the chip (PR 36):
+    one histogram kernel and one finder kernel a rung, the finder named
+    by the rung's 2w leaf rows; nothing pads a histogram to the frontier
+    cap W or to 2W, nothing concatenates W slots, and the only 2W-slot
+    concatenate is the widest rung's own finder operand (its halves) —
+    the glue between a pass and its finder is as wide as the pass."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.learner import grower as grower_module
+    from lightgbm_tpu.learner.grower import TreeGrower
+    from lightgbm_tpu.ops.histogram import FACTORED_RUNGS
+    rows, groups, W = 4096, 256, 126
+    monkeypatch.setattr(grower_module, "on_tpu", lambda: True)
+    rng = np.random.RandomState(0)
+    X = rng.lognormal(size=(rows, groups)).astype(np.float32)
+    params = {"objective": "binary", "num_leaves": LEAVES, "max_bin": BINS,
+              "verbose": -1, "hist_compute_dtype": "bfloat16",
+              "quantized_grad": True, "quant_stochastic_rounding": 1}
+    config = Config.from_params(params)
+    gr = TreeGrower(lgb.Dataset(X, label=(X[:, 0] > 1).astype(np.float32),
+                                params=params).construct(config), config)
+    assert gr.plan.tier == "ladder" and not gr.plan.interpret
+    assert (gr.frontier, gr.num_groups) == (W, groups)
+
+    def s(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    f32 = jax.ShapeDtypeStruct((gr.n_padded,), jnp.float32,
+                               sharding=one_chip)
+
+    def tree(g, h, c, fm, bins, binsT, valid, key, pool):
+        return gr._train_tree_impl(g, h, c, fm, None, bins, binsT, valid,
+                                   key, pool)
+    text = jax.jit(tree, donate_argnums=8).lower(
+        f32, f32, f32, s(np.ones(gr.num_features, bool)), s(gr.bins),
+        s(gr.binsT), s(gr._row_valid), s(jax.random.PRNGKey(0)),
+        s(jax.eval_shape(gr._zero_hist_cache))).as_text()
+    for k_cap, a, _ in FACTORED_RUNGS:
+        assert len(re.findall(
+            rf"compute_group_histograms_fused_factored_k{k_cap}_a{a}\b",
+            text)) == 1
+        assert len(re.findall(
+            rf"find_numerical_splits_fused_r{2 * k_cap}\b", text)) == 1
+    assert len(set(re.findall(r"find_numerical_splits_fused_r\d+",
+                              text))) == len(FACTORED_RUNGS)
+
+    def wide(op, slots):
+        return re.findall(rf"stablehlo\.{op}.*-> "
+                          rf"tensor<{slots}x{groups}x{BINS}x3xf32>", text)
+    assert not wide("pad", W) and not wide("pad", 2 * W)
+    assert not wide("concatenate", W)
+    assert len(wide("concatenate", 2 * W)) == 1

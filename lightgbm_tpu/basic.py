@@ -52,6 +52,18 @@ class Dataset:
             return self._core
         if config is None:
             config = Config.from_params(self.params)
+        from .telemetry import TELEMETRY
+        # the entry-point stage of set-up's first half: every route
+        # below runs in it, and its own time is what no stage under it
+        # took (docs/OBSERVABILITY.md)
+        with TELEMETRY.stage("construct") as stage:
+            core = self._construct(config)
+        if stage is not None and stage.wall_ms > 0:
+            TELEMETRY.gauge("construct_rows_per_s",
+                            round(core.num_data / stage.wall_ms * 1e3))
+        return core
+
+    def _construct(self, config: Config) -> CoreDataset:
         data = self.data
         label = self.label
         if isinstance(data, str):
@@ -126,17 +138,10 @@ class Dataset:
                         "falling back to in-RAM loading")
         if streaming_ok:
             # two-round streaming: the float matrix never exists
-            import time as _time
-
             from .data_loader import load_file_streaming
             from .telemetry import TELEMETRY
-            t0 = _time.perf_counter()
             with TELEMETRY.stage("binning"):
                 self._core = load_file_streaming(data, config)
-            wall = _time.perf_counter() - t0
-            if wall > 0:
-                TELEMETRY.gauge("construct_rows_per_s",
-                                round(self._core.num_data / wall))
             if isinstance(self.feature_name, (list, tuple)):
                 self._core.feature_names = list(self.feature_name)
             if self.label is not None:
@@ -196,8 +201,6 @@ class Dataset:
             _note_input((), 0 if _is_sparse(data) else data.nbytes)
         feature_names, cat_indices = self._resolve_columns(data)
 
-        import time as _time
-
         from .telemetry import TELEMETRY
         if sharded_on and ref_core is None:
             # mesh-sharded construction (lightgbm_tpu/sharded/,
@@ -210,7 +213,6 @@ class Dataset:
                             "using the single-matrix sparse path")
             else:
                 from .sharded import ShardedDataset, save_shard_cache
-                t0 = _time.perf_counter()
                 with TELEMETRY.stage("binning", rows=int(data.shape[0])):
                     self._core = ShardedDataset.construct_sharded(
                         data, label=label, weight=self.weight,
@@ -218,10 +220,6 @@ class Dataset:
                         config=config,
                         categorical_features=cat_indices,
                         feature_names=feature_names)
-                wall = _time.perf_counter() - t0
-                if wall > 0:
-                    TELEMETRY.gauge("construct_rows_per_s",
-                                    round(int(data.shape[0]) / wall))
                 if config.sharded_cache_dir:
                     save_shard_cache(self._core,
                                      config.sharded_cache_dir)
@@ -231,7 +229,6 @@ class Dataset:
                 if self.free_raw_data:
                     self.data = None
                 return self._core
-        t0 = _time.perf_counter()
         with TELEMETRY.stage("binning", rows=int(data.shape[0])):
             # host-side bin-mapper fit + matrix binning — the one
             # pre-device phase of training, decomposed into the
@@ -241,10 +238,6 @@ class Dataset:
                 init_score=self.init_score, config=config,
                 categorical_features=cat_indices,
                 feature_names=feature_names, reference=ref_core)
-        wall = _time.perf_counter() - t0
-        if wall > 0:
-            TELEMETRY.gauge("construct_rows_per_s",
-                            round(int(data.shape[0]) / wall))
         self._core._raw_data = None if self.free_raw_data else data
         if self.free_raw_data and not _is_sparse(data) \
                 and str(getattr(config, "quality", "off")).lower() \
@@ -273,8 +266,6 @@ class Dataset:
         matrix (sharded.ShardedDataset.from_row_shards), so a table the
         host cannot hold twice (nor once as float64) still constructs.
         Trees are those of the concatenated matrix."""
-        import time as _time
-
         from .sharded import ShardedDataset
         from .telemetry import TELEMETRY
         if ref_core is not None or self.group is not None:
@@ -282,16 +273,12 @@ class Dataset:
                       "and no query groups yet — pass one matrix")
         feature_names, cat_indices = self._resolve_columns(shards[0])
         rows = sum(a.shape[0] for a in shards)
-        t0 = _time.perf_counter()
         with TELEMETRY.stage("binning", rows=rows):
             self._core = ShardedDataset.from_row_shards(
                 shards, label=label, weight=self.weight,
                 init_score=self.init_score, config=config,
                 categorical_features=cat_indices,
                 feature_names=feature_names)
-        wall = _time.perf_counter() - t0
-        if wall > 0:
-            TELEMETRY.gauge("construct_rows_per_s", round(rows / wall))
         self._core._raw_data = None if self.free_raw_data \
             else np.concatenate(shards)
         self._core.pandas_categorical = None

@@ -381,7 +381,12 @@ class Booster:
                 callable(train_set.construct):
             train_set = train_set.construct(self.config)
 
-        from .boosting import create_boosting
+        from .telemetry import TELEMETRY
+        with TELEMETRY.stage("import_boosting"):
+            # a process's first training Booster imports the training
+            # modules here: the grower, the kernels and with them
+            # jax.experimental.pallas (over a second; a lookup after)
+            from .boosting import create_boosting
         self.gbdt = create_boosting(self.config, train_set,
                                     custom_objective=custom_objective)
         self.average_output = getattr(self.gbdt, "average_output", False)

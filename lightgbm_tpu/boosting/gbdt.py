@@ -15,7 +15,7 @@ device queue to drain, so the loop never blocks on one.
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -35,7 +35,7 @@ from ..reliability.faults import FAULTS
 from ..reliability.retry import RetryPolicy, retry_call
 from ..telemetry import TELEMETRY
 from ..tree import Tree
-from ..utils.log import Log, PhaseTimer
+from ..utils.log import Log
 
 
 def fit_chunk_slope(times: Dict[int, float]) -> Tuple[float, float]:
@@ -154,10 +154,6 @@ class GBDT:
                                for c in range(self.num_class)])
             self.scores = self.grower.policy.place_score_rows(padded)
 
-        # per-phase wall-clock accounting (the TIMETAG analog,
-        # reference gbdt.cpp:21-29/52-61); reported at Log.debug level
-        # when training finishes
-        self.timer = PhaseTimer()
         self._rng = np.random.RandomState(config.seed)
         self._bag_rng = jax.random.PRNGKey(config.bagging_seed)
         self._iter_key_rng = np.random.RandomState(config.bagging_seed)
@@ -222,8 +218,7 @@ class GBDT:
                                  self.grower.policy.place_rows(
                                      self.grower.pad_rows(
                                          w.astype(np.float32))))
-            if TELEMETRY.on:
-                jax.block_until_ready((self.scores, self._full_counts))
+            TELEMETRY.stage_fence((self.scores, self._full_counts))
         self._bag_mask: Optional[jax.Array] = None
 
         # EVERY O(N) device array must cross the jit boundary as an
@@ -645,7 +640,10 @@ class GBDT:
         leaves none behind and the next one starts a new pool."""
         pool, self._hist_pool = self._hist_pool, None
         if pool is None:
-            pool = self.grower.new_hist_pool()
+            # stage: the job's one per-leaf cache, zeros on the device
+            # (1.5 GB at 2,000 groups), made by the first dispatch
+            with TELEMETRY.stage("hist_pool"):
+                pool = self.grower.new_hist_pool()
         return pool
 
     def train_chunk(self, n_iters: int) -> bool:
@@ -722,7 +720,6 @@ class GBDT:
                     cache = jnp.zeros(n_iters, bool)
                     self._chunk_fresh = cache
                 fresh = cache
-        self.timer.start("tree")
 
         def _enqueue():
             # fault seam BEFORE the dispatch: an injected failure (or
@@ -742,7 +739,14 @@ class GBDT:
                 self._take_hist_pool())
 
         try:
-            with tm.span("host_dispatch"):
+            # a dispatch that builds its chunk program is a set-up
+            # stage, counted like chunk_program_build_ms from method
+            # entry: jax's trace / lower / compile of the program are
+            # the stages chunk_trace / chunk_lower / chunk_compile, its
+            # own time the prep above and the enqueue.  Every other
+            # dispatch opens nothing here.
+            with tm.stage("chunk_build", compiles="chunk", since=t0) \
+                    if built else nullcontext(), tm.span("host_dispatch"):
                 scores, vscores, bag, trees, nls, self._hist_pool = \
                     retry_call(
                         self._dispatch_guard(_enqueue, "gbdt.train_chunk"),
@@ -774,7 +778,6 @@ class GBDT:
             # seed/feature-mask sequence
             self._iter_key_rng.set_state(_rng_snap[0])
             self._feat_rng.set_state(_rng_snap[1])
-            self.timer.stop("tree")
             tm.end_span(span)
             raise
         if tm.on and self.grower.policy.nproc > 1:
@@ -815,7 +818,6 @@ class GBDT:
         self._nl_window.append(nls)          # stays stacked on device
         self._nl_count += n_iters
         self.iter_ += n_iters
-        self.timer.stop("tree")
         self._transport_epoch_tick()
         tm.end_span(commit)
         tm.end_span(span)
@@ -903,7 +905,6 @@ class GBDT:
         t0 = time.perf_counter() if tm.on else 0.0  # host wall from
         # method entry (same window discipline as train_chunk)
         self._before_boosting()
-        self.timer.start("tree")
         if self._fused_step is None:
             self._build_fused()
         cfg = self.config
@@ -950,7 +951,6 @@ class GBDT:
             # the fence): restore RNG streams for an exact retry
             self._iter_key_rng.set_state(_rng_snap[0])
             self._feat_rng.set_state(_rng_snap[1])
-            self.timer.stop("tree")
             tm.end_span(span)
             raise
         tm.end_span(span)
@@ -972,7 +972,6 @@ class GBDT:
         self._nl_count += 1
         self._after_iteration()
         self.iter_ += 1
-        self.timer.stop("tree")
         self._transport_epoch_tick()
         if self._nl_count >= self._stop_check_every:
             return self._check_stop_window()
@@ -1024,7 +1023,6 @@ class GBDT:
                       "gradient functions yet (host gradients cannot "
                       "follow the sharded row layout)")
         self._before_boosting()
-        self.timer.start("boosting")
         grad = np.asarray(grad, dtype=np.float32).reshape(
             self.num_class, self.num_data)
         hess = np.asarray(hess, dtype=np.float32).reshape(
@@ -1032,14 +1030,10 @@ class GBDT:
         pad = self.grower.n_padded - self.num_data
         g = jnp.asarray(np.pad(grad, ((0, 0), (0, pad))))
         h = jnp.asarray(np.pad(hess, ((0, 0), (0, pad))))
-        self.timer.stop("boosting")
-        self.timer.start("bagging")
         counts, bag_mask = self._bagging_counts(self.iter_)
         g, h, counts = self._sample_rows(g, h, counts)
         g, h = self._mask_gradients(g, h, counts)
-        self.timer.stop("bagging")
 
-        self.timer.start("tree")
         bias = self.init_score if (self.iter_ == 0 and
                                    self.init_score != 0.0) else 0.0
         nl = jnp.int32(1)
@@ -1066,7 +1060,6 @@ class GBDT:
             self._tree_scale.append(1.0)
             self._tree_shrink.append(self.shrinkage_rate)
             nl = jnp.maximum(nl, tree_arrays.num_leaves)
-        self.timer.stop("tree")
         if TELEMETRY.on:
             TELEMETRY.add("trees_dispatched", self.num_class)
             TELEMETRY.add("iterations", 1)
@@ -1377,12 +1370,8 @@ class GBDT:
         """Returns (dataset_name, metric_name, value, bigger_better).
         ``which``: 'all', 'train' or 'valid' — scoped so eval_train /
         eval_valid don't pay for metrics they discard."""
-        self.timer.start("metric")
-        try:
-            with TELEMETRY.span("eval_metrics"):
-                return self._eval_metrics_impl(which)
-        finally:
-            self.timer.stop("metric")
+        with TELEMETRY.span("eval_metrics"):
+            return self._eval_metrics_impl(which)
 
     def _eval_metrics_impl(self, which="all"):
         out = []

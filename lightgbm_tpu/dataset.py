@@ -256,8 +256,13 @@ class Dataset:
             cat_set = set(categorical_features or [])
             sampler = (_sample_feature_values_sparse if sparse
                        else _sample_feature_values)
-            sample_vals, total_cnt, sample_rows = sampler(
-                data, config.bin_construct_sample_cnt, config.data_random_seed)
+            from .telemetry import TELEMETRY
+            with TELEMETRY.stage("sample", rows=int(num_data)):
+                # the draw, the gather of the sampled rows, their
+                # float64 copy and its split into columns
+                sample_vals, total_cnt, sample_rows = sampler(
+                    data, config.bin_construct_sample_cnt,
+                    config.data_random_seed)
             self.mappers = self._fit_mappers(sample_vals, total_cnt,
                                              config, cat_set)
             self.used_features = [i for i, m in enumerate(self.mappers)

@@ -58,6 +58,7 @@ def train(params: Dict[str, Any], train_set: Dataset,
     checkpoint file.  A resumed run continues FULL training state
     (model, score cache, RNG streams, early-stopping bookkeeping) and
     produces byte-identical trees to an uninterrupted run."""
+    t_entry = time.perf_counter()
     params = dict(params or {})
     if feature_name != "auto" and hasattr(train_set, "set_feature_name"):
         train_set.set_feature_name(feature_name)
@@ -79,6 +80,22 @@ def train(params: Dict[str, Any], train_set: Dataset,
     if not has_num_iter:
         params["num_iterations"] = num_boost_round
     config = Config.from_params(params)
+    # the entry-point stage of set-up's second half, counted from the
+    # call's entry (the Config above is what may turn telemetry on):
+    # Booster construction, the chunk program's build and the wait for
+    # the first trees are stages under it, and its own time is what
+    # none of them took
+    with TELEMETRY.stage("train", since=t_entry,
+                         num_boost_round=config.num_iterations):
+        return _train(config, params, train_set, valid_sets, valid_names,
+                      fobj, feval, init_model, evals_result, verbose_eval,
+                      callbacks, keep_training_booster, resume)
+
+
+def _train(config, params, train_set, valid_sets, valid_names, fobj, feval,
+           init_model, evals_result, verbose_eval, callbacks,
+           keep_training_booster, resume):
+    """``train`` from its resolved Config on."""
     num_boost_round = config.num_iterations
     # config.verbosity routes to the process-global Log level on the
     # python API too, not only in CLI runs (the reference's Config
@@ -128,22 +145,24 @@ def train(params: Dict[str, Any], train_set: Dataset,
     valid_sets = aligned
     train_set = core_train
 
-    booster = Booster(config=config, train_set=train_set,
-                      init_model=init_model,
-                      custom_objective=fobj is not None)
-
     valid_sets = list(valid_sets or [])
     names = list(valid_names or [])
     while len(names) < len(valid_sets):
         names.append(f"valid_{len(names)}")
-    for vs, name in zip(valid_sets, names):
-        if vs is train_set:
+    # stage: the training state, less the upload / grower_init / binsT
+    # stages inside it (objective, initial scores, RNG streams, the
+    # validation sets and metrics)
+    with TELEMETRY.stage("booster_init"):
+        booster = Booster(config=config, train_set=train_set,
+                          init_model=init_model,
+                          custom_objective=fobj is not None)
+        for vs, name in zip(valid_sets, names):
+            if vs is train_set:
+                booster.gbdt.add_train_metrics()
+            else:
+                booster.gbdt.add_valid(vs, name)
+        if config.is_training_metric and not booster.gbdt.train_metrics:
             booster.gbdt.add_train_metrics()
-        else:
-            booster.gbdt.add_valid(vs, name)
-
-    if config.is_training_metric and not booster.gbdt.train_metrics:
-        booster.gbdt.add_train_metrics()
 
     eval_freq = (verbose_eval if isinstance(verbose_eval, int)
                  and not isinstance(verbose_eval, bool)
@@ -350,8 +369,6 @@ def train(params: Dict[str, Any], train_set: Dataset,
                         f"dispatch ({e}); downshifting dispatch_chunk "
                         f"to {chunk_size} and continuing")
 
-    train_span = TELEMETRY.start_span("train",
-                                      num_boost_round=num_boost_round)
     # tuner gate counts REMAINING iterations: a resumed run near its
     # target must not spend (or overshoot with) probe chunks
     if chunkable and chunk_cfg in ("auto", "") \
@@ -449,11 +466,11 @@ def train(params: Dict[str, Any], train_set: Dataset,
     if not stopped_early:
         booster.best_iteration = -1
     if booster.gbdt is not None:
-        booster.gbdt.flush_models(final=True)
-    TELEMETRY.end_span(train_span)
-    if booster.gbdt is not None and booster.gbdt.timer.acc:
-        Log.debug("training phase timings: "
-                  + booster.gbdt.timer.report())
+        # where a headless lgb.train first blocks on the device: the
+        # pull of the trees still queued (all of a job's first chunk,
+        # when the call trains one).  A wait that happens anyway.
+        with TELEMETRY.stage("first_chunk_wait"):
+            booster.gbdt.flush_models(final=True)
     if str(config.quality).lower() == "on" \
             and booster.gbdt is not None and booster.models:
         # model-quality reference profile (docs/MODEL_MONITORING.md):

@@ -368,10 +368,9 @@ class TreeGrower:
                     self.bins = self.policy.place_bins(bins_np)
                 self._row_valid = self.policy.place_rows(
                     np.concatenate([np.ones(n, bool), np.zeros(pad, bool)]))
-            if TELEMETRY.on:
-                # the stage ends when the matrix is on the device;
-                # nothing but Python overlaps the transfer today
-                jax.block_until_ready((self.bins, self._row_valid))
+            # the stage ends when the matrix is on the device;
+            # nothing but Python overlaps the transfer today
+            TELEMETRY.stage_fence((self.bins, self._row_valid))
         # which histogram kernels run: decided once, from facts, by
         # ops/hist_plan.py (the only reader of hist_kernel,
         # hist_precision and hist_exchange)
@@ -410,8 +409,7 @@ class TreeGrower:
         if self.plan.fused:
             with TELEMETRY.stage("binsT"):
                 self.binsT = jnp.transpose(self.bins)
-                if TELEMETRY.on:
-                    jax.block_until_ready(self.binsT)
+                TELEMETRY.stage_fence(self.binsT)
         self._route_cols = 15 + (self.max_feature_bin + 7) // 8
         # the float tier's resident one-hot.  Trace-scoped override:
         # callers thread it through their jit boundary as an ARGUMENT (a

@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import Config
+from ..telemetry import TELEMETRY
 
 DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
@@ -81,7 +82,6 @@ class ShardingPolicy:
         # shards (device_put of a full array cannot address other
         # hosts' devices)
         self.multihost = mesh is not None and self.nproc > 1
-        from ..telemetry import TELEMETRY
         if TELEMETRY.on and mesh is not None:
             # topology gauges: a scraped metrics page should say what
             # fabric the run is on without reading logs
@@ -205,21 +205,24 @@ class ShardingPolicy:
                 shape).items():
             lo = idx[0].start or 0
             hi = idx[0].stop if idx[0].stop is not None else n_padded
-            parts = []
-            for i, a in enumerate(arrs):
-                s, e = max(lo, int(starts[i])), \
-                    min(hi, int(starts[i + 1]))
-                if s < e:
-                    parts.append(a[s - int(starts[i]):
-                                   e - int(starts[i])])
-            have = sum(p.shape[0] for p in parts)
-            if have < hi - lo:          # zero tail pad on this device
-                parts.append(np.zeros((hi - lo - have,) + rest,
-                                      dtype=arrs[0].dtype))
-            block = parts[0] if len(parts) == 1 \
-                else np.concatenate(parts)
-            blocks.append(jax.device_put(
-                np.ascontiguousarray(block), dev))
+            # stage (inside "upload"): a device's row block as one
+            # host array, before the transfer
+            with TELEMETRY.stage("shard_bins"):
+                parts = []
+                for i, a in enumerate(arrs):
+                    s, e = max(lo, int(starts[i])), \
+                        min(hi, int(starts[i + 1]))
+                    if s < e:
+                        parts.append(a[s - int(starts[i]):
+                                       e - int(starts[i])])
+                have = sum(p.shape[0] for p in parts)
+                if have < hi - lo:      # zero tail pad on this device
+                    parts.append(np.zeros((hi - lo - have,) + rest,
+                                          dtype=arrs[0].dtype))
+                block = np.ascontiguousarray(
+                    parts[0] if len(parts) == 1
+                    else np.concatenate(parts))
+            blocks.append(jax.device_put(block, dev))
         return jax.make_array_from_single_device_arrays(shape, sh,
                                                         blocks)
 

@@ -172,12 +172,17 @@ def run_with_deadline(fn: Callable, deadline_s: float,
     re-raises unchanged in the caller."""
     if deadline_s is None or deadline_s <= 0:
         return fn(*args, **kwargs)
+    from ..telemetry import TELEMETRY
     box: dict = {}
     done = threading.Event()
+    # the set-up stages the worker runs hand their time to the stage
+    # that waits here, so its own time does not count them twice
+    waiting = TELEMETRY.current_stage()
 
     def _work():
         try:
-            box["result"] = fn(*args, **kwargs)
+            with TELEMETRY.stage_of(waiting):
+                box["result"] = fn(*args, **kwargs)
         except BaseException as e:  # noqa: BLE001 - relayed to caller
             box["error"] = e
         finally:

@@ -38,6 +38,7 @@ from ..config import Config
 from ..data_loader import split_sample_columns
 from ..parallel.collectives import HostCollectives
 from ..reliability.faults import FAULTS
+from ..telemetry import TELEMETRY
 from ..utils.log import Log
 
 
@@ -84,16 +85,18 @@ def collect_candidates(shard: np.ndarray, config: Optional[Config],
     shard = np.asarray(shard)
     n = shard.shape[0]
     quota = shard_sample_quota(cfg, world)
-    if n > quota:
-        rng = np.random.RandomState(cfg.data_random_seed + 7919 * rank)
-        idx = rng.choice(n, size=quota, replace=False)
-        idx.sort()
-        sample = shard[idx]
-    else:
-        sample = shard
-    # only the sampled rows are widened, never the shard
-    sample = np.asarray(sample, dtype=np.float64)
-    vals, rows = split_sample_columns(sample)
+    with TELEMETRY.stage("sample", rows=int(n)):
+        if n > quota:
+            rng = np.random.RandomState(cfg.data_random_seed
+                                        + 7919 * rank)
+            idx = rng.choice(n, size=quota, replace=False)
+            idx.sort()
+            sample = shard[idx]
+        else:
+            sample = shard
+        # only the sampled rows are widened, never the shard
+        sample = np.asarray(sample, dtype=np.float64)
+        vals, rows = split_sample_columns(sample)
     return BoundaryCandidates(rank, n, sample.shape[0], vals, rows)
 
 

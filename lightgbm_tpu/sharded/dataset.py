@@ -297,18 +297,21 @@ class ShardedDataset(CoreDataset):
             f"Column_{i}" for i in range(num_features)]
 
         # the sample dataset._sample_feature_values draws from one matrix
-        sample_cnt = config.bin_construct_sample_cnt
-        if num_data > sample_cnt:
-            idx = np.random.RandomState(config.data_random_seed).choice(
-                num_data, size=sample_cnt, replace=False)
-            idx.sort()
-        else:
-            idx = np.arange(num_data)
-        cuts = np.searchsorted(idx, starts)
-        sample = np.concatenate(
-            [np.asarray(a[idx[cuts[i]:cuts[i + 1]] - starts[i]],
-                        dtype=np.float64) for i, a in enumerate(shards)])
-        sample_vals, sample_rows = split_sample_columns(sample)
+        with TELEMETRY.stage("sample", rows=num_data):
+            sample_cnt = config.bin_construct_sample_cnt
+            if num_data > sample_cnt:
+                idx = np.random.RandomState(
+                    config.data_random_seed).choice(
+                        num_data, size=sample_cnt, replace=False)
+                idx.sort()
+            else:
+                idx = np.arange(num_data)
+            cuts = np.searchsorted(idx, starts)
+            sample = np.concatenate(
+                [np.asarray(a[idx[cuts[i]:cuts[i + 1]] - starts[i]],
+                            dtype=np.float64)
+                 for i, a in enumerate(shards)])
+            sample_vals, sample_rows = split_sample_columns(sample)
         self.mappers = self._fit_mappers(sample_vals, sample.shape[0],
                                          config,
                                          set(categorical_features or []))

@@ -541,6 +541,80 @@ class _Span:
         return False
 
 
+def read_rss_mb() -> Optional[float]:
+    """The resident set in MiB, a /proc read (None without /proc)."""
+    try:
+        with open("/proc/self/status") as f:
+            for ln in f:
+                if ln.startswith("VmRSS:"):
+                    return round(int(ln.split()[1]) / 1024, 1)
+    except (OSError, ValueError, IndexError):
+        pass
+    return None
+
+
+# the package's import, clocked by lightgbm_tpu/__init__.py whatever the
+# mode (telemetry is ``off`` while it runs): the first ``configure``
+# that turns counters on publishes it as the stages ``import`` and
+# ``import_sklearn`` (``Telemetry._publish_import``)
+_IMPORT: Dict[str, float] = {}
+
+
+def note_import(t0: float, t1: float, rss_before: Optional[float],
+                sklearn_s: float) -> None:
+    """``lightgbm_tpu/__init__.py``'s first and last line on the
+    ``perf_counter`` clock, the resident set at each, and the seconds
+    its ``from .sklearn import`` took."""
+    _IMPORT.update(ms=(t1 - t0) * 1e3, sklearn_ms=sklearn_s * 1e3,
+                   rss_before=rss_before, rss_after=read_rss_mb())
+
+
+class _Stage:
+    """An open stage on its thread's stack (``Telemetry.stage``)."""
+    __slots__ = ("parent", "split", "t0", "nested_ms", "wall_ms")
+
+    def __init__(self, parent, compiles, since):
+        self.parent = parent
+        self.t0 = time.perf_counter() if since is None else since
+        self.split = _CompileSplit(compiles, self.t0) if compiles else None
+        self.nested_ms = 0.0
+        self.wall_ms = 0.0
+
+    @staticmethod
+    def pop(stack, frame) -> None:
+        """Take ``frame`` off ``stack``, and with it whatever an inner
+        stage that never closed left above it."""
+        while stack and stack.pop() is not frame:
+            pass
+
+
+class _CompileSplit:
+    """What jax.monitoring reports while a ``stage(compiles=prefix)``
+    is open, as stages told after the fact.  jax reports the jits
+    nested in a program too, each before the one that holds it, and a
+    trace can compile (an eager op on a constant): a duration (of
+    ``_MIN_STEP_S`` or more: the listener drops the rest) counts less
+    what was already booked inside it, whatever its kind, so the parts
+    never add up to more than the stage's wall."""
+    __slots__ = ("prefix", "t0", "booked")
+
+    def __init__(self, prefix, t0):
+        self.prefix = prefix
+        self.t0 = t0            # the stage's start: nothing is older
+        self.booked = []        # disjoint (start, end), in order of end
+
+    def told(self, kind: str, secs: float) -> None:
+        end = time.perf_counter()
+        start = max(end - secs, self.t0)
+        inside = 0.0
+        while self.booked and self.booked[-1][0] >= start:
+            s, e = self.booked.pop()
+            inside += e - s
+        self.booked.append((start, end))
+        TELEMETRY.stage_told(f"{self.prefix}_{kind}",
+                             max(0.0, end - start - inside) * 1e3)
+
+
 class Telemetry:
     """Process-global telemetry registry (module singleton
     ``TELEMETRY``).  All methods are cheap no-ops at ``off``."""
@@ -565,6 +639,7 @@ class Telemetry:
         self._traces: Dict[str, set] = {}
         self._retrace_warned: set = set()
         self._atexit_armed = False
+        self._import_published = False
         # cross-host identity: host_id resolves lazily (env override
         # LTPU_HOST_ID, else jax.process_index() IF jax is already
         # imported — a pure-host tool must not boot a backend);
@@ -595,6 +670,9 @@ class Telemetry:
                              f"got {mode!r}")
         with self._lock:
             self.mode = min(MODES.index(mode), _SPANS)
+            if self.mode >= _COUNTERS and _IMPORT \
+                    and not self._import_published:
+                self._publish_import()
             if not self.run_id:
                 import uuid
                 self.run_id = uuid.uuid4().hex[:12]
@@ -608,6 +686,18 @@ class Telemetry:
                     self._atexit_armed = True
                     atexit.register(self._export_atexit)
         return self
+
+    def _publish_import(self) -> None:
+        """The package's import as the stages ``import`` (own time) and
+        ``import_sklearn`` (nested in it): once per process, since the
+        import ran once."""
+        self._import_published = True
+        imp = _IMPORT
+        self.add("setup_import_ms", imp["ms"] - imp["sklearn_ms"])
+        self.add("setup_import_sklearn_ms", imp["sklearn_ms"])
+        for when in ("before", "after"):
+            if imp[f"rss_{when}"] is not None:
+                self.gauge(f"rss_mb_{when}_import", imp[f"rss_{when}"])
 
     def reset(self) -> None:
         """Clear recorded state (events, counters, gauges, retrace
@@ -735,8 +825,15 @@ class Telemetry:
         if t0 is not None:
             self._record(name, t0, time.perf_counter() - t0, 0, attrs)
 
+    def _stage_stack(self) -> list:
+        st = getattr(self._tls, "stages", None)
+        if st is None:
+            st = self._tls.stages = []
+        return st
+
     @contextlib.contextmanager
-    def stage(self, name: str, **attrs):
+    def stage(self, name: str, compiles: str = "",
+              since: Optional[float] = None, **attrs):
         """A set-up stage that runs once per job (binning, upload, the
         grower's constructor): the span ``name``, plus — the part a
         benchmark at ``counters`` mode can read — its OWN wall time
@@ -744,30 +841,97 @@ class Telemetry:
         in counter ``setup_<name>_ms`` and the resident set around it
         in gauges ``rss_mb_before_<name>`` / ``rss_mb_after_<name>``
         (a /proc read each, which also raises ``rss_mb_peak``).
-        Nothing at ``off``."""
+        Yields the open stage (``wall_ms`` is set when it closes);
+        nothing at ``off``, where it yields None.
+
+        ``compiles="<prefix>"``: the stage wraps a dispatch that builds
+        a program, and what ``jax.monitoring`` reports of it on this
+        thread while the stage is open — trace, lowering, backend
+        compile or the cache's load — becomes the nested stages
+        ``<prefix>_trace`` / ``_lower`` / ``_compile``
+        (``watch_compile_cache`` registers the listener), so the
+        stage's own time is the rest: the enqueue.
+
+        ``since``: a ``perf_counter`` reading taken earlier on this
+        thread, with no stage between it and here; the stage counts
+        from there (an entry point whose first lines decide whether
+        telemetry is on, a dispatch that learns in its prep that it
+        builds a program)."""
         if self.mode < _COUNTERS:
-            yield
+            yield None
             return
         rss = self.sample_memory()
         if rss is not None:
             self.gauge(f"rss_mb_before_{name}", rss)
-        nested = getattr(self._tls, "stage_ms", None)
-        if nested is None:
-            nested = self._tls.stage_ms = []
-        nested.append(0.0)
-        t0 = time.perf_counter()
+        stack = self._stage_stack()
+        frame = _Stage(stack[-1] if stack else None, compiles, since)
+        stack.append(frame)
         try:
             with self.span(name, **attrs):
-                yield
+                yield frame
         finally:
-            total = (time.perf_counter() - t0) * 1e3
-            inside = nested.pop()
-            if nested:
-                nested[-1] += total
-            self.add(f"setup_{name}_ms", total - inside)
+            frame.wall_ms = (time.perf_counter() - frame.t0) * 1e3
+            _Stage.pop(stack, frame)
+            self._stage_done(frame.parent, name, frame.wall_ms,
+                             frame.nested_ms)
             rss = self.sample_memory()
             if rss is not None:
                 self.gauge(f"rss_mb_after_{name}", rss)
+
+    def _stage_done(self, parent, name: str, wall_ms: float,
+                    nested_ms: float = 0.0) -> None:
+        """Book a closed stage: its own time to its counter, its wall
+        to the stage it ran in.  Own time is floored at 0: stages that
+        worker threads ran side by side under one waiting stage can
+        hand it more than its wall."""
+        with self._lock:
+            if parent is not None:
+                parent.nested_ms += wall_ms
+        self.add(f"setup_{name}_ms", max(0.0, wall_ms - nested_ms))
+
+    def stage_told(self, name: str, ms: float) -> None:
+        """A stage whose duration is known only after the fact (the
+        compile listener's): ``ms`` to ``setup_<name>_ms`` and to the
+        stage open on this thread.  No span, no gauges."""
+        if self.mode < _COUNTERS:
+            return
+        stack = self._stage_stack()
+        self._stage_done(stack[-1] if stack else None, name, ms)
+
+    def current_stage(self):
+        """The innermost stage open on this thread (None at ``off`` or
+        outside every stage), to hand to ``stage_of`` on a worker."""
+        stack = self._stage_stack()
+        return stack[-1] if stack else None
+
+    @contextlib.contextmanager
+    def stage_of(self, frame):
+        """On a worker thread: run under ``frame``, a stage open on the
+        thread that waits for this one (``current_stage()`` there), so
+        that the stages the worker runs hand their time to it and its
+        own time does not count them twice."""
+        if frame is None:
+            yield
+            return
+        stack = self._stage_stack()
+        stack.append(frame)
+        try:
+            yield
+        finally:
+            _Stage.pop(stack, frame)
+
+    def stage_fence(self, x) -> None:
+        """The closing fence of a device stage (``upload``, ``binsT``):
+        ``block_until_ready`` of what the stage placed, made only with
+        telemetry on so that the stage ends when the device has the
+        data.  The wait is part of the stage's own time; counter
+        ``setup_fence_ms`` says how much of the stages' time it is."""
+        if self.mode < _COUNTERS:
+            return
+        import jax
+        t0 = time.perf_counter()
+        jax.block_until_ready(x)
+        self.add("setup_fence_ms", (time.perf_counter() - t0) * 1e3)
 
     def _record(self, name, t0, dur, depth, attrs):
         if self.flight.out:
@@ -939,16 +1103,9 @@ class Telemetry:
         set-up stage — a /proc read per call."""
         if self.mode < _COUNTERS:
             return None
-        rss = None
-        try:
-            with open("/proc/self/status") as f:
-                for ln in f:
-                    if ln.startswith("VmRSS:"):
-                        rss = round(int(ln.split()[1]) / 1024, 1)
-                        self.gauge_max("rss_mb_peak", rss)
-                        break
-        except (OSError, ValueError, IndexError):
-            pass
+        rss = read_rss_mb()
+        if rss is not None:
+            self.gauge_max("rss_mb_peak", rss)
         if device:
             try:
                 import jax
@@ -1309,6 +1466,15 @@ _CACHE_EVENT_COUNTERS = {
     "/jax/compilation_cache/cache_hits": "compile_cache_hits",
     "/jax/compilation_cache/cache_misses": "compile_cache_misses",
 }
+# the three steps of building a program, as jax.monitoring times them
+# (jax._src.dispatch): the kinds of a ``stage(compiles=...)``'s parts
+_COMPILE_STEP_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_MIN_STEP_S = 1e-3      # a shorter step is left to the one that holds it
 _CACHE_WATCH = {"armed": False}
 
 
@@ -1318,14 +1484,41 @@ def _compile_cache_event(event: str, **kwargs) -> None:
         TELEMETRY.add(name, 1)
 
 
+def _compile_duration_event(event: str, secs: float, **kwargs) -> None:
+    # jax reports every jit nested in a program, thousands a chunk
+    # program and nearly all under a millisecond: those return here, at
+    # the cost of a lookup and a compare.  Their time stays where it
+    # falls, inside the duration of the step that holds them.
+    kind = _COMPILE_STEP_EVENTS.get(event)
+    if kind is not None and secs < _MIN_STEP_S:
+        return
+    tm = TELEMETRY
+    if tm.mode < _COUNTERS:
+        return
+    if kind is None:
+        if event == _CACHE_LOAD_EVENT:
+            # part of backend_compile_duration on a hit: a counter of
+            # its own, not a stage beside ``<prefix>_compile``
+            tm.add("compile_cache_load_ms", secs * 1e3)
+        return
+    for frame in reversed(tm._stage_stack()):
+        if frame.split is not None:
+            frame.split.told(kind, secs)
+            return
+
+
 def watch_compile_cache() -> None:
-    """Register the jax monitoring listener mapping persistent-cache
-    hit/miss events to ``compile_cache_hits``/``compile_cache_misses``
-    counters.  Idempotent."""
+    """Register the jax monitoring listeners: persistent-cache hit/miss
+    events to the ``compile_cache_hits``/``compile_cache_misses``
+    counters, the cache's load time to ``compile_cache_load_ms``, and
+    the trace / lower / compile durations to the parts of an open
+    ``stage(compiles=...)``.  Idempotent."""
     if _CACHE_WATCH["armed"]:
         return
     import jax.monitoring
     jax.monitoring.register_event_listener(_compile_cache_event)
+    jax.monitoring.register_event_duration_secs_listener(
+        _compile_duration_event)
     _CACHE_WATCH["armed"] = True
 
 

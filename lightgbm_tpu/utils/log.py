@@ -6,7 +6,6 @@
 from __future__ import annotations
 
 import sys
-import time
 
 
 class LightGBMError(RuntimeError):
@@ -61,30 +60,3 @@ class Log:
     def fatal(cls, msg: str) -> None:
         cls._emit("Fatal", msg)
         raise LightGBMError(msg)
-
-
-class PhaseTimer:
-    """Per-phase accumulated wall-clock timing, the analog of the
-    reference's TIMETAG chrono counters (gbdt.cpp:21-29,
-    serial_tree_learner.cpp:13-20)."""
-
-    def __init__(self):
-        self.acc: dict[str, float] = {}
-        self._start: dict[str, float] = {}
-
-    def start(self, phase: str) -> None:
-        self._start[phase] = time.perf_counter()
-
-    def stop(self, phase: str) -> None:
-        t0 = self._start.pop(phase, None)
-        if t0 is not None:
-            dt = time.perf_counter() - t0
-            self.acc[phase] = self.acc.get(phase, 0.0) + dt
-            # mirror each phase into the telemetry counters so the
-            # TIMETAG accounting rides the same export as everything
-            # else (lazy import: telemetry imports this module)
-            from ..telemetry import TELEMETRY
-            TELEMETRY.add(f"phase_{phase}_ms", dt * 1e3)
-
-    def report(self) -> str:
-        return ", ".join(f"{k}={v:.3f}s" for k, v in sorted(self.acc.items()))

@@ -692,8 +692,14 @@ class GBDT:
                     self._chunk_keys = cache
                 keys = cache
             elif self._np_keys_ok:
-                keys = jnp.asarray(np.stack(
-                    [np.zeros(n_iters, np.uint32), seeds], axis=1))
+                # handed to the jitted chunk as numpy: its call path
+                # transfers it with no Python of its own, where
+                # ``jnp.asarray`` makes ~150 interpreter calls a chunk —
+                # 60% of a dispatch's, and a profiler trace's reduction
+                # scans every host event for every device gap (PERF.md
+                # §6 PR 38: the traced four-chip run)
+                keys = np.stack(
+                    [np.zeros(n_iters, np.uint32), seeds], axis=1)
             else:  # pragma: no cover - unexpected key layout
                 keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
             if self.config.feature_fraction >= 1.0:
@@ -1126,7 +1132,13 @@ class GBDT:
         host_plain, host_chunks, host_recs = jax.device_get(
             (stacked_plain, chunk_stacks, rec_stacks))
 
+        compact_rows = [0, 0]      # [in an active slot, streamed]
+
         def append_tree(arrs, shrinkage, bias):
+            if TELEMETRY.on:
+                active, streamed = self.grower.compact_pass_rows(arrs)
+                compact_rows[0] += active
+                compact_rows[1] += streamed
             t = Tree.from_grower_arrays(arrs, self.train_set)
             t.apply_shrinkage(shrinkage)
             if bias != 0.0:
@@ -1175,6 +1187,16 @@ class GBDT:
                         append_tree(arrs, shrinkage,
                                     bias0 if j == 0 else 0.0)
         TELEMETRY.add("trees_flushed", len(self.models) - n_before)
+        if compact_rows[1]:
+            # the compacting rungs' passes of the committed trees: rows
+            # put through the dots over rows streamed (the share of the
+            # job so far is the gauge)
+            TELEMETRY.add("hist_compact_active_rows", compact_rows[0])
+            TELEMETRY.add("hist_compact_streamed_rows", compact_rows[1])
+            total = TELEMETRY.counters()
+            TELEMETRY.gauge("hist_active_row_share",
+                            total["hist_compact_active_rows"]
+                            / total["hist_compact_streamed_rows"])
         TELEMETRY.end_span(span)
 
     # ------------------------------------------------------------------

@@ -456,6 +456,8 @@ class TreeGrower:
             TELEMETRY.gauge("grower.hist_kernel", plan.kernel)
             TELEMETRY.gauge("grower.hist_factored_rungs", ",".join(
                 f"{k}:{a}x{b}" for k, a, b in plan.factored_rungs))
+            TELEMETRY.gauge("grower.hist_compact_rungs", ",".join(
+                map(str, plan.compact_rungs)))
             TELEMETRY.gauge("grower.quantized", int(plan.quantized))
             # the split finder: its form, whether it reads the group
             # histogram itself, the scans it traces, and the fused
@@ -819,15 +821,17 @@ class TreeGrower:
 
         def factored_pass(k_cap, a):
             def go(leaf_id, slots):
-                from ..ops.histogram import \
-                    compute_group_histograms_fused_factored
+                from ..ops.histogram import (
+                    compact_shape, compute_group_histograms_fused_factored)
                 return self._on_row_shards(
                     functools.partial(
                         compute_group_histograms_fused_factored,
                         max_group_bin=B, k_cap=k_cap, a=a,
                         block=plan.block_factored,
                         interpret=plan.interpret,
-                        group_chunk=plan.group_chunk),
+                        group_chunk=plan.group_chunk,
+                        compact=compact_shape(k_cap, plan.block_factored)
+                        if k_cap in plan.compact_rungs else ()),
                     self.binsT, wT, scales, leaf_id, st.route_tab,
                     slots)
             return go
@@ -887,6 +891,58 @@ class TreeGrower:
         total, rows_i32, leaf2 = shard(binsT, wT, leaf_id, route_tab,
                                        slots)
         return (_scaled(total, scales), rows_i32), leaf2
+
+    # ------------------------------------------------------------------
+    def compact_pass_rows(self, arrs: Dict[str, np.ndarray]
+                          ) -> Tuple[int, int]:
+        """``(rows of an active slot, rows streamed)`` of one grown
+        tree's passes on the compacting rungs (``plan.compact_rungs``),
+        read off the tree on the host (``arrs``: its ``TreeArrays`` as
+        numpy): what ``hist_active_row_share`` is made of.
+
+        The kernel holds these counts a unit, but to carry them out of
+        it takes an output a pass, an add a round and, under a mesh, a
+        sum over the shards: device work in every pass for a number the
+        committed tree already holds.  A round splits every leaf it can
+        up to the frontier's width, in node order, so split ``s`` is in
+        the round after its parent's, or in a later one where that one
+        was full; a round's right children are the slots of the next
+        round's pass, which is made unless the tree ended on its leaf
+        budget; and a right child's rows are its count in the tree (the
+        rows in the bag, where there is one)."""
+        m = int(arrs["num_leaves"]) - 1
+        if not self.plan.compact_rungs or m <= 0 or self._is_voting \
+                or self._is_feature_par:
+            return 0, 0
+        rungs = [k for k, _, _ in self.plan.factored_rungs
+                 if k <= self.frontier]
+        right = arrs["node_right"][:m]
+        rows = np.where(right < 0, arrs["leaf_count"][~np.minimum(right, -1)],
+                        arrs["node_count"][np.maximum(right, 0)])
+        parent = np.full(m, -1)
+        for side in (arrs["node_left"][:m], right):
+            parent[side[side >= 0]] = np.nonzero(side >= 0)[0]
+        rounds = []                     # [slots, their rows] a round
+        round_of = np.zeros(m, np.int64)
+        for s in range(m):
+            r = 0 if s == 0 else max(round_of[s - 1],
+                                     round_of[parent[s]] + 1)
+            if r < len(rounds) and rounds[r][0] == self.frontier:
+                r += 1
+            if r == len(rounds):
+                rounds.append([0, 0])
+            round_of[s] = r
+            rounds[r][0] += 1
+            rounds[r][1] += int(rows[s])
+        if m + 1 >= self.num_leaves:
+            rounds.pop()                # no pass follows the budget's end
+        active = streamed = 0
+        for slots, slot_rows in rounds:
+            rung = next((k for k in rungs if k >= slots), None)
+            if rung in self.plan.compact_rungs:
+                active += slot_rows
+                streamed += self.n_padded
+        return active, streamed
 
     # ------------------------------------------------------------------
     def emit_tree_record(self, tree: TreeArrays) -> jax.Array:

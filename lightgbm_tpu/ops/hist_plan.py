@@ -36,8 +36,8 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 from .histogram import (CHUNK_VMEM_LIMIT, PACKED_STRIP, ROUTE_ROWS,
-                        _factored_rows, _round_up, factored_rungs,
-                        quant_row_segments, quant_rows_ok,
+                        _factored_rows, _round_up, compact_shape,
+                        factored_rungs, quant_row_segments, quant_rows_ok,
                         tiled_hist_width)
 
 #: frontier slots the fused kernels serve: three packed strips, and the
@@ -99,6 +99,9 @@ class HistPlan:
     # routes read the table's split rows: histogram.gather_split_rows)
     num_groups: int               # the table's groups
     factored_rungs: Tuple[Tuple[int, int, int], ...]  # rungs in force
+    compact_rungs: Tuple[int, ...]  # slot caps of those that bring a
+    # block's active rows to the front before the dot
+    # (histogram.COMPACT_RUNGS: the rung table's, no option reaches it)
     finder: str                   # "fused" | "xla": the numerical split
     # finder's form (ops/split_kernel.py), fused with the Pallas tiers
     warnings: Tuple[str, ...]     # for the caller to log, in order
@@ -162,17 +165,27 @@ def factored_vmem_bytes(rung: Tuple[int, int, int], groups: int,
     where the group axis is chunked or the rows are segmented, so
     twice; the whole array, once, where neither), the four scratch rows
     a group, the uint8 block in its two buffers and its int32 copies
-    (bins, key, lo), and a chunked pass's split rows."""
+    (bins, key, lo), and a chunked pass's split rows; on a compacting
+    rung also the slot side's weight rows (a scratch there), the
+    permutation of a unit and the unit's rows before and after the
+    move."""
     k_cap, a, b = rung
     pack = 128 // b
-    acc = -(-groups // pack) * pack * 4 * _factored_rows(k_cap, a)[1] \
-        * 128 * 4
-    per_block = groups * (4 * 4 + 2 + 3 * 4)
-    if chunked:
-        per_block += ROUTE_ROWS * (2 + 4)
+    rows_w = _factored_rows(k_cap, a)[1]
+    acc = -(-groups // pack) * pack * 4 * rows_w * 128 * 4
     if chunked or segmented:
         acc *= 2
-    return acc + per_block * block
+    route = ROUTE_ROWS * (2 + 4) if chunked else 0
+    compact = compact_shape(k_cap, block)
+    if not compact:
+        return acc + (groups * (4 * 4 + 2 + 3 * 4) + route) * block
+    # the bins' int32 copy is the one-chunk route's alone, key and lo
+    # are a unit's, and so are the moved rows (bytes, and int32 twice)
+    # and the permutation (its words: the int8 operand is their bitcast)
+    unit = compact[0]
+    per_block = groups * (4 * 4 + 2 + (0 if chunked else 4)) + rows_w * 4
+    per_unit = groups * 2 * 4 + (32 + groups) * (1 + 2 * 4) + unit
+    return acc + (per_block + route) * block + per_unit * unit
 
 
 def _group_chunk(rungs, num_groups: int, block: int,
@@ -449,5 +462,7 @@ def resolve_hist_plan(config, *, on_tpu: bool,
         group_chunk=group_chunk, num_groups=num_groups,
         # in force only where a group fills a 256-lane tile
         factored_rungs=rungs,
+        compact_rungs=tuple(k for k, _, _ in rungs
+                            if compact_shape(k, block_factored)),
         finder="xla" if tier == "xla" else "fused",
         warnings=tuple(warnings))

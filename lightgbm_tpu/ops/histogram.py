@@ -1271,6 +1271,20 @@ CHUNK_VMEM_LIMIT = 100 << 20
 #: one trip's operand builds under the dots before them)
 _FACTORED_UNROLL = 8
 
+#: slot caps of the rungs that COMPACT a block's rows before the dot: a
+#: pass builds the new right children only, so about half of a wide
+#: round's rows are in none of its slots, and their columns of the
+#: slot-side operand are zeros the MXU multiplies at full price.  These
+#: rungs bring the rows of an active slot to the front of each
+#: ``COMPACT_UNIT`` rows (a prefix sum and one permutation product) and
+#: contract over those alone, in steps of ``COMPACT_STEP``.  The
+#: narrower rungs are bound by their operand builds, not by the dot,
+#: and keep the uncompacted body.  Settled on the chip: PERF.md, PR 38;
+#: docs/ROOFLINE.md has the cost model and the sweep.
+COMPACT_RUNGS = (32, 64, 126)
+COMPACT_UNIT = 1024
+COMPACT_STEP = 128
+
 
 def factored_rungs(max_group_bin: int, packed_groups: int = 0):
     """The factored rung table in force for a bin matrix: the module's
@@ -1290,9 +1304,19 @@ def _factored_rows(k_cap: int, a: int):
     return per_channel, _round_up(3 * per_channel, 8)
 
 
+def compact_shape(k_cap: int, block: int):
+    """``(unit, step)`` of a rung that compacts its blocks' rows — rows
+    a permutation product moves at once, and columns a step of the
+    shortened contraction — or ``()`` for a rung that does not."""
+    if k_cap not in COMPACT_RUNGS:
+        return ()
+    unit = min(COMPACT_UNIT, block)
+    return unit, min(COMPACT_STEP, unit)
+
+
 def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
                                   num_groups, nb, route_rows=0,
-                                  segment_blocks=0):
+                                  segment_blocks=0, compact=()):
     """Fused route + FACTORED int8 histogram, a rung of ``FACTORED_RUNGS``.
 
         hist[slot, ch, g, hi, lo] =
@@ -1322,13 +1346,21 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
     split rows (a second, narrow input) and never another chunk's.
 
     ``segment_blocks`` > 0: ``hist_ref`` is the accumulator of the row
-    segment the block lies in, begun every that many row blocks."""
+    segment the block lies in, begun every that many row blocks.
+
+    ``compact`` = ``(unit, step)`` (:func:`compact_shape`): the rows of
+    an active slot are brought to the front of every ``unit`` rows
+    before the operands are built, the four scratches hold a unit a
+    leading index, and a unit's dots contract over its count rounded up
+    to ``step`` columns; two more scratches, the slot side's weight rows
+    and the units' counts (SMEM).  The same sums: an int32 accumulator
+    does not count the zero columns it is spared."""
     from jax.experimental.pallas import tpu as pltpu
 
     if route_rows:
         rowsT_ref, *refs = refs
     (wT_ref, leafT_ref, routeT_ref, slots_ref, hist_ref, leaf_out_ref,
-     key4_ref, ksh_ref, lo4_ref, bit_ref) = refs
+     key4_ref, ksh_ref, lo4_ref, bit_ref, *compact_refs) = refs
     i = pl.program_id(1 if route_rows else 0)
 
     @pl.when((i % segment_blocks if segment_blocks else i) == 0)
@@ -1336,7 +1368,9 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
         hist_ref[:] = jnp.zeros_like(hist_ref)
 
     leaf = leafT_ref[:]                                  # (1, C) int32
-    binb = binsT_ref[:].astype(jnp.int32)                # (G, C)
+    # (a compacting rung of a chunked pass reads the block as bytes only)
+    binb = None if compact and route_rows \
+        else binsT_ref[:].astype(jnp.int32)              # (G, C)
     if route_rows:
         new_leaf = _route_prologue_T(
             rowsT_ref[:].astype(jnp.int32), leaf, routeT_ref[:],
@@ -1353,37 +1387,44 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
     sj = jax.lax.broadcasted_iota(jnp.int32, slots_ref.shape, 0) * a
     skey = jnp.max(jnp.where(slots_ref[:] == new_leaf, sj, -4096),
                    axis=0, keepdims=True)                # (1, C)
-    key = (binb >> (b.bit_length() - 1)) + skey          # slot*a + hi
-    lo = binb & (b - 1)
-    key4_ref[:] = key >> 2                               # word row ...
-    ksh_ref[:] = (key & 3) << 3                          # ... and byte
-    lo4_ref[:] = lo >> 2
-    bit_ref[:] = jnp.left_shift(jnp.ones((), jnp.int32), (lo & 3) << 3)
     w = wT_ref[:] & 0xFF                                 # (3, C) bytes
     # slot-side word row (ch, j // 4): channel ch's weight byte, moved
     # to byte j % 4 where the row's key is j; pad rows match no key
     sig = jax.lax.broadcasted_iota(jnp.int32, (rows_w, 1), 0)
     ch = jnp.where(sig < ch_w, 0, jnp.where(sig < 2 * ch_w, 1, 2))
-    wsel = jnp.where(ch == 0, w[0:1, :],
-                     jnp.where(ch == 1, w[1:2, :], w[2:3, :]))
     srow = jnp.where(sig < 3 * ch_w, sig - ch * ch_w, -7)
     liota = jax.lax.broadcasted_iota(jnp.int32, (b // 4, 1), 0)
     zero = jnp.zeros((), jnp.int32)
-    c = binb.shape[1]
+    one = jnp.ones((), jnp.int32)
+    c = leaf.shape[1]
 
-    def group_dot(t, gs):
-        """Accumulate tile ``t``: groups [t*pack, t*pack + gs)."""
+    def word_rows(binb, skey, w):
+        """The four per-group word rows (slot-side word row and byte
+        shift, lo-side word row and byte) and the slot side's weight
+        rows, of rows whose bins, slot keys and weight bytes these are."""
+        key = (binb >> (b.bit_length() - 1)) + skey      # slot*a + hi
+        lo = binb & (b - 1)
+        wsel = jnp.where(ch == 0, w[0:1, :],
+                         jnp.where(ch == 1, w[1:2, :], w[2:3, :]))
+        return (key >> 2, (key & 3) << 3, lo >> 2,
+                jnp.left_shift(one, (lo & 3) << 3)), wsel
+
+    def group_dot(t, gs, row, wsel, width):
+        """Accumulate tile ``t``: groups [t*pack, t*pack + gs), over
+        ``width`` rows, ``row(ref, g)`` the ``(1, width)`` row of group
+        ``g`` in a scratch and ``wsel()`` the weight rows."""
         lhs, rhs = [], []
         for p in range(gs):
             g = t * pack + p
             lhs.append(jnp.where(
-                srow == key4_ref[pl.ds(g, 1), :],
-                jnp.left_shift(wsel, ksh_ref[pl.ds(g, 1), :]), zero))
-            rhs.append(jnp.where(liota == lo4_ref[pl.ds(g, 1), :],
-                                 bit_ref[pl.ds(g, 1), :], zero))
+                srow == row(key4_ref, g),
+                jnp.left_shift(wsel(), row(ksh_ref, g)), zero))
+            rhs.append(jnp.where(liota == row(lo4_ref, g),
+                                 row(bit_ref, g), zero))
         if gs < pack:
-            lhs.append(jnp.zeros(((pack - gs) * rows_w, c), jnp.int32))
-            rhs.append(jnp.zeros(((pack - gs) * (b // 4), c), jnp.int32))
+            lhs.append(jnp.zeros(((pack - gs) * rows_w, width), jnp.int32))
+            rhs.append(jnp.zeros(((pack - gs) * (b // 4), width),
+                                 jnp.int32))
         lhs = jnp.concatenate(lhs) if pack > 1 else lhs[0]
         rhs = jnp.concatenate(rhs) if pack > 1 else rhs[0]
         hist_ref[t] += jax.lax.dot_general(
@@ -1393,28 +1434,99 @@ def _fused_kernel_body_q_factored(binsT_ref, *refs, k_cap, a, b,
     full_tiles = num_groups // pack
     unroll = _FACTORED_UNROLL
 
-    def trip(t, carry):
-        for j in range(unroll):
-            group_dot(t * unroll + j, pack)
+    def group_loop(row, wsel, width):
+        def trip(t, carry):
+            for j in range(unroll):
+                group_dot(t * unroll + j, pack, row, wsel, width)
+            return carry
+
+        jax.lax.fori_loop(0, full_tiles // unroll, trip, 0)
+        for t in range(full_tiles // unroll * unroll, full_tiles):
+            group_dot(t, pack, row, wsel, width)
+        if num_groups % pack:
+            group_dot(full_tiles, num_groups % pack, row, wsel, width)
+
+    if not compact:
+        words, wsel = word_rows(binb, skey, w)
+        for ref, word in zip((key4_ref, ksh_ref, lo4_ref, bit_ref), words):
+            ref[:] = word
+        group_loop(lambda ref, g: ref[pl.ds(g, 1), :], lambda: wsel, c)
+        return
+
+    # -- the compacting rungs (COMPACT_RUNGS) ---------------------------
+    # A unit's rows that are in an active slot are brought to its front:
+    # their places are the exclusive prefix sum of ``active``, and the
+    # move is ONE int8 product with the permutation P[j, r] = [r is
+    # active and its place is j], built four rows j to a word as the
+    # operands below are.  An output is one term, a byte times one, so
+    # the product is exact: the compacted rows are the rows.  The moved
+    # rows are the unit's bins and, above them, one word a row: its
+    # three weight bytes and its slot key + 1 (0: a column past the
+    # unit's count, which then matches no slot, as a padded row does).
+    unit, step = compact
+    wsel_ref, count_ref = compact_refs
+    head = (w[0:1, :] | (w[1:2, :] << 8) | (w[2:3, :] << 16)
+            | (jnp.where(skey >= 0, skey + 1, 0) << 24))  # (1, C)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, unit), 1)
+    pio = jax.lax.broadcasted_iota(jnp.int32, (unit // 4, 1), 0)
+    hio = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    for u in range(c // unit):
+        at = slice(u * unit, (u + 1) * unit)
+        active = (skey[:, at] >= 0).astype(jnp.int32)
+        place = active                   # inclusive, a doubling a step
+        d = 1
+        while d < unit:
+            place = place + jnp.where(
+                lane >= d, pltpu.roll(place, d, axis=1), 0)
+            d *= 2
+        count_ref[u] = jnp.sum(active)
+        place = place - active
+        perm = jnp.where(pio == jnp.where(active > 0, place >> 2, -1),
+                         jnp.left_shift(one, (place & 3) << 3), zero)
+        rows = jnp.concatenate([
+            pltpu.bitcast(jnp.where(hio == 0, head[:, at], zero),
+                          jnp.int8),                     # (32, unit)
+            pltpu.bitcast(binsT_ref[:, at], jnp.int8)])
+        moved = jax.lax.dot_general(
+            rows, pltpu.bitcast(perm, jnp.int8),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32) & 0xFF     # (32 + G, unit)
+        words, wsel = word_rows(
+            moved[32:, :],
+            jnp.where(moved[3:4, :] > 0, moved[3:4, :] - 1, -4096),
+            moved[0:3, :])
+        for ref, word in zip((key4_ref, ksh_ref, lo4_ref, bit_ref), words):
+            ref[u] = word
+        wsel_ref[u] = wsel
+
+    def unit_dots(u, carry):
+        # the dots contract over the unit's count, rounded up to a step
+        # (the columns past the count are rows of no slot)
+        n = count_ref[u]
+        # (a row read at a dynamic sublane is two lane tiles at the least)
+        first = max(step, min(256, unit))
+        for width in range(first, unit + 1, step):
+            @pl.when((n > (width - step if width > first else 0))
+                     & (n <= width))
+            def _dots(width=width):
+                group_loop(
+                    lambda ref, g: ref[u, pl.ds(g, 1), pl.ds(0, width)],
+                    lambda: wsel_ref[u, :, pl.ds(0, width)], width)
         return carry
 
-    jax.lax.fori_loop(0, full_tiles // unroll, trip, 0)
-    for t in range(full_tiles // unroll * unroll, full_tiles):
-        group_dot(t, pack)
-    if num_groups % pack:
-        group_dot(full_tiles, num_groups % pack)
+    jax.lax.fori_loop(0, c // unit, unit_dots, 0)
 
 
 @functools.partial(
     jax.jit, static_argnames=("max_group_bin", "block", "k_cap", "a",
                               "interpret", "dequantize", "group_chunk",
-                              "segment_rows"))
+                              "segment_rows", "compact"))
 def compute_group_histograms_fused_factored(
         binsT: jax.Array, wT: jax.Array, scales: jax.Array,
         leaf_id: jax.Array, route_tab: jax.Array, slots: jax.Array, *,
         max_group_bin: int, k_cap: int, a: int, block: int = 2048,
         interpret: bool = False, dequantize: bool = True,
-        group_chunk: int = 0, segment_rows: int = 0):
+        group_chunk: int = 0, segment_rows: int = 0, compact=()):
     """Fused route + factored int8 histogram, one rung of
     ``FACTORED_RUNGS``: the contract of
     :func:`compute_group_histograms_fused_tiled` for at most ``k_cap``
@@ -1434,7 +1546,14 @@ def compute_group_histograms_fused_factored(
     ``segment_rows``: as in the tiled kernel — past that many rows the
     pass writes one accumulator a row segment (the row blocks of a
     segment follow each other, under a chunk too) and returns
-    ``(segments, k_cap, G, B, 3)`` int32."""
+    ``(segments, k_cap, G, B, 3)`` int32.
+
+    ``compact``: ``(unit, step)`` (:func:`compact_shape`, for the rungs
+    the plan names) — the block's rows of an active slot are brought to
+    the front of every ``unit`` rows in VMEM and the dots contract over
+    them alone, in steps of ``step`` columns — or ``()``, every row
+    through the dot.  The same integers and the same ``new_leaf`` either
+    way: an int32 sum does not count its zero terms."""
     from jax.experimental.pallas import tpu as pltpu
 
     num_groups, n = binsT.shape
@@ -1448,6 +1567,10 @@ def compute_group_histograms_fused_factored(
         raise ValueError(f"group_chunk ({group_chunk}) must be a multiple "
                          "of 32, a tile of uint8 sublanes")
     chunk = group_chunk if chunked else num_groups
+    if compact and (block % compact[0] or compact[0] % compact[1]
+                    or compact[1] % 128):
+        raise ValueError(f"compact {compact}: a block ({block}) is whole "
+                         "units, a unit whole steps of whole lane tiles")
     segments, seg_blocks = _segment_blocks(n, block, segment_rows,
                                            dequantize)
     kp = _round_up(k_cap, 8)
@@ -1465,7 +1588,18 @@ def compute_group_histograms_fused_factored(
                              a=a, b=b, num_groups=chunk,
                              nb=route_tab.shape[1] - ROUTE_FIXED_COLS,
                              route_rows=ROUTE_ROWS if chunked else 0,
-                             segment_blocks=seg_blocks)
+                             segment_blocks=seg_blocks, compact=compact)
+    if compact:
+        # a unit's rows lead its scratch; the weight rows and the
+        # units' counts have scratches of their own
+        units = (block // compact[0], )
+        scratch = [pltpu.VMEM(units + (chunk, compact[0]), jnp.int32)
+                   for _ in range(4)] + [
+            pltpu.VMEM(units + (rows_w, compact[0]), jnp.int32),
+            pltpu.SMEM(units, jnp.int32)]
+    else:
+        scratch = [pltpu.VMEM((chunk, block), jnp.int32)
+                   for _ in range(4)]
 
     def at(by_chunk, by_rows):
         """Index map of a two-axis block that follows the grid's group
@@ -1506,8 +1640,7 @@ def compute_group_histograms_fused_factored(
                 + (num_tiles, pack * rows, 128), jnp.int32),
             jax.ShapeDtypeStruct((1, n), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((chunk, block), jnp.int32)
-                        for _ in range(4)],
+        scratch_shapes=scratch,
         # one chunk, one segment: the whole-array accumulator, which XLA
         # keeps in VMEM outside the kernel's scoped allocation; chunks
         # or segments: theirs is a pipelined block inside it

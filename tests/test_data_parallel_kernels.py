@@ -113,6 +113,39 @@ def test_shard_accumulators_add_up_to_the_whole(kernel, shape):
                           np.asarray(whole.astype(jnp.float32) * scales))
 
 
+@pytest.mark.parametrize("k_cap,active", [(32, 20), (64, 50), (126, 126)])
+def test_compacting_rung_under_shard_map(k_cap, active):
+    """A compacting rung inside ``shard_map``, a shard a block of two
+    units: every shard compacts its own rows, and the sum of the shards'
+    accumulators and the routed leaf ids are those of the uncompacted
+    formulation under the same mesh, and of one device."""
+    from lightgbm_tpu.learner.grower import _get_shard_map
+    rows = 4096
+    inputs = _pass_inputs(rows, slots=126, leaves=140, active=active)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    cols, by_row, rep = P(None, "data"), P("data"), P()
+
+    def run(compact, sharded=True):
+        kernel = functools.partial(
+            H.compute_group_histograms_fused_factored, k_cap=k_cap, a=2,
+            block=1024, max_group_bin=255, interpret=True,
+            dequantize=False, compact=compact)
+
+        def shard(binsT, wT, leaf, route, slots):
+            acc, leaf2 = kernel(binsT, wT, None, leaf, route, slots)
+            return jax.lax.psum(acc, "data"), leaf2
+        if not sharded:
+            return kernel(inputs[0], inputs[1], None, *inputs[2:])
+        return jax.jit(_get_shard_map()(
+            shard, mesh=mesh, in_specs=(cols, cols, by_row, rep, rep),
+            out_specs=(rep, by_row)))(*inputs)
+    got, plain, one = run((512, 128)), run(()), run((512, 128), False)
+    assert got[0].dtype == jnp.int32 and int(jnp.abs(got[0]).max()) > 0
+    for other in (plain, one):
+        for g, o in zip(got, other):
+            assert np.array_equal(np.asarray(g), np.asarray(o))
+
+
 # -- (b) the same trees for 1, 2 and 4 row shards, and for serial -------
 @pytest.mark.parametrize("shards", [1, 2, 4])
 def test_model_text_does_not_depend_on_the_shards(table, serial_trees,

@@ -224,6 +224,7 @@ def test_criteo_plan_is_the_one_before_the_group_chunk():
     assert (got.pop("row_segments"), got.pop("segment_rows")) \
         == (1, 1 << 24)
     assert got.pop("finder") == "fused"
+    assert got.pop("compact_rungs") == (32, 64, 126)
     assert got == dict(
         tier="ladder", interpret=False, row_axis=None, row_shards=1,
         local_rows=1 << 24, mesh_kernels=False, exchange_limbs=0,
@@ -256,6 +257,125 @@ def test_group_chunk_fits_the_budget_the_plan_states(groups, monkeypatch):
     monkeypatch.setattr(hist_plan, "CHUNK_VMEM_BUDGET", 1 << 20)
     assert _plan(**{**EPSILON, "num_groups": groups}).group_chunk == 32
     assert _plan(**CRITEO).group_chunk == 32
+
+
+# -- the compacting rungs: the rung table's, and counted in VMEM ----------
+@pytest.mark.fast
+@pytest.mark.parametrize("params,facts,want", [
+    (FAST, {**TPU, **CRITEO}, (32, 64, 126)),
+    (FAST, {**TPU, **EPSILON}, (32, 64, 126)),
+    ({**FAST, **SEAM}, CRITEO, (32, 64, 126)),
+    ({**FAST, **DATA}, {**TPU, **ROW_MESH, **CRITEO,
+                        "rows_padded": 4 << 24}, (32, 64, 126)),
+    # no rung, nothing to compact: narrow tiles, the float and xla tiers
+    (FAST, {**TPU, **EPSILON, "max_group_bin": 63}, ()),
+    (BF16, {**TPU}, ()),
+    ({}, {}, ()),
+], ids=["criteo", "epsilon", "seam", "row_mesh", "63_bins", "float_tier",
+        "xla_tier"])
+def test_compacting_rungs_follow_from_the_rung_table(params, facts, want,
+                                                     monkeypatch):
+    """Which rungs compact is the rung table's fact: the rungs in force
+    whose slot cap ``histogram.COMPACT_RUNGS`` names — no parameter
+    reaches it (every ``Config`` field may vary: the plans above differ
+    in tier, mesh and seam) — and an empty constant leaves a plan that
+    differs in nothing else."""
+    from lightgbm_tpu.ops import histogram
+    config = Config.from_params({"verbose": -1, **params})
+    plan = resolve_hist_plan(config, **{**FACTS, **facts})
+    assert plan.compact_rungs == want
+    assert set(want) <= {k for k, _, _ in plan.factored_rungs}
+    assert not [f for f in dataclasses.fields(Config)
+                if "compact" in f.name]
+    monkeypatch.setattr(histogram, "COMPACT_RUNGS", ())
+    bare = resolve_hist_plan(config, **{**FACTS, **facts})
+    assert bare == dataclasses.replace(plan, compact_rungs=())
+
+
+@pytest.mark.fast
+def test_compaction_scratch_is_counted_and_keeps_the_cells_chunks():
+    """``factored_vmem_bytes`` counts what a compacting rung holds
+    besides (the weight rows, a unit's permutation and moved rows): more
+    than the uncompacted body's, 67 groups still in one chunk (in row
+    segments too) and ``epsilon-2000``'s chunk, 96 groups as before,
+    under the budget."""
+    from lightgbm_tpu.ops import histogram
+    widest = FACTORED_RUNGS[-1]
+    assert histogram.compact_shape(widest[0], 4096) \
+        == (histogram.COMPACT_UNIT, histogram.COMPACT_STEP)
+    assert histogram.compact_shape(widest[0], 512) \
+        == (512, histogram.COMPACT_STEP)
+    assert histogram.compact_shape(16, 4096) == ()
+    for groups, chunked, segmented in [(67, False, False),
+                                       (67, False, True), (96, True, False)]:
+        cost = factored_vmem_bytes(widest, groups, 4096, chunked, segmented)
+        assert cost <= hist_plan.CHUNK_VMEM_BUDGET
+    assert _plan(**CRITEO).group_chunk == 67
+    assert _plan(**{**CRITEO, "rows_padded": 1 << 25}).group_chunk == 67
+    assert _plan(**EPSILON).group_chunk == 96
+    # the narrow rungs' bytes are what they were
+    assert factored_vmem_bytes(FACTORED_RUNGS[2], 67, 4096, False) \
+        == 67 * 4 * 24 * 128 * 4 + 67 * 30 * 4096
+
+
+def _right_child_rows_by_depth(tree):
+    """[(splits, rows of their right children)] a depth of one tree of
+    ``dump_model()``."""
+    levels = {}
+
+    def walk(node, depth):
+        if "left_child" in node:
+            right = node["right_child"]
+            n, rows = levels.get(depth, (0, 0))
+            levels[depth] = (n + 1, rows + right.get(
+                "internal_count", right.get("leaf_count")))
+            walk(node["left_child"], depth + 1)
+            walk(right, depth + 1)
+    walk(tree["tree_structure"], 0)
+    return [levels[d] for d in sorted(levels)]
+
+
+def test_compaction_gauges_are_published_and_are_the_trees_counts():
+    """``grower.hist_compact_rungs`` at grower set-up, and, when the
+    trees reach the host, ``hist_active_row_share``: rows of the new
+    right children over rows streamed, in the passes of the compacting
+    rungs — on a small table, where a depth is a round, the trees' own
+    right-child counts (a depth of 17-32 splits is a pass of the rung
+    at 32 slots, of 33-64 the one at 64; the last depth has no pass,
+    the tree ended on its leaf budget)."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.telemetry import TELEMETRY
+    import numpy as np
+    rng = np.random.RandomState(3)
+    X = rng.lognormal(size=(2048, 7)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] - X[:, 2] + 0.3 * rng.randn(2048)
+         > 0.5).astype(float)
+    try:
+        TELEMETRY.reset()
+        bst = lgb.train(
+            {"objective": "binary", "verbose": -1, "num_leaves": 100,
+             "min_data_in_leaf": 2, "max_bin": 255, **FAST, **SEAM,
+             "quant_stochastic_rounding": 1, "telemetry": "counters"},
+            lgb.Dataset(X, label=y), 3, verbose_eval=False)
+        gauges, counters = TELEMETRY.gauges(), TELEMETRY.counters()
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    # (the plan's, as grower.hist_factored_rungs: a frontier of 99 slots
+    # traces the rungs up to 64 and then strips, which do not compact)
+    assert gauges["grower.hist_compact_rungs"] == "32,64,126"
+    active = streamed = 0
+    for tree in bst.dump_model()["tree_info"]:
+        assert tree["num_leaves"] == 100
+        levels = _right_child_rows_by_depth(tree)
+        assert levels[0] == (1, levels[0][1]) and len(levels) >= 7
+        for splits, rows in levels[:-1]:
+            if 16 < splits <= 64:
+                active, streamed = active + rows, streamed + 2048
+    assert streamed >= 3 * 2048
+    assert counters["hist_compact_active_rows"] == active
+    assert counters["hist_compact_streamed_rows"] == streamed
+    assert 0 < gauges["hist_active_row_share"] == active / streamed < 1
 
 
 # -- the split finder's form: decided here, once ------------------------

@@ -301,12 +301,15 @@ def _factored_rung_cases():
 
 @functools.lru_cache(maxsize=None)
 def _factored_inputs(B, strips=1, dequantize=True, G=_FACT_G,
-                     split_groups=(0, 2, 5, 3)):
+                     split_groups=(0, 2, 5, 3), N=_FACT_N, rows="any",
+                     segment_rows=0):
     """One table a bin width and frontier: padded rows (leaf -1), a route
     table that moves rows by ``split_groups``' bins, quantized weights,
     and the tiled kernel's answer on ``strips`` strips for every slot of
     ``_FACT_SLOTS`` (one strip) or of as many of ``_FACT_SLOTS_WIDE`` as
-    the strips hold."""
+    the strips hold.  ``rows``: where the ``N`` rows start — in ``any``
+    leaf (some padded), ``slots``: every row in a leaf of the frontier,
+    or ``head``: the first 300 rows there and the rest in no leaf of it."""
     from lightgbm_tpu.ops.histogram import (
         PACKED_STRIP, compute_group_histograms_fused_tiled,
         quantize_gradients)
@@ -314,7 +317,6 @@ def _factored_inputs(B, strips=1, dequantize=True, G=_FACT_G,
                                             MISSING_ZERO,
                                             build_route_table)
     rng = np.random.RandomState(B)
-    N = _FACT_N
     # leaves the rows start in, the four that split, and the table's rows
     leaves, L = (20, 40) if strips == 1 else (140, 160)
     slots = _FACT_SLOTS if strips == 1 \
@@ -323,11 +325,18 @@ def _factored_inputs(B, strips=1, dequantize=True, G=_FACT_G,
     leaf = rng.randint(-1, leaves, N).astype(np.int32)
     if strips > 1:
         leaf[-8:] = -1
+    split = [0, 1, 2, 3] if strips == 1 else [7, 31, 64, 120]
+    if rows != "any":
+        # (the frontier's rows stay where they start: in no split leaf)
+        front = np.setdiff1d([v for v in slots if v >= 0], split)
+        rest = np.setdiff1d(np.arange(leaves), slots)
+        leaf = front[rng.randint(0, len(front), N)].astype(np.int32)
+        if rows == "head":
+            leaf[300:] = rest[rng.randint(0, len(rest), N - 300)]
     wq, scales = quantize_gradients(
         jnp.asarray(rng.randn(N).astype(np.float32)),
         jnp.asarray(np.abs(rng.randn(N)).astype(np.float32)),
         jnp.asarray((rng.rand(N) > 0.2).astype(np.float32)))
-    split = [0, 1, 2, 3] if strips == 1 else [7, 31, 64, 120]
     sm = np.zeros(L, bool)
     sm[split] = True
 
@@ -350,10 +359,11 @@ def _factored_inputs(B, strips=1, dequantize=True, G=_FACT_G,
     want_h, want_leaf = compute_group_histograms_fused_tiled(
         *args, jnp.asarray(np.array(slots, np.int32)),
         max_group_bin=B, block=256, strips=strips, interpret=True,
-        dequantize=dequantize)
+        dequantize=dequantize, segment_rows=segment_rows)
     want_leaf = np.asarray(want_leaf)
-    assert (want_leaf != leaf).sum() > (20 if strips == 1 else 5)
-    assert (want_leaf == -1).sum() > 5          # padded rows stay out
+    if rows == "any":
+        assert (want_leaf != leaf).sum() > (20 if strips == 1 else 5)
+        assert (want_leaf == -1).sum() > 5      # padded rows stay out
     return args, slots, np.asarray(want_h), want_leaf
 
 
@@ -439,6 +449,88 @@ def test_factored_group_chunks_equal_one_chunk_interpret(k_cap, a, k):
     np.testing.assert_array_equal(got_h[:k],
                                   np.asarray(want).astype(np.int32))
     assert got_h[0].any()               # the routed-to child has rows
+
+
+# ---------------------------------------------------------------------------
+# the compacting rungs (ops/histogram.py COMPACT_RUNGS): a unit's rows
+# of an active slot brought to its front before the dot
+# ---------------------------------------------------------------------------
+#: rows of the compaction's table: two blocks of 1,024 rows, a block two
+#: units of 512, a unit's dots over 256, 384 or 512 columns
+_COMPACT_N, _COMPACT = 2048, (512, 128)
+#: id -> (k_cap, active slots, the slots' own places in the frontier,
+#: where the rows start, groups, group_chunk, segment_rows)
+_COMPACT_CASES = {
+    # no row is in a slot of the pass: the frontier's two idle slots
+    "share0": (64, 2, (2, 70), "any", _FACT_G, 0, 0),
+    # 65 of 140 leaves: counts of 220-260 a unit, no multiple of a step
+    "half": (126, 65, None, "any", _FACT_G, 0, 0),
+    # every row active: every unit takes its full width
+    "share1": (126, 126, None, "slots", _FACT_G, 0, 0),
+    # the active rows lead the first unit; three units hold none
+    "one_unit": (126, 126, None, "head", _FACT_G, 0, 0),
+    # a frontier far narrower than the rung's cap
+    "narrow": (126, 3, None, "any", _FACT_G, 0, 0),
+    "k32": (32, 32, None, "any", _FACT_G, 0, 0),
+    # the group axis a grid axis: a unit is compacted once a chunk
+    "chunks": (32, 20, None, "any", _CHUNK_G, 32, 0),
+    # one accumulator a row segment of one block
+    "segments": (64, 40, None, "any", _FACT_G, 0, 1024),
+}
+
+
+def _compact_case(case):
+    """A case's arguments, its slots, and the tiled kernel's int32
+    accumulators and leaf ids on the strips the pass had."""
+    k_cap, k, places, rows, G, _, segment_rows = _COMPACT_CASES[case]
+    args, all_slots, want_h, want_leaf = _factored_inputs(
+        255, 3, False, G, (0, 2, 5, 3), _COMPACT_N, rows, segment_rows)
+    slots = np.full(126, -1, np.int32)
+    slots[:k] = [all_slots[p] for p in places or range(k)]
+    if places:                          # the tiled answer follows places
+        want_h = want_h[..., list(places), :, :, :]
+    return args, slots, want_h[..., :k, :, :, :], want_leaf
+
+
+@pytest.mark.parametrize("case", list(_COMPACT_CASES))
+def test_compacting_rung_equals_tiled_and_uncompacted_interpret(case):
+    """A compacting rung returns the integers of the tiled kernel and of
+    its own uncompacted formulation (the parent's), and the same leaf
+    ids: whatever share of a unit's rows is active, wherever they lie,
+    whatever the count leaves of a step, under group chunks and in row
+    segments."""
+    from lightgbm_tpu.ops.histogram import (
+        COMPACT_RUNGS, compute_group_histograms_fused_factored)
+    k_cap, k, _, _, G, group_chunk, segment_rows = _COMPACT_CASES[case]
+    assert k_cap in COMPACT_RUNGS
+    args, slots, want_h, want_leaf = _compact_case(case)
+
+    def run(compact):
+        h, leaf = compute_group_histograms_fused_factored(
+            *args, jnp.asarray(slots), max_group_bin=255, block=1024,
+            k_cap=k_cap, a=2, interpret=True, dequantize=False,
+            group_chunk=group_chunk, segment_rows=segment_rows,
+            compact=compact)
+        return np.asarray(h), np.asarray(leaf)
+    got_h, got_leaf = run(_COMPACT)
+    plain_h, plain_leaf = run(())
+    assert got_h.dtype == np.int32
+    assert got_h.shape == ((2,) if segment_rows else ()) \
+        + (k_cap, G, 255, 3)
+    np.testing.assert_array_equal(got_leaf, want_leaf)
+    np.testing.assert_array_equal(got_leaf, plain_leaf)
+    np.testing.assert_array_equal(got_h, plain_h)
+    np.testing.assert_array_equal(got_h[..., :k, :, :, :], want_h)
+    active = np.isin(want_leaf, slots[slots >= 0]).reshape(
+        -1, _COMPACT[0]).sum(1)
+    if case == "share0":
+        assert not active.any() and not got_h.any()
+    elif case == "share1":
+        assert (active == _COMPACT[0]).all()
+    elif case == "one_unit":
+        assert active[0] == 300 and not active[1:].any()
+    else:
+        assert (active % _COMPACT[1]).all() and got_h.any()
 
 
 def test_route_apply_split_rows_equal_whole_table_interpret():
@@ -532,6 +624,46 @@ def test_factored_rungs_grow_identical_trees(leaves, extra, monkeypatch):
         splits = _splits_by_depth(bst.dump_model()["tree_info"][-1])
         assert splits[:6] == [1, 2, 4, 8, 16, 32]
         assert 33 <= splits[6] <= 64 and 65 <= splits[7] <= 126
+
+
+def test_compacting_rungs_grow_identical_trees(monkeypatch):
+    """The same seed grows the same model, byte for byte, with the
+    rungs at 32, 64 and 126 slots compacting their blocks' rows and with
+    ``COMPACT_RUNGS`` patched empty (every row through the dot, the
+    parent's formulation): 255 leaves, so all three ran — the gauges say
+    which compact, and the share of rows they put through their dots is
+    a share."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops import histogram as H
+    from lightgbm_tpu.telemetry import TELEMETRY
+
+    rng = np.random.RandomState(3)
+    X = rng.lognormal(size=(3000, 7)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] - X[:, 2] + 0.3 * rng.randn(3000)
+         > 0.5).astype(float)
+
+    def model(compacting):
+        monkeypatch.setattr(H, "COMPACT_RUNGS", compacting)
+        TELEMETRY.reset()
+        bst = lgb.train(_fast_255(255, telemetry="counters"),
+                        lgb.Dataset(X, label=y), 3, verbose_eval=False,
+                        keep_training_booster=True)
+        assert bst.gbdt.grower.plan.compact_rungs == compacting
+        return bst, TELEMETRY.gauges()
+
+    try:
+        bst, gauges = model(H.COMPACT_RUNGS)
+        assert gauges["grower.hist_compact_rungs"] == "32,64,126"
+        assert 0.2 < gauges["hist_active_row_share"] < 0.8
+        plain, gauges = model(())
+        assert gauges["grower.hist_compact_rungs"] == ""
+        assert "hist_active_row_share" not in gauges
+    finally:
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    assert bst.model_to_string() == plain.model_to_string()
+    splits = _splits_by_depth(bst.dump_model()["tree_info"][-1])
+    assert splits[5] == 32 and 33 <= splits[6] <= 64 < splits[7] <= 126
 
 
 def test_factored_rungs_leave_narrow_tiles_alone(monkeypatch):

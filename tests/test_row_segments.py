@@ -111,6 +111,7 @@ def test_the_plan_past_the_bound_keeps_the_ladder_in_segments():
     # segment's two fields, field for field (tests/test_hist_plan.py)
     at = dataclasses.asdict(_plan(1 << 24))
     assert (at.pop("row_segments"), at.pop("segment_rows")) == (1, 1 << 24)
+    assert at.pop("compact_rungs") == H.COMPACT_RUNGS
     assert at == dict(
         tier="ladder", interpret=False, row_axis=None, row_shards=1,
         local_rows=1 << 24, mesh_kernels=False, exchange_limbs=0,

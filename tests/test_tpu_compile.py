@@ -57,13 +57,14 @@ def test_factored_rung_compiles_in_group_chunks(one_chip, plan, k_cap, a):
     groups x 401,408 rows, in the plan's chunks, under the VMEM limit the
     kernel asks for."""
     from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_fused_factored)
+        compact_shape, compute_group_histograms_fused_factored)
     sh = _shapes(one_chip)
     assert plan.group_chunks > 1
     compiled = compute_group_histograms_fused_factored.lower(
         sh["binsT"], sh["wT"], sh["scales"], sh["leaf"], sh["route"],
         sh["slots"], max_group_bin=BINS, k_cap=k_cap, a=a,
-        block=plan.block_factored, group_chunk=plan.group_chunk).compile()
+        block=plan.block_factored, group_chunk=plan.group_chunk,
+        compact=compact_shape(k_cap, plan.block_factored)).compile()
     text = compiled.as_text()
     assert f"compute_group_histograms_fused_factored_k{k_cap}_a{a}" in text
     # the route's split rows, and no copy of the table for them
@@ -139,7 +140,7 @@ def test_factored_rung_compiles_in_row_segments(one_chip, k_cap, a):
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.ops.hist_plan import LADDER_WIDTH, resolve_hist_plan
     from lightgbm_tpu.ops.histogram import (
-        compute_group_histograms_fused_factored)
+        compact_shape, compute_group_histograms_fused_factored)
     rows, groups = 1 << 25, 67
     plan = resolve_hist_plan(
         Config.from_params({"verbose": -1, "hist_compute_dtype": "bfloat16",
@@ -157,7 +158,8 @@ def test_factored_rung_compiles_in_row_segments(one_chip, k_cap, a):
             s((126,), jnp.int32))
     static = dict(max_group_bin=BINS, k_cap=k_cap, a=a,
                   block=plan.block_factored, group_chunk=plan.group_chunk,
-                  dequantize=False, segment_rows=plan.segment_rows)
+                  dequantize=False, segment_rows=plan.segment_rows,
+                  compact=compact_shape(k_cap, plan.block_factored))
     compiled = compute_group_histograms_fused_factored.lower(
         *args, **static).compile()
     assert f"compute_group_histograms_fused_factored_k{k_cap}_a{a}" \
@@ -166,6 +168,34 @@ def test_factored_rung_compiles_in_row_segments(one_chip, k_cap, a):
         compute_group_histograms_fused_factored, **static), *args)
     assert out[0].shape == (2, k_cap, groups, BINS, 3)
     assert out[0].dtype == jnp.int32
+
+
+@pytest.mark.parametrize("k_cap", [32, 64, 126])
+def test_compacting_rung_compiles_at_the_criteo_shape(one_chip, k_cap):
+    """The compacting rungs at 67 groups x 2^24 rows, one chunk of one
+    segment, as the plan shapes them (units of 1,024 rows, steps of 128
+    columns): Mosaic takes the lane rotations of the prefix sum, the
+    int8 permutation product, the units' counts in SMEM and the ladder
+    of dots at dynamic sublanes — what the interpret seam cannot say."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import (
+        COMPACT_STEP, COMPACT_UNIT, compact_shape,
+        compute_group_histograms_fused_factored)
+    import jax
+    rows, groups = 1 << 24, 67
+    assert compact_shape(k_cap, 4096) == (COMPACT_UNIT, COMPACT_STEP)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = compute_group_histograms_fused_factored.lower(
+        s((groups, rows), jnp.uint8), s((3, rows), jnp.int32), None,
+        s((rows,), jnp.int32),
+        s((LEAVES, 15 + (BINS + 7) // 8), jnp.float32),
+        s((126,), jnp.int32), max_group_bin=BINS, k_cap=k_cap, a=2,
+        block=4096, dequantize=False,
+        compact=compact_shape(k_cap, 4096)).compile()
+    assert f"compute_group_histograms_fused_factored_k{k_cap}_a2" \
+        in compiled.as_text()
 
 
 def test_tree_program_refreshes_at_the_width_of_its_rung(one_chip,

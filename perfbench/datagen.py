@@ -1,4 +1,9 @@
-"""Synthetic tables made from ``--seed``, one general generator per kind.
+"""Synthetic tables made from a seed, one general generator per kind.
+
+The harness hands a generator the configuration's ``table_seed``, never
+``--seed``: a configuration stands for one table, so the concept and the
+rows are the same in every run of its cells (README.md, "A
+configuration").
 
 ``binary_dense`` follows ``bench.make_data`` (repo root): standard normal
 draws z, a sparse linear concept in z plus one interaction, logistic label

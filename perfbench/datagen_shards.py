@@ -5,7 +5,8 @@ table the host should not hold twice.
 y)``: the same rows, block for block and bit for bit, that
 ``datagen.binary_dense(seed, rows, features)`` returns as one matrix
 (each 2^18-row block is drawn from its own stream keyed by (seed,
-block)), cut into four equal runs of whole blocks.  ``lgb.Dataset``
+block)), cut into four equal runs of whole blocks.  ``seed`` is the
+configuration's ``table_seed``, as for every generator.  ``lgb.Dataset``
 takes the list as it stands and bins it run by run, so that neither one
 18 GB matrix nor its float64 copy is ever made (2^26 x 67: 4.5 GB a
 run); the comparison gets the same list.  Four is the deployment's

@@ -57,12 +57,19 @@ def load_cell(workload):
                          f"{sorted(cells)}")
     cell = cells[workload]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    config = load_json(ROOT, cfg_entry["file"])
+    if not isinstance(config.get("table_seed"), int):
+        raise SystemExit(
+            f"{cfg_entry['file']} names no integer table_seed: a "
+            "configuration stands for one table, so every run of its cells "
+            "trains on the table drawn from that seed, and --seed draws only "
+            "the comparison's samples (README.md, 'A configuration')")
 
     def mine(m):
         return workload in m.get("workloads", [workload])
     return {
         "cell": cell,
-        "config": load_json(ROOT, cfg_entry["file"]),
+        "config": config,
         "traffic": load_json(HERE, "traffic", cell["traffic"] + ".json"),
         "limits": load_json(HERE, "limits", workload + ".json"),
         "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
@@ -155,9 +162,11 @@ def run_cell(loaded, seed, seconds, trace, devices, interpret=False,
         clocks[name] = time.perf_counter() - t
         return out
 
-    # -- set-up: data from the seed, binning, upload, compile, warm chunk
+    # -- set-up: the configuration's table, binning, upload, compile, warm
+    # chunk.  The table comes from the configuration's table_seed, not from
+    # --seed, so that every run of a cell does the same work.
     data = clocked("datagen", lambda: resolve(datagen.GENERATORS, cfg["generator"])(
-        seed, cfg["rows"], cfg["features"]))
+        cfg["table_seed"], cfg["rows"], cfg["features"]))
     params = dict(cfg["params"], telemetry="counters")
     if interpret:
         params["force_pallas_interpret"] = True
@@ -236,7 +245,9 @@ def run_cell(loaded, seed, seconds, trace, devices, interpret=False,
                                        "unit": m["unit"]}
     line["compared"] = {name: {"value": value, "limit": limit}
                         for name, value, limit, _ in verdict}
-    info = {"clocks": clocks, "setup_s": setup_s, "window_s": window_s,
+    info = {"table_seed": cfg["table_seed"], "seed": seed,
+            "model_sha256": got.get("model_sha256"),
+            "clocks": clocks, "setup_s": setup_s, "window_s": window_s,
             "gauges": {k: v for k, v in gauges.items() if k.startswith("grower.")},
             "counters": counters, "setup_counters": tracer.counters_before,
             "numbers": numbers, "verdict": verdict,

@@ -1,9 +1,10 @@
 """``criteo_r25_train`` at a size a test run can hold on the CPU: the
 configuration's own file with rows and leaves shrunk to test_correct.py's
-size (so small_limits.json's readings are this size's: row segments grow
-the one-segment trees), and the row segment of the int8 histogram forced
-down from 2^24 rows to 32,768 of the table's 40,960 padded ones (by the
-kernels' module constant, not by an option): two segments, the second
+size and its table drawn from SEED (so small_limits.json's readings are
+this size's: row segments grow the one-segment trees), and the row
+segment of the int8 histogram forced down from 2^24 rows to 32,768 of
+the table's 40,960 padded ones (by the kernels' module constant, not by
+an option): two segments, the second
 uneven; the reference's pass in three row super-blocks likewise.  Shared
 by tests/test_r25_cell.py (tier-1)."""
 import json
@@ -21,7 +22,7 @@ SUPER_ROWS = 16384         # three row super-blocks of the reference's pass
 
 def small_cell():
     loaded = run.load_cell("criteo_r25_train")
-    loaded["config"].update(rows=ROWS)
+    loaded["config"].update(rows=ROWS, table_seed=SEED)
     loaded["config"]["params"].update(num_leaves=LEAVES)
     loaded["traffic"]["dispatch_chunk"] = 2
     kind = loaded["limits"]["compare"]["kind"]       # the cell's own

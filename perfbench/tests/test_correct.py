@@ -23,7 +23,7 @@ SEED = 2147484001          # above 2**31 - 1, as the driver's can be
 
 def small_cell():
     loaded = run.load_cell("criteo_train")
-    loaded["config"].update(rows=40000)
+    loaded["config"].update(rows=40000, table_seed=SEED)
     loaded["config"]["params"].update(num_leaves=31)
     loaded["traffic"]["dispatch_chunk"] = 2
     with open(os.path.join(HERE, "small_limits.json")) as f:

@@ -30,7 +30,8 @@ SEED = 2147484001          # above 2**31 - 1, as the driver's can be
 
 def small_cell():
     loaded = run.load_cell("criteo_dp4_train")
-    loaded["config"].update(rows=40960)      # 10 x 1024 rows a shard
+    loaded["config"].update(rows=40960,      # 10 x 1024 rows a shard
+                            table_seed=SEED)
     loaded["config"]["params"].update(num_leaves=31)
     with open(os.path.join(HERE, "small_limits.json")) as f:
         loaded["limits"] = dict(json.load(f),

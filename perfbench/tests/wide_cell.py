@@ -1,6 +1,6 @@
 """``epsilon_train`` at a size a test run can hold on the CPU: the
-configuration's own file with rows, features and leaves shrunk, the
-factored kernel's group chunk forced down to 32 of its 40 groups (by the
+configuration's own file with rows, features and leaves shrunk and its
+table drawn from SEED, the factored kernel's group chunk forced down to 32 of its 40 groups (by the
 plan's module constant, not by an option), the limits of
 small_limits_wide.json.  Shared by tests/test_epsilon_cell.py (tier-1)
 and test_correct_wide.py."""
@@ -18,7 +18,7 @@ ROWS, FEATURES, LEAVES = 6000, 40, 15
 
 def small_cell():
     loaded = run.load_cell("epsilon_train")
-    loaded["config"].update(rows=ROWS, features=FEATURES)
+    loaded["config"].update(rows=ROWS, features=FEATURES, table_seed=SEED)
     loaded["config"]["params"].update(num_leaves=LEAVES,
                                       min_sum_hessian_in_leaf=5)
     loaded["traffic"]["dispatch_chunk"] = 2

@@ -11,6 +11,8 @@ closes, and returns
     end_to_end              {metric: value} of every end-to-end metric but setup_s
     answer                  what the timed path produced, for the comparison
     work                    what the window did, for the per-layer readers
+    model_sha256            optional: a digest of the model, printed under
+                            ``info`` (one table gives one model on every seed)
 
 ``train_steady``: steady-state training.  Set-up calls the public entry
 once, ``lgb.train(params, ds, num_boost_round=c,
@@ -21,6 +23,7 @@ trees.  The window continues THAT booster through the call
 after chunk, waiting for the scores after each, until ``--seconds`` have
 passed; it ends on a chunk boundary.
 """
+import hashlib
 import time
 
 import numpy as np
@@ -52,7 +55,8 @@ def train_steady(lgb, jax, traffic, params, ds, seconds, tracer):
             break
     tracer.stop()                         # the memory peak is read here
     n_window = chunks * c
-    trees = modeltext.parse(bst.model_to_string())
+    text = bst.model_to_string()
+    trees = modeltext.parse(text)
     scores = np.asarray(bst.gbdt.scores)[0, :ds.num_data()].astype(np.float64)
     window_trees = trees[c:c + n_window]
     failed += sum(1 for t in window_trees if t["num_leaves"] <= 1) \
@@ -62,7 +66,8 @@ def train_steady(lgb, jax, traffic, params, ds, seconds, tracer):
             "end_to_end": {"train_ms_per_tree": 1e3 * (t1 - t0) / n_window},
             "answer": {"trees": trees, "scores": scores,
                        "phase_starts": [0, c]},
-            "work": {"trees": window_trees, "n_trees": n_window}}
+            "work": {"trees": window_trees, "n_trees": n_window},
+            "model_sha256": hashlib.sha256(text.encode()).hexdigest()}
 
 
 KINDS = {"train_steady": train_steady}

@@ -88,19 +88,35 @@ def merged(intervals):
     return out
 
 
-def _host_label(host_lines, lo, hi):
-    """What the host was in for most of [lo, hi): its most specific
-    (shortest) span among those that overlap the gap the most."""
-    best = None
-    for line, events in host_lines.items():
-        for name, start, dur in events:
-            ov = min(hi, start + dur) - max(lo, start)
-            if ov <= 0:
-                continue
-            key = (-ov, dur)
-            if best is None or key < best[0]:
-                best = (key, f"{line}: {name}")
-    return best[1][:120] if best else "no host span in the trace"
+def _host_labels(host_lines, gaps):
+    """For each gap [lo, hi) of ``gaps`` (disjoint, in order of time): what
+    the host was in for most of it, its most specific (shortest) span
+    among those that overlap the gap the most, the first in line and
+    event order on a tie.
+
+    One sweep: host events enter in order of start once one starts
+    before a gap's end, and leave once they end at or before a gap's
+    start, which no later gap can undo; so a gap looks only at the events
+    that overlap it, not at every event of the trace."""
+    events = []
+    for line, evs in host_lines.items():
+        for name, start, dur in evs:
+            events.append((start, start + dur, dur, len(events),
+                           f"{line}: {name}"))
+    events.sort()
+    labels, live, i = [], [], 0
+    for lo, hi in gaps:
+        while i < len(events) and events[i][0] < hi:
+            live.append(events[i])
+            i += 1
+        live = [e for e in live if e[1] > lo]
+        best = None
+        for start, end, dur, order, label in live:
+            ov = min(hi, end) - max(lo, start)
+            if ov > 0 and (best is None or (-ov, dur, order) < best[0]):
+                best = ((-ov, dur, order), label)
+        labels.append(best[1][:120] if best else "no host span in the trace")
+    return labels
 
 
 def reduce(planes, patterns=()):
@@ -127,10 +143,10 @@ def reduce(planes, patterns=()):
             ops[key] = ops.get(key, 0.0) + dur / 1e9 / n
             if any(r.search(name) for r in regs):
                 matched += dur / 1e9 / n
-        host = planes.get(HOST_PLANE, {})
-        for (_, hi0), (lo1, _) in zip(spans, spans[1:]):
-            label = _host_label(host, hi0, lo1)
-            gaps[label] = gaps.get(label, 0.0) + (lo1 - hi0) / 1e9 / n
+        idle = [(hi0, lo1) for (_, hi0), (lo1, _) in zip(spans, spans[1:])]
+        for (lo, hi), label in zip(
+                idle, _host_labels(planes.get(HOST_PLANE, {}), idle)):
+            gaps[label] = gaps.get(label, 0.0) + (hi - lo) / 1e9 / n
     top = lambda d: [[k, v] for k, v in
                      sorted(d.items(), key=lambda kv: -kv[1])[:10]]
     return {"busy_s": busy, "matched_s": matched, "devices": n,

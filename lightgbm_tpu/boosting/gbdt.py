@@ -104,6 +104,8 @@ class GBDT:
                 self.objective.init(train_set.metadata, self.num_data)
 
         self.grower = TreeGrower(train_set, config)
+        self.grower.quantized_root_totals = bool(getattr(
+            self.objective, "quantized_root_totals", False))
         # multi-host (finalize_global): device metadata arrays must
         # follow the assembled per-host-padded row layout, sharded
         self._mh = self.grower._mh_local is not None

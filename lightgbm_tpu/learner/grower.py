@@ -958,6 +958,14 @@ class TreeGrower:
     #: rows of one block of the root's float32 totals: a shard on the
     #: kernel path holds whole blocks (its rows are padded to 1024)
     ROOT_TOTAL_BLOCK = 1024
+    #: take the root's totals from the quantized gradients and hessians,
+    #: the sums every histogram holds, rather than from the exact ones
+    #: (set from the objective's ``quantized_root_totals``).  Where they
+    #: differ, a child's sums are its parent's less a sibling's quantized
+    #: ones, so the whole table's rounding residue lands in one leaf a
+    #: level (ROADMAP A1); it stays for the objectives that leave it
+    #: unset, whose trees it would change
+    quantized_root_totals = False
 
     def _root_totals(self, grad, hess, counts):
         """(1, 3) float32 root totals on the ladder's path, every
@@ -1026,12 +1034,19 @@ class TreeGrower:
             return (hist_cache, jnp.zeros(hist_cache.shape[:3], jnp.int32))
         return hist_cache
 
-    def _init_state(self, grad, hess, counts, hist_pool=None
+    def _init_state(self, grad, hess, counts, hist_pool=None, quant=None
                     ) -> GrowerState:
+        """``quant`` (the tree's int8 weights and scales) where the
+        root's totals are to be the sums of the quantized gradients and
+        hessians the histograms hold (``quantized_root_totals``)."""
         L = self.num_leaves
         M = L - 1
         B = self.max_feature_bin
         leaf_id = jnp.where(self._row_valid, 0, -1).astype(jnp.int32)
+        if quant is not None:
+            wq, scales = quant
+            grad = wq[0].astype(jnp.float32) * scales[0]
+            hess = wq[1].astype(jnp.float32) * scales[1]
         if self.plan.tier == "ladder":
             totals = self._root_totals(grad, hess, counts)
         else:
@@ -1131,8 +1146,22 @@ class TreeGrower:
                           qkey=None, hist_pool=None):
         # every op of a tree lies under a tel.<phase> scope (innermost
         # wins where they nest), so a device trace splits by phase
+        quant = None
+        if not (self._is_voting or self._is_feature_par):
+            # gradients are fixed for the whole tree, so the int8
+            # quantization (one scale per channel) happens once here;
+            # qkey enables the stochastic rounding the skewed-gradient
+            # objectives need (see quantize_gradients)
+            with TELEMETRY.phase("quantize"):
+                if self.plan.quantized:
+                    wq, scales = quantize_gradients(grad, hess, counts,
+                                                    key=qkey)
+                    # the ladder streams weights lane-major
+                    quant = (wq.T, scales)                  # (3, N)
         with TELEMETRY.phase("init_state"):
-            state = self._init_state(grad, hess, counts, hist_pool)
+            state = self._init_state(
+                grad, hess, counts, hist_pool,
+                quant if self.quantized_root_totals else None)
         if self._is_voting:
             def body_fn(st):
                 # histograms and the vote run inside one shard_map
@@ -1145,18 +1174,6 @@ class TreeGrower:
                     return self._round_feature(st, grad, hess, counts,
                                                feature_mask)
         else:
-            # gradients are fixed for the whole tree, so the int8
-            # quantization (one scale per channel) happens once here;
-            # qkey enables the stochastic rounding the skewed-gradient
-            # objectives need (see quantize_gradients)
-            with TELEMETRY.phase("quantize"):
-                quant = None
-                if self.plan.quantized:
-                    wq, scales = quantize_gradients(grad, hess, counts,
-                                                    key=qkey)
-                    # the ladder streams weights lane-major
-                    quant = (wq.T, scales)                  # (3, N)
-
             def body_fn(st):
                 return self._round(st, grad, hess, counts, feature_mask,
                                    quant)

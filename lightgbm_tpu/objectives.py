@@ -6,8 +6,8 @@ objective_function.cpp:10-80; interface objective_function.h:13-80).
 Every objective is a pure vectorized function score -> (grad, hess)
 executed on device inside the jitted boosting step; the per-row OpenMP
 loops become elementwise array ops, and lambdarank's per-query sorted
-pairwise loop (rank_objective.hpp:83-170) becomes a vmapped masked
-O(max_query_len^2) kernel over padded queries.
+pairwise loop (rank_objective.hpp:83-170) becomes masked pair tiles over
+queries grouped in length buckets, each bucket padded to its own width.
 """
 from __future__ import annotations
 
@@ -20,6 +20,7 @@ import numpy as np
 
 from .config import Config
 from .dataset import Metadata
+from .telemetry import TELEMETRY
 from .utils.log import Log
 
 K_EPSILON = 1e-15
@@ -80,6 +81,9 @@ class Objective:
     # most values far below the per-tree max; see ops/histogram.py
     # quantize_gradients)
     need_stochastic_quant = False
+    # int8 quantized training: whether the grower takes the root's totals
+    # from the quantized gradients (TreeGrower.quantized_root_totals)
+    quantized_root_totals = False
 
     def __init__(self, config: Config):
         self.config = config
@@ -527,15 +531,16 @@ class CrossEntropyLambda(Objective):
 
 
 def _banded_take_plan(positions: np.ndarray, tile: int = 128):
-    """Plan an exact monotone permutation out[i] = x[positions[i]]
-    (ascending positions, -1 = emit 0) as per-``tile`` window takes +
-    one-hot matmuls.
+    """Plan an exact take out[i] = x[positions[i]] (-1 = emit 0) as
+    per-``tile`` window takes + one-hot matmuls.
 
-    Because valid positions ascend by exactly +1 (query rows are
-    consecutive in both the flat and the padded order), every
-    ``tile``-slot output tile reads from a 2-tile (2*128-element)
-    window of the input: lo = min valid position, hi <= lo + tile - 1,
-    so hi - (lo//tile)*tile <= (lo % tile) + tile - 1 < 2*tile.  This
+    The valid positions of every ``tile``-slot output tile must lie
+    within ``tile`` consecutive input rows (each row of lambdarank's
+    length buckets holds a contiguous run of the flat rows and is a
+    multiple of the tile wide), so the tile reads from a 2-tile
+    (2*128-element) window of the input: lo = min valid position,
+    hi <= lo + tile - 1, so hi - (lo//tile)*tile <= (lo % tile) +
+    tile - 1 < 2*tile.  This
     is what makes the padded<->flat movement MXU work instead of an
     XLA row gather (~80M rows/s on v5e — 28 ms per 2.26M-row pass).
 
@@ -614,22 +619,142 @@ def _banded_scatter(gh, wtiles, local, nt_in, chunk):
     return out.at[wtiles.reshape(-1)].add(parts, mode="drop")
 
 
+# -- lambdarank's layout: queries in length buckets ----------------------
+# Every row of the bucketed layout is RANK_ROW slots or a multiple of it,
+# and holds a contiguous run of the flat rows: one query, or (queries of
+# at most RANK_PACK rows) the short queries that start in one
+# RANK_PACK-row stretch.  A 128-slot tile of it therefore reads a span of
+# under 128 flat rows, which is what the banded permutation needs.
+RANK_ROW = 128
+RANK_PACK = 64
+RANK_TILE = 512           # widest side of a pair tile
+RANK_PAIR_BUDGET = 1 << 23   # pair slots one step of the pair loop holds
+
+
+def _rank_grain(m):
+    """Width granularity of a bucket whose longest query is ``m``."""
+    return RANK_ROW if m <= 2048 else 4 * RANK_ROW
+
+
+def _rank_ladder(longest):
+    """Bucket limits: 128, then up by a quarter, each a multiple of its
+    grain, until ``longest`` fits."""
+    ladder = [RANK_ROW]
+    while ladder[-1] < longest:
+        w = ladder[-1] * 5 // 4
+        ladder.append(-(-w // _rank_grain(w)) * _rank_grain(w))
+    return np.asarray(ladder)
+
+
+class _RankBucket:
+    """One length bucket: ``rows`` rows of ``width`` slots starting at
+    slot ``start`` of the bucketed vector; its pairs in tiles of
+    ``tile`` x ``tile``, ``chunk`` rows a step."""
+
+    def __init__(self, start, rows, width, packed):
+        self.start, self.width, self.packed = start, width, packed
+        self.tile = width if width <= RANK_TILE else next(
+            t for t in (512, 384, 256, 128) if width % t == 0)
+        self.k = width // self.tile
+        chunk = min(rows, max(1, RANK_PAIR_BUDGET // self.tile ** 2))
+        self.steps = -(-rows // chunk)
+        self.chunk = -(-rows // self.steps)
+        self.rows = self.steps * self.chunk
+        self.size = self.rows * width
+
+
+def rank_layout(qb: np.ndarray):
+    """The length buckets of queries ``qb`` (boundaries, ascending), as
+    (buckets, pos, seg): per slot of the bucketed vector the flat row it
+    holds (-1: none) and that row's query (Q past the end).  Queries of
+    one row make no pair and have no slot."""
+    sizes = np.diff(qb).astype(np.int64)
+    n, nq = int(qb[-1]), len(sizes)
+    starts = qb[:-1].astype(np.int64)
+    ranked = sizes >= 2
+    groups = []                           # (packed, row bases, width)
+    row_of = np.full(nq + 1, -1, np.int64)  # bucket row of each query
+    rows = 0
+    short = np.flatnonzero(ranked & (sizes <= RANK_PACK))
+    if len(short):
+        stretch, row = np.unique(starts[short] // RANK_PACK,
+                                 return_inverse=True)
+        row_of[short] = rows + row
+        groups.append((True, stretch * RANK_PACK, RANK_ROW))
+        rows += len(stretch)
+    long_q = np.flatnonzero(ranked & (sizes > RANK_PACK))
+    if len(long_q):
+        ladder = _rank_ladder(int(sizes[long_q].max()))
+        which = np.searchsorted(ladder, sizes[long_q])
+        for j in np.unique(which):
+            qs = long_q[which == j]
+            m = int(sizes[qs].max())
+            width = -(-m // _rank_grain(m)) * _rank_grain(m)
+            row_of[qs] = rows + np.arange(len(qs))
+            groups.append((False, starts[qs], width))
+            rows += len(qs)
+    buckets, pos, seg = [], [], []
+    start = row0 = 0
+    for packed, bases, width in groups:
+        b = _RankBucket(start, len(bases), width, packed)
+        p = bases[:, None] + np.arange(width)[None, :]
+        q = np.searchsorted(qb, p, side="right") - 1
+        q = np.where(p >= n, nq, q)
+        mine = row_of[q] == (row0 + np.arange(len(bases)))[:, None]
+        extra = ((0, b.rows - len(bases)), (0, 0))
+        pos.append(np.pad(np.where(mine, p, -1), extra, constant_values=-1))
+        seg.append(np.pad(q, extra, constant_values=nq))
+        buckets.append(b)
+        start += b.size
+        row0 += len(bases)
+    if not buckets:
+        return [], np.zeros(0, np.int64), np.zeros(0, np.int64)
+    cat = lambda parts: np.concatenate([x.reshape(-1) for x in parts])
+    return buckets, cat(pos), cat(seg)
+
+
+def inverse_max_dcg(qb, label, label_gain, k):
+    """1 / (DCG at ``k`` of each query's labels in their best order), 0
+    where that is 0 (reference dcg_calculator.cpp), without a loop over
+    queries."""
+    sizes = np.diff(qb)
+    q = np.repeat(np.arange(len(sizes)), sizes)
+    order = np.lexsort((-label, q))
+    place = np.arange(len(q)) - qb[q]
+    lab = label[order].astype(np.int64)
+    top = place < k
+    dcg = np.bincount(q[top], label_gain[lab[top]] / np.log2(place[top] + 2.0),
+                      minlength=len(sizes))
+    return np.where(dcg > 0, 1.0 / np.where(dcg > 0, dcg, 1.0), 0.0)
+
+
 class LambdarankNDCG(Objective):
     """reference rank_objective.hpp:19-200: per-query pairwise lambdas
-    with |ΔNDCG| weighting; the sorted O(n^2) pair loop becomes a masked
-    pairwise matrix per padded query, vmapped across queries.
+    with |ΔNDCG| weighting.
 
-    The flat<->padded score/gradient movement runs as banded
-    permutation matmuls (:func:`_banded_take_plan`): XLA's row
-    gather/scatter on TPU costs ~28 ms per 2.26M rows per pass (~87
-    ms/tree at the MS-LTR bench shape), while the banded form is
-    ~6x cheaper and exact."""
+    The queries are grouped by length into buckets (``rank_layout``),
+    each padded to its own width, so the pairs evaluated stay within a
+    small factor of the sum of squared query lengths (gauge
+    ``objective.rank_pair_padding``) whatever the longest query.  The
+    scores move flat -> bucketed and the lambdas back by one static
+    permutation each way, as banded one-hot products
+    (:func:`_banded_take_plan`: exact, and ~6x cheaper than XLA's row
+    gather on the chip).  A bucket's pairs are computed in tiles of at
+    most ``RANK_TILE`` x ``RANK_TILE`` slots and ``RANK_PAIR_BUDGET``
+    slots a step, so no intermediate grows with the longest query
+    squared."""
     name = "lambdarank"
     need_accurate_prediction = False
     # pairwise lambdas are long-tailed: deterministic int8 rounding
     # zeroes most of them (measured 0.33 vs 0.64 NDCG@10 at the
     # MS-LTR bench shape) — stochastic rounding restores the signal
     need_stochastic_quant = True
+    # a query's lambdas cancel, so the table's gradients sum to 0 and its
+    # hessians are long-tailed: the quantization's residue, booked into
+    # one leaf when the root's totals are exact, outweighs that leaf's
+    # own sums (a leaf value 100-400 times the reference's at 40,000 rows
+    # on the CPU seam: PERF.md section 6)
+    quantized_root_totals = True
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -646,156 +771,164 @@ class LambdarankNDCG(Objective):
         super().init(metadata, num_data)
         if metadata.query_boundaries is None:
             Log.fatal("Lambdarank tasks require query information")
-        qb = metadata.query_boundaries
+        qb = np.asarray(metadata.query_boundaries, np.int64)
         self.num_queries = len(qb) - 1
         sizes = np.diff(qb)
-        self.max_query = int(sizes.max())
+        self.max_query = int(sizes.max(initial=0))
         if np.any(self.label < 0) or \
                 int(self.label.max()) >= len(self.label_gain):
             Log.fatal("Label exceeds label_gain range in lambdarank")
-        # padded (Q, M) row-index matrix; -1 = padding
-        Q, M = self.num_queries, self.max_query
-        # query-chunked pairwise: the (Q, M, M) pair tensor at MS-LTR
-        # scale (~19k queries x 140 docs) would need tens of GB if
-        # materialized at once; chunks bound the live intermediate to
-        # ~128 MB and lax.map runs them sequentially
-        qc = max(1, min(Q, (1 << 25) // max(M * M, 1)))
-        q_pad = (Q + qc - 1) // qc * qc
-        self._q_chunk = qc
-        idx = np.full((q_pad, M), -1, dtype=np.int32)
-        for q in range(Q):
-            idx[q, :sizes[q]] = np.arange(qb[q], qb[q + 1])
-        self._qidx = jnp.asarray(idx)
-        self._qmask = jnp.asarray(idx >= 0)
-        # inverse max DCG at k per query (reference dcg_calculator.cpp)
-        inv = np.zeros(q_pad, dtype=np.float64)
-        for q in range(Q):
-            lab = np.sort(self.label[qb[q]:qb[q + 1]])[::-1]
-            k = min(self.optimize_pos_at, len(lab))
-            dcg = float(np.sum(self.label_gain[lab[:k].astype(np.int32)]
-                               / np.log2(np.arange(2, k + 2))))
-            inv[q] = 1.0 / dcg if dcg > 0 else 0.0
-        self._inv_max_dcg = jnp.asarray(inv.astype(np.float32))
-        self._label_gain_dev = jnp.asarray(
-            self.label_gain.astype(np.float32))
-        # per-row labels (and weights) gathered into padded layout ONCE
-        # — they are static across trees
-        safe = np.maximum(idx, 0)
-        self._qlabel = jnp.asarray(
-            self.label[safe].astype(np.float32) * (idx >= 0))
+        self._buckets, pos, seg = rank_layout(qb)
+        inv = inverse_max_dcg(qb, self.label, self.label_gain,
+                              self.optimize_pos_at)
+        # per slot, static across trees: label (-1 where no row), its
+        # gain, the query's inverse max DCG, the row's weight; its
+        # query (Q past the end)
+        mine = pos >= 0
+        row = np.where(mine, pos, 0)
+        lab = np.where(mine, self.label[row], -1.0)
+        q = np.minimum(seg, self.num_queries - 1)
+        cols = [lab, np.where(mine, self.label_gain[
+                    np.maximum(lab, 0).astype(np.int64)], 0.0),
+                np.where(mine, inv[q], 0.0)]
         if self.weight is not None:
-            self._qweight = jnp.asarray(
-                np.asarray(self.weight)[safe].astype(np.float32)
-                * (idx >= 0))
-        else:
-            self._qweight = None
-        # banded flat<->padded movement plan (see _banded_take_plan):
-        # positions = flattened qidx, padded to a 128-slot multiple and
-        # chunk-aligned so both lax.maps split evenly
-        flat_pos = idx.reshape(-1)
-        npos = len(flat_pos)
-        nt = -(-npos // 128)
-        # tile chunk bounds the per-step one-hot to ~67 MB at 512; tiny
-        # (test-sized) datasets keep their raw tile count instead of
-        # paying a 512-tile round-up
+            cols.append(np.where(mine, self.weight[row], 0.0))
+        self._slot_f_dev = jnp.asarray(np.stack(cols).astype(np.float32))
+        self._slot_seg_dev = jnp.asarray(seg[None].astype(np.int32))
+        # banded flat <-> bucketed plan over the slots, chunk-aligned
+        nt = max(1, -(-len(pos) // 128))
         self._tile_chunk = min(512, nt)
         nt = -(-nt // self._tile_chunk) * self._tile_chunk
-        flat_pos = np.concatenate(
-            [flat_pos, np.full(nt * 128 - npos, -1, np.int64)])
-        wtiles, local, nt_in_min = _banded_take_plan(flat_pos)
-        self._bp_wtiles = jnp.asarray(wtiles)
-        self._bp_local = jnp.asarray(local)
-        self._bp_nt_in_min = nt_in_min
-        self._bp_out_len = npos
+        self._n_slots = len(pos)
+        wtiles, local, self._bp_nt_in_min = _banded_take_plan(np.concatenate(
+            [pos, np.full(nt * 128 - len(pos), -1, np.int64)]))
+        self._bp_wtiles_dev = jnp.asarray(wtiles)
+        self._bp_local_dev = jnp.asarray(local)
+        pairs = float(np.sum(sizes.astype(np.float64) ** 2))
+        evaluated = float(sum(b.rows * b.width ** 2 for b in self._buckets))
+        self.pair_padding = evaluated / pairs if pairs else 1.0
+        TELEMETRY.gauge("objective.rank_buckets", len(self._buckets))
+        TELEMETRY.gauge("objective.rank_max_query", self.max_query)
+        TELEMETRY.gauge("objective.rank_pair_padding", self.pair_padding)
 
-    def _padded_scores(self, score):
-        """Flat (padded) training scores -> (q_pad, M) padded layout
-        via the banded plan; -inf outside valid slots."""
-        S = score.shape[0]
-        target = max(self._bp_nt_in_min, -(-S // 128)) * 128
-        if target != S:
-            score = jnp.pad(score, (0, target - S))
-        xt = score.reshape(-1, 128)
-        ps = _banded_gather(xt, self._bp_wtiles, self._bp_local,
-                            self._tile_chunk)
-        ps = ps.reshape(-1)[:self._bp_out_len]
-        q_pad, M = self._qidx.shape
-        ps = ps.reshape(q_pad, M)
-        return jnp.where(self._qmask, ps, -jnp.inf)
+    @staticmethod
+    def _above(si, sj, i0, j0, ok):
+        """Per i-slot of a tile, how many j-slots with ``ok`` rank above
+        it: a higher score, or an equal one on an earlier row (the
+        rank is stable in the row order).  ``i0``/``j0``: the tiles'
+        first slots in the row."""
+        t = si.shape[1]
+        earlier = (j0 + jnp.arange(t))[None, None, :] \
+            < (i0 + jnp.arange(t))[None, :, None]
+        before = (sj[:, None, :] > si[:, :, None]) \
+            | ((sj[:, None, :] == si[:, :, None]) & earlier)
+        return jnp.sum(before & ok, axis=2, dtype=jnp.int32)
+
+    def _pairs(self, si, sj, fi, fj, spread, ok):
+        """One tile of pairs (i high, j low) of ``c`` rows: i-side and
+        j-side slot values (``s`` (c, T); ``f`` label, gain, discount,
+        inverse max DCG, each (c, T)); ``ok`` (c, T, T) where j is a
+        slot of i's query.  Returns the lambda and hessian sums over j
+        for each i and over i for each j."""
+        li, gi, di, inv = fi
+        lj, gj, dj, _ = fj
+        x = lambda a: a[:, :, None]
+        y = lambda a: a[:, None, :]
+        ok = ok & (x(li) > y(lj))
+        ds = x(si) - y(sj)
+        dn = jnp.abs((x(gi) - y(gj)) * (x(di) - y(dj))) * x(inv)
+        dn = jnp.where(x(spread), dn / (0.01 + jnp.abs(ds)), dn)
+        p = 2.0 / (1.0 + jnp.exp(2.0 * self.sigmoid * ds))
+        lam = jnp.where(ok, -p * dn, 0.0)
+        hes = jnp.where(ok, 2.0 * p * (2.0 - p) * dn, 0.0)
+        return lam.sum(2), lam.sum(1), hes.sum(2), hes.sum(1)
+
+    def _bucket_lambdas(self, b, s, f, seg):
+        """Gradients and hessians of bucket ``b``'s slots, (R, W) each,
+        from its scores ``s`` and statics ``f`` (label, gain, inverse
+        max DCG[, weight]; (C, R, W)) and ``seg`` (R, W).  A slot's rank
+        in its query, and so its discount, is counted from the same
+        pairs (no sort)."""
+        R, W, T, c = b.rows, b.width, b.tile, b.chunk
+        lab, gain, inv = f[0], f[1], f[2]
+        disc = lambda above: 1.0 / jnp.log2(2.0 + above.astype(jnp.float32))
+        if b.k == 1:
+            def chunk(a):
+                s_, lab_, gain_, inv_, seg_ = a
+                ok = lab_[:, None, :] >= 0
+                if b.packed:              # several queries a row
+                    ok = ok & (seg_[:, :, None] == seg_[:, None, :])
+                # whether the query's scores differ (best != worst)
+                spread = jnp.any(ok & (s_[:, :, None] != s_[:, None, :]),
+                                 axis=2)
+                fi = (lab_, gain_, disc(self._above(s_, s_, 0, 0, ok)), inv_)
+                gi, gj, hi, hj = self._pairs(s_, s_, fi, fi, spread, ok)
+                return gi - gj, hi + hj
+            g, h = jax.lax.map(chunk, tuple(
+                a.reshape(b.steps, c, W) for a in (s, lab, gain, inv, seg)))
+            g, h = g.reshape(R, W), h.reshape(R, W)
+        else:
+            # one query a row: its spread from the row, its ranks and
+            # then its pairs a tile at a time
+            k, valid = b.k, lab >= 0
+            spread = jnp.broadcast_to(
+                jnp.max(jnp.where(valid, s, -jnp.inf), 1, keepdims=True)
+                != jnp.min(jnp.where(valid, s, jnp.inf), 1, keepdims=True),
+                (R, W))
+
+            def tiles(t):
+                r0, ti, tj = t // (k * k) * c, t // k % k * T, t % k * T
+                at = lambda a, j: jax.lax.dynamic_slice(a, (r0, j), (c, T))
+                put = lambda a, j, v: jax.lax.dynamic_update_slice(
+                    a, at(a, j) + v, (r0, j))
+                ok = jnp.broadcast_to(at(lab, tj)[:, None, :] >= 0, (c, T, T))
+                return at, put, ti, tj, ok
+
+            def count(t, above):
+                at, put, ti, tj, ok = tiles(t)
+                return put(above, ti, self._above(at(s, ti), at(s, tj),
+                                                  ti, tj, ok))
+            d = disc(jax.lax.fori_loop(0, b.steps * k * k, count,
+                                       jnp.zeros((R, W), jnp.int32)))
+
+            def body(t, acc):
+                g, h = acc
+                at, put, ti, tj, ok = tiles(t)
+                fi = tuple(at(a, ti) for a in (lab, gain, d, inv))
+                fj = tuple(at(a, tj) for a in (lab, gain, d, inv))
+                gi, gj, hi, hj = self._pairs(at(s, ti), at(s, tj), fi, fj,
+                                             at(spread, ti), ok)
+                return (put(put(g, ti, gi), tj, -gj),
+                        put(put(h, ti, hi), tj, hj))
+            zero = jnp.zeros((R, W), jnp.float32)
+            g, h = jax.lax.fori_loop(0, b.steps * k * k, body, (zero, zero))
+        if f.shape[0] > 3:
+            g, h = g * f[3], h * f[3]
+        return g, h
 
     def get_gradients(self, score):
-        sig = self.sigmoid
-        qidx = self._qidx
-        qmask = self._qmask
-        q_pad, M = qidx.shape
-        qc = self._q_chunk
-        nc = q_pad // qc
-        pscore = self._padded_scores(score)
-        pweight = self._qweight
-
-        def chunk(args):
-            qmask_c, qlabel_c, inv_c, s, w_c = args
-            labels = qlabel_c.astype(jnp.int32)
-            gains = self._label_gain_dev[jnp.clip(labels, 0, None)]
-
-            # rank positions (descending score, stable)
-            order = jnp.argsort(-s, axis=1, stable=True)
-            rank = jnp.argsort(order, axis=1)              # (qc, M)
-            discount = 1.0 / jnp.log2(2.0 + rank.astype(jnp.float32))
-
-            best = jnp.max(jnp.where(qmask_c, s, -jnp.inf), axis=1,
-                           keepdims=True)
-            worst = jnp.min(jnp.where(qmask_c, s, jnp.inf), axis=1,
-                            keepdims=True)
-            has_spread = best != worst
-
-            # pairwise (qc, M, M): i = high (larger label), j = low
-            li = labels[:, :, None]
-            lj = labels[:, None, :]
-            pair_ok = (li > lj) & qmask_c[:, :, None] & qmask_c[:, None, :]
-            ds = s[:, :, None] - s[:, None, :]            # delta score
-            dg = gains[:, :, None] - gains[:, None, :]
-            pd = jnp.abs(discount[:, :, None] - discount[:, None, :])
-            delta_ndcg = dg * pd * inv_c[:, None, None]
-            delta_ndcg = jnp.where(
-                has_spread[:, :, None],
-                delta_ndcg / (0.01 + jnp.abs(ds)), delta_ndcg)
-            ds_safe = jnp.where(pair_ok, ds, 0.0)
-            p_lambda = 2.0 / (1.0 + jnp.exp(2.0 * ds_safe * sig))
-            p_hess = p_lambda * (2.0 - p_lambda)
-            lam = jnp.where(pair_ok, -p_lambda * delta_ndcg, 0.0)
-            hes = jnp.where(pair_ok, 2.0 * p_hess * delta_ndcg, 0.0)
-            # high gets +lambda, low gets -lambda; hessian adds on both
-            g_q = lam.sum(axis=2) - lam.sum(axis=1)        # (qc, M)
-            h_q = hes.sum(axis=2) + hes.sum(axis=1)
-
-            if pweight is not None:       # static at trace time
-                g_q = g_q * w_c
-                h_q = h_q * w_c
-            return g_q, h_q
-
-        # no-weight runs map a broadcast dummy so the pytree shape is
-        # fixed; chunk never reads it (static branch above)
-        wmap = (pweight.reshape(nc, qc, M) if pweight is not None
-                else jnp.zeros((nc, 1, 1), jnp.float32))
-        g_all, h_all = jax.lax.map(chunk, (
-            qmask.reshape(nc, qc, M),
-            self._qlabel.reshape(nc, qc, M),
-            self._inv_max_dcg.reshape(nc, qc),
-            pscore.reshape(nc, qc, M), wmap))
-
-        # padded (q_pad, M) lambdas -> flat rows through the transposed
-        # banded plan (an exact scatter-add; see _banded_scatter)
-        gh = jnp.stack([g_all.reshape(-1), h_all.reshape(-1)], axis=-1)
-        pad_tail = self._bp_local.shape[0] * 128 - gh.shape[0]
-        if pad_tail:
-            gh = jnp.pad(gh, ((0, pad_tail), (0, 0)))
-        nt_in = max(self._bp_nt_in_min, -(-score.shape[0] // 128))
-        flat = _banded_scatter(gh.reshape(-1, 128, 2), self._bp_wtiles,
-                               self._bp_local, nt_in, self._tile_chunk)
-        grad = flat[..., 0].reshape(-1)[:score.shape[0]]
-        hess = flat[..., 1].reshape(-1)[:score.shape[0]]
-        return grad, hess
+        n = score.shape[0]
+        nt = self._bp_local_dev.shape[0]
+        nt_in = max(self._bp_nt_in_min, -(-n // 128))
+        with TELEMETRY.phase("rank_layout"):
+            xt = jnp.pad(score, (0, nt_in * 128 - n)).reshape(-1, 128)
+            slots = _banded_gather(xt, self._bp_wtiles_dev,
+                                   self._bp_local_dev, self._tile_chunk)
+            slots = slots.reshape(-1)
+        with TELEMETRY.phase("rank_lambdas"):
+            out = [self._bucket_lambdas(b, *(
+                a[..., b.start:b.start + b.size].reshape(
+                    a.shape[:-1] + (b.rows, b.width))
+                for a in (slots, self._slot_f_dev, self._slot_seg_dev[0])))
+                for b in self._buckets]
+        with TELEMETRY.phase("rank_layout"):
+            pad = jnp.zeros(nt * 128 - self._n_slots, jnp.float32)
+            gh = jnp.stack([jnp.concatenate([p[c].reshape(-1) for p in out]
+                                            + [pad]) for c in (0, 1)], -1)
+            flat = _banded_scatter(gh.reshape(nt, 128, 2), self._bp_wtiles_dev,
+                                   self._bp_local_dev, nt_in, self._tile_chunk)
+            flat = flat.reshape(-1, 2)[:n]
+        return flat[:, 0], flat[:, 1]
 
 
 _OBJECTIVE_REGISTRY = {

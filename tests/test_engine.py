@@ -277,10 +277,10 @@ def test_reset_parameter_callback():
 
 
 def test_lambdarank_banded_gradients():
-    """The banded flat<->padded permutation path must reproduce the
-    direct per-query pairwise lambdas (reference
-    rank_objective.hpp:83-170) exactly, on ragged query sizes with
-    weights — the regime where the padded layout has real gaps."""
+    """The length-bucketed layout, moved flat <-> bucketed by the banded
+    permutation, must reproduce the direct per-query pairwise lambdas
+    (reference rank_objective.hpp:83-170) exactly, on ragged query sizes
+    with weights — the regime where a bucket's rows have real gaps."""
     import jax.numpy as jnp
 
     from lightgbm_tpu.config import Config
@@ -302,6 +302,9 @@ def test_lambdarank_banded_gradients():
     md.set_weight(weight)
     md.set_group(sizes)
     obj.init(md, n)
+    # queries of 1-39 rows: every ranked one shares a packed 128-slot row
+    assert [b.packed for b in obj._buckets] == [True]
+    assert obj._n_slots < 3 * n
 
     n_pad = ((n + 127) // 128) * 128
     score = np.zeros(n_pad, np.float32)

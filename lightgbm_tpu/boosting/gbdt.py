@@ -28,7 +28,7 @@ from ..dataset import Dataset
 from ..learner.grower import TreeGrower, TreeArrays
 from ..metrics import Metric, create_metrics
 from ..objectives import Objective, create_objective
-from ..ops.histogram import leaf_value_broadcast
+from ..ops.histogram import ROUTE_ROWS, leaf_value_broadcast
 from ..ops.predict import predict_binned
 from ..reliability.checkpoint import CheckpointError
 from ..reliability.faults import FAULTS
@@ -1135,12 +1135,16 @@ class GBDT:
             (stacked_plain, chunk_stacks, rec_stacks))
 
         compact_rows = [0, 0]      # [in an active slot, streamed]
+        split_rows = [0, 0]        # [split rows copied, gathers]
 
         def append_tree(arrs, shrinkage, bias):
             if TELEMETRY.on:
                 active, streamed = self.grower.compact_pass_rows(arrs)
                 compact_rows[0] += active
                 compact_rows[1] += streamed
+                copied, gathers = self.grower.split_row_copies(arrs)
+                split_rows[0] += copied
+                split_rows[1] += gathers
             t = Tree.from_grower_arrays(arrs, self.train_set)
             t.apply_shrinkage(shrinkage)
             if bias != 0.0:
@@ -1199,6 +1203,17 @@ class GBDT:
             TELEMETRY.gauge("hist_active_row_share",
                             total["hist_compact_active_rows"]
                             / total["hist_compact_streamed_rows"])
+        if split_rows[1]:
+            # the committed trees' split-row gathers (a chunked table's
+            # passes and exit routes): rows copied over ROUTE_ROWS a
+            # gather, what copying every route row takes
+            TELEMETRY.add("route_split_rows_copied", split_rows[0])
+            TELEMETRY.add("route_split_row_gathers", split_rows[1])
+            total = TELEMETRY.counters()
+            TELEMETRY.gauge("route_split_row_share",
+                            total["route_split_rows_copied"]
+                            / (total["route_split_row_gathers"]
+                               * ROUTE_ROWS))
         TELEMETRY.end_span(span)
 
     # ------------------------------------------------------------------

@@ -40,7 +40,7 @@ feature election is a collective over freshly built local histograms.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -893,29 +893,21 @@ class TreeGrower:
         return (_scaled(total, scales), rows_i32), leaf2
 
     # ------------------------------------------------------------------
-    def compact_pass_rows(self, arrs: Dict[str, np.ndarray]
-                          ) -> Tuple[int, int]:
-        """``(rows of an active slot, rows streamed)`` of one grown
-        tree's passes on the compacting rungs (``plan.compact_rungs``),
-        read off the tree on the host (``arrs``: its ``TreeArrays`` as
-        numpy): what ``hist_active_row_share`` is made of.
+    def _pass_rounds(self, arrs: Dict[str, np.ndarray]) -> List[List[int]]:
+        """``[splits, rows of their right children]`` of every round of
+        one grown tree that a histogram pass follows, read off the tree
+        on the host (``arrs``: its ``TreeArrays`` as numpy).
 
-        The kernel holds these counts a unit, but to carry them out of
-        it takes an output a pass, an add a round and, under a mesh, a
-        sum over the shards: device work in every pass for a number the
-        committed tree already holds.  A round splits every leaf it can
-        up to the frontier's width, in node order, so split ``s`` is in
-        the round after its parent's, or in a later one where that one
-        was full; a round's right children are the slots of the next
-        round's pass, which is made unless the tree ended on its leaf
-        budget; and a right child's rows are its count in the tree (the
-        rows in the bag, where there is one)."""
+        A round splits every leaf it can up to the frontier's width, in
+        node order, so split ``s`` is in the round after its parent's,
+        or in a later one where that one was full; a round's right
+        children are the slots of the next round's pass, which is made
+        unless the tree ended on its leaf budget; and a right child's
+        rows are its count in the tree (the rows in the bag, where there
+        is one)."""
         m = int(arrs["num_leaves"]) - 1
-        if not self.plan.compact_rungs or m <= 0 or self._is_voting \
-                or self._is_feature_par:
-            return 0, 0
-        rungs = [k for k, _, _ in self.plan.factored_rungs
-                 if k <= self.frontier]
+        if m <= 0:
+            return []
         right = arrs["node_right"][:m]
         rows = np.where(right < 0, arrs["leaf_count"][~np.minimum(right, -1)],
                         arrs["node_count"][np.maximum(right, 0)])
@@ -936,13 +928,63 @@ class TreeGrower:
             rounds[r][1] += int(rows[s])
         if m + 1 >= self.num_leaves:
             rounds.pop()                # no pass follows the budget's end
+        return rounds
+
+    def _factored_rung(self, slots: int):
+        """The factored rung that serves a pass of ``slots`` slots, or
+        None where the strips do."""
+        return next((k for k, _, _ in self.plan.factored_rungs
+                     if slots <= k <= self.frontier), None)
+
+    def compact_pass_rows(self, arrs: Dict[str, np.ndarray]
+                          ) -> Tuple[int, int]:
+        """``(rows of an active slot, rows streamed)`` of one grown
+        tree's passes on the compacting rungs (``plan.compact_rungs``),
+        read off the tree on the host: what ``hist_active_row_share`` is
+        made of.
+
+        The kernel holds these counts a unit, but to carry them out of
+        it takes an output a pass, an add a round and, under a mesh, a
+        sum over the shards: device work in every pass for a number the
+        committed tree already holds (:meth:`_pass_rounds`)."""
+        if not self.plan.compact_rungs or self._is_voting \
+                or self._is_feature_par:
+            return 0, 0
         active = streamed = 0
-        for slots, slot_rows in rounds:
-            rung = next((k for k in rungs if k >= slots), None)
-            if rung in self.plan.compact_rungs:
+        for slots, slot_rows in self._pass_rounds(arrs):
+            if self._factored_rung(slots) in self.plan.compact_rungs:
                 active += slot_rows
                 streamed += self.n_padded
         return active, streamed
+
+    def split_row_copies(self, arrs: Dict[str, np.ndarray]
+                         ) -> Tuple[int, int]:
+        """``(split rows copied, gathers)`` of one grown tree on a table
+        whose groups are chunked: every chunked pass and the exit route
+        call ``histogram.gather_split_rows`` once, which copies a row
+        for each split it routes (``route_split_row_share`` is rows over
+        ``gathers x ROUTE_ROWS``, what copying every route row would
+        take).  Read off the committed tree for the reason
+        :meth:`compact_pass_rows` gives: a count carried out of the
+        loop costs device work in every call.
+
+        The root's pass routes nothing; a later pass routes the splits
+        of the round before it; the exit route routes the last round's,
+        or nothing where a pass followed that round.  With no histogram
+        cache a pass runs its kernel twice, over the same route table."""
+        if self.plan.group_chunks <= 1 or self.plan.tier != "ladder" \
+                or self._is_voting or self._is_feature_par:
+            return 0, 0
+        per_pass = 1 if self.use_hist_cache else 2
+        splits = [k for k, _ in self._pass_rounds(arrs)]
+        rows = gathers = 0
+        # (slots, splits routed) of the root's pass and of every other
+        for slots, routed in [(1, 0)] + [(k, k) for k in splits]:
+            if self._factored_rung(slots) is not None:
+                rows += per_pass * routed
+                gathers += per_pass
+        exit_routed = int(arrs["num_leaves"]) - 1 - sum(splits)
+        return rows + exit_routed, gathers + 1
 
     # ------------------------------------------------------------------
     def emit_tree_record(self, tree: TreeArrays) -> jax.Array:

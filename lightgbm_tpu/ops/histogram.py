@@ -1689,17 +1689,26 @@ def gather_split_rows(binsT: jax.Array, route_tab: jax.Array):
     with that row in the place of the leaf's group, so that the kernels'
     route prologue selects among ``ROUTE_ROWS`` rows and not among every
     group of the table.  At most ``ROUTE_ROWS`` leaves are active: the
-    ladder's frontier is narrower."""
+    ladder's frontier is narrower.
+
+    Only the active leaves' rows are copied, so a call costs the splits
+    it routes and not ``ROUTE_ROWS`` copies; the rows past them are
+    zeros, which no leaf selects (an inactive leaf routes nothing)."""
+    from ..telemetry import TELEMETRY
     with jax.named_scope("tel.route"):
         active = route_tab[:, 10] > 0.5
         grp = (route_tab[:, 0] * 256 + route_tab[:, 1]).astype(jnp.int32)
         rank = jnp.cumsum(active.astype(jnp.int32)) - 1
         row_group = jnp.zeros(ROUTE_ROWS, jnp.int32).at[
             jnp.where(active, rank, ROUTE_ROWS)].set(grp, mode="drop")
-        # a row at a time: XLA's gather of whole rows copies the table
-        rowsT = jax.lax.map(
-            lambda g: jax.lax.dynamic_index_in_dim(binsT, g, 0, False),
-            row_group)
+        with TELEMETRY.phase("split_rows"):
+            # a row at a time: XLA's gather of whole rows copies the table
+            def copy(r, rowsT):
+                row = jax.lax.dynamic_index_in_dim(binsT, row_group[r], 0)
+                return jax.lax.dynamic_update_index_in_dim(rowsT, row, r, 0)
+            rowsT = jax.lax.fori_loop(
+                0, jnp.sum(active, dtype=jnp.int32), copy,
+                jnp.zeros((ROUTE_ROWS, binsT.shape[1]), binsT.dtype))
         tab = route_tab.at[:, 0].set(0.0).at[:, 1].set(
             jnp.where(active, rank, 0).astype(jnp.float32))
         return rowsT, tab

@@ -556,6 +556,154 @@ def test_route_apply_split_rows_equal_whole_table_interpret():
     np.testing.assert_array_equal(np.asarray(got[0]), want_leaf)
 
 
+def _all_route_rows(binsT, route_tab):
+    """The split-row gather as it was: every one of the ``ROUTE_ROWS``
+    rows copied, an inactive leaf's from group 0 (the reference of the
+    active-rows-only gather)."""
+    import jax
+    from lightgbm_tpu.ops.histogram import ROUTE_ROWS
+    active = route_tab[:, 10] > 0.5
+    grp = (route_tab[:, 0] * 256 + route_tab[:, 1]).astype(jnp.int32)
+    rank = jnp.cumsum(active.astype(jnp.int32)) - 1
+    row_group = jnp.zeros(ROUTE_ROWS, jnp.int32).at[
+        jnp.where(active, rank, ROUTE_ROWS)].set(grp, mode="drop")
+    rowsT = jax.lax.map(
+        lambda g: jax.lax.dynamic_index_in_dim(binsT, g, 0, False),
+        row_group)
+    tab = route_tab.at[:, 0].set(0.0).at[:, 1].set(
+        jnp.where(active, rank, 0).astype(jnp.float32))
+    return rowsT, tab
+
+
+def _route_tab_with(active):
+    """The chunked table's route table grown to 168 leaves with
+    ``active`` of them splitting: leaves 0..3 as the table has them
+    (groups ``_CHUNK_SPLITS``), then leaves past the rows' own, each
+    with leaf 3's split on a group of its own or, for the fifth, on
+    leaf 1's group (40) — two active leaves on one group."""
+    (_, _, _, _, tab), _, _, _ = _chunk_inputs()
+    tab = np.asarray(tab)
+    out = np.zeros((168, tab.shape[1]), np.float32)
+    out[:4] = tab[:4]
+    for i in range(4, active):
+        g = 40 if i == 4 else (7 * i) % _CHUNK_G
+        out[36 + i] = tab[3]
+        out[36 + i, :2] = divmod(g, 256)
+    out[active:4, 10] = 0.0
+    assert (out[:, 10] > 0.5).sum() == active
+    return jnp.asarray(out)
+
+
+@pytest.mark.parametrize("active", [0, 1, 7, 128])
+def test_split_row_gather_copies_the_active_rows_interpret(active,
+                                                           monkeypatch):
+    """The split-row gather copies the rows of the active leaves alone:
+    active rank ``r`` gets its split group's row of the table (where two
+    leaves split on one group, each its own copy), the rows past them
+    are zeros, the route table is the one of the gather that copied
+    every route row, and through either gather the chunked factored pass
+    gives the same int32 histogram and leaf ids to the bit, as does the
+    exit route over the split rows and over the whole table."""
+    import jax
+    from lightgbm_tpu.ops import histogram as H
+    args, all_slots, _, _ = _chunk_inputs()
+    binsT = args[0]
+    tab = _route_tab_with(active)
+    rowsT, tab_rows = jax.jit(H.gather_split_rows)(binsT, tab)
+    want_rows, want_tab = _all_route_rows(binsT, tab)
+    assert rowsT.shape == (H.ROUTE_ROWS, _FACT_N) \
+        and rowsT.dtype == binsT.dtype
+    np.testing.assert_array_equal(np.asarray(tab_rows),
+                                  np.asarray(want_tab))
+    groups = np.asarray(tab[:, 0] * 256 + tab[:, 1]).astype(int)[
+        np.asarray(tab[:, 10]) > 0.5]
+    np.testing.assert_array_equal(np.asarray(rowsT[:active]),
+                                  np.asarray(binsT)[groups])
+    np.testing.assert_array_equal(np.asarray(rowsT[:active]),
+                                  np.asarray(want_rows[:active]))
+    assert not np.asarray(rowsT[active:]).any()
+
+    slots = np.full(126, -1, np.int32)
+    slots[:11] = all_slots[:11]
+    fused = H.compute_group_histograms_fused_factored.__wrapped__
+
+    def chunked_pass(gather):
+        monkeypatch.setattr(H, "gather_split_rows", gather)
+        return jax.jit(functools.partial(
+            fused, max_group_bin=_CHUNK_B, block=256, k_cap=16, a=2,
+            interpret=True, dequantize=False, group_chunk=32))(
+            *args[:4], tab, jnp.asarray(slots))
+    got = chunked_pass(H.gather_split_rows)
+    want = chunked_pass(_all_route_rows)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    moved = (np.asarray(got[1]) != np.asarray(args[3])).sum()
+    assert moved > 0 if active else moved == 0
+
+    values = jnp.asarray(np.random.RandomState(5).randn(
+        tab.shape[0]).astype(np.float32))
+    route = functools.partial(H.route_apply_tiled, block=256,
+                              interpret=True)
+    for g, w in zip(route(rowsT, args[3], tab_rows, values),
+                    route(binsT, args[3], tab, values)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_split_rows_copied_are_the_trees_splits(monkeypatch):
+    """A short quantized training on a table whose groups are chunked
+    (40 groups, chunks of 32): the counter ``route_split_rows_copied``,
+    read off the committed trees, is the trees' splits, and the rows
+    and gathers it books are those the device ran (counted in each call
+    of the every-row gather patched in); that gather grows the same
+    model text."""
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops import hist_plan
+    from lightgbm_tpu.ops import histogram as H
+    from lightgbm_tpu.telemetry import TELEMETRY
+
+    rng = np.random.RandomState(11)
+    X = rng.lognormal(size=(2048, 40)).astype(np.float32)
+    y = (X[:, 0] * X[:, 1] - X[:, 2] + X[:, 35]
+         + 0.3 * rng.randn(2048) > 1.0).astype(float)
+    monkeypatch.setattr(hist_plan, "CHUNK_VMEM_BUDGET", 1 << 20)
+    calls = []
+
+    def counted(binsT, route_tab):
+        jax.debug.callback(lambda n: calls.append(int(n)),
+                           jnp.sum(route_tab[:, 10] > 0.5))
+        return _all_route_rows(binsT, route_tab)
+
+    def model():
+        # the kernels' jit caches hold the gather they were traced with
+        jax.clear_caches()
+        TELEMETRY.reset()
+        bst = lgb.train(_fast_255(15, telemetry="counters"),
+                        lgb.Dataset(X, label=y), 3, verbose_eval=False,
+                        keep_training_booster=True)
+        assert bst.gbdt.grower.plan.group_chunks == 2
+        return (bst.model_to_string(), dict(TELEMETRY.counters()),
+                dict(TELEMETRY.gauges()))
+
+    try:
+        text, counters, gauges = model()
+        monkeypatch.setattr(H, "gather_split_rows", counted)
+        ref_text, _, _ = model()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+        TELEMETRY.configure("off")
+        TELEMETRY.reset()
+    splits = sum(int(v) - 1 for v in re.findall(r"^num_leaves=(\d+)$",
+                                                text, re.M))
+    assert splits > 3 * 4
+    assert counters["route_split_rows_copied"] == splits == sum(calls)
+    assert counters["route_split_row_gathers"] == len(calls)
+    assert gauges["route_split_row_share"] == splits / (
+        len(calls) * H.ROUTE_ROWS)
+    assert text == ref_text
+
+
 def _fast_255(leaves, **extra):
     return dict({"objective": "binary", "num_leaves": leaves,
                  "max_bin": 255, "verbose": -1, "min_data_in_leaf": 2,

@@ -6,6 +6,7 @@ interpret seam cannot.  One file, so that one worker loads the
 TPU's library; the topology is described inside a fixture."""
 import functools
 import os
+import re
 
 import pytest
 
@@ -51,6 +52,23 @@ def _shapes(one_chip):
                 slots=s((126,), jnp.int32), values=s((LEAVES,), jnp.float32))
 
 
+def _split_row_loops(text):
+    """The condition computations of the loops that copy a compiled
+    program's split rows (the ops under ``tel.split_rows``)."""
+    conds = re.findall(r" while\(.*condition=%([\w.\-]+).*"
+                       r"tel\.split_rows/while\"", text)
+    return [text.split(f"%{c} (")[1].split("\n}")[0] for c in conds]
+
+
+def _copies_the_active_rows(text):
+    """The split-row gather is there, and its loop runs to the active
+    count it reads and not to ``ROUTE_ROWS``."""
+    from lightgbm_tpu.ops.histogram import ROUTE_ROWS
+    loops = _split_row_loops(text)
+    return bool(loops) and not any(f"constant({ROUTE_ROWS})" in c
+                                   for c in loops)
+
+
 @pytest.mark.parametrize("k_cap,a", [(126, 2), (2, 4)])
 def test_factored_rung_compiles_in_group_chunks(one_chip, plan, k_cap, a):
     """The widest rung and the narrowest (two groups a tile) at 2,000
@@ -67,12 +85,15 @@ def test_factored_rung_compiles_in_group_chunks(one_chip, plan, k_cap, a):
         compact=compact_shape(k_cap, plan.block_factored)).compile()
     text = compiled.as_text()
     assert f"compute_group_histograms_fused_factored_k{k_cap}_a{a}" in text
+    assert _copies_the_active_rows(text)
     # the route's split rows, and no copy of the table for them
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * GROUPS * ROWS
 
 
 def test_exit_route_compiles_over_split_rows(one_chip, plan):
+    """The exit route of the wide table over its split rows, which a
+    loop of as many steps as the route table has active leaves copies."""
     import jax
     from lightgbm_tpu.ops.histogram import (gather_split_rows,
                                             route_apply_tiled)
@@ -85,6 +106,7 @@ def test_exit_route_compiles_over_split_rows(one_chip, plan):
     compiled = jax.jit(route).lower(sh["binsT"], sh["leaf"], sh["route"],
                                     sh["values"]).compile()
     assert "route_apply_tiled" in compiled.as_text()
+    assert _copies_the_active_rows(compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
